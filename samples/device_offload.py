@@ -1,8 +1,9 @@
 """Device offload: annotate a query with @device to run it on the compiled
 TPU path (micro-batched XLA kernels); the host interpreter remains the
-fallback for shapes outside kernel coverage. This sample runs on the CPU
-backend so it works anywhere — on a TPU host the same code compiles to the
-chip."""
+fallback for shapes outside kernel coverage. Unless JAX_PLATFORMS says
+otherwise this sample runs on the CPU backend, so it works anywhere — on a TPU
+host, ``JAX_PLATFORMS=tpu python samples/device_offload.py`` compiles the same
+code to the chip."""
 
 import _common  # noqa: F401
 

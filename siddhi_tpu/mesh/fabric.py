@@ -298,7 +298,11 @@ class MeshFabric:
                  devices: Optional[list] = None):
         self.cfg = config or MeshConfig()
         if devices is None:
-            devices = self._probe_devices(num_hosts)
+            # process mode binds no devices and must not initialise a JAX
+            # backend here: a chip belongs to one process, and this parent
+            # only supervises workers that run the NumPy tiers
+            devices = [None] * num_hosts if self.cfg.mode == "process" \
+                else self._probe_devices(num_hosts)
         # the fabric's own control-plane ring (created before the hosts:
         # process-mode supervision records its spawn/restart decisions
         # here); migration decisions ALSO fan out to the involved tenant

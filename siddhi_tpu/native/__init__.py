@@ -8,14 +8,18 @@ partition lanes (crc32 — bit-identical to ``tpu/partition.py::_hash_key``)
 and packs fixed-capacity SoA column buffers that ``emit_lane`` copies into
 numpy arrays ready for ``jax.device_put``.
 
-Built on first import with ``g++ -O3`` into ``_build/``; if no toolchain is
-available ``NATIVE_AVAILABLE`` is False and callers fall back to the pure
-Python packers (``tpu/batch.py``).
+Built on first use with ``g++ -O3`` into ``_build/``, under a file name that
+carries a hash of ``ingress.cpp``: a binary is loaded only if it was built
+from exactly the source beside it (mtimes say nothing after a copy or a
+checkout). If it cannot be built, ``native_available()`` is False,
+``native_unavailable_reason()`` holds the compiler's complaint, and callers
+that can fall back to the pure Python packers (``tpu/batch.py``).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,47 +29,62 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "ingress.cpp")
 _BUILD_DIR = os.path.join(_HERE, "_build")
-_SO = os.path.join(_BUILD_DIR, "libsiddhi_ingress.so")
 
 _lib = None
 _lib_lock = threading.Lock()
+_unavailable_reason = None
 NATIVE_AVAILABLE = False
 
 
-def _build() -> bool:
+def so_path() -> str:
+    """The shared object for the ``ingress.cpp`` on disk now."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libsiddhi_ingress-{digest}.so")
+
+
+def _build(so: str) -> bool:
+    """Compile ``ingress.cpp`` to ``so`` (atomically: a killed build must not
+    leave a truncated file under the name a later run trusts)."""
+    global _unavailable_reason
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return True
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", _SO, _SRC],
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)
         return True
-    except Exception:
-        return False
+    except subprocess.CalledProcessError as e:
+        _unavailable_reason = (
+            f"g++ exited {e.returncode}: "
+            f"{e.stderr.decode(errors='replace').strip()[-2000:]}")
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _unavailable_reason = f"{type(e).__name__}: {e}"
+    if os.path.exists(tmp):
+        os.remove(tmp)
+    return False
 
 
 def _load():
-    global _lib, NATIVE_AVAILABLE
+    global _lib, NATIVE_AVAILABLE, _unavailable_reason
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not _build():
+        so = so_path()
+        if not os.path.exists(so) and not _build(so):
             return None
         try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
-            # stale/wrong-arch .so (e.g. leftover from another platform):
-            # force a rebuild from source and retry once
-            try:
-                os.remove(_SO)
-            except OSError:
-                return None
-            if not _build():
+            lib = ctypes.CDLL(so)
+        except OSError as e:
+            # the name matches the source, the contents do not load (another
+            # architecture's build dir was copied in): rebuild once
+            if not _build(so):
                 return None
             try:
-                lib = ctypes.CDLL(_SO)
-            except OSError:
+                lib = ctypes.CDLL(so)
+            except OSError as e2:
+                _unavailable_reason = f"dlopen failed: {e}; after rebuild: {e2}"
                 return None
         lib.sp_create.restype = ctypes.c_void_p
         lib.sp_create.argtypes = [ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
@@ -94,13 +113,9 @@ def _load():
             ctypes.c_void_p, ctypes.c_int32,
             ctypes.POINTER(ctypes.c_void_p), ctypes.c_void_p, ctypes.c_void_p,
             ctypes.c_void_p]
-        try:
-            # wide emit ('d' columns stay float64 — the host tier's f64
-            # policy); absent only on a stale pre-wide .so
-            lib.sp_emit_lane_wide.restype = ctypes.c_int64
-            lib.sp_emit_lane_wide.argtypes = lib.sp_emit_lane.argtypes
-        except AttributeError:          # pragma: no cover
-            pass
+        # wide emit: 'd' columns stay float64 (the host tier's f64 policy)
+        lib.sp_emit_lane_wide.restype = ctypes.c_int64
+        lib.sp_emit_lane_wide.argtypes = lib.sp_emit_lane.argtypes
         _lib = lib
         NATIVE_AVAILABLE = True
         return lib
@@ -129,7 +144,8 @@ class NativeIngress:
                  capacity: int = 1024):
         lib = _load()
         if lib is None:
-            raise RuntimeError("native ingress unavailable (no g++?)")
+            raise RuntimeError(
+                f"native ingress unavailable: {native_unavailable_reason()}")
         self._lib = lib
         self.types = types
         self.n_lanes = n_lanes
@@ -227,3 +243,9 @@ class NativeIngress:
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def native_unavailable_reason():
+    """Why the library could not be built or loaded (the compiler's stderr,
+    or the OS error); None while it is available or was never tried."""
+    return _unavailable_reason

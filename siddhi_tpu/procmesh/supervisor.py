@@ -269,6 +269,10 @@ class ProcMeshSupervisor:
     def _spawn(self, h: ProcWorkerHandle) -> None:
         env = child_env()
         env["SIDDHI_PROCMESH_CHILD"] = "1"      # no recursive pools
+        # workers run the NumPy tiers only (fleet/manager.py): pinned to the
+        # CPU backend like the lane pool's children, so on a chip host no
+        # worker claims the chip or hangs on one its parent holds
+        env.setdefault("JAX_PLATFORMS", "cpu")
         env.update(self.cfg.env)
         cmd = [sys.executable, "-m", "siddhi_tpu.procmesh.worker",
                "--index", str(h.index),

@@ -14,7 +14,7 @@ import jax
 # x64 is enabled ONLY so int64 timestamps/LONG columns are representable
 # (TPU lowers s64 as paired s32 — fine for the compares/adds event time
 # needs). Float compute is pinned to float32 by the dtype policy
-# (``dtypes.py``); no float64 array is ever created on the device path.
+# (``dtypes.py``, which also lists the four places that step outside it).
 jax.config.update("jax_enable_x64", True)
 
 from .batch import BatchBuilder, BatchSchema, StringDictionary, columns_from_rows

@@ -1,8 +1,9 @@
 """Device dtype policy: TPU-safe representations for every ``DataType``.
 
-TPU (v5e) has no native float64 — f64 HLOs fail to lower or run emulated at
-unusable speed — and the VPU/MXU want f32/bf16. int64 lowers (as paired s32)
-and is cheap for the compare/subtract arithmetic timestamps need. Policy:
+TPU (v5e) has no native float64 and the VPU/MXU want f32/bf16; f64 and
+uint64 HLOs do lower on a v5e (libtpu 0.0.34, ``chip_smoke.py`` stage S5, PR
+21) but run emulated, at a cost nobody has measured. int64 lowers (as paired
+s32) and is cheap for the compare/subtract arithmetic timestamps need. Policy:
 
 - ``DOUBLE``/``FLOAT`` → float32 on device (host interpreter keeps Python
   float64 semantics; parity tests compare with f32 tolerances).
@@ -13,10 +14,26 @@ and is cheap for the compare/subtract arithmetic timestamps need. Policy:
   sums use cumsum *differences* over bounded buffers, so error stays at
   O(sqrt(N)·eps·magnitude), well inside the engine's advertised precision.
 
-``jax_enable_x64`` stays on solely so int64 arrays are representable; no
-float64 array is ever created on the device path (reference contrast:
-``io.siddhi.query.api.definition.Attribute.Type`` keeps Java's 8-byte
-long/double everywhere — fine for a JVM, hostile to a TPU).
+``jax_enable_x64`` stays on so int64 arrays are representable. Columns,
+window buffers, group tables and match tables never hold float64 (reference
+contrast: ``io.siddhi.query.api.definition.Attribute.Type`` keeps Java's
+8-byte long/double everywhere — fine for a JVM, hostile to a TPU). Four places
+inside jitted steps do step outside the policy, each for exactness the
+interpreter's doubles set the bar for, and each compiles and matches the
+interpreter on the chip (S5):
+
+- ``query_compile._window_svars``: windowed stdDev moments in f64 (prefix-sum
+  differences of near-equal totals cancel catastrophically in f32);
+- ``query_compile`` lossyFrequent: the ``total * error`` / ``total * support``
+  thresholds in f64 (the host compares in doubles; an f32 product flips
+  emissions at the boundary);
+- ``aggregation_compile``: float sums, counts and stdDev moments of the
+  incremental-aggregation partials in f64 (they merge into host doubles);
+- ``backend.avalanche``: the splitmix64 multiply in uint64, once per event
+  when a device group-by buckets a LONG key or more than one key column.
+
+A new kernel stays inside the policy; moving these four inside it is a change
+to their numerics and needs the parity fuzz, not just a cast.
 """
 
 from __future__ import annotations

@@ -163,16 +163,9 @@ class PartitionedNFARuntime:
         if mesh is not None:
             spec = P(axis)
             specs6 = (spec, spec, spec, spec, spec, spec)
-            try:
-                from jax import shard_map          # jax >= 0.8
-                vstep = shard_map(
-                    vstep, mesh=mesh, in_specs=specs6,
-                    out_specs=(spec, spec), check_vma=False)
-            except ImportError:                    # pragma: no cover
-                from jax.experimental.shard_map import shard_map
-                vstep = shard_map(
-                    vstep, mesh=mesh, in_specs=specs6,
-                    out_specs=(spec, spec), check_rep=False)
+            vstep = jax.shard_map(
+                vstep, mesh=mesh, in_specs=specs6,
+                out_specs=(spec, spec), check_vma=False)
             self._sharding = NamedSharding(mesh, spec)
         else:
             self._sharding = None
@@ -206,10 +199,8 @@ class PartitionedNFARuntime:
         per-event loop): parse → dict-encode → crc32 lane routing → SoA pack.
         Single-input-stream patterns only (the bench/north-star shape)."""
         from ..query_api.definition import DataType
-        from ..native import NativeIngress, native_available
+        from ..native import NativeIngress
 
-        if not native_available():
-            raise RuntimeError("native ingress unavailable (no g++)")
         if len(self.compiler.merged.stream_ids) != 1:
             raise ValueError("native CSV ingress supports single-stream patterns")
         sid = self.compiler.merged.stream_ids[0]

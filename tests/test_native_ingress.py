@@ -179,3 +179,49 @@ select e1.v as a, e2.v as b insert into Alerts;
     rt.enable_native_ingress()
     with pytest.raises(RuntimeError, match="native ingress"):
         rt.send("S", ["d1", 60.0], 1000)
+
+
+def test_so_not_built_from_this_source_is_rebuilt(tmp_path, monkeypatch):
+    """Freshness is a hash of ingress.cpp, not an mtime: a binary built from
+    another source — newer on disk, as after a copy or checkout — is never
+    loaded; and a source that does not compile leaves the compiler's words
+    behind instead of a silent False."""
+    import ctypes
+    import os
+    import shutil
+
+    from siddhi_tpu import native
+
+    built_from_old = native.so_path()
+    assert os.path.exists(built_from_old)       # pytestmark loaded it
+    src = tmp_path / "ingress.cpp"
+    src.write_bytes(open(native._SRC, "rb").read()
+                    + b'\nextern "C" int sp_marker() { return 4242; }\n')
+    build = tmp_path / "_build"
+    build.mkdir()
+    stale = build / os.path.basename(built_from_old)
+    legacy = build / "libsiddhi_ingress.so"
+    shutil.copy(built_from_old, stale)
+    shutil.copy(built_from_old, legacy)
+    later = os.path.getmtime(src) + 3600
+    os.utime(stale, (later, later))
+    os.utime(legacy, (later, later))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_BUILD_DIR", str(build))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_unavailable_reason", None)
+
+    fresh = native.so_path()
+    assert fresh not in (str(stale), str(legacy))
+    assert not os.path.exists(fresh)
+    assert native.native_available()
+    assert os.path.exists(fresh)
+    assert ctypes.CDLL(fresh).sp_marker() == 4242
+    assert native._lib.sp_marker() == 4242
+
+    src.write_bytes(b"this is not C++\n")
+    monkeypatch.setattr(native, "_lib", None)
+    assert not native.native_available()
+    assert "g++ exited" in native.native_unavailable_reason()
+    with pytest.raises(RuntimeError, match="g\\+\\+ exited"):
+        NativeIngress("sd")

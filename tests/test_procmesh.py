@@ -332,6 +332,44 @@ def test_process_mode_fixed_fleet(tmp_path):
         fab.close()
 
 
+def test_fabric_parent_claims_no_device_and_pins_workers_to_cpu(tmp_path):
+    """One process per chip: on a chip host (no JAX_PLATFORMS set) a
+    process-mode fabric parent must initialise no JAX backend — a parent that
+    has touched JAX holds the chip — and its workers, which run the NumPy
+    tiers only, are spawned pinned to the CPU backend. Runs in a fresh
+    interpreter: in this one a backend is long since up."""
+    import json
+    import subprocess
+    import sys
+    script = """
+import json, os, subprocess, sys
+os.environ.pop("JAX_PLATFORMS", None)
+real, envs = subprocess.Popen, []
+def spy(*a, **kw):
+    envs.append(kw["env"].get("JAX_PLATFORMS"))
+    return real(*a, **kw)
+subprocess.Popen = spy
+from siddhi_tpu.mesh import MeshConfig, MeshFabric
+fab = MeshFabric(1, sys.argv[1], config=MeshConfig(
+    mode="process", heartbeat_interval_s=0.2, capacity_per_host=4))
+try:
+    from jax._src import xla_bridge
+    print(json.dumps({"backend_up": xla_bridge.backends_are_initialized(),
+                      "devices": [h.device for h in fab.hosts.values()],
+                      "worker_platforms": envs}))
+finally:
+    fab.close()
+"""
+    from siddhi_tpu.procmesh.protocol import child_env
+    p = subprocess.run([sys.executable, "-c", script, str(tmp_path / "m")],
+                       capture_output=True, text=True, timeout=120,
+                       env=child_env())
+    assert p.returncode == 0, p.stderr[-2000:]
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    assert out == {"backend_up": False, "devices": [None],
+                   "worker_platforms": ["cpu"]}
+
+
 def test_procmesh_metrics_register_and_teardown(tmp_path):
     """procmesh.* worker gauges and the scraped per-child mesh.h{i}.child.*
     families render while the fleet lives and unregister on close() — no
