@@ -349,12 +349,21 @@ def check(text: str) -> list[str]:
             problems.append(
                 f"{family}{dict(series)}: +Inf bucket {buckets[-1][1]} "
                 f"!= _count {total}")
+    from siddhi_tpu.observability.phases import PHASES
     for (family, label), values in label_values.items():
         if len(values) > MAX_LABEL_VALUES:
             problems.append(
                 f"{family}: label '{label}' has {len(values)} distinct "
                 f"values (bound {MAX_LABEL_VALUES}) — cardinality must not "
                 f"scale with population")
+        if label == "phase":
+            # one vocabulary: a phase label outside observability.phases
+            # PHASES is a tracker somebody named by hand
+            stray = values - set(PHASES) - {"end_to_end"}
+            if stray:
+                problems.append(
+                    f"{family}: phase label values {sorted(stray)} are "
+                    f"not in observability.phases.PHASES")
     # parent/child collision: a federated sample that equals a parent
     # sample once its worker label is stripped would make the two series
     # indistinguishable under sum()/avg() aggregation over workers
@@ -383,6 +392,12 @@ def main() -> int:
         problems.append(
             "lint deployment rendered no worker=\"fabric\" merged series — "
             "the federated collector is unwired or produced nothing")
+    for phase in ("device_step", "egress_fence", "egress_decode"):
+        if f'phase="{phase}"' not in text:
+            problems.append(
+                f"lint deployment's device query rendered no "
+                f"phase=\"{phase}\" histogram — the step's dispatch / "
+                f"fence / decode split is unwired")
     if not re.search(r'siddhi_tpu_phase_latency_seconds_bucket\{'
                      r'[^}]*worker="h\d+"', text):
         problems.append(

@@ -16,7 +16,9 @@ Hops checked:
    span and re-activates the trace;
 2. **device dispatch/collect** — the bridge registers pending traces at
    packing, the seal closes groups FIFO, the driver's egress observes
-   every consumed batch (so groups can't desynchronize);
+   every consumed batch (so groups can't desynchronize) with its segments
+   told apart (fence / decode, lock wait / publish, ring wait), each also
+   a span on the profiler's clock;
 3. **DCN forward/receive** — outgoing frames carry sampled TraceContexts;
    both receive paths parse and re-activate them with a ``dcn`` hop span;
 4. **fleet group step** — staging registers the active trace per member;
@@ -84,6 +86,21 @@ def main() -> int:
     check("driver egress observes every consumed batch (probe drains FIFO)",
           "observe" in src(AsyncDeviceDriver._collect_oldest)
           and "phases" in src(AsyncDeviceDriver._collect_oldest))
+    from siddhi_tpu.observability.phases import PHASES
+    check("phase vocabulary tells fence / decode, lock wait / publish and "
+          "the ring wait apart",
+          {"egress_fence", "egress_decode", "lock_wait", "sink_publish",
+           "ring_wait", "ingress_queue"} <= set(PHASES))
+    check("driver egress measures the split and puts it on the profiler's "
+          "clock",
+          all(k in src(AsyncDeviceDriver._collect_oldest) for k in (
+              "decode_s", "lock_s", "ring_s", "deliver.lock",
+              "deliver.publish"))
+          and "ring_wait" in src(AsyncDeviceDriver.submit)
+          and "collect.fence" in src(AdaptiveFlushMixin._fence)
+          and "seal.pack" in src(AdaptiveFlushMixin._emit_batch))
+    check("sync path measures the same split",
+          "decode_s" in src(AdaptiveFlushMixin._timed_process))
     check("probe closes fill-wait + device spans per batch",
           "fill-wait" in src(DeviceStepProbe.on_step)
           and "add_span" in src(DeviceStepProbe.on_step))

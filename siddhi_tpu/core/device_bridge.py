@@ -30,6 +30,7 @@ from ..query_api import (
 )
 from ..query_api.annotation import find_annotation
 from ..flow.adaptive_batch import AdaptiveFlushMixin
+from ..observability.profiler import span
 from .event import Event, EventType, StreamEvent
 
 log = logging.getLogger("siddhi_tpu.device")
@@ -50,9 +51,14 @@ class AsyncDeviceDriver:
       the device still computes, and the carried state round-trips through
       donated buffers (``jax.jit(..., donate_argnums=(0,))``), so dispatch is
       fire-and-forget;
-    - **egress** (worker): ``rt.collect(token)`` fences (the ``np.asarray``
-      inside decode is the only host sync on the path) and delivers rows
-      under the engine lock.
+    - **egress** (worker): ``rt.collect(token)`` fences (the only host sync
+      on the path) and decodes, then the rows are delivered under the
+      engine lock.
+
+    Each edge is a span on the profiler's clock and a phase tracker, split
+    where the waits are (``observability/profiler.py``, ``phases.py``): the
+    producer's wait on a full ring, dispatch, fence against decode inside
+    ``collect``, the wait for the engine lock against the publishing.
 
     With ``window=2`` (double buffering) the worker keeps one dispatch in
     flight while fencing the previous token: the device computes batch N
@@ -86,12 +92,12 @@ class AsyncDeviceDriver:
         self._paused = False
         self._stopped = False
         self.batches_stepped = 0
-        self.step_seconds = 0.0          # cumulative dispatch+fence time
-        self.pack_seconds = 0.0          # producer pack spans (from batches)
-        self.busy_wall_seconds = 0.0     # wall the pipeline was processing
-        self.starved_seconds = 0.0       # idle with a partial batch staging
+        self.step_seconds = 0.0     # cumulative dispatch + collect time
         self.deadline_flushes = 0
-        self._span_t0 = None
+        q = rt.query_name
+        self._spans = {call: f"siddhi:{call}:{q}" for call in (
+            "submit.ring_wait", "dispatch", "collect", "deliver",
+            "deliver.lock", "deliver.publish")}
         # counter-check cadence under sustained load: on_drained normally
         # runs when the pipeline empties, but a saturated pipeline never
         # empties — force the bookkeeping every N collected batches (one
@@ -109,7 +115,10 @@ class AsyncDeviceDriver:
             # engine lock the delivery path needs, so a full queue waits
             # briefly then grows (bounded in practice by the wait)
             if len(self._q) >= self.depth:
-                self._cv.wait(timeout=0.2)
+                t0 = time.perf_counter()
+                with span(self._spans["submit.ring_wait"]):
+                    self._cv.wait(timeout=0.2)
+                batch["_ring_wait_s"] = time.perf_counter() - t0
             self._q.append(batch)
             self._cv.notify_all()
 
@@ -118,33 +127,6 @@ class AsyncDeviceDriver:
     def pipeline_depth(self) -> int:
         """Batches in the driver: packed-but-undispatched + in flight."""
         return len(self._q) + len(self._inflight)
-
-    def _wall_seconds(self) -> float:
-        """Pipeline wall incl. the OPEN busy span — work counters grow per
-        batch, so a gauge read mid-span (saturated pipelines may never
-        drain) must see the matching wall or the ratios inflate unbounded."""
-        wall = self.busy_wall_seconds + self.starved_seconds
-        t0 = self._span_t0
-        if t0 is not None:
-            wall += max(0.0, time.perf_counter() - t0)
-        return wall
-
-    @property
-    def overlap_efficiency(self) -> float:
-        """(pack + step) work per unit of pipeline wall: 1.0 = serialized,
-        2.0 = two equal phases perfectly hidden behind each other."""
-        wall = self._wall_seconds()
-        if wall <= 0.0:
-            return 0.0
-        return (self.pack_seconds + self.step_seconds) / wall
-
-    @property
-    def device_idle_frac(self) -> float:
-        """Fraction of pipeline wall the device spent waiting on the host."""
-        wall = self._wall_seconds()
-        if wall <= 0.0:
-            return 0.0
-        return max(0.0, 1.0 - self.step_seconds / wall)
 
     # -- worker ---------------------------------------------------------------
     def _run(self) -> None:
@@ -176,50 +158,35 @@ class AsyncDeviceDriver:
                 log.exception("on_drained failed")
 
     def _next_action(self):
-        import time
         with self._cv:
             while True:
                 if self._q and not self._paused \
                         and len(self._inflight) < self.window:
-                    if self._span_t0 is None:
-                        self._span_t0 = time.perf_counter()
                     self._busy = True
                     return "dispatch", self._q.popleft()
                 if self._inflight:
                     # window full, paused, or queue empty: fence the oldest
                     # token (strict FIFO egress)
                     return "collect", None
-                # pipeline drained: close the busy span, then idle-wait
-                # (the drained bookkeeping runs in _run, outside this lock)
+                # pipeline drained: idle-wait (the drained bookkeeping runs
+                # in _run, outside this lock)
                 if self._busy:
-                    if self._span_t0 is not None:
-                        self.busy_wall_seconds += \
-                            time.perf_counter() - self._span_t0
-                        self._span_t0 = None
                     self._busy = False
                     self._cv.notify_all()
                     return "drained", None
                 if self._stopped:
                     return "stop", None
                 wait_s = 0.5
-                staging = self._builder_staging()
-                if staging and not self._paused:
+                if not self._paused and self._builder_staging():
                     due_in = self._deadline_due_in_s()
                     if due_in is not None and due_in <= 0.0:
                         return "deadline", None
                     if due_in is not None:
                         wait_s = min(wait_s, max(due_in, 0.001))
-                t0 = time.perf_counter()
                 self._cv.wait(timeout=wait_s)
-                if staging:
-                    # the device sat idle while a partial batch staged — the
-                    # starvation the overlap accounting must charge as wall
-                    # (and, in latency mode, the deadline flush bounds)
-                    self.starved_seconds += time.perf_counter() - t0
 
     def _builder_staging(self) -> bool:
-        """Rows staged in the producer's builder while the worker idles —
-        time spent here is device starvation, in any controller mode."""
+        """Rows staged in the producer's builder while the worker idles."""
         try:
             return len(self.rt.builder) > 0
         except Exception:   # noqa: BLE001 — advisory read without the lock
@@ -242,7 +209,6 @@ class AsyncDeviceDriver:
         t0 = getattr(self.rt.builder, "_pack_t0", None)
         if t0 is None:
             return None
-        import time
         return deadline_ms / 1e3 - (time.perf_counter() - t0)
 
     def _deadline_flush(self) -> None:
@@ -259,13 +225,12 @@ class AsyncDeviceDriver:
             self.rt.flush()
 
     def _dispatch(self, batch) -> None:
-        import time
-        self.pack_seconds += float(batch.get("pack_s", 0.0) or 0.0)
         t0 = time.perf_counter()
         err = None
         token = None
         try:
-            token = self.rt.dispatch(batch)
+            with span(self._spans["dispatch"]):
+                token = self.rt.dispatch(batch)
         except Exception as e:  # noqa: BLE001 — without a DeviceGuard
             # installed a dispatch failure must not kill the worker; the
             # batch is consumed (counted at its egress slot)
@@ -277,15 +242,18 @@ class AsyncDeviceDriver:
             self._cv.notify_all()
 
     def _collect_oldest(self) -> None:
-        import time
         with self._cv:
             batch, token, t_disp0, disp_s, err = self._inflight.popleft()
+        rt = self.rt
+        rt.fence_s = None       # the runtime's own collect leaves its wait
+        # for the device here; a guard replay, which fences nothing, none
         t0 = time.perf_counter()
         rows = []
         ok = False
         try:
             if err is None:
-                rows = self.rt.collect(token)
+                with span(self._spans["collect"]):
+                    rows = rt.collect(token)
                 ok = True
         except Exception:   # noqa: BLE001 — an async-dispatched step's
             # failure surfaces at the fence; with the resilience layer
@@ -293,41 +261,56 @@ class AsyncDeviceDriver:
             # host path before this can trigger
             log.exception("device step failed")
             rows = []
-        fence_s = time.perf_counter() - t0
-        dt = disp_s + fence_s
+        collect_s = time.perf_counter() - t0
+        fence_s = rt.fence_s if rt.fence_s is not None else collect_s
+        dt = disp_s + collect_s
         self.step_seconds += dt
         self.batches_stepped += 1
-        publish_s = 0.0
+        lock_s = publish_s = 0.0
         if rows:
+            lock = self.app_context.root_lock
             tp0 = time.perf_counter()
-            try:
-                with self.app_context.root_lock:
+            with span(self._spans["deliver"]):
+                with span(self._spans["deliver.lock"]):
+                    lock.acquire()
+                tp1 = time.perf_counter()
+                try:
                     # stamp outputs with the batch's own last event time —
                     # the producer-side _out_ts has already advanced to
                     # newer events by delivery time
-                    self.rt.deliver(rows, batch.get("last_ts"))
-            except Exception:   # noqa: BLE001 — a raising downstream
-                # receiver must not kill the sole device worker, and the
-                # probe below must still see this batch (FIFO trace groups)
-                log.exception("device delivery failed")
-            publish_s = time.perf_counter() - tp0
+                    with span(self._spans["deliver.publish"]):
+                        rt.deliver(rows, batch.get("last_ts"))
+                except Exception:   # noqa: BLE001 — a raising downstream
+                    # receiver must not kill the sole device worker, and the
+                    # probe below must still see this batch (FIFO trace
+                    # groups)
+                    log.exception("device delivery failed")
+                finally:
+                    lock.release()
+            lock_s = tp1 - tp0
+            publish_s = time.perf_counter() - tp1
         try:
             # the probe must see EVERY consumed batch (success or not) or
             # its FIFO trace groups desynchronize; observed AFTER delivery
             # so the phase attribution covers the whole serial waterfall
-            # (fill → pack → ring wait → dispatch → fence → publish)
-            observe = getattr(self.rt, "observe_step", None)
+            # (fill → pack → ring wait → queue → dispatch → fence → decode
+            # → lock wait → publish)
+            observe = getattr(rt, "observe_step", None)
             if observe is not None:
                 t_emit = batch.get("_t_emit")
                 queue_s = max(0.0, t_disp0 - t_emit) \
                     if t_emit is not None else 0.0
                 queue_s += max(0.0, t0 - (t_disp0 + disp_s))
+                ring_s = batch.get("_ring_wait_s", 0.0)
                 observe(batch.get("count", 0), dt, device_path=ok, phases={
                     "fill_span_s": batch.get("pack_s", 0.0),
                     "pack_s": batch.get("pack_exec_s", 0.0),
-                    "queue_s": queue_s,
+                    "ring_s": ring_s,
+                    "queue_s": max(0.0, queue_s - ring_s),
                     "step_s": disp_s,
                     "fence_s": fence_s,
+                    "decode_s": collect_s - fence_s,
+                    "lock_s": lock_s,
                     "publish_s": publish_s,
                     "cause": batch.get("_cause"),
                 })
@@ -347,7 +330,6 @@ class AsyncDeviceDriver:
         """Wait until the ring is empty and no dispatch, fence, or delivery
         is in flight. Must NOT be called while holding the engine lock (the
         worker's egress edge needs it)."""
-        import time
         deadline = time.monotonic() + timeout
         with self._cv:
             while self._q or self._inflight or self._busy:
@@ -378,11 +360,7 @@ class AsyncDeviceDriver:
             if len(self.rt.builder):
                 if cause is not None:
                     self.rt._count_flush(cause)
-                self.rt._seal()     # trace group closes WITH the emit,
-                # under the lock producers pack under
-                b = self.rt.builder.emit()
-                b["_cause"] = self.rt._take_cause()
-                self.submit(b)
+                self.submit(self.rt._emit_batch())
         self.quiesce()
 
     def pause(self) -> None:
@@ -416,7 +394,7 @@ class _DeviceRTBase(AdaptiveFlushMixin):
     The step is two-phase: ``dispatch(batch)`` fires the jitted step without
     fencing (JAX async dispatch — state advances through donated buffers)
     and returns the un-fetched output pytree; ``collect(token)`` fences at
-    the egress edge (the ``np.asarray`` inside decode) and returns rows.
+    the egress edge, decodes and returns rows.
     ``process`` is one dispatch immediately collected — the synchronous
     path, and the shape the DeviceGuard wraps on both phases. Host-sync
     bookkeeping that would stall the pipeline (counter checks read device
@@ -438,7 +416,9 @@ class _DeviceRTBase(AdaptiveFlushMixin):
 
     def collect(self, out):
         """Egress fence + decode for one dispatched step."""
-        return self.compiled.decode_outputs(out)
+        self._fence(out["valid"])
+        with span(f"siddhi:collect.decode:{self.query_name}"):
+            return self.compiled.decode_outputs(out)
 
     def process(self, batch):
         """Synchronous step + decode (async: worker thread, no engine lock —
@@ -461,10 +441,7 @@ class _DeviceRTBase(AdaptiveFlushMixin):
     def flush(self):
         if len(self.builder) == 0:
             return
-        self._seal()            # trace group closes exactly at the emit
-        b = self.builder.emit()
-        b["_cause"] = self._take_cause()    # phase attribution keys the
-        # deadline-queueing share off the flush cause riding the batch
+        b = self._emit_batch()
         if self.driver is not None:
             self.driver.submit(b)
             return
@@ -513,6 +490,7 @@ class DeviceQueryBridge:
         self.guard = None                   # DeviceGuard (resilience layer)
         self.probe = None                   # DeviceStepProbe (observability)
         self._on_rows_accepts_ts = True     # deliver() passes the batch ts
+        runtime.query_name = query_name     # the profiler spans' <query>
         runtime.add_callback(self._on_rows)
         self._out_ts = 0
         self.rate_limiter = None
@@ -867,13 +845,16 @@ def try_build_device_query(query: Query, app_context, stream_defs: dict,
                     """Egress fence + decode. Hopping drains deferred
                     boundary flushes here with empty steps — the runtime is
                     pipeline-unsafe, so the state read is this step's own."""
-                    rows = self.compiled.decode_outputs(out)
-                    if self.compiled.window_kind == "hopping":
-                        from ..tpu.runtime import drain_hop_boundaries
-                        self.state = drain_hop_boundaries(
-                            self.compiled, self.state, self._drain_builder,
-                            lambda o: rows.extend(
-                                self.compiled.decode_outputs(o)))
+                    self._fence(out["valid"])
+                    with span(f"siddhi:collect.decode:{self.query_name}"):
+                        rows = self.compiled.decode_outputs(out)
+                        if self.compiled.window_kind == "hopping":
+                            from ..tpu.runtime import drain_hop_boundaries
+                            self.state = drain_hop_boundaries(
+                                self.compiled, self.state,
+                                self._drain_builder,
+                                lambda o: rows.extend(
+                                    self.compiled.decode_outputs(o)))
                     return rows
 
                 def on_drained(self):

@@ -39,6 +39,10 @@ import collections
 import time
 from typing import Optional
 
+import numpy as np
+
+from ..observability.profiler import span
+
 
 class AdaptiveBatchController:
     """AIMD controller over the device flush threshold."""
@@ -238,6 +242,10 @@ class AdaptiveFlushMixin:
     flush_causes = None         # probe's flush-cause counter dict
     flight = None               # FlightRecorder (observability wiring)
     flight_site = ""
+    query_name = ""             # the profiler spans' <query> (bridge sets it)
+    fence_s = None              # the last collect's wait for the device,
+    # left by _fence for whoever called collect (driver thread, or the
+    # client on the sync path); None after a collect that never fenced
     _pending_cause = None       # cause of the flush whose emit comes next
 
     def _count_flush(self, cause: str) -> None:
@@ -279,6 +287,32 @@ class AdaptiveFlushMixin:
         if s is not None:
             s()
 
+    def _emit_batch(self) -> dict:
+        """Seal and emit the staged batch (every flush's first half): the
+        probe's trace group closes exactly at the emit, and the flush cause
+        rides the batch (phase attribution keys the deadline-queueing share
+        off it)."""
+        self._seal()
+        with span(f"siddhi:seal.pack:{self.query_name}"):
+            batch = self.builder.emit()
+        batch["_cause"] = self._take_cause()
+        return batch
+
+    def _fence(self, first) -> None:
+        """``collect``'s first half, told apart from the decode: wait until
+        the step's outputs are ready on the device, by fetching ``first``,
+        the output the decode reads first (the array keeps its host copy, so
+        the decode's own ``np.asarray`` of it is free). This is the one
+        synchronisation ``collect`` always had, in its place, plus that one
+        copy. A ``block_until_ready`` of the outputs ahead of it is the purer
+        fence and costs a paced query a millisecond of detection latency:
+        the first copy then no longer queues behind the step on the device
+        but waits for the host to wake and ask (PERF.md, PR 25)."""
+        t0 = time.perf_counter()
+        with span(f"siddhi:collect.fence:{self.query_name}"):
+            np.asarray(first)
+        self.fence_s = time.perf_counter() - t0
+
     def observe_step(self, n_events: int, latency_s: float,
                      device_path: bool = True,
                      phases: Optional[dict] = None) -> None:
@@ -298,9 +332,10 @@ class AdaptiveFlushMixin:
 
     def _timed_process(self, batch: dict):
         """Sync-path step, timed for the controller/probe with the
-        dispatch/fence split measured separately (the ``device_step`` /
-        ``egress_fence`` phases; on the sync path there is no ring wait,
-        so ``ingress_queue`` is the emit→dispatch gap alone)."""
+        dispatch/fence/decode split measured separately (the ``device_step``
+        / ``egress_fence`` / ``egress_decode`` phases; on the sync path
+        there is no ring, so ``ingress_queue`` is the emit→dispatch gap
+        alone)."""
         if self.batch_controller is None and self.step_observer is None:
             return self.process(batch)
         cause = batch.get("_cause")
@@ -321,11 +356,15 @@ class AdaptiveFlushMixin:
                 "pack_s": batch.get("pack_exec_s", 0.0),
                 "host_s": dt, "cause": cause})
             return rows
+        q = self.query_name
+        self.fence_s = None
         t0 = time.perf_counter()
         try:
-            token = self.dispatch(batch)
+            with span(f"siddhi:dispatch:{q}"):
+                token = self.dispatch(batch)
             t1 = time.perf_counter()
-            rows = self.collect(token)
+            with span(f"siddhi:collect:{q}"):
+                rows = self.collect(token)
         except BaseException:
             # a raising step still consumed its batch: the probe must pop
             # this batch's trace group or every later device span would be
@@ -334,13 +373,15 @@ class AdaptiveFlushMixin:
                               time.perf_counter() - t0, device_path=False)
             raise
         t2 = time.perf_counter()
+        fence_s = self.fence_s if self.fence_s is not None else t2 - t1
         t_emit = batch.get("_t_emit")
         self.observe_step(batch.get("count", 0), t2 - t0, phases={
             "fill_span_s": batch.get("pack_s", 0.0),
             "pack_s": batch.get("pack_exec_s", 0.0),
             "queue_s": max(0.0, t0 - t_emit) if t_emit is not None else 0.0,
             "step_s": t1 - t0,
-            "fence_s": t2 - t1,
+            "fence_s": fence_s,
+            "decode_s": t2 - t1 - fence_s,
             "cause": cause,
         })
         return rows

@@ -29,10 +29,13 @@ and counters; PR 3 added the gaps this package closes:
   phase evidence and moves one actuator per decision (shed / shrink /
   split / eject), every decision on the flight recorder first
   (``GET /siddhi-apps/{name}/slo``, ``siddhi_tpu_slo_*`` gauges);
-- **device profiling** (``profiler.py`` + the step probe below).
+- **device profiling** (``profiler.py`` + the step probe below): the
+  driver thread's calls are always-armed ``siddhi:<call>[.<part>]:<query>``
+  spans on the profiler's clock, at the boundaries the phase trackers
+  measure; ``@app:profile(dir=...)`` captures a trace.
 
-Apps without ``@app:trace`` / ``@app:profile`` pay one ``is None`` check
-per hot-path event; phase attribution and the flight recorder are
+Apps without ``@app:trace`` pay one ``is None`` check per hot-path event;
+phase attribution, the profiler spans and the flight recorder are
 per-batch / per-transition, never per-event.
 """
 
@@ -129,10 +132,12 @@ class DeviceStepProbe:
                 device_path: bool = True,
                 phases: Optional[dict] = None) -> None:
         """One consumed batch. ``phases`` (async driver / sync flush)
-        carries the measured serial segments of this batch's waterfall:
-        ``{"fill_span_s", "pack_s", "queue_s", "step_s", "fence_s",
-        "publish_s", "cause"}`` — recorded event-weighted into the
-        per-phase histograms."""
+        carries the measured serial segments of this batch's waterfall,
+        keyed as :meth:`PhaseBreakdown.record_batch` names them
+        (``fill_span_s``, ``pack_s``, ``ring_s``, ``queue_s``, ``step_s``,
+        ``fence_s``, ``decode_s``, ``lock_s``, ``publish_s``, ``host_s``,
+        ``cause``) — recorded event-weighted into the per-phase
+        histograms."""
         if device_path:
             self.steps += 1
             self.events += int(n_events)
@@ -174,15 +179,8 @@ class DeviceStepProbe:
         if device_path:
             self.latency_tracker.record_seconds(latency_s, exemplar=exemplar)
             if self.phases is not None and phases is not None:
-                self.phases.record_batch(
-                    int(n_events), fill_span_s=phases.get("fill_span_s", 0.0),
-                    pack_s=phases.get("pack_s", 0.0),
-                    queue_s=phases.get("queue_s", 0.0),
-                    step_s=phases.get("step_s", 0.0),
-                    fence_s=phases.get("fence_s", 0.0),
-                    publish_s=phases.get("publish_s", 0.0),
-                    host_s=phases.get("host_s", 0.0),
-                    cause=phases.get("cause"), exemplar=exemplar)
+                self.phases.record_batch(int(n_events), exemplar=exemplar,
+                                         **phases)
         if self.flight is not None:
             # control-plane cross-reference, transition-deduped per site: a
             # quarantine-long fallback storm is ONE timeline entry at onset
@@ -204,30 +202,12 @@ class DeviceStepProbe:
             return 0.0
         return 1.0 - self.events / (self.steps * self.capacity)
 
-    # -- pipeline health (async double-buffered driver) ----------------------
-    # all three read the driver's counters so the pack/step overlap win is
-    # visible OUTSIDE the bench, as siddhi_tpu_device_* families; a bridge
-    # without a driver (sync mode) reports the serialized identity values
     @property
     def pipeline_depth(self) -> int:
-        """Micro-batches inside the driver ring (staged + in flight)."""
+        """Micro-batches inside the driver ring (staged + in flight); 0 on
+        the sync path."""
         d = self.driver
         return d.pipeline_depth if d is not None else 0
-
-    @property
-    def overlap_efficiency(self) -> float:
-        """(pack + step) work per unit of pipeline wall: ~2.0 when a
-        2-deep ring fully hides packing behind device compute, 1.0 when
-        the phases serialize (always 1.0 on the sync path)."""
-        d = self.driver
-        return d.overlap_efficiency if d is not None \
-            else (1.0 if self.steps else 0.0)
-
-    @property
-    def device_idle_frac(self) -> float:
-        """Fraction of pipeline wall the device waited on the host."""
-        d = self.driver
-        return d.device_idle_frac if d is not None else 0.0
 
 
 class ObservabilitySubsystem:
@@ -344,20 +324,12 @@ class ObservabilitySubsystem:
                              lambda p=probe: p.compile_seconds)
             sm.gauge_tracker(f"device.{q}.pad_ratio",
                              lambda p=probe: round(p.pad_ratio, 4))
-            # pipeline-health gauges: the pack/step overlap win measured by
-            # the bench, continuously visible in the exposition
             sm.gauge_tracker(f"device.{q}.pipeline_depth",
                              lambda p=probe: p.pipeline_depth)
-            sm.gauge_tracker(f"device.{q}.overlap_efficiency",
-                             lambda p=probe: round(p.overlap_efficiency, 4))
-            sm.gauge_tracker(f"device.{q}.device_idle_frac",
-                             lambda p=probe: round(p.device_idle_frac, 4))
             for cause in FLUSH_CAUSES:
                 sm.gauge_tracker(
                     f"device.{q}.flush_{cause}_total",
                     lambda p=probe, c=cause: p.flush_causes.get(c, 0))
-            if self.profiler is not None:
-                self.profiler.install(bridge)
 
         # columnar host bridges: their step latency doubles as the
         # host_exec phase (same histogram object registered under the

@@ -16,29 +16,48 @@ trackers, and the bench ``latency_breakdown`` line):
 - ``ingress_parse`` — transport bytes → columns at the edge (CSV/SoA
   parse + dictionary encode in a columnar source, PR 11);
 - ``ingress_queue`` — waiting in an @async junction buffer or the device
-  driver's staged/in-flight ring;
+  driver's staged/in-flight ring: seal → dispatch, and dispatched → its
+  turn to be collected (less ``ring_wait``);
+- ``ring_wait``     — the client's wait in ``AsyncDeviceDriver.submit`` on
+  a full ring (engine lock held; stamped on the batch, zero where the
+  ring had room);
 - ``fill_wait``     — waiting for a micro-batch window to fill (recorded
   as the per-event AVERAGE wait, span/2, under the uniform-arrival
   approximation — the only non-measured segment);
 - ``pack``          — SoA staging/emit of the batch;
-- ``device_step``   — the jitted dispatch;
-- ``egress_fence``  — the egress sync + decode (``np.asarray`` fence);
+- ``device_step``   — the host's time inside ``rt.dispatch``: copies in
+  and the launch of the jitted step. An ENQUEUE, not device time (JAX
+  returns while the device computes; device time comes from a trace);
+- ``egress_fence``  — the wait, inside ``rt.collect``, until the step's
+  outputs are ready on the device, taken as the fetch of the first output
+  the decode reads (the wait plus that one copy);
+- ``egress_decode`` — the rest of ``rt.collect``: the other copies to the
+  host and the row loop of ``decode_outputs`` / ``decode_block_outputs``;
 - ``host_exec``     — host-tier execution (interpreter, columnar,
   fleet lanes, shadow replays);
-- ``sink_publish``  — delivery/publish downstream of the step;
+- ``lock_wait``     — the driver thread asking for the engine lock (which
+  a client holds inside every ``send``) until it is held;
+- ``sink_publish``  — delivery/publish downstream of the step, lock held:
+  rows to events, junction, callbacks;
 - ``dcn_transit``   — the cross-host hop (send wall-clock → apply);
 - ``procmesh_transit`` — the parent→child control-socket hop in a
   process-per-host fabric (dispatch wall-clock → child apply, including
   any lost-ack retry delay).
+
+The driver's segments are also spans on the profiler's clock
+(``profiler.py``): ``siddhi:seal.pack`` = ``pack``, ``submit.ring_wait`` =
+``ring_wait``, ``dispatch`` = ``device_step``, ``collect.fence`` =
+``egress_fence``, ``collect.decode`` = ``egress_decode``, ``deliver.lock`` =
+``lock_wait``, ``deliver.publish`` = ``sink_publish``.
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
-PHASES = ("ingress_parse", "ingress_queue", "fill_wait", "pack",
-          "device_step", "egress_fence", "host_exec", "sink_publish",
-          "dcn_transit", "procmesh_transit")
+PHASES = ("ingress_parse", "ingress_queue", "ring_wait", "fill_wait", "pack",
+          "device_step", "egress_fence", "egress_decode", "host_exec",
+          "lock_wait", "sink_publish", "dcn_transit", "procmesh_transit")
 
 # span stage → phase (unknown stages are host work by default: every
 # host-side processor span nests inside the query chain)
@@ -88,16 +107,18 @@ class PhaseBreakdown:
                      pack_s: float = 0.0, queue_s: float = 0.0,
                      step_s: float = 0.0, fence_s: float = 0.0,
                      publish_s: float = 0.0, host_s: float = 0.0,
-                     parse_s: float = 0.0,
+                     parse_s: float = 0.0, ring_s: float = 0.0,
+                     decode_s: float = 0.0, lock_s: float = 0.0,
                      cause: Optional[str] = None,
                      exemplar=None) -> None:
         if n <= 0:
             return
         fill_avg = max(0.0, fill_span_s) / 2.0
         segs = (("ingress_parse", parse_s), ("fill_wait", fill_avg),
-                ("pack", pack_s),
+                ("pack", pack_s), ("ring_wait", ring_s),
                 ("ingress_queue", queue_s), ("device_step", step_s),
-                ("egress_fence", fence_s), ("sink_publish", publish_s),
+                ("egress_fence", fence_s), ("egress_decode", decode_s),
+                ("lock_wait", lock_s), ("sink_publish", publish_s),
                 ("host_exec", host_s))
         total = 0.0
         for phase, v in segs:

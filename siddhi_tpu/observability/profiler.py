@@ -1,24 +1,57 @@
-"""Device-path profiling: ``@app:profile`` brackets device steps with
-``jax.profiler`` trace annotations.
+"""Device-path profiling: the program's own spans on the profiler's clock.
 
-    @app:profile                       -- annotate device steps only
-    @app:profile(dir='/tmp/jaxtrace')  -- also capture a full profiler trace
-                                          between start() and shutdown()
+``span(name)`` is a ``jax.profiler.TraceAnnotation``: a host span that lands
+in whatever profiler session is running (``@app:profile(dir=...)`` below,
+``jax.profiler.start_trace`` of an embedding program, a benchmark's traced
+run), on the same clock as the device's operations. Outside a session it is
+a flag test, so the spans are always armed: no annotation, option or
+environment variable turns them on. They are per micro-batch, never per
+event. Names are ``siddhi:<call>[.<part>]:<query>``:
 
-Annotations name each micro-batch step ``siddhi:step:<query>`` so a
-captured trace (TensorBoard / Perfetto) attributes device time to the
-query that spent it. Everything degrades to a no-op when ``jax.profiler``
-is unavailable — profiling must never take an app down.
+===============================  ========  ================================
+span                             thread    opens / closes
+===============================  ========  ================================
+``siddhi:seal.pack:<q>``         client    the builder's ``emit()`` in the
+                                           runtime's ``flush`` (engine lock
+                                           held)
+``siddhi:submit.ring_wait:<q>``  client    the wait of
+                                           ``AsyncDeviceDriver.submit``,
+                                           only when the ring is full
+``siddhi:dispatch:<q>``          driver    ``rt.dispatch(batch)``: copies in
+                                           and the launch, one enqueue
+``siddhi:collect:<q>``           driver    ``rt.collect(token)``, whole
+``siddhi:collect.fence:<q>``     driver    inside the runtime's ``collect``:
+                                           until the step's outputs are
+                                           ready on the device and the first
+                                           of them is on the host
+``siddhi:collect.decode:<q>``    driver    the rest of ``collect``: the
+                                           other copies out, the row loop
+``siddhi:deliver:<q>``           driver    from asking for the engine lock
+                                           to ``rt.deliver`` returning
+``siddhi:deliver.lock:<q>``      driver    asking for the engine lock until
+                                           it is held
+``siddhi:deliver.publish:<q>``   driver    ``rt.deliver(rows)``: rows to
+                                           events, junction, callbacks
+===============================  ========  ================================
+
+(On the synchronous path the driver's spans open on the client thread, and
+there is no ring, no lock wait and no ``deliver`` span.) The same boundaries
+feed the ``phase.*`` trackers (``phases.py``), so an untraced run sees them
+too. ``@app:profile(dir='/tmp/jaxtrace')`` captures a full profiler trace
+between ``start()`` and ``shutdown()``. Everything degrades to a no-op when
+``jax.profiler`` is unavailable — profiling must never take an app down.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 
 log = logging.getLogger("siddhi_tpu.observability")
 
 
+@functools.cache
 def _jax_profiler():
     try:
         import jax.profiler as jp
@@ -27,41 +60,22 @@ def _jax_profiler():
         return None
 
 
+def span(name: str):
+    """Context manager: a host span named ``name`` on the profiler's clock."""
+    jp = _jax_profiler()
+    if jp is None:
+        return contextlib.nullcontext()
+    return jp.TraceAnnotation(name)
+
+
 class DeviceProfiler:
-    """Opt-in step bracketing + optional trace capture for one app."""
+    """``@app:profile(dir=...)``: trace capture for one app's lifetime."""
 
     def __init__(self, trace_dir=None):
         self.trace_dir = trace_dir
         self._jp = _jax_profiler()
         self._tracing = False
 
-    def annotate(self, name: str):
-        """Context manager naming the enclosed device work in a trace."""
-        if self._jp is None:
-            return contextlib.nullcontext()
-        try:
-            return self._jp.TraceAnnotation(name)
-        except Exception:       # noqa: BLE001 — annotation is best-effort
-            return contextlib.nullcontext()
-
-    def install(self, bridge) -> None:
-        """Wrap the bridge runtime's ``dispatch`` so every device step runs
-        under a ``siddhi:step:<query>`` annotation (wraps whatever is
-        installed — including a DeviceGuard's guarded dispatch). Both paths
-        route through dispatch: the async driver calls it directly and the
-        sync ``process`` is ``collect(dispatch(batch))``."""
-        rt = bridge.runtime
-        inner = rt.dispatch
-        label = f"siddhi:step:{bridge.query_name}"
-        profiler = self
-
-        def annotated_dispatch(batch):
-            with profiler.annotate(label):
-                return inner(batch)
-
-        rt.dispatch = annotated_dispatch
-
-    # -- trace capture ---------------------------------------------------------
     def start(self) -> None:
         if self.trace_dir is None or self._jp is None or self._tracing:
             return

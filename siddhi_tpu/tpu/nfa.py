@@ -51,6 +51,7 @@ import numpy as np
 
 from ..core.pattern import CompiledPattern, PatternCompiler
 from ..flow.adaptive_batch import AdaptiveFlushMixin
+from ..observability.profiler import span
 from ..query_api import (
     Query,
     StateInputStream,
@@ -1854,9 +1855,8 @@ class DeviceNFARuntime(AdaptiveFlushMixin):
 
     # two-phase step (the async driver's double-buffered pipeline): dispatch
     # fires the jitted step WITHOUT fencing (JAX async dispatch returns while
-    # the device computes); collect decodes — the np.asarray() inside decode
-    # IS the egress fence. NFA state carries no host-sync bookkeeping, so
-    # dispatch N+1 can overlap collect N.
+    # the device computes); collect fences, then decodes. NFA state carries
+    # no host-sync bookkeeping, so dispatch N+1 can overlap collect N.
     pipeline_safe = True
 
     def dispatch(self, batch: dict):
@@ -1868,7 +1868,9 @@ class DeviceNFARuntime(AdaptiveFlushMixin):
 
     def collect(self, ys) -> list[list]:
         """Egress edge: fence + decode one dispatched step's outputs."""
-        return self.compiler.decode_outputs(ys)
+        self._fence(ys["mask"])
+        with span(f"siddhi:collect.decode:{self.query_name}"):
+            return self.compiler.decode_outputs(ys)
 
     def process(self, batch: dict) -> list[list]:
         """Synchronous step + decode (one dispatch immediately collected)."""
@@ -1886,9 +1888,7 @@ class DeviceNFARuntime(AdaptiveFlushMixin):
     def flush(self, decode: bool = True):
         if len(self.builder) == 0:
             return None
-        self._seal()            # trace group closes exactly at the emit
-        batch = self.builder.emit()
-        batch["_cause"] = self._take_cause()
+        batch = self._emit_batch()
         if self.driver is not None:
             self.driver.submit(batch)
             return None

@@ -165,12 +165,13 @@ def make_block_step(nfa: "DeviceNFACompiler"):
         vidx = jnp.cumsum(valid.astype(jnp.int32))        # 1-based at valids
         ts_last = jnp.max(jnp.where(valid, ts, jnp.int64(-(2**62))))
 
-        # ---- seeds: state-0 predicate over the raw batch ------------------
-        st0 = states[0]
-        gate0 = valid & (tag == st0.stream_idx)
-        if st0.predicate is not None:
-            p0 = jnp.broadcast_to(jnp.asarray(st0.predicate(ev_env)), (B,))
-            gate0 = gate0 & p0
+        with jax.named_scope("nfa.admit"):
+            # ---- seeds: state-0 predicate over the raw batch --------------
+            st0 = states[0]
+            gate0 = valid & (tag == st0.stream_idx)
+            if st0.predicate is not None:
+                p0 = jnp.broadcast_to(jnp.asarray(st0.predicate(ev_env)), (B,))
+                gate0 = gate0 & p0
 
         if S == 1:
             # single-state every-pattern: each matching event IS a match
@@ -215,136 +216,147 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                 jnp.sum(ex.astype(jnp.int64)) - K, 0)
             return out, dropped
 
-        # creations entering state 1
-        cre0 = {
-            "exists": gate0,
-            "born": jidx,                                  # batch position
-            "vb": vidx,                                    # vidx[born]
-            "first_ts": ts,
-            "bind": new_binding_cols(0, cols),             # b0_* [B]
-        }
-        if has_ew:
-            cre0["last_ts"] = ts
-        creations, dropped = compact(cre0)
-        drops = drops + dropped
+        with jax.named_scope("nfa.admit"):
+            # creations entering state 1
+            cre0 = {
+                "exists": gate0,
+                "born": jidx,                                  # batch position
+                "vb": vidx,                                    # vidx[born]
+                "first_ts": ts,
+                "bind": new_binding_cols(0, cols),             # b0_* [B]
+            }
+            if has_ew:
+                cre0["last_ts"] = ts
+            creations, dropped = compact(cre0)
+            drops = drops + dropped
 
         out_mask = out_j = out_ts = None
         out_cols = {}
 
         for s in range(1, S):
-            st = states[s]
-            tbl = tables[f"t{s}"]
-            Pc = creations["exists"].shape[0]
-            P = C + Pc
+            with jax.named_scope(f"nfa.stage{s}"):
+                st = states[s]
+                tbl = tables[f"t{s}"]
+                Pc = creations["exists"].shape[0]
+                P = C + Pc
 
-            # candidate arrays: old slots first, then creations (born order)
-            cand_exists = jnp.concatenate([tbl["valid"], creations["exists"]])
-            cand_born = jnp.concatenate(
-                [jnp.full((C,), -1, jnp.int32), creations["born"]])
-            cand_vb = jnp.concatenate(
-                [jnp.zeros((C,), jnp.int32), creations["vb"]])
-            cand_first = jnp.concatenate(
-                [tbl["first_ts"], creations["first_ts"]])
-            cand_last = jnp.concatenate(
-                [tbl["last_ts"], creations["last_ts"]]) if has_ew else None
-            cand_bind = {}
-            for key in binding_keys(s):
-                dt = key_dtype(key)
-                old = tbl[key]
-                new = creations["bind"].get(key)
-                if new is None:
-                    new = jnp.zeros((Pc,), dt)
-                cand_bind[key] = jnp.concatenate(
-                    [old.astype(dt), new.astype(dt)])
+                # candidates: old slots first, then creations (born order)
+                cand_exists = jnp.concatenate(
+                    [tbl["valid"], creations["exists"]])
+                cand_born = jnp.concatenate(
+                    [jnp.full((C,), -1, jnp.int32), creations["born"]])
+                cand_vb = jnp.concatenate(
+                    [jnp.zeros((C,), jnp.int32), creations["vb"]])
+                cand_first = jnp.concatenate(
+                    [tbl["first_ts"], creations["first_ts"]])
+                cand_last = jnp.concatenate(
+                    [tbl["last_ts"], creations["last_ts"]]) if has_ew else None
+                cand_bind = {}
+                for key in binding_keys(s):
+                    dt = key_dtype(key)
+                    old = tbl[key]
+                    new = creations["bind"].get(key)
+                    if new is None:
+                        new = jnp.zeros((Pc,), dt)
+                    cand_bind[key] = jnp.concatenate(
+                        [old.astype(dt), new.astype(dt)])
 
-            # ---- the [B, P] grid ----------------------------------------
-            gate = valid & (tag == st.stream_idx)          # [B]
-            grid = gate[:, None] & cand_exists[None, :]
-            if st.predicate is not None:
-                env = {k: v[:, None] for k, v in ev_env.items()}
-                env.update({k: v[None, :] for k, v in cand_bind.items()})
-                pred = jnp.asarray(st.predicate(env))
-                grid = grid & jnp.broadcast_to(pred, (B, P))
-            if within is not None:
-                grid = grid & ((ts[:, None] - cand_first[None, :]) <= within)
-            if st.within_ms is not None:
-                # element-level: the gap since the PREVIOUS element's bind
-                grid = grid & ((ts[:, None] - cand_last[None, :])
-                               <= st.within_ms)
-            if is_seq:
-                grid = grid & (vidx[:, None] == cand_vb[None, :] + 1)
-            else:
-                grid = grid & (jidx[:, None] > cand_born[None, :])
+                # ---- the [B, P] grid ----------------------------------------
+                gate = valid & (tag == st.stream_idx)          # [B]
+                grid = gate[:, None] & cand_exists[None, :]
+                if st.predicate is not None:
+                    env = {k: v[:, None] for k, v in ev_env.items()}
+                    env.update({k: v[None, :] for k, v in cand_bind.items()})
+                    pred = jnp.asarray(st.predicate(env))
+                    grid = grid & jnp.broadcast_to(pred, (B, P))
+                if within is not None:
+                    grid = grid & (
+                        (ts[:, None] - cand_first[None, :]) <= within)
+                if st.within_ms is not None:
+                    # element-level: the gap since the PREVIOUS element's bind
+                    grid = grid & ((ts[:, None] - cand_last[None, :])
+                                   <= st.within_ms)
+                if is_seq:
+                    grid = grid & (vidx[:, None] == cand_vb[None, :] + 1)
+                else:
+                    grid = grid & (jidx[:, None] > cand_born[None, :])
 
-            adv = jnp.any(grid, axis=0)                    # [P]
-            jstar = jnp.argmax(grid, axis=0).astype(jnp.int32)
+                adv = jnp.any(grid, axis=0)                    # [P]
+                jstar = jnp.argmax(grid, axis=0).astype(jnp.int32)
 
-            if s == S - 1:
-                # ---- emission --------------------------------------------
-                out_mask = adv
-                out_j = jstar
-                out_ts = ts[jstar]
-                emit_env = {k: v[jstar] for k, v in ev_env.items()}
-                emit_env.update(cand_bind)
-                emit_env.update(new_binding_cols(s, cols, idx=jstar))
-                for (name, fn, t) in out_specs:
-                    out_cols[name] = jnp.broadcast_to(
-                        jnp.asarray(fn(emit_env)), (P,)).astype(_JNP[t])
-                matches = matches + jnp.sum(adv.astype(jnp.int64))
-            else:
-                # ---- creations for state s+1 -----------------------------
-                nbind = {}
-                for key in binding_keys(s + 1):
-                    if key in cand_bind:
-                        nbind[key] = cand_bind[key]
-                nbind.update(new_binding_cols(s, cols, idx=jstar))
-                cre_n = {
-                    "exists": adv,
-                    "born": jstar,
-                    "vb": vidx[jstar],
-                    "first_ts": jnp.where(cand_first >= 0, cand_first,
-                                          ts[jstar]),
-                    "bind": nbind,
-                }
-                if has_ew:
-                    cre_n["last_ts"] = ts[jstar]
-                creations, dropped = compact(cre_n)
-                drops = drops + dropped
+                if s == S - 1:
+                    with jax.named_scope("nfa.emit"):
+                        # ---- emission ------------------------------------
+                        out_mask = adv
+                        out_j = jstar
+                        out_ts = ts[jstar]
+                        emit_env = {k: v[jstar] for k, v in ev_env.items()}
+                        emit_env.update(cand_bind)
+                        emit_env.update(new_binding_cols(s, cols, idx=jstar))
+                        for (name, fn, t) in out_specs:
+                            out_cols[name] = jnp.broadcast_to(
+                                jnp.asarray(fn(emit_env)), (P,)).astype(
+                                    _JNP[t])
+                        matches = matches + jnp.sum(adv.astype(jnp.int64))
+                else:
+                    # ---- creations for state s+1 -------------------------
+                    nbind = {}
+                    for key in binding_keys(s + 1):
+                        if key in cand_bind:
+                            nbind[key] = cand_bind[key]
+                    nbind.update(new_binding_cols(s, cols, idx=jstar))
+                    cre_n = {
+                        "exists": adv,
+                        "born": jstar,
+                        "vb": vidx[jstar],
+                        "first_ts": jnp.where(cand_first >= 0, cand_first,
+                                              ts[jstar]),
+                        "bind": nbind,
+                    }
+                    if has_ew:
+                        cre_n["last_ts"] = ts[jstar]
+                    creations, dropped = compact(cre_n)
+                    drops = drops + dropped
 
-            # ---- survivors → new table s (truncate to C, drop-newest) ----
-            surv = cand_exists & ~adv
-            if within is not None:
-                surv = surv & ((ts_last - cand_first) <= within)
-            if st.within_ms is not None:
-                # an element-window that lapsed against the newest event can
-                # never match again (monotonic time) — prune, or dead
-                # partials wedge the keep-oldest slots (review finding)
-                surv = surv & ((ts_last - cand_last) <= st.within_ms)
-            if is_seq:
-                # strict continuity: survive only if no valid event followed
-                surv = surv & (cand_vb == n_valid)
-            # candidates are already in creation order (see block_init_state
-            # invariant) — pack survivors by rank, ranks ≥ C drop off
-            rank = jnp.cumsum(surv.astype(jnp.int32)) - 1
-            tgt = jnp.where(surv, rank, C)
+                with jax.named_scope("nfa.compact"):
+                    # ---- survivors → new table s (truncate to C,
+                    # drop-newest) ----
+                    surv = cand_exists & ~adv
+                    if within is not None:
+                        surv = surv & ((ts_last - cand_first) <= within)
+                    if st.within_ms is not None:
+                        # an element-window that lapsed against the newest
+                        # event can never match again (monotonic time) —
+                        # prune, or dead partials wedge the keep-oldest slots
+                        # (review finding)
+                        surv = surv & ((ts_last - cand_last) <= st.within_ms)
+                    if is_seq:
+                        # strict continuity: survive only if no valid event
+                        # followed
+                        surv = surv & (cand_vb == n_valid)
+                    # candidates are already in creation order (see
+                    # block_init_state invariant) — pack survivors by rank,
+                    # ranks ≥ C drop off
+                    rank = jnp.cumsum(surv.astype(jnp.int32)) - 1
+                    tgt = jnp.where(surv, rank, C)
 
-            def pack(vals, fill):
-                return jnp.full((C,), fill, vals.dtype).at[tgt].set(
-                    jnp.where(surv, vals, fill), mode="drop")
+                    def pack(vals, fill):
+                        return jnp.full((C,), fill, vals.dtype).at[tgt].set(
+                            jnp.where(surv, vals, fill), mode="drop")
 
-            ntbl = {
-                "valid": jnp.zeros((C,), jnp.bool_).at[tgt].set(
-                    surv, mode="drop"),
-                "first_ts": pack(cand_first, jnp.int64(-1)),
-            }
-            if has_ew:
-                ntbl["last_ts"] = pack(cand_last, jnp.int64(-1))
-            for key in binding_keys(s):
-                ntbl[key] = pack(cand_bind[key],
-                                 jnp.zeros((), key_dtype(key)))
-            tables[f"t{s}"] = ntbl
-            n_surv = jnp.sum(surv.astype(jnp.int64))
-            drops = drops + jnp.maximum(n_surv - C, 0)
+                    ntbl = {
+                        "valid": jnp.zeros((C,), jnp.bool_).at[tgt].set(
+                            surv, mode="drop"),
+                        "first_ts": pack(cand_first, jnp.int64(-1)),
+                    }
+                    if has_ew:
+                        ntbl["last_ts"] = pack(cand_last, jnp.int64(-1))
+                    for key in binding_keys(s):
+                        ntbl[key] = pack(cand_bind[key],
+                                         jnp.zeros((), key_dtype(key)))
+                    tables[f"t{s}"] = ntbl
+                    n_surv = jnp.sum(surv.astype(jnp.int64))
+                    drops = drops + jnp.maximum(n_surv - C, 0)
 
         new_state = {"tables": tables, "matches": matches, "drops": drops}
         ys = {"mask": out_mask, "j": out_j, "ts": out_ts}

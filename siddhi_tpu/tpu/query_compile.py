@@ -630,33 +630,38 @@ class CompiledStreamQuery:
         mdt = {i: self._mdtype(i) for i in magg_idx}
         m_ident = {i: _ident(mdt[i], specs[i].kind == "min") for i in magg_idx}
         m_ismin = {i: specs[i].kind == "min" for i in magg_idx}
+        kernel_scope = f"window.{window_kind}" if window_kind is not None \
+            else "groupby" if group_keys else "aggregate"
 
         def step(state, cols, ts, valid):
             cols = dict(cols)
             cols["__ts__"] = ts
-            mask = valid
-            for fn in filter_fns:
-                mask = jnp.logical_and(mask, fn(cols))
-            k = jnp.sum(mask.astype(jnp.int32))
+            with jax.named_scope("filter"):
+                mask = valid
+                for fn in filter_fns:
+                    mask = jnp.logical_and(mask, fn(cols))
+                k = jnp.sum(mask.astype(jnp.int32))
 
-            # stable compaction: accepted event i → slot rank_i; rejected rows
-            # all target slot B-1 with value 0 — that slot only holds a real
-            # event when k == B, in which case nothing was rejected
-            rank = jnp.cumsum(mask.astype(jnp.int32)) - 1
-            pos = jnp.where(mask, rank, B - 1)
+                # stable compaction: accepted event i → slot rank_i; rejected
+                # rows all target slot B-1 with value 0 — that slot only
+                # holds a real event when k == B, in which case nothing was
+                # rejected
+                rank = jnp.cumsum(mask.astype(jnp.int32)) - 1
+                pos = jnp.where(mask, rank, B - 1)
 
             def compact(x, fill=None):
                 f = jnp.zeros((), x.dtype) if fill is None else fill
                 out = jnp.full((B,), f, dtype=x.dtype)
                 return out.at[pos].set(jnp.where(mask, x, f), mode="drop")
 
-            cts = compact(ts)
-            proj_c = {i: compact(specs[i].fn(cols)) for i in value_idx}
-            # fleet per-tenant parameter columns (injected by the caller, not
-            # part of the schema): compacted so having programs over hoisted
-            # constants stay row-aligned with the output columns
-            pcols = {kk: compact(cols[kk]) for kk in cols
-                     if kk.startswith("__fleet_p")}
+            with jax.named_scope("compact"):
+                cts = compact(ts)
+                proj_c = {i: compact(specs[i].fn(cols)) for i in value_idx}
+                # fleet per-tenant parameter columns (injected by the caller,
+                # not part of the schema): compacted so having programs over
+                # hoisted constants stay row-aligned with the output columns
+                pcols = {kk: compact(cols[kk]) for kk in cols
+                         if kk.startswith("__fleet_p")}
 
             def make_keys():
                 """Bucket id [B] + exact packed key [B] for the group-by
@@ -691,410 +696,417 @@ class CompiledStreamQuery:
                     rows.append(compact(jnp.where(mask, v, jnp.zeros((), dt))))
                 return jnp.stack(rows) if rows else jnp.zeros((0, B), dt)
 
-            av_f = agg_stack(fagg_idx, FACC)
-            av_i = agg_stack(iagg_idx, _IACC)
-            av_s = agg_stack(sagg_idx, FACC)          # raw values
-            av_m = {i: compact(specs[i].fn(cols).astype(mdt[i]),
-                               fill=m_ident[i]) for i in magg_idx}
-            ones_c = compact(mask.astype(jnp.int32))
-            out_valid = jnp.arange(B) < k
+            with jax.named_scope("compact"):
+                av_f = agg_stack(fagg_idx, FACC)
+                av_i = agg_stack(iagg_idx, _IACC)
+                av_s = agg_stack(sagg_idx, FACC)          # raw values
+                av_m = {i: compact(specs[i].fn(cols).astype(mdt[i]),
+                                   fill=m_ident[i]) for i in magg_idx}
+                ones_c = compact(mask.astype(jnp.int32))
+                out_valid = jnp.arange(B) < k
 
             def finish(state, sums_f, sums_i, cnts, mins, svars,
                        ovalid=out_valid, ots=cts, proj=proj_c, count=None):
-                out = _materialize(specs, value_idx, fagg_idx, iagg_idx,
-                                   magg_idx, sagg_idx, proj, sums_f, sums_i,
-                                   cnts, mins, svars)
-                if having_fn is not None:
-                    ovalid = ovalid & jnp.broadcast_to(
-                        having_fn({**pcols, **out} if pcols else out),
-                        ovalid.shape)
+                with jax.named_scope("select"):
+                    out = _materialize(specs, value_idx, fagg_idx, iagg_idx,
+                                       magg_idx, sagg_idx, proj, sums_f,
+                                       sums_i, cnts, mins, svars)
+                    if having_fn is not None:
+                        ovalid = ovalid & jnp.broadcast_to(
+                            having_fn({**pcols, **out} if pcols else out),
+                            ovalid.shape)
                 return state, {"out": out, "valid": ovalid, "ts": ots,
                                "count": k if count is None else count}
 
-            if window_kind in ("length", "time", "timeLength"):
-                if window_kind == "length":
-                    z_f, z_i, z_s, zo, zm = _length_concat(
-                        state, av_f, av_i, av_s, av_m, magg_idx, ones_c)
-                    j = jnp.arange(B) + N
-                    n_tail = jnp.sum(state["tail_ones"])
-                    lo = jnp.maximum(j - N + 1, N - n_tail)
-                    new_state = _slide_tails(state, z_f, z_i, z_s, zo, zm,
-                                             k, N)
-                else:
-                    wts = compact(cols[time_key].astype(jnp.int64),
-                                  fill=jnp.asarray(_TS_POS, jnp.int64)) \
+            # one scope per kernel: the window, else the dense group-by table,
+            # else the running aggregates (scopes are metadata on the
+            # compiled operations; a trace names device time by them)
+            with jax.named_scope(kernel_scope):
+                if window_kind in ("length", "time", "timeLength"):
+                    if window_kind == "length":
+                        z_f, z_i, z_s, zo, zm = _length_concat(
+                            state, av_f, av_i, av_s, av_m, magg_idx, ones_c)
+                        j = jnp.arange(B) + N
+                        n_tail = jnp.sum(state["tail_ones"])
+                        lo = jnp.maximum(j - N + 1, N - n_tail)
+                        new_state = _slide_tails(state, z_f, z_i, z_s, zo, zm,
+                                                 k, N)
+                    else:
+                        wts = compact(cols[time_key].astype(jnp.int64),
+                                      fill=jnp.asarray(_TS_POS, jnp.int64)) \
+                            if time_key else compact(
+                                ts, fill=jnp.asarray(_TS_POS, jnp.int64))
+                        (z_f, z_i, z_s, zo, zm, j, lo, new_state) = \
+                            _time_window_bounds(state, av_f, av_i, av_s, av_m,
+                                                magg_idx, ones_c, wts, k, N, B,
+                                                window_ms)
+                        if window_kind == "timeLength":
+                            # the live range is ALSO bounded by the newest
+                            # window_n events; evicting past the length bound is
+                            # the window's own semantics (host TimeLengthWindow
+                            # pops the oldest), not a capacity overflow — the
+                            # tail is sized to window_n, so un-count the drops
+                            lo = jnp.maximum(lo, j - N + 1)
+                            new_state["window_drops"] = state["window_drops"]
+                    if group_keys:
+                        # per-key aggregates over the live window range: one-hot
+                        # [M,K] cumulative grids; output j reads its own bucket at
+                        # the range bounds (reference: per-group aggregator map
+                        # fed by CURRENT+EXPIRED window events — here expiry is
+                        # the range lower bound, no retraction needed)
+                        with jax.named_scope("groupby"):
+                            keys_b, packed = make_keys()
+                            zk = jnp.concatenate([state["tail_gkey"], keys_b])
+                            sums_f = _keyed_range_sums(z_f, zk, K, lo, j, keys_b)
+                            sums_i = _keyed_range_sums(z_i, zk, K, lo, j, keys_b)
+                            ohz = jax.nn.one_hot(zk, K, dtype=jnp.int32) \
+                                * zo[:, None]
+                            csk = jnp.concatenate(
+                                [jnp.zeros((1, K), jnp.int32),
+                                 jnp.cumsum(ohz, axis=0)])
+                            cnts = (csk[j + 1, keys_b] - csk[lo, keys_b]).astype(
+                                jnp.int64)
+                            new_state["tail_gkey"] = jax.lax.dynamic_slice(
+                                zk, (k,), (N,))
+                            # collision accounting (carried ownership, same policy as
+                            # the unwindowed dense table)
+                            onehot_b = (jax.nn.one_hot(keys_b, K, dtype=jnp.int32)
+                                        * out_valid[:, None].astype(jnp.int32))
+                            first_occ = (jnp.cumsum(onehot_b, axis=0) == 1) & \
+                                onehot_b.astype(bool)
+                            batch_first = jnp.sum(
+                                jnp.where(first_occ, packed[:, None], 0), axis=0)
+                            owned = state["key_owned"]
+                            claimed = jnp.where(owned, state["key_owner"],
+                                                batch_first)
+                            coll = out_valid & (packed != claimed[keys_b])
+                            new_state["key_owner"] = claimed
+                            new_state["key_owned"] = owned | jnp.any(
+                                first_occ, axis=0)
+                            new_state["group_collisions"] = \
+                                state["group_collisions"] + jnp.sum(
+                                    coll.astype(jnp.int64))
+                        return finish(new_state, sums_f, sums_i, cnts, {},
+                                      jnp.zeros((0, B), FACC))
+                    sums_f = _range_sums(z_f, lo, j)
+                    sums_i = _range_sums(z_i, lo, j)
+                    cso = jnp.concatenate(
+                        [jnp.zeros((1,), jnp.int32), jnp.cumsum(zo)])
+                    cnts = (cso[j + 1] - cso[lo]).astype(jnp.int64)
+                    mins = {i: _range_reduce(zm[i], lo, j, m_ismin[i])
+                            for i in magg_idx}
+                    svars = _window_svars(z_s, zo, lo, j, cnts, k, N, B)
+                    return finish(new_state, sums_f, sums_i, cnts, mins, svars)
+
+                if window_kind == "lengthBatch":
+                    return _length_batch(state, specs, value_idx, fagg_idx,
+                                         iagg_idx, magg_idx, sagg_idx, m_ismin,
+                                         proj_c, av_f, av_i, av_s, av_m, ones_c,
+                                         cts, k, N, B, finish,
+                                         agg_collapse=has_agg)
+
+                if window_kind in ("timeBatch", "session"):
+                    # externalTimeBatch reads the segment clock from a column
+                    cts_pos = compact(
+                        cols[time_key].astype(jnp.int64),
+                        fill=jnp.asarray(_TS_POS, jnp.int64)) \
                         if time_key else compact(
                             ts, fill=jnp.asarray(_TS_POS, jnp.int64))
-                    (z_f, z_i, z_s, zo, zm, j, lo, new_state) = \
-                        _time_window_bounds(state, av_f, av_i, av_s, av_m,
-                                            magg_idx, ones_c, wts, k, N, B,
-                                            window_ms)
-                    if window_kind == "timeLength":
-                        # the live range is ALSO bounded by the newest
-                        # window_n events; evicting past the length bound is
-                        # the window's own semantics (host TimeLengthWindow
-                        # pops the oldest), not a capacity overflow — the
-                        # tail is sized to window_n, so un-count the drops
-                        lo = jnp.maximum(lo, j - N + 1)
-                        new_state["window_drops"] = state["window_drops"]
-                if group_keys:
-                    # per-key aggregates over the live window range: one-hot
-                    # [M,K] cumulative grids; output j reads its own bucket at
-                    # the range bounds (reference: per-group aggregator map
-                    # fed by CURRENT+EXPIRED window events — here expiry is
-                    # the range lower bound, no retraction needed)
-                    keys_b, packed = make_keys()
-                    zk = jnp.concatenate([state["tail_gkey"], keys_b])
-                    sums_f = _keyed_range_sums(z_f, zk, K, lo, j, keys_b)
-                    sums_i = _keyed_range_sums(z_i, zk, K, lo, j, keys_b)
-                    ohz = jax.nn.one_hot(zk, K, dtype=jnp.int32) \
-                        * zo[:, None]
-                    csk = jnp.concatenate(
-                        [jnp.zeros((1, K), jnp.int32),
-                         jnp.cumsum(ohz, axis=0)])
-                    cnts = (csk[j + 1, keys_b] - csk[lo, keys_b]).astype(
-                        jnp.int64)
-                    new_state["tail_gkey"] = jax.lax.dynamic_slice(
-                        zk, (k,), (N,))
-                    # collision accounting (carried ownership, same policy as
-                    # the unwindowed dense table)
-                    onehot_b = (jax.nn.one_hot(keys_b, K, dtype=jnp.int32)
-                                * out_valid[:, None].astype(jnp.int32))
-                    first_occ = (jnp.cumsum(onehot_b, axis=0) == 1) & \
-                        onehot_b.astype(bool)
-                    batch_first = jnp.sum(
-                        jnp.where(first_occ, packed[:, None], 0), axis=0)
-                    owned = state["key_owned"]
-                    claimed = jnp.where(owned, state["key_owner"],
-                                        batch_first)
-                    coll = out_valid & (packed != claimed[keys_b])
-                    new_state["key_owner"] = claimed
-                    new_state["key_owned"] = owned | jnp.any(
-                        first_occ, axis=0)
-                    new_state["group_collisions"] = \
-                        state["group_collisions"] + jnp.sum(
-                            coll.astype(jnp.int64))
+                    return _segmented_batch(state, value_idx, fagg_idx, iagg_idx,
+                                            magg_idx, sagg_idx, m_ismin, proj_c,
+                                            av_f, av_i, av_s, av_m, ones_c,
+                                            cts_pos, k, N, B, finish,
+                                            window_kind, window_ms,
+                                            agg_collapse=has_agg)
+
+                if window_kind == "batch":
+                    # the accepted sub-batch IS the chunk (reference
+                    # BatchWindowProcessor expires the previous chunk + RESET,
+                    # so aggregates restart per step); with aggregates the chunk
+                    # collapses to ONE row — the last accepted slot (reference
+                    # QuerySelector.processInBatchNoGroupBy keeps lastEvent)
+                    j = jnp.arange(B)
+                    lo0 = jnp.zeros((B,), jnp.int32)
+                    sums_f = _range_sums(av_f, lo0, j)
+                    sums_i = _range_sums(av_i, lo0, j)
+                    cnts = jnp.cumsum(ones_c).astype(jnp.int64)
+                    mins = {i: _range_reduce(av_m[i], lo0, j, m_ismin[i])
+                            for i in magg_idx}
+                    svars = _window_svars(av_s, ones_c, lo0, j, cnts, k, 0, B)
+                    ovalid = out_valid
+                    if has_agg:
+                        ovalid = ovalid & (j == k - 1)
+                    return finish(state, sums_f, sums_i, cnts, mins, svars,
+                                  ovalid=ovalid,
+                                  count=jnp.sum(ovalid.astype(jnp.int32)))
+
+                if window_kind == "sort":
+                    kv = cols[sort_key].astype(sort_kdt)
+                    if sort_desc:
+                        # stored negated: ascending order IS the sort order and
+                        # the evicted slot (N-1) is the per-order worst; int
+                        # min would wrap under negation (it has no positive
+                        # counterpart), so clamp it one up first
+                        if not jnp.issubdtype(sort_kdt, jnp.floating):
+                            lowest = jnp.iinfo(sort_kdt).min
+                            kv = jnp.where(kv == lowest, lowest + 1, kv)
+                        kv = -kv
+                    skey_c = compact(kv, fill=_ident(sort_kdt, True))
+                    new_state, sums_f, sums_i, cnts, mins, svars = _sort_window(
+                        state, skey_c, av_f, av_i, av_s, av_m, magg_idx,
+                        m_ismin, k, N, B)
+                    return finish(new_state, sums_f, sums_i, cnts, mins, svars)
+
+                if window_kind == "hopping":
+                    wts = compact(ts, fill=jnp.asarray(_TS_POS, jnp.int64))
+                    return _hopping_flushes(
+                        state, value_idx, av_f, av_i, av_s, av_m, magg_idx,
+                        m_ismin, ones_c, proj_c, wts, k, N, B,
+                        window_ms, hop_ms, finish)
+
+                if window_kind in ("frequent", "lossyFrequent"):
+                    k64 = [compact(cols[kk].astype(jnp.int64))
+                           for kk in hh_keys]
+                    if len(k64) == 2:
+                        kcode = (k64[0] << 32) | (k64[1] & 0xFFFFFFFF)
+                    else:
+                        kcode = k64[0]
+                    new_state, emit, sums_f, sums_i, cnts = _heavy_hitters(
+                        state, kcode, av_f, av_i, k, N, B,
+                        lossy=(window_kind == "lossyFrequent"),
+                        support=hh_support, error=hh_error)
                     return finish(new_state, sums_f, sums_i, cnts, {},
-                                  jnp.zeros((0, B), FACC))
-                sums_f = _range_sums(z_f, lo, j)
-                sums_i = _range_sums(z_i, lo, j)
-                cso = jnp.concatenate(
-                    [jnp.zeros((1,), jnp.int32), jnp.cumsum(zo)])
-                cnts = (cso[j + 1] - cso[lo]).astype(jnp.int64)
-                mins = {i: _range_reduce(zm[i], lo, j, m_ismin[i])
-                        for i in magg_idx}
-                svars = _window_svars(z_s, zo, lo, j, cnts, k, N, B)
-                return finish(new_state, sums_f, sums_i, cnts, mins, svars)
+                                  jnp.zeros((0, B), FACC),
+                                  ovalid=out_valid & emit,
+                                  count=jnp.sum((out_valid & emit)
+                                                .astype(jnp.int32)))
 
-            if window_kind == "lengthBatch":
-                return _length_batch(state, specs, value_idx, fagg_idx,
-                                     iagg_idx, magg_idx, sagg_idx, m_ismin,
-                                     proj_c, av_f, av_i, av_s, av_m, ones_c,
-                                     cts, k, N, B, finish,
-                                     agg_collapse=has_agg)
+                if window_kind == "delay":
+                    # pass-through after a fixed delay: hold rows until the
+                    # newest arrival passes held_ts + delay; emitted rows carry
+                    # ts = held_ts + delay (the host's timer fires then, before
+                    # the surfacing event is processed)
+                    r = state["rem_count"]
+                    M = N + B
+                    total = r + k
+                    zm_mask = jnp.concatenate(
+                        [jnp.arange(N) < r, jnp.arange(B) < k])
+                    zrank = jnp.cumsum(zm_mask.astype(jnp.int32)) - 1
+                    zpos = jnp.where(zm_mask, zrank, M - 1)
 
-            if window_kind in ("timeBatch", "session"):
-                # externalTimeBatch reads the segment clock from a column
-                cts_pos = compact(
-                    cols[time_key].astype(jnp.int64),
-                    fill=jnp.asarray(_TS_POS, jnp.int64)) \
-                    if time_key else compact(
-                        ts, fill=jnp.asarray(_TS_POS, jnp.int64))
-                return _segmented_batch(state, value_idx, fagg_idx, iagg_idx,
-                                        magg_idx, sagg_idx, m_ismin, proj_c,
-                                        av_f, av_i, av_s, av_m, ones_c,
-                                        cts_pos, k, N, B, finish,
-                                        window_kind, window_ms,
-                                        agg_collapse=has_agg)
+                    def zc(x_rem, x_batch, fill=None):
+                        x = jnp.concatenate([x_rem, x_batch])
+                        f = jnp.zeros((), x.dtype) if fill is None else fill
+                        outv = jnp.full((M,), f, dtype=x.dtype)
+                        return outv.at[zpos].set(
+                            jnp.where(zm_mask, x, f), mode="drop")
 
-            if window_kind == "batch":
-                # the accepted sub-batch IS the chunk (reference
-                # BatchWindowProcessor expires the previous chunk + RESET,
-                # so aggregates restart per step); with aggregates the chunk
-                # collapses to ONE row — the last accepted slot (reference
-                # QuerySelector.processInBatchNoGroupBy keeps lastEvent)
-                j = jnp.arange(B)
-                lo0 = jnp.zeros((B,), jnp.int32)
-                sums_f = _range_sums(av_f, lo0, j)
-                sums_i = _range_sums(av_i, lo0, j)
-                cnts = jnp.cumsum(ones_c).astype(jnp.int64)
-                mins = {i: _range_reduce(av_m[i], lo0, j, m_ismin[i])
-                        for i in magg_idx}
-                svars = _window_svars(av_s, ones_c, lo0, j, cnts, k, 0, B)
-                ovalid = out_valid
-                if has_agg:
-                    ovalid = ovalid & (j == k - 1)
-                return finish(state, sums_f, sums_i, cnts, mins, svars,
-                              ovalid=ovalid,
-                              count=jnp.sum(ovalid.astype(jnp.int32)))
+                    j2 = jnp.arange(M)
+                    zts_raw = zc(state["rem_ts"], cts,
+                                 fill=jnp.asarray(_TS_POS, jnp.int64))
+                    # monotonize (same loud clamp as every time kernel): the
+                    # release mask must be a PREFIX, or a held out-of-order row
+                    # gets silently discarded by the newest-N remainder slice
+                    zts = jax.lax.cummax(zts_raw)
+                    regressions = jnp.sum(((zts > zts_raw) & (j2 < total))
+                                          .astype(jnp.int64))
+                    zproj = {i: zc(state[f"rem_proj_{i}"], proj_c[i])
+                             for i in value_idx}
+                    newest = jnp.where(
+                        total > 0, zts[jnp.clip(total - 1, 0, M - 1)], _TS_NEG)
+                    release = (j2 < total) & (zts + window_ms <= newest)
+                    n_rel = jnp.sum(release.astype(jnp.int32))
+                    rem_n = jnp.minimum(total - n_rel, N)
+                    dropped = (total - n_rel - rem_n).astype(jnp.int64)
+                    slice_from = jnp.maximum(total - rem_n, 0)
 
-            if window_kind == "sort":
-                kv = cols[sort_key].astype(sort_kdt)
-                if sort_desc:
-                    # stored negated: ascending order IS the sort order and
-                    # the evicted slot (N-1) is the per-order worst; int
-                    # min would wrap under negation (it has no positive
-                    # counterpart), so clamp it one up first
-                    if not jnp.issubdtype(sort_kdt, jnp.floating):
-                        lowest = jnp.iinfo(sort_kdt).min
-                        kv = jnp.where(kv == lowest, lowest + 1, kv)
-                    kv = -kv
-                skey_c = compact(kv, fill=_ident(sort_kdt, True))
-                new_state, sums_f, sums_i, cnts, mins, svars = _sort_window(
-                    state, skey_c, av_f, av_i, av_s, av_m, magg_idx,
-                    m_ismin, k, N, B)
-                return finish(new_state, sums_f, sums_i, cnts, mins, svars)
+                    def rem_slice(row):
+                        padded = jnp.concatenate(
+                            [row, jnp.zeros((N,), row.dtype)])
+                        return jax.lax.dynamic_slice(padded, (slice_from,), (N,))
 
-            if window_kind == "hopping":
-                wts = compact(ts, fill=jnp.asarray(_TS_POS, jnp.int64))
-                return _hopping_flushes(
-                    state, value_idx, av_f, av_i, av_s, av_m, magg_idx,
-                    m_ismin, ones_c, proj_c, wts, k, N, B,
-                    window_ms, hop_ms, finish)
+                    keep = jnp.arange(N) < rem_n
+                    new_state = {**state,
+                                 "rem_count": rem_n.astype(jnp.int32),
+                                 "window_drops": state["window_drops"] + dropped,
+                                 "ts_regressions":
+                                     state["ts_regressions"] + regressions}
+                    new_state["rem_ts"] = jnp.where(keep, rem_slice(zts), 0)
+                    for i in value_idx:
+                        z_p = zproj[i]
+                        new_state[f"rem_proj_{i}"] = jnp.where(
+                            keep, rem_slice(z_p), jnp.zeros((), z_p.dtype))
+                    out = {specs[i].name: zproj[i] for i in value_idx}
+                    ovalid = release
+                    if having_fn is not None:
+                        ovalid = ovalid & jnp.broadcast_to(
+                            having_fn(out), ovalid.shape)
+                    return new_state, {"out": out, "valid": ovalid,
+                                       "ts": zts + window_ms,
+                                       "count": jnp.sum(
+                                           release.astype(jnp.int32))}
 
-            if window_kind in ("frequent", "lossyFrequent"):
-                k64 = [compact(cols[kk].astype(jnp.int64))
-                       for kk in hh_keys]
-                if len(k64) == 2:
-                    kcode = (k64[0] << 32) | (k64[1] & 0xFFFFFFFF)
-                else:
-                    kcode = k64[0]
-                new_state, emit, sums_f, sums_i, cnts = _heavy_hitters(
-                    state, kcode, av_f, av_i, k, N, B,
-                    lossy=(window_kind == "lossyFrequent"),
-                    support=hh_support, error=hh_error)
-                return finish(new_state, sums_f, sums_i, cnts, {},
-                              jnp.zeros((0, B), FACC),
-                              ovalid=out_valid & emit,
-                              count=jnp.sum((out_valid & emit)
-                                            .astype(jnp.int32)))
+                if group_keys:
+                    # exact packed key (for collision detection) + bucket id —
+                    # see make_keys(). A bucket claimed by a different packed key
+                    # is COUNTED (group_collisions) — loud, bounded-table
+                    # overflow policy like window/slot drops.
+                    keys, packed = make_keys()
+                    onehot = (jax.nn.one_hot(keys, K, dtype=jnp.int32)
+                              * out_valid[:, None].astype(jnp.int32))     # [B,K]
+                    first_occ = (jnp.cumsum(onehot, axis=0) == 1) & \
+                        onehot.astype(bool)                               # [B,K]
 
-            if window_kind == "delay":
-                # pass-through after a fixed delay: hold rows until the
-                # newest arrival passes held_ts + delay; emitted rows carry
-                # ts = held_ts + delay (the host's timer fires then, before
-                # the surfacing event is processed)
-                r = state["rem_count"]
-                M = N + B
-                total = r + k
-                zm_mask = jnp.concatenate(
-                    [jnp.arange(N) < r, jnp.arange(B) < k])
-                zrank = jnp.cumsum(zm_mask.astype(jnp.int32)) - 1
-                zpos = jnp.where(zm_mask, zrank, M - 1)
+                    # collision accounting: the bucket's owner is its carried
+                    # claimant or, if empty, the first claimant in this batch
+                    # (ownership validity is a separate flag: any int64 is a
+                    # legal packed key, so no value can serve as a sentinel)
+                    batch_first = jnp.sum(
+                        jnp.where(first_occ, packed[:, None], 0), axis=0)  # [K]
+                    has_batch = jnp.any(first_occ, axis=0)
+                    owned = state["key_owned"]
+                    claimed = jnp.where(owned, state["key_owner"], batch_first)
+                    coll = out_valid & (packed != claimed[keys])
+                    new_owner = claimed
+                    new_owned = owned | has_batch
 
-                def zc(x_rem, x_batch, fill=None):
-                    x = jnp.concatenate([x_rem, x_batch])
-                    f = jnp.zeros((), x.dtype) if fill is None else fill
-                    outv = jnp.full((M,), f, dtype=x.dtype)
-                    return outv.at[zpos].set(
-                        jnp.where(zm_mask, x, f), mode="drop")
+                    def per_key(av, base, dt):
+                        contrib = onehot[None].astype(dt) * av[:, :, None]  # [A,B,K]
+                        ccum = jnp.cumsum(contrib, axis=1)
+                        per_ev = jnp.take_along_axis(
+                            ccum, keys[None, :, None], axis=2)[:, :, 0] \
+                            + base[:, keys]
+                        return per_ev, contrib.sum(axis=1)
 
-                j2 = jnp.arange(M)
-                zts_raw = zc(state["rem_ts"], cts,
-                             fill=jnp.asarray(_TS_POS, jnp.int64))
-                # monotonize (same loud clamp as every time kernel): the
-                # release mask must be a PREFIX, or a held out-of-order row
-                # gets silently discarded by the newest-N remainder slice
-                zts = jax.lax.cummax(zts_raw)
-                regressions = jnp.sum(((zts > zts_raw) & (j2 < total))
-                                      .astype(jnp.int64))
-                zproj = {i: zc(state[f"rem_proj_{i}"], proj_c[i])
-                         for i in value_idx}
-                newest = jnp.where(
-                    total > 0, zts[jnp.clip(total - 1, 0, M - 1)], _TS_NEG)
-                release = (j2 < total) & (zts + window_ms <= newest)
-                n_rel = jnp.sum(release.astype(jnp.int32))
-                rem_n = jnp.minimum(total - n_rel, N)
-                dropped = (total - n_rel - rem_n).astype(jnp.int64)
-                slice_from = jnp.maximum(total - rem_n, 0)
+                    sums_f, add_f = per_key(av_f, state["key_fsums"], FACC) \
+                        if len(fagg_idx) else (jnp.zeros((0, B), FACC),
+                                               jnp.zeros((0, K), FACC))
+                    sums_i, add_i = per_key(av_i, state["key_isums"], _IACC) \
+                        if len(iagg_idx) else (jnp.zeros((0, B), _IACC),
+                                               jnp.zeros((0, K), _IACC))
+                    ocum = jnp.cumsum(onehot, axis=0)
+                    cnts = (jnp.take_along_axis(ocum, keys[:, None], axis=1)[:, 0]
+                            .astype(jnp.int64) + state["key_counts"][keys])
+                    nf, nc = _kahan_add(state["key_fsums"], state["key_fcomp"],
+                                        add_f)
+                    new_state = {**state, "key_fsums": nf, "key_fcomp": nc,
+                                 "key_isums": state["key_isums"] + add_i,
+                                 "key_counts": state["key_counts"]
+                                 + onehot.sum(axis=0).astype(jnp.int64),
+                                 "key_owner": new_owner,
+                                 "key_owned": new_owned,
+                                 "group_collisions": state["group_collisions"]
+                                 + jnp.sum(coll.astype(jnp.int64))}
 
-                def rem_slice(row):
-                    padded = jnp.concatenate(
-                        [row, jnp.zeros((N,), row.dtype)])
-                    return jax.lax.dynamic_slice(padded, (slice_from,), (N,))
+                    # min/max per key: cumulative reduction over one-hot grids
+                    mins = {}
+                    for i in magg_idx:
+                        ident = m_ident[i]
+                        grid = jnp.where(onehot.astype(bool),
+                                         av_m[i][:, None], ident)          # [B,K]
+                        red = jax.lax.cummin if m_ismin[i] else jax.lax.cummax
+                        g = red(grid, axis=0)
+                        per_ev = jnp.take_along_axis(g, keys[:, None], axis=1)[:, 0]
+                        carried = state[f"key_m{i}"][keys]
+                        mins[i] = jnp.minimum(per_ev, carried) if m_ismin[i] \
+                            else jnp.maximum(per_ev, carried)
+                        new_state[f"key_m{i}"] = (
+                            jnp.minimum(state[f"key_m{i}"], g[-1]) if m_ismin[i]
+                            else jnp.maximum(state[f"key_m{i}"], g[-1]))
 
-                keep = jnp.arange(N) < rem_n
-                new_state = {**state,
-                             "rem_count": rem_n.astype(jnp.int32),
-                             "window_drops": state["window_drops"] + dropped,
-                             "ts_regressions":
-                                 state["ts_regressions"] + regressions}
-                new_state["rem_ts"] = jnp.where(keep, rem_slice(zts), 0)
-                for i in value_idx:
-                    z_p = zproj[i]
-                    new_state[f"rem_proj_{i}"] = jnp.where(
-                        keep, rem_slice(z_p), jnp.zeros((), z_p.dtype))
-                out = {specs[i].name: zproj[i] for i in value_idx}
-                ovalid = release
-                if having_fn is not None:
-                    ovalid = ovalid & jnp.broadcast_to(
-                        having_fn(out), ovalid.shape)
-                return new_state, {"out": out, "valid": ovalid,
-                                   "ts": zts + window_ms,
-                                   "count": jnp.sum(
-                                       release.astype(jnp.int32))}
+                    # stdDev per key: shifted moments centered at the key's
+                    # carried mean (Welford merged at batch granularity)
+                    svars = jnp.zeros((len(sagg_idx), B), FACC)
+                    for si in range(len(sagg_idx)):
+                        # center at the key's carried mean; for a never-seen key
+                        # use its first value in this batch — centering at 0 would
+                        # cancel catastrophically in f32 for near-equal values
+                        firstval = jnp.sum(
+                            jnp.where(first_occ, av_s[si][:, None], 0.0), axis=0)
+                        c_key = jnp.where(state["key_scnt"][si] > 0,
+                                          state["key_smean"][si], firstval)  # [K]
+                        c_ev = c_key[keys]                                # [B]
+                        d = (av_s[si] - c_ev) * onehot.sum(axis=1).astype(FACC)
+                        d2 = d * d
+                        grid1 = onehot.astype(FACC) * d[:, None]
+                        grid2 = onehot.astype(FACC) * d2[:, None]
+                        cs1 = jnp.cumsum(grid1, axis=0)
+                        cs2 = jnp.cumsum(grid2, axis=0)
+                        s1 = jnp.take_along_axis(cs1, keys[:, None], axis=1)[:, 0]
+                        s2 = jnp.take_along_axis(cs2, keys[:, None], axis=1)[:, 0]
+                        m2p = state["key_sm2"][si][keys]
+                        # per-key event count at this row (aggregates share the
+                        # accepted-event axis)
+                        nsc = state["key_scnt"][si][keys] + \
+                            jnp.take_along_axis(ocum, keys[:, None],
+                                                axis=1)[:, 0].astype(FACC)
+                        var = jnp.maximum(
+                            (m2p + s2) / jnp.maximum(nsc, 1.0)
+                            - ((s1) / jnp.maximum(nsc, 1.0)) ** 2, 0.0)
+                        svars = svars.at[si].set(jnp.sqrt(var))
+                        # state update: recenter to the new mean
+                        add1 = cs1[-1]                                     # [K]
+                        add2 = cs2[-1]
+                        addn = onehot.sum(axis=0).astype(FACC)
+                        n_new = state["key_scnt"][si] + addn
+                        mean_new = c_key + add1 / jnp.maximum(n_new, 1.0)
+                        m2_new = state["key_sm2"][si] + add2 - \
+                            jnp.maximum(n_new, 1.0) * (mean_new - c_key) ** 2
+                        new_state["key_smean"] = new_state["key_smean"].at[si].set(
+                            mean_new)
+                        new_state["key_sm2"] = new_state["key_sm2"].at[si].set(
+                            jnp.maximum(m2_new, 0.0))
+                        new_state["key_scnt"] = new_state["key_scnt"].at[si].set(
+                            n_new)
+                    return finish(new_state, sums_f, sums_i, cnts, mins, svars)
 
-            if group_keys:
-                # exact packed key (for collision detection) + bucket id —
-                # see make_keys(). A bucket claimed by a different packed key
-                # is COUNTED (group_collisions) — loud, bounded-table
-                # overflow policy like window/slot drops.
-                keys, packed = make_keys()
-                onehot = (jax.nn.one_hot(keys, K, dtype=jnp.int32)
-                          * out_valid[:, None].astype(jnp.int32))     # [B,K]
-                first_occ = (jnp.cumsum(onehot, axis=0) == 1) & \
-                    onehot.astype(bool)                               # [B,K]
-
-                # collision accounting: the bucket's owner is its carried
-                # claimant or, if empty, the first claimant in this batch
-                # (ownership validity is a separate flag: any int64 is a
-                # legal packed key, so no value can serve as a sentinel)
-                batch_first = jnp.sum(
-                    jnp.where(first_occ, packed[:, None], 0), axis=0)  # [K]
-                has_batch = jnp.any(first_occ, axis=0)
-                owned = state["key_owned"]
-                claimed = jnp.where(owned, state["key_owner"], batch_first)
-                coll = out_valid & (packed != claimed[keys])
-                new_owner = claimed
-                new_owned = owned | has_batch
-
-                def per_key(av, base, dt):
-                    contrib = onehot[None].astype(dt) * av[:, :, None]  # [A,B,K]
-                    ccum = jnp.cumsum(contrib, axis=1)
-                    per_ev = jnp.take_along_axis(
-                        ccum, keys[None, :, None], axis=2)[:, :, 0] \
-                        + base[:, keys]
-                    return per_ev, contrib.sum(axis=1)
-
-                sums_f, add_f = per_key(av_f, state["key_fsums"], FACC) \
-                    if len(fagg_idx) else (jnp.zeros((0, B), FACC),
-                                           jnp.zeros((0, K), FACC))
-                sums_i, add_i = per_key(av_i, state["key_isums"], _IACC) \
-                    if len(iagg_idx) else (jnp.zeros((0, B), _IACC),
-                                           jnp.zeros((0, K), _IACC))
-                ocum = jnp.cumsum(onehot, axis=0)
-                cnts = (jnp.take_along_axis(ocum, keys[:, None], axis=1)[:, 0]
-                        .astype(jnp.int64) + state["key_counts"][keys])
-                nf, nc = _kahan_add(state["key_fsums"], state["key_fcomp"],
-                                    add_f)
-                new_state = {**state, "key_fsums": nf, "key_fcomp": nc,
-                             "key_isums": state["key_isums"] + add_i,
-                             "key_counts": state["key_counts"]
-                             + onehot.sum(axis=0).astype(jnp.int64),
-                             "key_owner": new_owner,
-                             "key_owned": new_owned,
-                             "group_collisions": state["group_collisions"]
-                             + jnp.sum(coll.astype(jnp.int64))}
-
-                # min/max per key: cumulative reduction over one-hot grids
+                # running aggregates, no window/grouping
+                cs_f = jnp.cumsum(av_f, axis=1)
+                cs_i = jnp.cumsum(av_i, axis=1)
+                cso = jnp.cumsum(ones_c).astype(jnp.int64)
+                sums_f = cs_f + state["run_fsums"][:, None]
+                sums_i = cs_i + state["run_isums"][:, None]
+                cnts = cso + state["run_count"]
+                nf, nc = _kahan_add(state["run_fsums"], state["run_fcomp"],
+                                    av_f.sum(axis=1))
+                new_state = {**state, "run_fsums": nf, "run_fcomp": nc,
+                             "run_isums": state["run_isums"] + av_i.sum(axis=1),
+                             "run_count": state["run_count"]
+                             + ones_c.sum().astype(jnp.int64)}
                 mins = {}
                 for i in magg_idx:
-                    ident = m_ident[i]
-                    grid = jnp.where(onehot.astype(bool),
-                                     av_m[i][:, None], ident)          # [B,K]
                     red = jax.lax.cummin if m_ismin[i] else jax.lax.cummax
-                    g = red(grid, axis=0)
-                    per_ev = jnp.take_along_axis(g, keys[:, None], axis=1)[:, 0]
-                    carried = state[f"key_m{i}"][keys]
-                    mins[i] = jnp.minimum(per_ev, carried) if m_ismin[i] \
-                        else jnp.maximum(per_ev, carried)
-                    new_state[f"key_m{i}"] = (
-                        jnp.minimum(state[f"key_m{i}"], g[-1]) if m_ismin[i]
-                        else jnp.maximum(state[f"key_m{i}"], g[-1]))
-
-                # stdDev per key: shifted moments centered at the key's
-                # carried mean (Welford merged at batch granularity)
+                    pre = red(av_m[i])
+                    carried = state[f"run_m{i}"]
+                    mins[i] = jnp.minimum(pre, carried) if m_ismin[i] \
+                        else jnp.maximum(pre, carried)
+                    new_state[f"run_m{i}"] = mins[i][-1]
                 svars = jnp.zeros((len(sagg_idx), B), FACC)
                 for si in range(len(sagg_idx)):
-                    # center at the key's carried mean; for a never-seen key
-                    # use its first value in this batch — centering at 0 would
-                    # cancel catastrophically in f32 for near-equal values
-                    firstval = jnp.sum(
-                        jnp.where(first_occ, av_s[si][:, None], 0.0), axis=0)
-                    c_key = jnp.where(state["key_scnt"][si] > 0,
-                                      state["key_smean"][si], firstval)  # [K]
-                    c_ev = c_key[keys]                                # [B]
-                    d = (av_s[si] - c_ev) * onehot.sum(axis=1).astype(FACC)
+                    # center at the carried mean; on the very first events use the
+                    # first accepted value (0-centering cancels catastrophically)
+                    c = jnp.where(state["run_scnt"][si] > 0,
+                                  state["run_smean"][si], av_s[si][0])
+                    occ = ones_c.astype(FACC)
+                    d = (av_s[si] - c) * occ
                     d2 = d * d
-                    grid1 = onehot.astype(FACC) * d[:, None]
-                    grid2 = onehot.astype(FACC) * d2[:, None]
-                    cs1 = jnp.cumsum(grid1, axis=0)
-                    cs2 = jnp.cumsum(grid2, axis=0)
-                    s1 = jnp.take_along_axis(cs1, keys[:, None], axis=1)[:, 0]
-                    s2 = jnp.take_along_axis(cs2, keys[:, None], axis=1)[:, 0]
-                    m2p = state["key_sm2"][si][keys]
-                    # per-key event count at this row (aggregates share the
-                    # accepted-event axis)
-                    nsc = state["key_scnt"][si][keys] + \
-                        jnp.take_along_axis(ocum, keys[:, None],
-                                            axis=1)[:, 0].astype(FACC)
+                    s1 = jnp.cumsum(d)
+                    s2 = jnp.cumsum(d2)
+                    nsc = state["run_scnt"][si] + jnp.cumsum(occ)
                     var = jnp.maximum(
-                        (m2p + s2) / jnp.maximum(nsc, 1.0)
-                        - ((s1) / jnp.maximum(nsc, 1.0)) ** 2, 0.0)
+                        (state["run_sm2"][si] + s2) / jnp.maximum(nsc, 1.0)
+                        - (s1 / jnp.maximum(nsc, 1.0)) ** 2, 0.0)
                     svars = svars.at[si].set(jnp.sqrt(var))
-                    # state update: recenter to the new mean
-                    add1 = cs1[-1]                                     # [K]
-                    add2 = cs2[-1]
-                    addn = onehot.sum(axis=0).astype(FACC)
-                    n_new = state["key_scnt"][si] + addn
-                    mean_new = c_key + add1 / jnp.maximum(n_new, 1.0)
-                    m2_new = state["key_sm2"][si] + add2 - \
-                        jnp.maximum(n_new, 1.0) * (mean_new - c_key) ** 2
-                    new_state["key_smean"] = new_state["key_smean"].at[si].set(
+                    n_new = state["run_scnt"][si] + occ.sum()
+                    mean_new = c + s1[-1] / jnp.maximum(n_new, 1.0)
+                    m2_new = state["run_sm2"][si] + s2[-1] - \
+                        jnp.maximum(n_new, 1.0) * (mean_new - c) ** 2
+                    new_state["run_smean"] = new_state["run_smean"].at[si].set(
                         mean_new)
-                    new_state["key_sm2"] = new_state["key_sm2"].at[si].set(
+                    new_state["run_sm2"] = new_state["run_sm2"].at[si].set(
                         jnp.maximum(m2_new, 0.0))
-                    new_state["key_scnt"] = new_state["key_scnt"].at[si].set(
-                        n_new)
+                    new_state["run_scnt"] = new_state["run_scnt"].at[si].set(n_new)
                 return finish(new_state, sums_f, sums_i, cnts, mins, svars)
-
-            # running aggregates, no window/grouping
-            cs_f = jnp.cumsum(av_f, axis=1)
-            cs_i = jnp.cumsum(av_i, axis=1)
-            cso = jnp.cumsum(ones_c).astype(jnp.int64)
-            sums_f = cs_f + state["run_fsums"][:, None]
-            sums_i = cs_i + state["run_isums"][:, None]
-            cnts = cso + state["run_count"]
-            nf, nc = _kahan_add(state["run_fsums"], state["run_fcomp"],
-                                av_f.sum(axis=1))
-            new_state = {**state, "run_fsums": nf, "run_fcomp": nc,
-                         "run_isums": state["run_isums"] + av_i.sum(axis=1),
-                         "run_count": state["run_count"]
-                         + ones_c.sum().astype(jnp.int64)}
-            mins = {}
-            for i in magg_idx:
-                red = jax.lax.cummin if m_ismin[i] else jax.lax.cummax
-                pre = red(av_m[i])
-                carried = state[f"run_m{i}"]
-                mins[i] = jnp.minimum(pre, carried) if m_ismin[i] \
-                    else jnp.maximum(pre, carried)
-                new_state[f"run_m{i}"] = mins[i][-1]
-            svars = jnp.zeros((len(sagg_idx), B), FACC)
-            for si in range(len(sagg_idx)):
-                # center at the carried mean; on the very first events use the
-                # first accepted value (0-centering cancels catastrophically)
-                c = jnp.where(state["run_scnt"][si] > 0,
-                              state["run_smean"][si], av_s[si][0])
-                occ = ones_c.astype(FACC)
-                d = (av_s[si] - c) * occ
-                d2 = d * d
-                s1 = jnp.cumsum(d)
-                s2 = jnp.cumsum(d2)
-                nsc = state["run_scnt"][si] + jnp.cumsum(occ)
-                var = jnp.maximum(
-                    (state["run_sm2"][si] + s2) / jnp.maximum(nsc, 1.0)
-                    - (s1 / jnp.maximum(nsc, 1.0)) ** 2, 0.0)
-                svars = svars.at[si].set(jnp.sqrt(var))
-                n_new = state["run_scnt"][si] + occ.sum()
-                mean_new = c + s1[-1] / jnp.maximum(n_new, 1.0)
-                m2_new = state["run_sm2"][si] + s2[-1] - \
-                    jnp.maximum(n_new, 1.0) * (mean_new - c) ** 2
-                new_state["run_smean"] = new_state["run_smean"].at[si].set(
-                    mean_new)
-                new_state["run_sm2"] = new_state["run_sm2"].at[si].set(
-                    jnp.maximum(m2_new, 0.0))
-                new_state["run_scnt"] = new_state["run_scnt"].at[si].set(n_new)
-            return finish(new_state, sums_f, sums_i, cnts, mins, svars)
 
         return step
 

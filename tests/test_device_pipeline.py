@@ -117,16 +117,20 @@ def test_driver_overlap_counters_and_gauges():
     rt.flush_device()
     assert drv.batches_stepped >= 4
     assert drv.step_seconds > 0.0
-    assert drv.busy_wall_seconds > 0.0
-    assert drv.pack_seconds > 0.0           # builders stamped pack spans
     assert drv.pipeline_depth == 0          # drained
-    assert drv.overlap_efficiency > 0.0
-    # the probe exports the pipeline-health gauges
     sm = rt.ctx.statistics_manager
     q = bridge.query_name
     assert sm.gauges[f"device.{q}.pipeline_depth"].value == 0
-    assert sm.gauges[f"device.{q}.overlap_efficiency"].value > 0.0
-    assert sm.gauges[f"device.{q}.device_idle_frac"].value >= 0.0
+    # every stepped batch left its serial segments in the phase trackers:
+    # builders stamped the pack, the driver the queue and the dispatch, the
+    # runtime's collect its fence and its decode (event-weighted counts)
+    trackers = bridge.probe.phases.trackers
+    for phase in ("pack", "ingress_queue", "device_step", "egress_fence",
+                  "egress_decode"):
+        assert trackers[phase].count == 20_000, phase
+    # rows went out under the engine lock: asked for, then held
+    assert trackers["lock_wait"].count == trackers["sink_publish"].count > 0
+    assert trackers["ring_wait"].count == 0     # the ring never filled
     m.shutdown()
 
 

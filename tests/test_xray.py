@@ -675,3 +675,267 @@ def test_phase_of_stage_total():
     assert phase_of_stage("mystery") == "host_exec"
     for ph in PHASES:
         assert isinstance(ph, str)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: the driver thread's segments told apart (fence / decode, lock
+# wait / publish, ring wait), as phase trackers and as profiler spans; the
+# jitted stages named
+# ---------------------------------------------------------------------------
+
+SPLIT_APP = """
+@app(name='Split')
+define stream S (v double);
+define stream O (v double, t double);
+@info(name='agg')
+@device(batch='64'{extra}) from S[v >= 0.0]#window.length(16)
+select v, sum(v) as t insert into O;
+"""
+
+
+def _split_runtime(manager, async_mode):
+    rt = manager.create_siddhi_app_runtime(
+        SPLIT_APP.format(extra=", async='true'" if async_mode else ""),
+        playback=True)
+    rt.start()
+    return rt, rt.device_bridges[0]
+
+
+def _send(rt, n, start=0):
+    ih = rt.input_handler("S")
+    for i in range(start, start + n):
+        ih.send([float(i)], timestamp=1000 + i)
+
+
+def _assert_reconciles(rt, events):
+    q = rt.observability.latency_report()["queries"]["agg"]
+    assert q["end_to_end"]["count"] == events
+    assert q["reconciliation_ratio"] == pytest.approx(1.0, abs=1e-5)
+    return q
+
+
+def test_the_three_new_phases_are_in_the_vocabulary():
+    assert {"egress_decode", "lock_wait", "ring_wait"} <= set(PHASES)
+    # the waterfall's order: a wait sits before the work it delays
+    assert PHASES.index("egress_fence") < PHASES.index("egress_decode")
+    assert PHASES.index("lock_wait") < PHASES.index("sink_publish")
+
+
+@pytest.mark.parametrize("async_mode", [True, False], ids=["async", "sync"])
+def test_collect_split_reconciles_on_both_paths(manager, async_mode):
+    """Every batch's segments, the carved ones included, still sum to its
+    end-to-end sample; fence and decode are both measured on every batch."""
+    rt, bridge = _split_runtime(manager, async_mode)
+    _send(rt, 64 * 3)         # fewer than the ring holds: no ring wait
+    rt.flush_device()
+    q = _assert_reconciles(rt, 64 * 3)
+    trackers = bridge.probe.phases.trackers
+    for phase in ("device_step", "egress_fence", "egress_decode"):
+        assert trackers[phase].count == 64 * 3, phase
+    assert trackers["ring_wait"].count == 0
+    if async_mode:
+        # step_seconds is dispatch + the whole of collect, so the three
+        # trackers add up to it (what step.host_ms_per_batch reads)
+        parts = sum(trackers[p].hist.sum for p in
+                    ("device_step", "egress_fence", "egress_decode")) / 64
+        assert parts == pytest.approx(bridge.driver.step_seconds, rel=1e-6)
+        assert trackers["lock_wait"].count == trackers["sink_publish"].count \
+            == 64 * 3
+    else:
+        # the client already holds the engine lock: no wait to measure
+        assert trackers["lock_wait"].count == 0
+    assert {"egress_fence", "egress_decode"} <= set(q["phases"])
+
+
+def test_lock_wait_is_the_drivers_wait_for_the_engine_lock(manager):
+    rt, bridge = _split_runtime(manager, True)
+    drv, lock = bridge.driver, bridge.app_context.root_lock
+    held_s = 0.08
+    with lock:                          # a client inside a long send
+        _send(rt, 64)                   # seals one batch (lock re-entered)
+        deadline = time.monotonic() + 20.0
+        while drv.batches_stepped < 1 and time.monotonic() < deadline:
+            time.sleep(0.002)           # collected: the driver now wants
+        assert drv.batches_stepped == 1     # the lock for its rows
+        time.sleep(held_s)
+    rt.flush_device()
+    trackers = bridge.probe.phases.trackers
+    assert trackers["lock_wait"].count == 64
+    assert trackers["lock_wait"].hist.sum / 64 >= held_s * 0.9
+    # the publishing itself was not charged for the wait
+    assert trackers["sink_publish"].hist.sum / 64 < held_s / 2
+    _assert_reconciles(rt, 64)
+
+
+def test_ring_wait_is_the_clients_wait_on_a_full_ring(manager):
+    rt, bridge = _split_runtime(manager, True)
+    drv = bridge.driver
+    drv.pause()
+    try:
+        _send(rt, 64 * (drv.depth + 1))     # the last seal finds it full
+        assert drv.pipeline_depth == drv.depth + 1
+    finally:
+        drv.resume()
+    rt.flush_device()
+    trackers = bridge.probe.phases.trackers
+    # one batch waited, for the whole 0.2 s (nothing woke the producer)
+    assert trackers["ring_wait"].count == 64
+    assert trackers["ring_wait"].hist.sum / 64 == pytest.approx(0.2, abs=0.05)
+    # carved OUT of the queue wait, not counted twice
+    _assert_reconciles(rt, 64 * (drv.depth + 1))
+
+
+def test_a_guard_replay_is_neither_fence_nor_decode(manager):
+    """A step whose collect fails after its fence is replayed on the host:
+    the batch leaves nothing in the device-side trackers."""
+    rt, bridge = _split_runtime(manager, True)
+    compiled = bridge.runtime.compiled
+    inner, left = compiled.decode_outputs, [1]
+
+    def decode_once_broken(out):
+        if left[0]:
+            left[0] -= 1
+            raise RuntimeError("sabotaged decode")
+        return inner(out)
+
+    compiled.decode_outputs = decode_once_broken
+    _send(rt, 64 * 3)
+    rt.flush_device()
+    assert bridge.guard.failures == 1 and bridge.guard.fallback_events == 64
+    trackers = bridge.probe.phases.trackers
+    for phase in ("device_step", "egress_fence", "egress_decode"):
+        assert trackers[phase].count == 64 * 2, phase
+    _assert_reconciles(rt, 64 * 2)
+
+
+SPAN_PARENTS = {"siddhi:collect.fence": "siddhi:collect",
+                "siddhi:collect.decode": "siddhi:collect",
+                "siddhi:deliver.lock": "siddhi:deliver",
+                "siddhi:deliver.publish": "siddhi:deliver"}
+CLIENT_SPANS = ("siddhi:seal.pack", "siddhi:submit.ring_wait")
+DRIVER_SPANS = ("siddhi:dispatch", "siddhi:collect", "siddhi:deliver")
+
+
+def test_profiler_trace_holds_every_span_nested_and_on_its_thread(
+        manager, tmp_path):
+    """No annotation, option or environment variable: an app that runs
+    inside somebody's profiler session leaves its spans there."""
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    rt, bridge = _split_runtime(manager, True)
+    _send(rt, 64)
+    rt.flush_device()                   # compiled before the session
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with TraceAnnotation("test:client"):
+            bridge.driver.pause()
+            try:                        # fill the ring: one ring_wait span
+                _send(rt, 64 * (bridge.driver.depth + 1), start=64)
+            finally:
+                bridge.driver.resume()
+        rt.flush_device()
+        time.sleep(0.2)
+    finally:
+        jax.profiler.stop_trace()
+    found = [p for p in tmp_path.rglob("*.xplane.pb")]
+    assert found
+    lines = []      # one per host thread: {span name: [(start, end), ...]}
+    for plane in ProfileData.from_file(str(found[0])).planes:
+        for line in plane.lines:
+            spans = {}
+            for ev in line.events:
+                if ev.name.startswith(("siddhi:", "test:")):
+                    spans.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+            if spans:
+                lines.append(spans)
+    q = ":agg"
+    client = [ln for ln in lines if "test:client" in ln]
+    driver = [ln for ln in lines if "siddhi:dispatch" + q in ln]
+    assert len(client) == 1 and len(driver) == 1
+    client, driver = client[0], driver[0]
+    assert client is not driver
+    for name in CLIENT_SPANS:
+        assert name + q in client and name + q not in driver, name
+    for name in DRIVER_SPANS + tuple(SPAN_PARENTS):
+        assert name + q in driver and name + q not in client, name
+    assert len(client["siddhi:submit.ring_wait" + q]) == 1
+    (w0, w1), = client["siddhi:submit.ring_wait" + q]
+    assert 0.15e9 <= w1 - w0 <= 0.4e9
+    for child, parent in SPAN_PARENTS.items():
+        for s, e in driver[child + q]:
+            assert any(ps <= s and e <= pe for ps, pe in driver[parent + q]), \
+                (child, s, e)
+    # the children cover their parent but for clock reads: fence + decode
+    # is collect, lock + publish is deliver
+    for parent in ("siddhi:collect", "siddhi:deliver"):
+        whole = sum(e - s for s, e in driver[parent + q])
+        parts = sum(e - s for c, p in SPAN_PARENTS.items() if p == parent
+                    for s, e in driver[c + q])
+        assert 0.5 * whole <= parts <= whole, (parent, parts, whole)
+
+
+SCOPED_PATTERN_APP = """
+define stream S (dev string, v double);
+@device(batch='64', slots='16')
+from every a=S[v > 90.0] -> b=S[v > a.v] -> c=S[v > b.v] within 1000
+select a.dev as d, c.v as v insert into M;
+"""
+SCOPED_STREAM_APP = """
+define stream S (k int, v double);
+@device(batch='64')
+from S[v > 1.0]#window.length(16)
+select k, v * 2.0 as d, sum(v) as t, avg(v) as m group by k having t > 0.0
+insert into O;
+"""
+
+
+def _compiled_step_text(app_text):
+    """Optimized HLO of the app's one jitted device step."""
+    import numpy as np
+    m = SiddhiManager()
+    try:
+        r = m.create_siddhi_app_runtime(
+            app_text, playback=True).device_bridges[0].runtime
+        b = r.builder.emit()
+        if hasattr(r, "compiler"):
+            low = r.compiler._step.lower(
+                r.state, b["cols"], b["tag"], b["ts"], b["ts_base"],
+                np.int32(b["count"]))
+        else:
+            low = r.compiled._step.lower(r.state, b["cols"], b["ts"],
+                                         b["valid"])
+        return low.compile().as_text()
+    finally:
+        m.shutdown()
+
+
+@pytest.mark.parametrize("app_text,scopes", [
+    (SCOPED_PATTERN_APP, ("nfa.admit", "nfa.stage1", "nfa.stage2",
+                          "nfa.emit", "nfa.compact")),
+    (SCOPED_STREAM_APP, ("filter", "compact", "window.length", "groupby",
+                         "select")),
+], ids=["nfa_block", "stream_query"])
+def test_jitted_stages_are_named_and_the_names_cost_no_operation(
+        monkeypatch, app_text, scopes):
+    import contextlib
+    import re
+
+    import jax
+
+    def instructions(text):
+        return len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", text, re.M))
+
+    named = _compiled_step_text(app_text)
+    op_names = re.findall(r'op_name="([^"]*)"', named)
+    for scope in scopes:
+        assert any(scope in name.split("/") for name in op_names), scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _compiled_step_text(app_text)
+    assert not any(s in n.split("/") for s in scopes
+                   for n in re.findall(r'op_name="([^"]*)"', bare))
+    assert instructions(bare) == instructions(named) > 50
