@@ -685,8 +685,9 @@ class SiddhiAppRuntime:
     def add_callback(self, stream_id: str, callback: StreamCallback) -> None:
         if stream_id not in self.ctx.stream_junctions:
             raise KeyError(f"stream '{stream_id}' is not defined")
-        self.ctx.stream_junctions[stream_id].subscribe(
-            _StreamCallbackReceiver(callback))
+        j = self.ctx.stream_junctions[stream_id]
+        j.subscribe(_StreamCallbackReceiver(
+            callback, j.definition.attribute_names))
 
     def add_rows_callback(self, stream_id: str, fn) -> None:
         """Columns-capable subscription: ``fn(cols, ts, n)`` receives whole

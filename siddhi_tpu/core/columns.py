@@ -184,49 +184,95 @@ class ColumnsOut:
     """A query's columnar output chunk: raw plan columns (strings still
     dictionary codes) + the specs/dictionaries that decode them. Decoding
     and row materialization are lazy — the zero-object egress hands
-    ``decoded()`` columns to rows-capable sinks and never builds rows."""
+    ``decoded()`` columns to rows-capable sinks and never builds rows.
 
-    __slots__ = ("ts", "cols", "n", "specs", "dictionaries",
+    The unit of egress of every batched tier: the host runtimes' ``process``
+    and the device runtimes' ``collect`` both return one. ``ts`` is the
+    per-row event time (the host tier's), or None until the device bridge
+    stamps the batch's emit time on it (``stamp``). ``nulls`` maps a column
+    name to a bool mask of its NULL cells (outer joins, absent pattern
+    states). An empty chunk is falsy."""
+
+    __slots__ = ("ts", "cols", "n", "specs", "dictionaries", "nulls",
                  "_decoded", "_rows")
 
-    def __init__(self, ts: np.ndarray, cols: dict, n: int, specs: list,
-                 dictionaries: dict):
+    def __init__(self, ts: Optional[np.ndarray], cols: dict, n: int,
+                 specs: list, dictionaries: dict,
+                 nulls: Optional[dict] = None):
         self.ts = ts
         self.cols = cols
         self.n = n
         self.specs = specs              # [(name, fn, DataType)]
         self.dictionaries = dictionaries
+        self.nulls = nulls
         self._decoded = None
         self._rows = None
 
+    def __len__(self) -> int:
+        return self.n
+
+    @classmethod
+    def empty(cls, specs: list, dictionaries: dict) -> "ColumnsOut":
+        return cls(None, {name: np.empty(0, dtype=object)
+                          for (name, _fn, _t) in specs}, 0, specs,
+                   dictionaries)
+
+    @classmethod
+    def concat(cls, chunks: list) -> "ColumnsOut":
+        """The chunks' rows in order as one chunk (a hopping window's
+        drain steps behind their batch's own step). Unstamped chunks only."""
+        first = chunks[0]
+        if len(chunks) == 1:
+            return first
+        names = [name for (name, _fn, _t) in first.specs]
+        cols = {name: np.concatenate([c.cols[name] for c in chunks])
+                for name in names}
+        nulls = None
+        if first.nulls is not None:
+            nulls = {name: np.concatenate([c.nulls[name] for c in chunks])
+                     for name in first.nulls}
+        return cls(None, cols, sum(c.n for c in chunks), first.specs,
+                   first.dictionaries, nulls)
+
+    def stamp(self, ts: int) -> None:
+        """One emit time on every row (the device tier's batch stamp)."""
+        self.ts = np.full(self.n, ts, dtype=np.int64)
+
     def decoded(self) -> dict:
         """{name: numpy column} with dictionary codes decoded to value
-        object arrays — the payload ``StreamJunction.deliver_columns``
-        carries to rows-capable receivers."""
+        object arrays and NULL cells set to None — the payload
+        ``StreamJunction.deliver_columns`` carries to rows-capable
+        receivers."""
         if self._decoded is None:
             out = {}
-            table = None
-            for dic in self.dictionaries.values():
-                table = dic
-                break
+            table = next(iter(self.dictionaries.values()), None)
+            vals = None         # the dictionary's value table, built once
+            nulls = self.nulls or {}
             for (name, _fn, t) in self.specs:
                 v = self.cols[name]
                 if t == DataType.STRING and table is not None:
-                    vals = np.empty(len(table._values), dtype=object)
-                    vals[:] = table._values
-                    codes = np.clip(np.asarray(v, np.int64), 0,
-                                    len(vals) - 1)
-                    out[name] = vals[codes]
+                    if vals is None:
+                        vals = np.empty(len(table._values), dtype=object)
+                        vals[:] = table._values
+                    v = vals[np.clip(np.asarray(v, np.int64), 0,
+                                     len(vals) - 1)]
                 else:
-                    out[name] = np.asarray(v)
+                    v = np.asarray(v)
+                null = nulls.get(name)
+                if null is not None and null.any():
+                    v = v.astype(object)
+                    v[null] = None
+                out[name] = v
             self._decoded = out
         return self._decoded
 
     def rows(self) -> list[list]:
+        """Host rows of Python scalars: whole columns through ``tolist()``
+        (C-side), one ``zip`` — no per-cell Python."""
         if self._rows is None:
-            from ..tpu.host_exec import decode_columns
-            self._rows = decode_columns(self.specs, self.cols,
-                                        self.dictionaries)
+            self._rows = columns_to_rows(
+                self.decoded(), [name for (name, _fn, _t) in self.specs],
+                self.n)
         return self._rows
 
     def ts_list(self) -> list:

@@ -31,7 +31,8 @@ from ..query_api import (
 from ..query_api.annotation import find_annotation
 from ..flow.adaptive_batch import AdaptiveFlushMixin
 from ..observability.profiler import span
-from .event import Event, EventType, StreamEvent
+from .egress import ChunkEgress
+from .event import EventType, StreamEvent
 
 log = logging.getLogger("siddhi_tpu.device")
 
@@ -52,8 +53,8 @@ class AsyncDeviceDriver:
       donated buffers (``jax.jit(..., donate_argnums=(0,))``), so dispatch is
       fire-and-forget;
     - **egress** (worker): ``rt.collect(token)`` fences (the only host sync
-      on the path) and decodes, then the rows are delivered under the
-      engine lock.
+      on the path) and decodes into one ``ColumnsOut`` chunk, which is
+      then delivered whole under the engine lock.
 
     Each edge is a span on the profiler's clock and a phase tracker, split
     where the waits are (``observability/profiler.py``, ``phases.py``): the
@@ -394,7 +395,7 @@ class _DeviceRTBase(AdaptiveFlushMixin):
     The step is two-phase: ``dispatch(batch)`` fires the jitted step without
     fencing (JAX async dispatch — state advances through donated buffers)
     and returns the un-fetched output pytree; ``collect(token)`` fences at
-    the egress edge, decodes and returns rows.
+    the egress edge and decodes into one ``ColumnsOut`` chunk.
     ``process`` is one dispatch immediately collected — the synchronous
     path, and the shape the DeviceGuard wraps on both phases. Host-sync
     bookkeeping that would stall the pipeline (counter checks read device
@@ -405,9 +406,6 @@ class _DeviceRTBase(AdaptiveFlushMixin):
     callback = None
     pipeline_safe = True    # False → the driver pins the window to 1
 
-    def add_callback(self, fn):
-        self.callback = fn
-
     def dispatch(self, batch):
         """Fire-and-forget device step: advances ``self.state`` and returns
         the un-fenced output pytree as the egress token."""
@@ -415,10 +413,14 @@ class _DeviceRTBase(AdaptiveFlushMixin):
         return out
 
     def collect(self, out):
-        """Egress fence + decode for one dispatched step."""
+        """Egress fence + decode for one dispatched step: one ``ColumnsOut``
+        chunk (falsy when empty), its string codes already resolved so
+        that ``deliver`` holds the engine lock for the junction alone."""
         self._fence(out["valid"])
         with span(f"siddhi:collect.decode:{self.query_name}"):
-            return self.compiled.decode_outputs(out)
+            chunk = self.compiled.decode_outputs(out)
+            chunk.decoded()
+            return chunk
 
     def process(self, batch):
         """Synchronous step + decode (async: worker thread, no engine lock —
@@ -429,14 +431,10 @@ class _DeviceRTBase(AdaptiveFlushMixin):
         """Called when the pipeline empties — the safe point for host-sync
         bookkeeping (device_get with nothing in flight)."""
 
-    def deliver(self, rows, emit_ts=None):
+    def deliver(self, out, emit_ts=None):
         fn = self.callback
-        if fn and rows:
-            if getattr(getattr(fn, "__self__", None),
-                       "_on_rows_accepts_ts", False):
-                fn(rows, emit_ts)
-            else:           # plain user callback: rows only
-                fn(rows)
+        if fn and out:
+            fn(out, emit_ts)
 
     def flush(self):
         if len(self.builder) == 0:
@@ -460,12 +458,14 @@ class _LimiterSink:
         self.bridge = bridge
 
     def process(self, events: list[StreamEvent]) -> None:
-        self.bridge._emit(events)
+        self.bridge._publish_events(events)
 
 
-class DeviceQueryBridge:
+class DeviceQueryBridge(ChunkEgress):
     """Junction subscriber feeding a compiled device query; outputs re-enter the
-    engine through the query's output junction.
+    engine through the query's output junction, a batch's chunk at a time
+    (``ChunkEgress``): as columns when every subscriber takes columns, else
+    as one chunk of events.
 
     Output rate limiting (``output [all|first|last] every ...`` /
     ``output snapshot``) runs host-side on the decoded device rows — the
@@ -489,9 +489,9 @@ class DeviceQueryBridge:
         self.query_callbacks: list = []
         self.guard = None                   # DeviceGuard (resilience layer)
         self.probe = None                   # DeviceStepProbe (observability)
-        self._on_rows_accepts_ts = True     # deliver() passes the batch ts
+        self._init_egress()
         runtime.query_name = query_name     # the profiler spans' <query>
-        runtime.add_callback(self._on_rows)
+        runtime.callback = self._on_chunk   # deliver()'s fn(chunk, emit_ts)
         self._out_ts = 0
         self.rate_limiter = None
         if output_rate is not None:
@@ -600,28 +600,12 @@ class DeviceQueryBridge:
         if self.driver is not None:
             self.driver.flush_sync()
 
-    def _on_rows(self, rows: list[list], emit_ts=None) -> None:
-        # async delivery passes the source batch's last event time; the
-        # producer-side _out_ts may already have advanced past it
-        ts = self._out_ts if emit_ts is None else emit_ts
-        events = [StreamEvent(ts, row, EventType.CURRENT) for row in rows]
-        if self.rate_limiter is not None:
-            self.rate_limiter.process(events)   # → _LimiterSink → _emit
-        else:
-            self._emit(events)
-
-    def _emit(self, events: list[StreamEvent]) -> None:
-        if not events:
-            return
-        if self.query_callbacks:
-            ts = events[-1].timestamp
-            evs = [Event(e.timestamp, e.data) for e in events]
-            for cb in self.query_callbacks:
-                cb.receive(ts, evs, None)
-        if self.output_junction is None:
-            return
-        for e in events:
-            self.output_junction.send_event(e)
+    def _on_chunk(self, out, emit_ts=None) -> None:
+        """One batch's output chunk, every row stamped with the source
+        batch's last event time (async delivery passes it; the producer-side
+        ``_out_ts`` may already have advanced past it)."""
+        out.stamp(self._out_ts if emit_ts is None else emit_ts)
+        self._on_out(out)
 
 
 def _input_single_streams(ist) -> list[SingleInputStream]:
@@ -739,6 +723,7 @@ def try_build_device_query(query: Query, app_context, stream_defs: dict,
         if isinstance(ist, SingleInputStream):
             from ..tpu.batch import BatchBuilder
             from ..tpu.query_compile import CompiledStreamQuery
+            from .columns import ColumnsOut
 
             d = stream_defs.get(ist.stream_id)
             if d is None:
@@ -844,18 +829,21 @@ def try_build_device_query(query: Query, app_context, stream_defs: dict,
                 def collect(self, out):
                     """Egress fence + decode. Hopping drains deferred
                     boundary flushes here with empty steps — the runtime is
-                    pipeline-unsafe, so the state read is this step's own."""
+                    pipeline-unsafe, so the state read is this step's own —
+                    and their chunks follow the batch's in order."""
                     self._fence(out["valid"])
                     with span(f"siddhi:collect.decode:{self.query_name}"):
-                        rows = self.compiled.decode_outputs(out)
+                        chunks = [self.compiled.decode_outputs(out)]
                         if self.compiled.window_kind == "hopping":
                             from ..tpu.runtime import drain_hop_boundaries
                             self.state = drain_hop_boundaries(
                                 self.compiled, self.state,
                                 self._drain_builder,
-                                lambda o: rows.extend(
+                                lambda o: chunks.append(
                                     self.compiled.decode_outputs(o)))
-                    return rows
+                        chunk = ColumnsOut.concat(chunks)
+                        chunk.decoded()
+                    return chunk
 
                 def on_drained(self):
                     # counter checks device_get state scalars — deferred to

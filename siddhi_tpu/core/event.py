@@ -72,6 +72,16 @@ class Event:
         self.data = list(data)
         self.is_expired = is_expired
 
+    @classmethod
+    def _own(cls, timestamp: int, data: list) -> "Event":
+        """A CURRENT event that keeps ``data`` itself: a row list built from
+        a columnar chunk for this event alone, which nobody else holds."""
+        e = cls.__new__(cls)
+        e.timestamp = timestamp
+        e.data = data
+        e.is_expired = False
+        return e
+
     def __repr__(self) -> str:
         flag = ", expired" if self.is_expired else ""
         return f"Event({self.timestamp}, {self.data}{flag})"

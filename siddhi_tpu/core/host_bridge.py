@@ -49,7 +49,8 @@ from ..query_api import (
 )
 from ..query_api.annotation import find_annotation
 from ..flow.adaptive_batch import AdaptiveFlushMixin
-from .event import Event, EventType, StreamEvent
+from .egress import ChunkEgress
+from .event import EventType, StreamEvent
 
 log = logging.getLogger("siddhi_tpu.host_batch")
 
@@ -119,7 +120,7 @@ class _HostRTBase(AdaptiveFlushMixin):
         self.flush()
 
 
-class HostQueryBridge:
+class HostQueryBridge(ChunkEgress):
     """Junction subscriber feeding a columnar host runtime; outputs re-enter
     the engine through the query's output junction with per-row timestamps."""
 
@@ -134,6 +135,7 @@ class HostQueryBridge:
         self.query_callbacks: list = []
         self.events_in = 0
         self.batches = 0
+        self._init_egress()
         runtime.add_callback(self._on_out)
         sm = app_context.statistics_manager
         self._step_tracker = (
@@ -206,46 +208,13 @@ class HostQueryBridge:
         self.flush(cause="final")
         self.runtime.finalize()
 
-    # -- output ---------------------------------------------------------------
-    def _on_out(self, out) -> None:
-        """``out`` is a :class:`~siddhi_tpu.core.columns.ColumnsOut`: the
-        zero-object egress hands decoded columns straight to a
-        columns-capable output junction (rows-capable sinks); everything
-        else falls back to per-event materialization."""
-        if out is None or not out.n:
-            return
-        oj = self.output_junction
-        if not self.query_callbacks:
-            if oj is None:
-                return
-            if oj.columns_capable():
-                self._deliver_columns_out(out, oj)
-                return
-        self._deliver_events_out(out, oj)
-
-    def _deliver_columns_out(self, out, oj) -> None:
-        # zero-object egress: dictionary codes decode to value columns (one
-        # vectorized take per string column), no Event/StreamEvent builds
-        oj.deliver_columns(out.decoded(), np.asarray(out.ts, dtype=np.int64),
-                           out.n)
-
-    def _deliver_events_out(self, out, oj) -> None:
-        ts_list, rows = out.ts_list(), out.rows()
-        events = [StreamEvent(ts, row, EventType.CURRENT)
-                  for ts, row in zip(ts_list, rows)]
-        if not events:
-            return
-        if self.query_callbacks:
-            evs = [Event(e.timestamp, e.data) for e in events]
-            for cb in self.query_callbacks:
-                cb.receive(events[-1].timestamp, evs, None)
-        if oj is not None:
-            oj.send_events(events)
+    # -- output: ChunkEgress._on_out (core/egress.py), the chunk carrying its
+    # own per-row timestamps
 
     def report(self) -> dict:
         return {"query": self.query_name, "engine": "columnar",
                 "kind": self.kind, "events": self.events_in,
-                "batches": self.batches}
+                "batches": self.batches, "egress": self.egress_report()}
 
 
 class _HostBridgeState:
@@ -317,7 +286,6 @@ class _HostStreamRT(_HostRTBase):
 
     @staticmethod
     def _copy_state(v):
-        import numpy as np
         if isinstance(v, np.ndarray):
             return v.copy()
         if isinstance(v, dict):

@@ -167,9 +167,11 @@ class StreamJunction:
     def columns_capable(self) -> bool:
         """True when every subscriber accepts whole columnar chunks — the
         zero-object edge then hands numpy columns end to end (source →
-        junction → sink) with no per-event Python objects at all. Unlike
-        ``rows_capable`` an empty receiver list IS capable: the chunk is
-        counted and dropped, same as ``send_events`` to a bare junction."""
+        junction → sink) with no per-event Python objects on the way (a
+        ``StreamCallback`` builds its own ``Event`` list from the columns,
+        since events are what it asked for). Unlike ``rows_capable`` an
+        empty receiver list IS capable: the chunk is counted and dropped,
+        same as ``send_events`` to a bare junction."""
         return self.dispatcher is None and self.flow is None and \
             all(hasattr(r, "receive_columns") for r in self.receivers)
 
@@ -563,7 +565,15 @@ class InputHandler:
 
 
 class StreamCallback:
-    """Subscribe to a stream's output events (subclass or wrap a function)."""
+    """Subscribe to a stream's output events (subclass or wrap a function).
+
+    ``receive(events)`` gets the events of ONE delivery in one list, as the
+    reference's ``StreamCallback.receive(Event[] events)`` gets a chunk's
+    array: every event of a chunk that reached the stream whole (a batched
+    query's output batch — ``@device``, ``@host_batch``, fleet lanes; a
+    ``send`` of an ``Event`` list, ``send_rows``, ``send_columns``), in
+    order; a list of one for a per-event delivery. CURRENT and EXPIRED
+    events only."""
 
     def __init__(self, fn: Optional[Callable[[list[Event]], None]] = None):
         self._fn = fn
@@ -572,21 +582,42 @@ class StreamCallback:
         if self._fn:
             self._fn(events)
 
-    # junction receiver adapter
-    def receive_stream_event(self, event: StreamEvent) -> None:
-        self.receive([Event(event.timestamp, event.data,
-                            event.type == EventType.EXPIRED)])
-
 
 class _StreamCallbackReceiver:
-    """Adapts a StreamCallback to the junction receiver interface."""
+    """Adapts a StreamCallback to the junction receiver interface: one
+    ``receive`` per delivery, whatever its shape (an event, a chunk of
+    events, a columnar chunk)."""
 
-    def __init__(self, callback: StreamCallback):
+    def __init__(self, callback: StreamCallback,
+                 names: Optional[list] = None):
         self.callback = callback
+        self.names = names      # the stream's attribute names, in order
 
     def receive(self, event: StreamEvent) -> None:
         if event.type in (EventType.CURRENT, EventType.EXPIRED):
-            self.callback.receive_stream_event(event)
+            self.callback.receive([Event(event.timestamp, event.data,
+                                         event.type is EventType.EXPIRED)])
+
+    def receive_chunk(self, events: list[StreamEvent]) -> None:
+        """One ``receive`` for a delivered chunk, its ``Event`` list built
+        once."""
+        cur, exp = EventType.CURRENT, EventType.EXPIRED
+        out = [Event(e.timestamp, e.data, e.type is exp) for e in events
+               if e.type is cur or e.type is exp]
+        if out:
+            self.callback.receive(out)
+
+    def receive_columns(self, cols: dict, ts, n: int) -> None:
+        """One ``receive`` for a columnar chunk (``deliver_columns``): the
+        ``Event`` list is built straight from the columns, whole columns
+        through ``tolist()``, with no ``StreamEvent`` in between."""
+        from .columns import columns_to_rows
+        rows = columns_to_rows(cols, self.names or list(cols), n)
+        if rows:
+            own = Event._own
+            self.callback.receive(
+                [own(t, row)
+                 for t, row in zip(np.asarray(ts).tolist(), rows)])
 
 
 class RowsCallback:

@@ -330,6 +330,15 @@ class ObservabilitySubsystem:
                 sm.gauge_tracker(
                     f"device.{q}.flush_{cause}_total",
                     lambda p=probe, c=cause: p.flush_causes.get(c, 0))
+            # egress by shape (core/egress.py): rows over deliveries is the
+            # rows a delivery carries — a batch's, not one
+            for shape, count in bridge.egress.items():
+                sm.gauge_tracker(
+                    f"device.{q}.egress_{shape}_deliveries_total",
+                    lambda c=count: c[0])
+                sm.gauge_tracker(
+                    f"device.{q}.egress_{shape}_rows_total",
+                    lambda c=count: c[1])
 
         # columnar host bridges: their step latency doubles as the
         # host_exec phase (same histogram object registered under the
@@ -394,6 +403,10 @@ class ObservabilitySubsystem:
         for q, probe in by_probe.items():
             if probe.phases is not None:
                 out["queries"][q] = probe.phases.report()
+        for bridge in self.runtime.device_bridges:
+            rep = out["queries"].get(bridge.query_name)
+            if rep is not None:
+                rep["egress"] = bridge.egress_report()
         for q, phases in phase_queries.items():
             if q in out["queries"]:
                 continue

@@ -32,13 +32,16 @@ trackers, and the bench ``latency_breakdown`` line):
   outputs are ready on the device, taken as the fetch of the first output
   the decode reads (the wait plus that one copy);
 - ``egress_decode`` — the rest of ``rt.collect``: the other copies to the
-  host and the row loop of ``decode_outputs`` / ``decode_block_outputs``;
+  host and the columns masked into one ``ColumnsOut`` chunk
+  (``decode_outputs`` / ``decode_block_outputs``) with its string codes
+  resolved; no row is built here;
 - ``host_exec``     — host-tier execution (interpreter, columnar,
   fleet lanes, shadow replays);
 - ``lock_wait``     — the driver thread asking for the engine lock (which
   a client holds inside every ``send``) until it is held;
 - ``sink_publish``  — delivery/publish downstream of the step, lock held:
-  rows to events, junction, callbacks;
+  the chunk to the junction (``core/egress.py``: columns as they are, or
+  rows and events built in bulk), callbacks;
 - ``dcn_transit``   — the cross-host hop (send wall-clock → apply);
 - ``procmesh_transit`` — the parent→child control-socket hop in a
   process-per-host fabric (dispatch wall-clock → child apply, including

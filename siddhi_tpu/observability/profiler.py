@@ -25,13 +25,14 @@ span                             thread    opens / closes
                                            ready on the device and the first
                                            of them is on the host
 ``siddhi:collect.decode:<q>``    driver    the rest of ``collect``: the
-                                           other copies out, the row loop
+                                           other copies out, the mask,
+                                           string codes resolved
 ``siddhi:deliver:<q>``           driver    from asking for the engine lock
                                            to ``rt.deliver`` returning
 ``siddhi:deliver.lock:<q>``      driver    asking for the engine lock until
                                            it is held
-``siddhi:deliver.publish:<q>``   driver    ``rt.deliver(rows)``: rows to
-                                           events, junction, callbacks
+``siddhi:deliver.publish:<q>``   driver    ``rt.deliver(chunk)``: the chunk
+                                           to the junction, callbacks
 ===============================  ========  ================================
 
 (On the synchronous path the driver's spans open on the client thread, and

@@ -410,28 +410,11 @@ class HostRowStager:
 # ---------------------------------------------------------------------------
 
 def decode_columns(out_specs, cols: dict, dictionaries: dict) -> list[list]:
-    """{name: np[n]} → host rows, with dictionary-encoded strings decoded.
-
-    ``tolist()`` converts whole columns at once (C-side), replacing the
-    per-row/per-value ``_decode_scalar`` loop on this path.
-    """
-    py_cols = []
-    for (name, _fn, t) in out_specs:
-        v = cols[name]
-        if t == DataType.STRING:
-            table = None
-            for dic in dictionaries.values():
-                table = dic
-                break
-            if table is not None:
-                vals = np.asarray(table._values, dtype=object)
-                codes = np.clip(np.asarray(v, np.int64), 0, len(vals) - 1)
-                py_cols.append(vals[codes].tolist())
-            else:                                      # pragma: no cover
-                py_cols.append(np.asarray(v).tolist())
-        else:
-            py_cols.append(np.asarray(v).tolist())
-    return [list(r) for r in zip(*py_cols)]
+    """{name: np[n]} → host rows, with dictionary-encoded strings decoded:
+    the package's one decode of codes to rows, ``ColumnsOut.rows``."""
+    from ..core.columns import ColumnsOut
+    n = len(cols[out_specs[0][0]]) if out_specs else 0
+    return ColumnsOut(None, cols, n, out_specs, dictionaries).rows()
 
 
 # ---------------------------------------------------------------------------
@@ -992,7 +975,7 @@ class HostStreamQuery:
         self.ilanes = [(i, c.specs[i].fn) for i in c.iagg_idx]
         self.mlanes = [(i, c.specs[i].fn, c.specs[i].kind == "min",
                         NP_HOST[c.specs[i].dtype]) for i in c.magg_idx]
-        self.out_specs = [(s.name, s.fn, s.dtype) for s in c.specs]
+        self.out_specs = c.out_specs
 
     # -- state -----------------------------------------------------------
     def init_state(self) -> dict:
@@ -1270,7 +1253,6 @@ class HostStreamQuery:
 
     def decode(self, res: dict) -> tuple[list[int], list[list]]:
         cols = res["out"]
-        rows = decode_columns(
-            [(s.name, s.fn, s.dtype) for s in self.c.specs], cols,
-            self.c.schema.dictionaries)
+        rows = decode_columns(self.out_specs, cols,
+                              self.c.schema.dictionaries)
         return np.asarray(res["ts"]).tolist(), rows

@@ -191,6 +191,8 @@ class CompiledJoinQuery:
             fn, t = compile_expression(oa.expr, resolver)
             self.out_specs.append(
                 (oa.name, fn, t, frozenset(resolver.sides_touched)))
+        # the select list as ColumnsOut reads it
+        self._columns_specs = [(n, fn, t) for (n, fn, t, _) in self.out_specs]
 
         self._step = jax.jit(self.make_step(), donate_argnums=(0,))
 
@@ -441,34 +443,19 @@ class CompiledJoinQuery:
         return self._step(state, batch["cols"], batch["tag"], batch["ts"],
                           batch["ts_base"], np.int32(batch["count"]))
 
-    def decode_outputs(self, out) -> list[list]:
-        valid = np.asarray(out["valid"])
+    def decode_outputs(self, out):
+        """One step's outputs → a :class:`~siddhi_tpu.core.columns.ColumnsOut`
+        (string codes stay codes; an outer join's NULL cells ride as
+        masks)."""
+        from ..core.columns import ColumnsOut
+        idx = np.flatnonzero(np.asarray(out["valid"]))
         cols = {}
         nulls = {}
         for (name, _, t, _) in self.out_specs:
-            cols[name] = np.asarray(out["out"][name])
-            nulls[name] = np.asarray(out["null"][name])
-        rows = []
-        shared = next(iter(self.merged.dictionaries.values()), None)
-        for i in np.nonzero(valid)[0]:
-            row = []
-            for (name, _, t, _) in self.out_specs:
-                if nulls[name][i]:
-                    row.append(None)
-                    continue
-                v = cols[name][i]
-                if t == DataType.STRING and shared is not None:
-                    row.append(shared.decode(int(v)))
-                elif isinstance(v, np.floating):
-                    row.append(float(v))
-                elif isinstance(v, np.bool_):
-                    row.append(bool(v))
-                elif isinstance(v, np.integer):
-                    row.append(int(v))
-                else:
-                    row.append(v)
-            rows.append(row)
-        return rows
+            cols[name] = np.asarray(out["out"][name])[idx]
+            nulls[name] = np.asarray(out["null"][name])[idx]
+        return ColumnsOut(None, cols, int(idx.size), self._columns_specs,
+                          self.merged.dictionaries, nulls)
 
 
 def _compact_side(vals, mask, B, fill=0):
@@ -512,7 +499,7 @@ class DeviceJoinRuntime:
         batch = self.builder.emit()
         self.state, out = self.compiler.step(self.state, batch)
         if decode:
-            rows = self.compiler.decode_outputs(out)
+            rows = self.compiler.decode_outputs(out).rows()
             if self.callback is not None and rows:
                 self.callback(rows)
             return rows

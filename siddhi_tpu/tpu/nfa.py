@@ -1779,50 +1779,24 @@ class DeviceNFACompiler:
         return self._step(state, batch["cols"], batch["tag"], batch["ts"],
                           batch["ts_base"], np.int32(batch["count"]))
 
-    def decode_outputs(self, ys) -> list[list]:
+    def decode_outputs(self, ys):
+        """One step's outputs → a :class:`~siddhi_tpu.core.columns.ColumnsOut`
+        (string codes stay codes; NULL cells ride as masks)."""
         if self.blocked:
             from .nfa_block import decode_block_outputs
             return decode_block_outputs(self, ys)
+        from ..core.columns import ColumnsOut
         mask = np.asarray(ys["mask"])              # [B, 2, C]
-        rows = []
-        cols = {name: np.asarray(ys[name]) for (name, _, t) in self.out_specs}
-        # decode dictionary-encoded outputs
-        dec = {}
-        for (name, fn, t) in self.out_specs:
-            dec[name] = t
-        nulls = {name: np.asarray(ys[f"null__{name}"])
+        # a boolean index walks [b, src, c] in row-major order: match
+        # event, then source, then candidate
+        cols = {name: np.asarray(ys[name])[mask]
+                for (name, _, t) in self.out_specs}
+        nulls = {name: np.asarray(ys[f"null__{name}"])[mask]
                  for (name, _, t) in self.out_specs
                  if f"null__{name}" in ys}
-        idx = np.argwhere(mask)
-        for b, srci, c in idx:
-            row = []
-            for (name, _, t) in self.out_specs:
-                nm = nulls.get(name)
-                if nm is not None and nm[b, srci, c]:
-                    row.append(None)
-                    continue
-                v = cols[name][b, srci, c]
-                row.append(_decode_scalar(self, name, v, t))
-            rows.append(row)
-        return rows
-
-
-def _decode_scalar(nfa: DeviceNFACompiler, name: str, v, t: DataType):
-    if t == DataType.STRING:
-        # find any dictionary able to decode; outputs referencing string
-        # columns share the merged dictionaries
-        for dic in nfa.merged.dictionaries.values():
-            s = dic.decode(int(v))
-            if s is not None:
-                return s
-        return int(v)
-    if isinstance(v, np.floating):
-        return float(v)
-    if isinstance(v, np.integer):
-        return int(v)
-    if isinstance(v, np.bool_):
-        return bool(v)
-    return v
+        return ColumnsOut(None, cols, int(np.count_nonzero(mask)),
+                          self.out_specs, self.merged.dictionaries,
+                          nulls or None)
 
 
 class DeviceNFARuntime(AdaptiveFlushMixin):
@@ -1843,11 +1817,13 @@ class DeviceNFARuntime(AdaptiveFlushMixin):
         # runtime start time (host: seed placed at start() with the playback
         # clock's current value)
         self.state = self.compiler.init_state(start_time)
-        self.callback: Optional[Callable[[list[list]], None]] = None
+        self.callback: Optional[Callable] = None     # fn(chunk, emit_ts)
         self.driver = None          # AsyncDeviceDriver when @async device mode
 
     def add_callback(self, fn) -> None:
-        self.callback = fn
+        """``fn(rows)`` per batch, for the runtime used by itself.
+        ``callback`` is what ``deliver`` calls: ``fn(chunk, emit_ts)``."""
+        self.callback = lambda out, emit_ts=None: fn(out.rows())
 
     def send(self, stream_id: str, row: list, timestamp: int) -> None:
         self.builder.append(stream_id, row, timestamp)
@@ -1866,24 +1842,23 @@ class DeviceNFARuntime(AdaptiveFlushMixin):
         self.state, ys = self.compiler.step(self.state, batch)
         return ys
 
-    def collect(self, ys) -> list[list]:
-        """Egress edge: fence + decode one dispatched step's outputs."""
+    def collect(self, ys):
+        """Egress edge: fence + decode one dispatched step's outputs into
+        one ``ColumnsOut`` chunk, its string codes already resolved."""
         self._fence(ys["mask"])
         with span(f"siddhi:collect.decode:{self.query_name}"):
-            return self.compiler.decode_outputs(ys)
+            out = self.compiler.decode_outputs(ys)
+            out.decoded()
+            return out
 
-    def process(self, batch: dict) -> list[list]:
+    def process(self, batch: dict):
         """Synchronous step + decode (one dispatch immediately collected)."""
         return self.collect(self.dispatch(batch))
 
-    def deliver(self, rows: list[list], emit_ts=None) -> None:
+    def deliver(self, out, emit_ts=None) -> None:
         fn = self.callback
-        if fn is not None and rows:
-            if getattr(getattr(fn, "__self__", None),
-                       "_on_rows_accepts_ts", False):
-                fn(rows, emit_ts)
-            else:           # plain user callback: rows only
-                fn(rows)
+        if fn is not None and out:
+            fn(out, emit_ts)
 
     def flush(self, decode: bool = True):
         if len(self.builder) == 0:
@@ -1893,9 +1868,9 @@ class DeviceNFARuntime(AdaptiveFlushMixin):
             self.driver.submit(batch)
             return None
         if decode:
-            rows = self._timed_process(batch)
-            self.deliver(rows)
-            return rows
+            out = self._timed_process(batch)
+            self.deliver(out)
+            return out
         self.state, ys = self.compiler.step(self.state, batch)
         return ys
 

@@ -366,21 +366,15 @@ def make_block_step(nfa: "DeviceNFACompiler"):
     return step
 
 
-def decode_block_outputs(nfa: "DeviceNFACompiler", ys) -> list[list]:
-    """ys → host rows, ordered by match event (j), then candidate rank."""
-    mask = np.asarray(ys["mask"])
-    if not mask.any():
-        return []
-    idx = np.nonzero(mask)[0]
+def decode_block_outputs(nfa: "DeviceNFACompiler", ys):
+    """ys → a ``ColumnsOut``, ordered by match event (j), then candidate
+    rank."""
+    from ..core.columns import ColumnsOut
+    idx = np.flatnonzero(np.asarray(ys["mask"]))
+    if not idx.size:
+        return ColumnsOut.empty(nfa.out_specs, nfa.merged.dictionaries)
     j = np.asarray(ys["j"])[idx]
-    order = np.argsort(j, kind="stable")
-    idx = idx[order]
-    cols = {name: np.asarray(ys[name]) for (name, _, t) in nfa.out_specs}
-    from .nfa import _decode_scalar
-    rows = []
-    for p in idx:
-        row = []
-        for (name, _, t) in nfa.out_specs:
-            row.append(_decode_scalar(nfa, name, cols[name][p], t))
-        rows.append(row)
-    return rows
+    idx = idx[np.argsort(j, kind="stable")]
+    cols = {name: np.asarray(ys[name])[idx] for (name, _, t) in nfa.out_specs}
+    return ColumnsOut(None, cols, int(idx.size), nfa.out_specs,
+                      nfa.merged.dictionaries)

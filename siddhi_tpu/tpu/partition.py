@@ -452,7 +452,7 @@ class PartitionedNFARuntime:
         rows = []
         for lane in range(self.P):
             lane_ys = jax.tree_util.tree_map(lambda x: x[lane], ys)
-            rows.extend(self.compiler.decode_outputs(lane_ys))
+            rows.extend(self.compiler.decode_outputs(lane_ys).rows())
         if self.callback is not None and rows:
             self.callback(rows)
         return rows

@@ -441,6 +441,8 @@ class CompiledStreamQuery:
                     else None
                 self.specs.append(_Spec(oa.name, "value", fn, t, src))
 
+        # the select list as ColumnsOut / decode_columns read it
+        self.out_specs = [(s.name, s.fn, s.dtype) for s in self.specs]
         self.value_idx = [i for i, s in enumerate(self.specs) if s.kind == "value"]
         # aggregate lanes: counts ride the ones/cnts axis; sums/avgs split
         # into an exact-int stack and a float stack; min/max keep individual
@@ -1117,19 +1119,17 @@ class CompiledStreamQuery:
         """batch: output of BatchBuilder.emit() (numpy); returns (state, out)."""
         return self._step(state, batch["cols"], batch["ts"], batch["valid"])
 
-    def decode_outputs(self, out) -> list[list]:
-        valid = np.asarray(out["valid"])
-        host_cols = {}
-        for s in self.specs:
-            col = np.asarray(out["out"][s.name])
-            if s.dtype == DataType.STRING and s.source_attr:
-                dic = self.schema.dictionaries[s.source_attr]
-                col = np.array([dic.decode(int(c)) for c in col], dtype=object)
-            host_cols[s.name] = col
-        rows = []
-        for i in np.nonzero(valid)[0]:
-            rows.append([_pyval(host_cols[s.name][i], s.dtype) for s in self.specs])
-        return rows
+    def decode_outputs(self, out):
+        """One step's outputs → a :class:`~siddhi_tpu.core.columns.ColumnsOut`:
+        the copies to the host, then every column masked by ``valid`` in one
+        NumPy index each (string codes stay codes until ``decoded()`` /
+        ``rows()``)."""
+        from ..core.columns import ColumnsOut
+        idx = np.flatnonzero(np.asarray(out["valid"]))
+        cols = {s.name: np.asarray(out["out"][s.name])[idx]
+                for s in self.specs}
+        return ColumnsOut(None, cols, int(idx.size), self.out_specs,
+                          self.schema.dictionaries)
 
 
 # ---------------------------------------------------------------------------
@@ -1837,13 +1837,3 @@ def _materialize(specs, value_idx, fagg_idx, iagg_idx, magg_idx, sagg_idx,
                 else sums_f[fpos[i]]
             outputs[s.name] = num / jnp.maximum(cnts, 1).astype(FACC)
     return outputs
-
-
-def _pyval(v, dtype: DataType):
-    if isinstance(v, np.floating):
-        return float(v)
-    if isinstance(v, np.integer):
-        return int(v)
-    if isinstance(v, np.bool_):
-        return bool(v)
-    return v

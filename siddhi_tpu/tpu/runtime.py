@@ -96,11 +96,12 @@ class DeviceStreamRuntime:
         ``decode_outputs`` blocks until the step completed). Hopping windows
         drain deferred boundary flushes here — pipeline-safe only at
         window=1 (see ``pipeline_safe``)."""
-        rows = self.compiled.decode_outputs(out)
+        rows = self.compiled.decode_outputs(out).rows()
         if self.compiled.window_kind == "hopping":
             self.state = drain_hop_boundaries(
                 self.compiled, self.state, self._drain_builder,
-                lambda o: rows.extend(self.compiled.decode_outputs(o)))
+                lambda o: rows.extend(
+                    self.compiled.decode_outputs(o).rows()))
         return rows
 
     def process(self, batch: dict) -> list[list]:
@@ -108,7 +109,7 @@ class DeviceStreamRuntime:
 
     def _deliver(self, out, decode: bool) -> None:
         if decode:
-            rows = self.compiled.decode_outputs(out)
+            rows = self.compiled.decode_outputs(out).rows()
             if self.callback is not None and rows:
                 self.callback(rows)
         else:
