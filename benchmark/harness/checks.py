@@ -52,7 +52,8 @@ def compare_rows(ref: dict, got: dict, n_got: int) -> dict:
     Returns ``rows_missing``, ``rows_extra``, ``rows_wrong`` and ``map``:
     for each delivered row the index of the reference row it is, or -1.
     Ordered output is compared place by place; unordered output (a pattern
-    may emit the matches of one event in any order) as multisets of rows.
+    may emit the matches of one event in any order) as multisets of rows,
+    where what is left over on both sides counts as rows wrong.
     """
     names = list(ref["columns"])
     n_ref = len(ref["last_event"])
@@ -89,9 +90,12 @@ def compare_rows(ref: dict, got: dict, n_got: int) -> dict:
         else:
             extra += 1
             j += 1
-    return {"rows_missing": missing + (n_ref - i),
-            "rows_extra": extra + (n_got - j), "rows_wrong": 0,
-            "map": mapping}
+    # a reference row nothing equals and a delivered row that equals nothing
+    # pair off as one row wrong (an answer altered is one answer, not two)
+    missing, extra = missing + (n_ref - i), extra + (n_got - j)
+    wrong = min(missing, extra)
+    return {"rows_missing": missing - wrong, "rows_extra": extra - wrong,
+            "rows_wrong": wrong, "map": mapping}
 
 
 def served_numbers(rt, sent: int, platform: str) -> dict:
@@ -129,7 +133,15 @@ def served_numbers(rt, sent: int, platform: str) -> dict:
         out["events_unaccounted"] = sent
     else:
         out["events_unaccounted"] = abs(int(probe.events) - sent)
-    for key in OVERFLOW_COUNTERS:
-        if key in state:
-            out["overflow_counters"] += int(state[key])
+    out["overflow_counters"] = overflow_count(state)
     return out
+
+
+def overflow_count(state) -> int:
+    """Sum of the kernels' overflow counters in a runtime's state, whatever
+    their shape: a scalar in a single query's state, one count per key lane
+    (``[P]``) in lane-stacked state."""
+    import jax
+
+    return sum(int(np.sum(jax.device_get(state[key])))
+               for key in OVERFLOW_COUNTERS if key in state)

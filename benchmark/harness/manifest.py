@@ -6,6 +6,16 @@ its traffic mix is ``traffic/<traffic>.json`` and what is particular to the
 cell (a paced cell's fixed rate) is ``cells/<cell>.json``, merged over the
 mix. A per-layer metric is read by ``metrics/<name>.py``. Adding a cell, a
 mix, a configuration or a metric adds files and one entry and edits nothing.
+
+A configuration whose deployment is too large for a CPU test carries a
+``small`` block: the sizes the yardstick's own tests and ``--rehearsal``
+run it at, and nothing a timed run ever sees (``Cell(..., small=True)``)::
+
+    "small": {"config": {<keys of the configuration, replaced>},
+              "app": "<name>.small.siddhi",   (optional: the same query at
+                                               smaller engine sizes)
+              "interpreter_events": 9000, "interpreter_rows_min": 50,
+              "control_events": 60000}        (each optional)
 """
 
 from __future__ import annotations
@@ -27,8 +37,8 @@ def _json(path: str) -> dict:
         return json.load(f)
 
 
-def load_manifest() -> dict:
-    return _json(os.path.join(ROOT, "BENCHMARK.json"))
+def load_manifest(path: str | None = None) -> dict:
+    return _json(path or os.path.join(ROOT, "BENCHMARK.json"))
 
 
 def load_module(path: str, name: str):
@@ -43,14 +53,20 @@ def load_module(path: str, name: str):
 class Cell:
     """One entry of ``workloads`` with every file it names loaded."""
 
-    def __init__(self, manifest: dict, name: str):
+    # what the yardstick's tests draw where the configuration says nothing
+    TEST_SIZES = {"interpreter_events": 9000, "interpreter_rows_min": 50,
+                  "control_events": 60_000}
+
+    def __init__(self, manifest: dict, name: str, small: bool = False):
         entry = next((w for w in manifest["workloads"] if w["name"] == name),
                      None)
         if entry is None:
             known = [w["name"] for w in manifest["workloads"]]
             raise KeyError(f"no workload '{name}' in BENCHMARK.json "
                            f"(it has {known})")
+        self.manifest = manifest
         self.name = name
+        self.small = small
         self.chips = int(entry["chips"])
         cfg_entry = next(c for c in manifest["configs"]
                          if c["name"] == entry["config"])
@@ -58,7 +74,18 @@ class Cell:
         cfg_path = os.path.join(ROOT, cfg_entry["file"])
         self.config = _json(cfg_path)
         stem = cfg_path[:-len(".json")]
-        with open(stem + ".siddhi", encoding="utf-8") as f:
+        app_path = stem + ".siddhi"
+        # a timed run drops the block unread; only `small` sizes come from it
+        block = self.config.pop("small", None) or {}
+        self.test_sizes = dict(self.TEST_SIZES)
+        if small:
+            self.config.update(block.get("config", {}))
+            self.test_sizes.update({k: int(block[k]) for k in self.TEST_SIZES
+                                    if k in block})
+            if "app" in block:
+                app_path = os.path.join(os.path.dirname(cfg_path),
+                                        block["app"])
+        with open(app_path, encoding="utf-8") as f:
             self.app_text = f.read()
         self.reference = load_module(stem + ".py",
                                      "bench_reference_" + re.sub(
@@ -75,6 +102,11 @@ class Cell:
         self.per_layer = [m for m in manifest["per_layer"]
                           if (name in m["workloads"] if "workloads" in m
                               else m["moves"] in e2e_names)]
+
+    def shrunk(self) -> "Cell":
+        """This cell at its configuration's ``small`` sizes."""
+        return self if self.small else Cell(self.manifest, self.name,
+                                            small=True)
 
 
 def metric_reader(name: str):

@@ -23,6 +23,7 @@ import os
 import shutil
 import sys
 import time
+from typing import NamedTuple
 
 import numpy as np
 
@@ -213,19 +214,43 @@ class Run:
         return (1.0 - t["busy_s"] / t["window_s"]) * 100.0
 
 
-def _annotate_program(bridge, annotation) -> None:
-    """Traced runs only: put the driver thread's three calls into the
-    program on the profiler's clock, from here (spans inside the program are
-    a later change)."""
-    rt, q = bridge.runtime, bridge.query_name
-    for attr in ("dispatch", "collect", "deliver"):
-        inner = getattr(rt, attr)
+class Sizes(NamedTuple):
+    batch: int          # events of a full device batch
+    closed: bool        # closed loop (else open, at `rate`)
+    rate: float         # events/s of an open loop, 0 in a closed one
+    warm_s: float
+    warm_steps: int
+    pool_n: int         # events drawn from the seed
+    columnar: bool      # send_columns (else per-event send)
+    block: int          # events between two clock reads of the sender
+    max_events: int     # what the sender's and the rows' arrays must hold
+    rows_cap: int
 
-        def wrapped(*a, _inner=inner, _label=f"siddhi:{attr}:{q}", **kw):
-            with annotation(_label):
-                return _inner(*a, **kw)
 
-        setattr(rt, attr, wrapped)
+def plan(cell: Cell, seconds: float, rehearsal: bool) -> Sizes:
+    """The sizes of one run, from the cell's own files. A rehearsal shrinks
+    the pool, the warm stretch and the rate (the configuration's ``small``
+    block has shrunk the engine's sizes before, ``Cell.shrunk``)."""
+    cfg, mix = cell.config, cell.traffic
+    batch = int(cfg["batch"])
+    closed = mix["loop"] == "closed"
+    rate = 0.0 if closed else float(mix["rate_eps"])
+    warm_s, warm_steps = float(mix["warm_seconds"]), int(mix["warm_steps"])
+    pool_n = int(cfg["pool_events"])
+    if rehearsal:
+        warm_s, warm_steps, pool_n = 0.3, 1, min(pool_n, 1 << 16)
+        rate = 0.0 if closed else min(rate, 1500.0)
+    columnar = cfg["ingress"] == "columns"
+    block = int(mix["chunk_rows"] if columnar else mix["block_events"])
+    if columnar and pool_n % block:
+        raise ValueError(f"pool of {pool_n} is no multiple of the chunk "
+                         f"({block})")
+    total_s = warm_s + seconds + 5.0
+    max_events = int((float(mix["max_rate_eps"]) if closed else rate)
+                     * total_s) + 4 * batch + warm_steps * batch
+    rows_cap = int(max_events * float(cfg["rows_per_event_max"])) + 1024
+    return Sizes(batch, closed, rate, warm_s, warm_steps, pool_n, columnar,
+                 block, max_events, rows_cap)
 
 
 def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
@@ -235,6 +260,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     printed (no accelerator, fewer chips than the cell asks for)."""
     import jax
 
+    if rehearsal:
+        cell = cell.shrunk()
     devices = jax.devices()
     platform, kind = devices[0].platform, devices[0].device_kind
     if not rehearsal and (platform != "tpu" or len(devices) < cell.chips):
@@ -258,23 +285,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     t_import = _pc()
 
     cfg, mix = cell.config, cell.traffic
-    batch = int(cfg["batch"])
-    closed = mix["loop"] == "closed"
-    rate = 0.0 if closed else float(mix["rate_eps"])
-    warm_s, warm_steps = float(mix["warm_seconds"]), int(mix["warm_steps"])
-    pool_n = int(cfg["pool_events"])
-    if rehearsal:
-        warm_s, warm_steps, pool_n = 0.3, 1, min(pool_n, 1 << 16)
-        rate = 0.0 if closed else min(rate, 1500.0)
-    columnar = cfg["ingress"] == "columns"
-    block = int(mix["chunk_rows"] if columnar else mix["block_events"])
-    if columnar and pool_n % block:
-        raise ValueError(f"pool of {pool_n} is no multiple of the chunk "
-                         f"({block})")
-    total_s = warm_s + seconds + 5.0
-    max_events = int((float(mix["max_rate_eps"]) if closed else rate)
-                     * total_s) + 4 * batch + warm_steps * batch
-    rows_cap = int(max_events * float(cfg["rows_per_event_max"])) + 1024
+    (batch, closed, rate, warm_s, warm_steps, pool_n, columnar, block,
+     max_events, rows_cap) = plan(cell, seconds, rehearsal)
 
     run = Run(cell, kind)
     run.rate = rate
@@ -317,8 +329,6 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         if trace:
             from jax.profiler import TraceAnnotation
             annotation = TraceAnnotation
-            if bridge is not None:
-                _annotate_program(bridge, TraceAnnotation)
             shutil.rmtree(trace_dir, ignore_errors=True)
 
         gc.collect()
