@@ -319,6 +319,20 @@ class SiddhiAppRuntime:
                     # position among the app's partitions: the lane-pool
                     # child re-parses and indexes to the same block
                     host_cfg["part_index"] = part_count - 1
+                # an @device query in a one-pattern value partition lowers
+                # to the chip: keys hash to lane-stacked match tables under
+                # one served bridge; otherwise the tiers below, as before
+                from .device_bridge import try_build_device_partition
+                bridge = try_build_device_partition(
+                    element, ctx, self._stream_defs(), self._get_junction,
+                    name)
+                if bridge is not None:
+                    self.device_bridges.append(bridge)
+                    for sid in bridge.stream_ids:
+                        self._get_junction(sid).subscribe(
+                            bridge.receiver_for(sid))
+                    self._fill_implicit(element.queries[0], bridge)
+                    continue
                 if fleet_mgr is not None:
                     fbridges = fleet_mgr.enroll_partition(
                         element, ctx, self._stream_defs(),

@@ -309,6 +309,7 @@ class AsyncDeviceDriver:
                     "ring_s": ring_s,
                     "queue_s": max(0.0, queue_s - ring_s),
                     "step_s": disp_s,
+                    "route_s": batch.get("_route_s", 0.0),
                     "fence_s": fence_s,
                     "decode_s": collect_s - fence_s,
                     "lock_s": lock_s,
@@ -480,8 +481,9 @@ class DeviceQueryBridge(ChunkEgress):
     def __init__(self, kind: str, runtime, app_context, stream_ids: list[str],
                  output_junction, query_name: str, async_mode: bool = False,
                  output_rate=None, pipeline_window: int = 2):
-        self.kind = kind                  # 'stream' | 'nfa' | 'join'
-        self.runtime = runtime            # DeviceStreamRuntime | DeviceNFARuntime
+        self.kind = kind        # 'stream' | 'nfa' | 'join' | 'partition'
+        self.runtime = runtime  # a _DeviceRTBase, DeviceNFARuntime or (kind
+        # 'partition') a served PartitionedNFARuntime
         self.app_context = app_context
         self.stream_ids = stream_ids
         self.output_junction = output_junction
@@ -512,13 +514,15 @@ class DeviceQueryBridge(ChunkEgress):
             def receive(self, event: StreamEvent) -> None:
                 bridge.on_event(stream_id, event)
 
-        if self.kind == "stream" and hasattr(self.runtime, "send_columns"):
+        if self.kind in ("stream", "partition") \
+                and hasattr(self.runtime, "send_columns"):
             # single-stream device queries take columnar chunks straight
             # into the staging BatchBuilder (append_columns — bulk
             # slice-copy, no per-event appends): the last per-event hop on
             # the DCN-ingest → device path the mesh fabric forwards over.
-            # Merged (nfa/join) builders stay per-event by design — their
-            # probe/trace FIFO is stamped per interleaved stream event.
+            # A served partition routes one stream and takes them the same
+            # way. Merged (nfa/join) builders stay per-event by design —
+            # their probe/trace FIFO is stamped per interleaved stream event.
             class _ColsR(_R):
                 def receive_rows(self, rows: list, timestamps) -> None:
                     bridge.on_rows_chunk(stream_id, rows, timestamps)
@@ -560,8 +564,12 @@ class DeviceQueryBridge(ChunkEgress):
         materialization, one trace registration per chunk."""
         self._register_chunk_trace()
         send = self.runtime.send
-        for row, ts in zip(rows, timestamps):
-            send(row, timestamp=ts)
+        if self.kind == "stream":
+            for row, ts in zip(rows, timestamps):
+                send(row, timestamp=ts)
+        else:
+            for row, ts in zip(rows, timestamps):
+                send(stream_id, row, ts)
         if timestamps:
             self._out_ts = timestamps[-1]
 
@@ -621,6 +629,121 @@ def _input_single_streams(ist) -> list[SingleInputStream]:
     return out
 
 
+def _device_options(ann, query: Query, stream_defs: dict) -> dict:
+    """``@device(...)`` as the builders read it. ``async`` is the explicit
+    key or any input stream annotated ``@async`` (the reference's Disruptor
+    opt-in): packing then overlaps the step."""
+    async_mode = (ann.get("async") or "false").lower() == "true"
+    if not async_mode:
+        ist = query.input_stream
+        sids = [s.stream_id for s in _input_single_streams(ist)
+                if getattr(s, "stream_id", None) is not None]
+        async_mode = any(
+            find_annotation(stream_defs[sid].annotations, "async") is not None
+            for sid in sids if sid in stream_defs)
+    return {
+        "strict": (ann.get("strict") or "false").lower() == "true",
+        "batch": int(ann.get("batch") or 1024),
+        "slots": int(ann.get("slots") or 64),
+        "window": int(ann.get("window") or 4096),
+        # in-flight dispatch window of the async pipeline (2 = double
+        # buffering; 1 = serialize dispatch/egress, for A/B comparison)
+        "pipeline": int(ann.get("pipeline") or 2),
+        # key lanes of a served `partition with` block
+        "lanes": int(ann.get("lanes") or 64),
+        "async": async_mode,
+    }
+
+
+def _audit_device_surface(query: Query, app_context, get_junction):
+    """The full Query-surface audit: anything the device compilers do not
+    model raises ``DeviceCompileError`` (-> host fallback), never silently
+    drops semantics (reference surface: Query.java — selector
+    order-by/limit/offset QuerySelector.java:44, output_rate
+    OutputRateLimiter.java:43, fault/inner streams, events_for). Returns
+    the output junction."""
+    from ..tpu.expr_compile import DeviceCompileError
+
+    sel = query.selector
+    if sel is not None and (sel.order_by or sel.limit is not None
+                            or sel.offset is not None):
+        raise DeviceCompileError(
+            "order by / limit / offset take the host path (device "
+            "micro-batch chunking would change their per-chunk "
+            "semantics)")
+    if query.output_rate is not None:
+        from ..query_api import EventOutputRate
+        if not isinstance(query.output_rate, EventOutputRate):
+            # time/snapshot limiters key off per-event output timestamps,
+            # which device batching coarsens to the batch timestamp —
+            # host fallback preserves exact semantics
+            raise DeviceCompileError(
+                "time/snapshot output rate limiting takes the host path")
+        if isinstance(query.input_stream, JoinInputStream):
+            # host join selectors can feed EXPIRED events into the
+            # limiter; the device join emits CURRENT rows only
+            raise DeviceCompileError(
+                "output rate limiting on joins takes the host path")
+        from ..query_api import OutputRateType
+        if sel is not None and sel.group_by and \
+                query.output_rate.type in (OutputRateType.FIRST,
+                                           OutputRateType.LAST):
+            # grouped first/last emit PER KEY per batch (reference
+            # FirstGroupByPerEventOutputRateLimiter); device rows do
+            # not carry group keys through the limiter
+            raise DeviceCompileError(
+                "group-by with first/last output rate limiting takes "
+                "the host path")
+    if not isinstance(query.output_stream, InsertIntoStream):
+        raise DeviceCompileError(
+            "device path handles insert-into-stream outputs only")
+    if query.output_stream.events_for != OutputEventsFor.CURRENT_EVENTS:
+        raise DeviceCompileError(
+            "insert into ... for expired/all events takes the host path "
+            "(device kernels emit CURRENT rows only)")
+    if query.output_stream.is_fault_stream:
+        raise DeviceCompileError("fault-stream outputs take the host path")
+    for s in _input_single_streams(query.input_stream):
+        if s.is_fault_stream or s.is_inner_stream:
+            raise DeviceCompileError(
+                "fault / partition-inner input streams take the host "
+                "path")
+    tid = query.output_stream.target_id
+    if tid in app_context.tables or tid in app_context.named_windows:
+        raise DeviceCompileError(
+            f"device path cannot target table/window '{tid}'")
+    return get_junction(tid, query.output_stream.is_inner_stream)
+
+
+def _finish_bridge(bridge: "DeviceQueryBridge", element, name: str,
+                   app_context, stream_defs: dict, get_junction,
+                   batch: int) -> "DeviceQueryBridge":
+    """What every device bridge gets once its runtime compiled: the
+    adaptive controller, the DeviceGuard, the snapshot registration.
+    ``element`` is what a guard replays through on the host: the query, or
+    the whole ``Partition`` of a served partition."""
+    rt = bridge.runtime
+    bridge.batch_capacity = batch       # pad-ratio denominator (observability)
+    if app_context.adaptive_cfg is not None:
+        # @app:adaptive: flush thresholds track observed rate/latency; the
+        # query's own batch capacity caps the adjustable range
+        from ..flow.adaptive_batch import AdaptiveBatchController
+        cfg = dict(app_context.adaptive_cfg)
+        cfg["max_batch"] = min(cfg.get("max_batch", batch), batch)
+        cfg["min_batch"] = min(cfg.get("min_batch", 64), cfg["max_batch"])
+        rt.batch_controller = AdaptiveBatchController(**cfg)
+    # device quarantine: a RUNTIME step failure (compile-time failures fell
+    # back before) reroutes the batch through the host interpreter, and
+    # repeated failures circuit-break the device path itself
+    resilience = getattr(app_context.runtime, "resilience", None)
+    if resilience is not None:
+        bridge.guard = resilience.guard_device(
+            rt, element, name, dict(stream_defs), get_junction, bridge.kind)
+        resilience.bind_bridge(bridge.guard, bridge)
+    app_context.register_state(f"device-{name}", _BridgeState(bridge))
+    return bridge
+
+
 def try_build_device_query(query: Query, app_context, stream_defs: dict,
                            get_junction, name: str) -> Optional[DeviceQueryBridge]:
     """Returns a bridge when the query opts in via @device AND compiles on the
@@ -628,97 +751,15 @@ def try_build_device_query(query: Query, app_context, stream_defs: dict,
     ann = find_annotation(query.annotations, "device")
     if ann is None:
         return None
-    strict = (ann.get("strict") or "false").lower() == "true"
-    batch = int(ann.get("batch") or 1024)
-    slots = int(ann.get("slots") or 64)
-    window_cap = int(ann.get("window") or 4096)
-    # in-flight dispatch window of the async pipeline (2 = double
-    # buffering; 1 = serialize dispatch/egress, for A/B comparison)
-    pipeline_window = int(ann.get("pipeline") or 2)
-
-    def _input_stream_ids(ist) -> list[str]:
-        if isinstance(ist, SingleInputStream):
-            return [ist.stream_id]
-        if isinstance(ist, StateInputStream):
-            return ist.stream_ids()
-        if isinstance(ist, JoinInputStream):
-            out = []
-            for side in (ist.left, ist.right):
-                sid = getattr(side, "stream_id", None)
-                if sid is not None:
-                    out.append(sid)
-            return out
-        return []
-
-    # async packing/compute overlap: explicit @device(async='true'), or any
-    # input stream annotated @async (the reference's Disruptor opt-in)
-    async_mode = (ann.get("async") or "false").lower() == "true"
-    if not async_mode:
-        for sid in _input_stream_ids(query.input_stream):
-            d = stream_defs.get(sid)
-            if d is not None and \
-                    find_annotation(d.annotations, "async") is not None:
-                async_mode = True
-                break
+    opts = _device_options(ann, query, stream_defs)
+    strict, batch, slots = opts["strict"], opts["batch"], opts["slots"]
+    window_cap, pipeline_window = opts["window"], opts["pipeline"]
+    async_mode = opts["async"]
 
     from ..tpu.expr_compile import DeviceCompileError
 
-    target = None
     try:
-        # ---- full Query-surface audit: anything the device compilers do not
-        # model must raise DeviceCompileError (→ host fallback) here, never
-        # silently drop semantics (reference surface: Query.java — selector
-        # order-by/limit/offset QuerySelector.java:44, output_rate
-        # OutputRateLimiter.java:43, fault/inner streams, events_for).
-        sel = query.selector
-        if sel is not None and (sel.order_by or sel.limit is not None
-                                or sel.offset is not None):
-            raise DeviceCompileError(
-                "order by / limit / offset take the host path (device "
-                "micro-batch chunking would change their per-chunk "
-                "semantics)")
-        if query.output_rate is not None:
-            from ..query_api import EventOutputRate
-            if not isinstance(query.output_rate, EventOutputRate):
-                # time/snapshot limiters key off per-event output timestamps,
-                # which device batching coarsens to the batch timestamp —
-                # host fallback preserves exact semantics
-                raise DeviceCompileError(
-                    "time/snapshot output rate limiting takes the host path")
-            if isinstance(query.input_stream, JoinInputStream):
-                # host join selectors can feed EXPIRED events into the
-                # limiter; the device join emits CURRENT rows only
-                raise DeviceCompileError(
-                    "output rate limiting on joins takes the host path")
-            from ..query_api import OutputRateType
-            if sel is not None and sel.group_by and \
-                    query.output_rate.type in (OutputRateType.FIRST,
-                                               OutputRateType.LAST):
-                # grouped first/last emit PER KEY per batch (reference
-                # FirstGroupByPerEventOutputRateLimiter); device rows do
-                # not carry group keys through the limiter
-                raise DeviceCompileError(
-                    "group-by with first/last output rate limiting takes "
-                    "the host path")
-        if not isinstance(query.output_stream, InsertIntoStream):
-            raise DeviceCompileError(
-                "device path handles insert-into-stream outputs only")
-        if query.output_stream.events_for != OutputEventsFor.CURRENT_EVENTS:
-            raise DeviceCompileError(
-                "insert into ... for expired/all events takes the host path "
-                "(device kernels emit CURRENT rows only)")
-        if query.output_stream.is_fault_stream:
-            raise DeviceCompileError("fault-stream outputs take the host path")
-        for s in _input_single_streams(query.input_stream):
-            if s.is_fault_stream or s.is_inner_stream:
-                raise DeviceCompileError(
-                    "fault / partition-inner input streams take the host "
-                    "path")
-        tid = query.output_stream.target_id
-        if tid in app_context.tables or tid in app_context.named_windows:
-            raise DeviceCompileError(
-                f"device path cannot target table/window '{tid}'")
-        target = get_junction(tid, query.output_stream.is_inner_stream)
+        target = _audit_device_surface(query, app_context, get_junction)
         ist = query.input_stream
         if isinstance(ist, SingleInputStream):
             from ..tpu.batch import BatchBuilder
@@ -974,25 +1015,73 @@ def try_build_device_query(query: Query, app_context, stream_defs: dict,
         log.info("query '%s' falls back to host path: %s", name, e)
         return None
 
-    bridge.batch_capacity = batch       # pad-ratio denominator (observability)
-    if app_context.adaptive_cfg is not None:
-        # @app:adaptive: flush thresholds track observed rate/latency; the
-        # query's own batch capacity caps the adjustable range
-        from ..flow.adaptive_batch import AdaptiveBatchController
-        cfg = dict(app_context.adaptive_cfg)
-        cfg["max_batch"] = min(cfg.get("max_batch", batch), batch)
-        cfg["min_batch"] = min(cfg.get("min_batch", 64), cfg["max_batch"])
-        rt.batch_controller = AdaptiveBatchController(**cfg)
-    # device quarantine: a RUNTIME step failure (compile-time failures fell
-    # back above) reroutes the batch through the host interpreter, and
-    # repeated failures circuit-break the device path itself
-    resilience = getattr(app_context.runtime, "resilience", None)
-    if resilience is not None:
-        bridge.guard = resilience.guard_device(
-            rt, query, name, dict(stream_defs), get_junction, bridge.kind)
-        resilience.bind_bridge(bridge.guard, bridge)
-    app_context.register_state(f"device-{name}", _BridgeState(bridge))
-    return bridge
+    return _finish_bridge(bridge, query, name, app_context, stream_defs,
+                          get_junction, batch)
+
+
+def try_build_device_partition(partition_ast, app_context, stream_defs: dict,
+                               get_junction,
+                               name: str) -> Optional[DeviceQueryBridge]:
+    """The device branch of a ``partition with`` block: ONE bridge (kind
+    ``'partition'``) over a served ``PartitionedNFARuntime`` when an inner
+    query opts in via ``@device`` and the block is one value partition
+    ``partition with (<attr> of <Stream>)`` holding one blocked-eligible
+    pattern: keys hash to ``@device(lanes=)`` lane-stacked match tables and
+    every lane steps in one vmapped program. None -> the caller's tiers
+    (fleet, host partition, per-key interpreter); ``strict='true'`` raises
+    instead."""
+    from ..query_api import Variable
+    from ..tpu.expr_compile import DeviceCompileError
+
+    anns = [find_annotation(q.annotations, "device")
+            for q in partition_ast.queries]
+    ann = next((a for a in anns if a is not None), None)
+    if ann is None:
+        return None
+    query = partition_ast.queries[anns.index(ann)]
+    opts = _device_options(ann, query, stream_defs)
+    batch = opts["batch"]
+    try:
+        if len(partition_ast.queries) != 1:
+            raise DeviceCompileError(
+                "a partition of several queries keeps the host tiers (one "
+                "lane-stacked program holds one pattern)")
+        if len(partition_ast.partition_types) != 1:
+            raise DeviceCompileError(
+                "multi-stream partitions keep the host tiers")
+        pt = partition_ast.partition_types[0]
+        if getattr(pt, "value_expr", None) is None or \
+                not isinstance(pt.value_expr, Variable) or \
+                pt.value_expr.stream_index is not None:
+            raise DeviceCompileError(
+                "range/expression partitions keep the host tiers")
+        if not isinstance(query.input_stream, StateInputStream):
+            raise DeviceCompileError(
+                "non-pattern partition queries keep the host tiers")
+        if query.output_rate is not None:
+            raise DeviceCompileError(
+                "output rate limiting in a partition is per key (host "
+                "tiers)")
+        target = _audit_device_surface(query, app_context, get_junction)
+        from ..tpu.partition import PartitionedNFARuntime
+        rt = PartitionedNFARuntime(
+            None, opts["lanes"], pt.value_expr.attribute,
+            slot_capacity=opts["slots"], batch=batch, query=query,
+            stream_defs=stream_defs)
+    except DeviceCompileError as e:
+        if opts["strict"]:
+            raise
+        log.info("partition '%s' falls back to the host tiers: %s", name, e)
+        return None
+    qname = query.name() or f"{name}-query-0"
+    bridge = DeviceQueryBridge(
+        "partition", rt, app_context, rt.compiler.compiled.stream_ids,
+        target, qname, async_mode=opts["async"],
+        pipeline_window=opts["pipeline"])
+    bridge.output_schema = ([n for n, _, _ in rt.compiler.out_specs],
+                            [t for _, _, t in rt.compiler.out_specs])
+    return _finish_bridge(bridge, partition_ast, qname, app_context,
+                          stream_defs, get_junction, batch)
 
 
 class _BridgeState:

@@ -304,8 +304,12 @@ class InputHandler:
             outcome = self._send(data, timestamp) or "ok"
         finally:
             tracer.pop()
+            # the start is the stamp taken above, not back-dated from a
+            # second clock read (a preemption between the two would put the
+            # ingress span after the spans nested in it)
             tr.add_span("ingress", self.stream_id,
-                        time.perf_counter_ns() - t0, n, outcome)
+                        time.perf_counter_ns() - t0, n, outcome,
+                        start_offset_ns=t0 - tr._t0_ns)
 
     def _send(self, data, timestamp: Optional[int] = None):
         if self.flow is not None and not self.flow.replaying:
@@ -443,7 +447,8 @@ class InputHandler:
                 finally:
                     tracer.pop()
                     tr.add_span("ingress", self.stream_id,
-                                time.perf_counter_ns() - t0, len(rows))
+                                time.perf_counter_ns() - t0, len(rows),
+                                start_offset_ns=t0 - tr._t0_ns)
                 return
         self._send_rows(rows, timestamps)
 
@@ -524,7 +529,8 @@ class InputHandler:
                 finally:
                     tracer.pop()
                     tr.add_span("ingress", self.stream_id,
-                                time.perf_counter_ns() - t0, n)
+                                time.perf_counter_ns() - t0, n,
+                                start_offset_ns=t0 - tr._t0_ns)
                 return
         self._send_columns(cols, ts, n)
 

@@ -380,6 +380,7 @@ class AdaptiveFlushMixin:
             "pack_s": batch.get("pack_exec_s", 0.0),
             "queue_s": max(0.0, t0 - t_emit) if t_emit is not None else 0.0,
             "step_s": t1 - t0,
+            "route_s": batch.get("_route_s", 0.0),
             "fence_s": fence_s,
             "decode_s": t2 - t1 - fence_s,
             "cause": cause,

@@ -75,7 +75,8 @@ __all__ = [
 # every flush site reports one of these causes; registered as counters even
 # when still zero so dashboards see the full breakdown ("deadline" = the
 # async driver's latency-mode wall-clock flush of a partial batch)
-FLUSH_CAUSES = ("capacity", "adaptive", "deadline", "drain", "final")
+FLUSH_CAUSES = ("capacity", "adaptive", "deadline", "drain", "final",
+                "lane_full")
 
 
 class DeviceStepProbe:
@@ -330,6 +331,13 @@ class ObservabilitySubsystem:
                 sm.gauge_tracker(
                     f"device.{q}.flush_{cause}_total",
                     lambda p=probe, c=cause: p.flush_causes.get(c, 0))
+            if bridge.kind == "partition":
+                # a served partition's lanes, as its drain points last
+                # read them (tpu/partition.py on_drained; never per step)
+                for g in bridge.runtime.lane_gauges:
+                    sm.gauge_tracker(
+                        f"device.{q}.lanes_{g}",
+                        lambda r=bridge.runtime, g=g: r.lane_gauges[g])
             # egress by shape (core/egress.py): rows over deliveries is the
             # rows a delivery carries — a batch's, not one
             for shape, count in bridge.egress.items():
@@ -407,6 +415,8 @@ class ObservabilitySubsystem:
             rep = out["queries"].get(bridge.query_name)
             if rep is not None:
                 rep["egress"] = bridge.egress_report()
+                if bridge.kind == "partition":
+                    rep["lanes"] = dict(bridge.runtime.lane_gauges)
         for q, phases in phase_queries.items():
             if q in out["queries"]:
                 continue

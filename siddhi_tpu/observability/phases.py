@@ -47,6 +47,11 @@ trackers, and the bench ``latency_breakdown`` line):
   process-per-host fabric (dispatch wall-clock → child apply, including
   any lost-ack retry delay).
 
+One part of a phase is told apart without joining the serial sum
+(``NESTED``): ``route`` — a served partition's lane layout of the flat
+batch, inside ``device_step`` (``tpu/partition.py`` ``dispatch``; span
+``siddhi:dispatch.route``).
+
 The driver's segments are also spans on the profiler's clock
 (``profiler.py``): ``siddhi:seal.pack`` = ``pack``, ``submit.ring_wait`` =
 ``ring_wait``, ``dispatch`` = ``device_step``, ``collect.fence`` =
@@ -61,6 +66,10 @@ from typing import Optional
 PHASES = ("ingress_parse", "ingress_queue", "ring_wait", "fill_wait", "pack",
           "device_step", "egress_fence", "egress_decode", "host_exec",
           "lock_wait", "sink_publish", "dcn_transit", "procmesh_transit")
+
+# a part of a phase told apart: recorded like a phase, outside the serial
+# sum (its parent already carries the time)
+NESTED = {"route": "device_step"}
 
 # span stage → phase (unknown stages are host work by default: every
 # host-side processor span nests inside the query chain)
@@ -99,7 +108,7 @@ class PhaseBreakdown:
     def __init__(self, make_tracker):
         """``make_tracker(name)`` → a LatencyTracker-like with
         ``record_seconds(seconds, n=1, exemplar=None)``."""
-        self.trackers = {p: make_tracker(p) for p in PHASES}
+        self.trackers = {p: make_tracker(p) for p in PHASES + tuple(NESTED)}
         self.end_to_end = make_tracker("end_to_end")
         # queueing attributable to flush policy, split by flush cause —
         # the "deadline-flush queueing share" field reads from these
@@ -112,6 +121,7 @@ class PhaseBreakdown:
                      publish_s: float = 0.0, host_s: float = 0.0,
                      parse_s: float = 0.0, ring_s: float = 0.0,
                      decode_s: float = 0.0, lock_s: float = 0.0,
+                     route_s: float = 0.0,
                      cause: Optional[str] = None,
                      exemplar=None) -> None:
         if n <= 0:
@@ -128,6 +138,9 @@ class PhaseBreakdown:
             if v > 0.0:
                 self.trackers[phase].record_seconds(v, n, exemplar=exemplar)
                 total += v
+        if route_s > 0.0:       # inside device_step: not a segment
+            self.trackers["route"].record_seconds(route_s, n,
+                                                  exemplar=exemplar)
         self.end_to_end.record_seconds(total, n, exemplar=exemplar)
         self.e2e_sum += total * n
         if cause is not None:
@@ -154,7 +167,8 @@ class PhaseBreakdown:
         # Σ(phase sums) == Σ(e2e samples) by construction, so this ratio is
         # exactly 1.0 unless a measurement bug slips in.
         total_events = self.end_to_end.count
-        mean_sum = (sum(t.hist.sum for t in self.trackers.values())
+        mean_sum = (sum(t.hist.sum for p, t in self.trackers.items()
+                        if p not in NESTED)
                     / total_events * 1e3) if total_events else 0.0
         out = {
             "end_to_end": e2e,

@@ -9,7 +9,9 @@ whole micro-batch. The guard wraps every bridge runtime's ``process``:
   (``_ShadowBuilder`` wraps the bridge's batch builder);
 - a failing step records a breaker failure and replays the shadow through a
   lazily-built host interpreter runtime for the same query (the reference's
-  CPU ``QueryRuntime`` role), so no event is lost;
+  CPU ``QueryRuntime`` role; for a served ``partition with`` block, kind
+  ``'partition'``, the per-key ``PartitionRuntime`` of the same block), so
+  no event is lost;
 - after ``device.circuit.threshold`` consecutive failures the device path is
   **quarantined** — steps short-circuit straight to the host fallback without
   touching the device — and after ``device.circuit.cooldown.ms`` the next
@@ -113,9 +115,9 @@ class _ShadowBuilder:
         take = self._inner.append_columns(cols, ts, start)
         if take:
             sl = slice(start, start + take)
+            names = self._inner.column_names
             self._rows.append(_ShadowCols(
-                {n: cols[n][sl] for n in self._inner.schema.names},
-                ts[sl], self._inner.schema.names))
+                {n: cols[n][sl] for n in names}, ts[sl], names))
         return take
 
     def append_many(self, *args, **kwargs):
@@ -140,6 +142,30 @@ class _ShadowBuilder:
 
     def __getattr__(self, name):
         return getattr(self._inner, name)
+
+
+class _PartitionFallback:
+    """The host engine a served partition's failed batch replays through:
+    the per-key interpreter ``PartitionRuntime`` of the same ``partition
+    with`` block, shaped like a query runtime (``subscriptions``,
+    ``start``) so that the guard feeds it as it feeds one."""
+
+    def __init__(self, partition_ast, app_context, stream_defs: dict,
+                 get_junction, name: str, query_callbacks: list):
+        from ..core.partition import PartitionRuntime, PartitionStreamReceiver
+        self.prt = PartitionRuntime(partition_ast, app_context, stream_defs,
+                                    get_junction, name)
+        # callbacks registered on the device query see replayed rows too
+        for q in partition_ast.queries:
+            if q.name() is not None:
+                self.prt.query_callbacks[q.name()] = query_callbacks
+        self.subscriptions = [
+            (sid, PartitionStreamReceiver(self.prt, sid,
+                                          self.prt.key_executors.get(sid)))
+            for sid in sorted(self.prt.consumed)]
+
+    def start(self) -> None:
+        """Key instances start as their first event arrives."""
 
 
 class _GuardToken:
@@ -288,6 +314,15 @@ class DeviceGuard:
         # it. _fb_lock then serializes the build itself.
         with self.app_context.root_lock:
             with self._fb_lock:
+                if self._fb_runtime is None and self.kind == "partition":
+                    # `query` is the whole Partition element: its keys'
+                    # state lives in per-key interpreter instances
+                    self._fb_runtime = _PartitionFallback(
+                        self.query, self.app_context, self.stream_defs,
+                        self.get_junction, f"{self.query_name}__hostfb",
+                        self.bridge.query_callbacks
+                        if self.bridge is not None else [])
+                    self._fb_engine = "scalar"
                 if self._fb_runtime is None:
                     # COLUMNAR first: quarantine/shadow-replay through the
                     # vectorized host engine (tpu/host_exec.py) — degraded

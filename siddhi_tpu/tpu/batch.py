@@ -64,6 +64,22 @@ class StringDictionary:
     def __len__(self) -> int:
         return len(self._values)
 
+    def encode_list(self, values: list) -> "np.ndarray":
+        """Codes of a list of strings through the dictionary's own hash
+        table: one C-level ``map`` over the list, and a Python call only
+        for a value not seen before (a new key, not an event). The cost is
+        the chunk's, whatever the dictionary holds: ``encode_array``
+        rebuilds a sorted copy of every value each time one was added,
+        which a stream of 10^5 keys pays for chunk after chunk."""
+        import itertools
+        import numpy as np
+        codes = np.fromiter(
+            map(self._codes.get, values, itertools.repeat(-1)),
+            dtype=np.int32, count=len(values))
+        for i in np.flatnonzero(codes < 0).tolist():
+            codes[i] = self.encode(values[i])     # None -> 0
+        return codes
+
     def encode_array(self, values) -> "np.ndarray":
         """Vectorized encode of a string array via a sorted lookup cache:
         ``searchsorted`` against the known values (O(n log u) C-side string
@@ -261,6 +277,12 @@ class BatchBuilder:
     def append_rows(self, rows: list[list], ts_list) -> None:
         for row, ts in zip(rows, ts_list):
             self.append(row, ts)
+
+    @property
+    def column_names(self) -> list:
+        """The chunk columns ``append_columns`` reads, by raw attribute name
+        (what a guard's shadow of a columnar chunk keeps)."""
+        return self.schema.names
 
     def append_columns(self, cols: dict, ts, start: int = 0) -> int:
         """Bulk slice-copy of a columnar chunk (``{name: numpy array |

@@ -14,6 +14,10 @@ inside one JVM (``PartitionStreamReceiver.java:82-117``). TPU-native redesign:
 
 from __future__ import annotations
 
+import logging
+import math
+import time
+import zlib
 from typing import Callable, Optional
 
 import jax
@@ -22,15 +26,26 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..compiler import parse as _parse
+from ..flow.adaptive_batch import AdaptiveFlushMixin
+from ..observability.profiler import span
+from ..query_api.definition import DataType
 from .expr_compile import DeviceCompileError
 from .nfa import DeviceNFACompiler, MergedBatchBuilder
 
+log = logging.getLogger("siddhi_tpu.device")
+
 
 def _hash_key(v) -> int:
-    import zlib
     # stable across processes (hash() randomization would break resumed
     # checkpoints whose lane assignment must match)
     return zlib.crc32(str(v).encode()) & 0x7FFFFFFF
+
+
+def lane_capacity_for(batch: int, lanes: int) -> int:
+    """Events one lane of a served batch holds: the next multiple of 64 at
+    or above 2.5 x ``batch / lanes`` (the fullest lane of a Zipf stream
+    reads about twice the mean; ``PERF.md`` section 7's sizing table)."""
+    return max(64, math.ceil(2.5 * batch / lanes / 64) * 64)
 
 
 def _inject_key_equality(query, key_attr: str):
@@ -117,27 +132,166 @@ def _inject_key_equality(query, key_attr: str):
     return query
 
 
-class PartitionedNFARuntime:
+class LaneFull(Exception):
+    """The event's lane holds its capacity: seal the batch, then append."""
+
+
+class RoutedChunk(dict):
+    """A columnar chunk's raw columns (what a guard shadows) with what
+    routing made of them once: ``enc`` = the staged columns by wire key,
+    ``lanes`` = the lane of every row. A chunk split over batches is routed
+    once, not once a piece."""
+
+    __slots__ = ("enc", "lanes")
+
+
+class LaneBatchBuilder(MergedBatchBuilder):
+    """One FLAT batch of up to ``capacity`` events in arrival order, with
+    the lane of every event beside it and a running count per lane.
+
+    The seal rule of the served partition: the batch is full when it holds
+    ``capacity`` events OR when the next event would be its lane's
+    ``lane_capacity + 1``-th. ``append`` raises :class:`LaneFull` for such
+    an event and ``append_columns`` takes a chunk only as far as it, so the
+    caller seals and resumes: nothing is dropped, and a key's events keep
+    their order because they stay in arrival order within one lane. The
+    layout into ``[lanes, lane_capacity]`` happens at ``dispatch``."""
+
+    def __init__(self, schema, capacity: int, stream_defs: dict,
+                 used_cols, stream_id: str, lanes: int, lane_capacity: int,
+                 route_row: Callable, route_chunk: Callable):
+        super().__init__(schema, capacity, stream_defs, used_cols=used_cols)
+        self.stream_id = stream_id
+        self.lanes = lanes
+        self.lane_capacity = lane_capacity
+        # raw attribute names of the one input stream: what a guard's shadow
+        # of a columnar chunk keeps (``_ShadowBuilder.append_columns``)
+        self.column_names = [a.name
+                             for a in stream_defs[stream_id].attributes]
+        self._route_row = route_row         # row -> lane
+        self._route_chunk = route_chunk     # (stream, cols, n) -> RoutedChunk
+        self._lane = np.zeros(capacity, dtype=np.int32)
+        self._lane_n = np.zeros(lanes, dtype=np.int64)
+        self.lane_full = False      # the next event met a full lane
+
+    @property
+    def full(self) -> bool:
+        return self._n >= self.capacity or self.lane_full
+
+    def append(self, stream_id: str, row: list, ts: int) -> None:
+        lane = self._route_row(row)
+        if self._lane_n[lane] >= self.lane_capacity:
+            self.lane_full = True
+            raise LaneFull(lane)
+        i = self._n
+        super().append(stream_id, row, ts)
+        self._lane[i] = lane
+        self._lane_n[lane] += 1
+
+    def append_columns(self, cols: dict, ts, start: int = 0) -> int:
+        """Rows ``[start, start + take)`` of a columnar chunk, ``take``
+        being what fits the batch and every lane; returns ``take``. No
+        per-row Python: one ``bincount`` of the slice's lanes, and where a
+        lane would overflow one stable argsort to find the first row that
+        does."""
+        ts = np.asarray(ts, dtype=np.int64)
+        if not isinstance(cols, RoutedChunk):
+            cols = self._route_chunk(self.stream_id, cols, int(ts.shape[0]))
+        take = min(int(ts.shape[0]) - start, self.capacity - self._n)
+        if take <= 0:
+            return 0
+        lanes = cols.lanes[start:start + take]
+        counts = np.bincount(lanes, minlength=self.lanes)
+        room = self.lane_capacity - self._lane_n
+        if (counts > room).any():
+            order = np.argsort(lanes, kind="stable")
+            ls = lanes[order]
+            rank = np.arange(take) - (np.cumsum(counts) - counts)[ls]
+            take = int(order[rank >= room[ls]].min())
+            self.lane_full = True
+            if take == 0:
+                return 0
+            lanes = lanes[:take]
+            counts = np.bincount(lanes, minlength=self.lanes)
+        if self._pack_t0 is None:
+            self._pack_t0 = time.perf_counter()
+        i = self._n
+        for key, col in self._cols.items():
+            col[i:i + take] = cols.enc[key][start:start + take]
+        self._tag[i:i + take] = self.schema.stream_index[self.stream_id]
+        self._ts[i:i + take] = ts[start:start + take]
+        self._lane[i:i + take] = lanes
+        self._lane_n += counts
+        self._n += take
+        return take
+
+    def emit(self) -> dict:
+        n = self._n
+        out = super().emit()
+        valid = np.zeros(self.capacity, dtype=bool)
+        valid[:n] = True
+        out["valid"] = valid
+        out["lane"] = self._lane.copy()
+        self._lane_n[:] = 0
+        self.lane_full = False
+        return out
+
+    def snapshot(self) -> dict:
+        snap = super().snapshot()
+        snap["lane"] = self._lane[:self._n].copy()
+        return snap
+
+    def restore(self, snap: dict) -> None:
+        super().restore(snap)
+        n = snap["n"]
+        self._lane[:n] = snap["lane"]
+        self._lane_n[:] = np.bincount(snap["lane"], minlength=self.lanes)
+        self.lane_full = False
+
+
+class PartitionedNFARuntime(AdaptiveFlushMixin):
     """P-lane partitioned pattern matching, optionally sharded over a mesh.
 
     ``partition with (<key> of <stream>)`` over a pattern query: every lane runs
     the compiled NFA independently on its key subset.
+
+    Two ways in. **Served** (``batch=`` given; what ``@device`` on a
+    ``partition with`` block builds, ``core/device_bridge.py``
+    ``try_build_device_partition``): one flat :class:`LaneBatchBuilder` of
+    ``batch`` events, the interface ``DeviceNFARuntime`` has (``send`` /
+    ``send_columns``, ``dispatch`` / ``collect`` / ``process`` / ``deliver``,
+    ``flush``, ``on_drained``, ``snapshot_state`` / ``restore_state``) under
+    the bridge's driver, probe and guard. **Direct** (no ``batch``; the
+    bring-up and multi-host harnesses): one builder a lane in ``builders``,
+    ``send`` / ``send_many`` / ``ingest_csv`` and a synchronous ``flush``.
+    Both step the same jitted ``vstep`` over ``[P, lane_batch]`` and decode
+    its stacked outputs in one pass (``decode_stacked``).
     """
+
+    pipeline_safe = True        # no host-sync state between steps
 
     def __init__(self, app_or_text, num_partitions: int,
                  key_attr: str,
                  slot_capacity: int = 32,
-                 lane_batch: int = 256,
+                 lane_batch: Optional[int] = None,
                  mesh: Optional[Mesh] = None,
                  axis: str = "p",
                  query_index: int = 0,
-                 creation_cap: Optional[int] = None):
-        app = _parse(app_or_text) if isinstance(app_or_text, str) else app_or_text
-        # partition queries may live inside a `partition with` block
-        if app.queries:
-            query = app.queries[query_index]
-        else:
-            query = app.partitions[0].queries[query_index]
+                 creation_cap: Optional[int] = None,
+                 batch: Optional[int] = None,
+                 query=None, stream_defs: Optional[dict] = None):
+        if query is None:
+            app = _parse(app_or_text) if isinstance(app_or_text, str) \
+                else app_or_text
+            # partition queries may live inside a `partition with` block
+            if app.queries:
+                query = app.queries[query_index]
+            else:
+                query = app.partitions[0].queries[query_index]
+            stream_defs = app.stream_definitions
+        if lane_batch is None:
+            lane_batch = 256 if batch is None \
+                else lane_capacity_for(batch, num_partitions)
         self.P = num_partitions
         self.key_attr = key_attr
         self.lane_batch = lane_batch
@@ -146,16 +300,42 @@ class PartitionedNFARuntime:
         # per-key semantics on shared lanes: every later state carries an
         # implicit `key == e1.key` filter (see _inject_key_equality)
         query = _inject_key_equality(query, key_attr)
+        self.stream_defs = dict(stream_defs)
         self.compiler = DeviceNFACompiler(
-            query, dict(app.stream_definitions), slot_capacity, lane_batch,
+            query, self.stream_defs, slot_capacity, lane_batch,
             creation_cap=creation_cap)
-        self.stream_defs = dict(app.stream_definitions)
-        self.builders = [
-            MergedBatchBuilder(self.compiler.merged, lane_batch,
-                               self.stream_defs,
-                               used_cols=self.compiler.used_cols)
-            for _ in range(num_partitions)
-        ]
+        merged = self.compiler.merged
+        # the dictionary a string key is coded in (ONE shared by every
+        # string column of the merged schema); None for other key types
+        self._key_dict = next(
+            (merged.dictionaries[merged.col_key(sid, key_attr)]
+             for sid in merged.stream_ids
+             if self.stream_defs[sid].attribute_type(key_attr)
+             == DataType.STRING), None)
+        self._lane_by_code = np.zeros(1, np.int32)     # dictionary code -> lane
+        self._lane_by_key: dict = {}                   # non-string key -> lane
+        self.builders: list = []
+        self.builder: Optional[LaneBatchBuilder] = None
+        if batch is None:
+            self.builders = [
+                MergedBatchBuilder(merged, lane_batch, self.stream_defs,
+                                   used_cols=self.compiler.used_cols)
+                for _ in range(num_partitions)
+            ]
+        else:
+            if len(merged.stream_ids) != 1:
+                raise DeviceCompileError(
+                    "a served partition routes one input stream")
+            if not self.compiler.blocked:
+                raise DeviceCompileError(
+                    "a served partition steps the blocked kernel: count, "
+                    "logical and absent states keep the host tiers")
+            sid = merged.stream_ids[0]
+            self._key_pos = self.stream_defs[sid].attribute_position(key_attr)
+            self.builder = LaneBatchBuilder(
+                merged, batch, self.stream_defs, self.compiler.used_cols,
+                sid, num_partitions, lane_batch,
+                route_row=self._lane_of_row, route_chunk=self.route_chunk)
 
         # vmap the single-lane step over the lane axis
         step = self.compiler.make_step()
@@ -175,7 +355,13 @@ class PartitionedNFARuntime:
         self._vstep = self.vstep      # backwards-compat alias
 
         self.state = self.init_state()
-        self.callback: Optional[Callable[[list[list]], None]] = None
+        # direct use: fn(rows); served: the bridge's fn(chunk, emit_ts)
+        self.callback: Optional[Callable] = None
+        self.driver = None          # AsyncDeviceDriver when @device(async)
+        # read at drain points only (on_drained), never per step
+        self.lane_gauges = {"fullest_table_share": 0.0,
+                            "fullest_lane_events": 0, "drops": 0}
+        self._warned_drops = 0
 
     def init_state(self):
         """Fresh [P, ...]-stacked lane state (sharded if a mesh was given)."""
@@ -191,14 +377,40 @@ class PartitionedNFARuntime:
         return state
 
     def lane_of(self, key) -> int:
-        return _hash_key(key) % self.P
+        """Lane of one key: ``crc32(key) mod P``, computed once a key. A
+        string key's lane sits in a table by its dictionary code (the code
+        the builder stages anyway), any other key's in a dict."""
+        dic = self._key_dict
+        if dic is None or not isinstance(key, str):
+            lane = self._lane_by_key.get(key)
+            if lane is None:
+                lane = self._lane_by_key[key] = _hash_key(key) % self.P
+            return lane
+        code = dic.encode(key)
+        if code >= len(self._lane_by_code):
+            self._grow_lane_table(dic)
+        return int(self._lane_by_code[code])
+
+    def _lane_of_row(self, row: list) -> int:
+        return self.lane_of(row[self._key_pos])
+
+    def _grow_lane_table(self, dic) -> np.ndarray:
+        """``_lane_by_code`` extended to the dictionary's size: one crc32
+        for each code minted since (a new key, not an event)."""
+        tbl = self._lane_by_code
+        if len(tbl) < len(dic):
+            ext = np.fromiter(
+                ((_hash_key(dic.decode(c)) % self.P)
+                 for c in range(len(tbl), len(dic))),
+                dtype=np.int32, count=len(dic) - len(tbl))
+            tbl = self._lane_by_code = np.concatenate([tbl, ext])
+        return tbl
 
     # -- native (C++) CSV ingress ------------------------------------------
     def enable_native_ingress(self) -> None:
         """Routes raw CSV bytes through the C++ data-loader (no Python in the
         per-event loop): parse → dict-encode → crc32 lane routing → SoA pack.
         Single-input-stream patterns only (the bench/north-star shape)."""
-        from ..query_api.definition import DataType
         from ..native import NativeIngress
 
         if len(self.compiler.merged.stream_ids) != 1:
@@ -299,18 +511,79 @@ class PartitionedNFARuntime:
             # know about, silently corrupting decode — one ingress owns codes
             raise RuntimeError(
                 "native ingress enabled: use ingest_csv(), not send()")
+        if self.builder is not None:
+            try:
+                self.builder.append(stream_id, row, timestamp)
+            except LaneFull:
+                self._maybe_flush()         # seals: cause `lane_full`
+                self.builder.append(stream_id, row, timestamp)
+            self._maybe_flush()
+            return
         d = self.stream_defs[stream_id]
-        key = row[d.attribute_position(self.key_attr)]
-        lane = self.lane_of(key)
-        b = self.builders[lane]
+        b = self.builders[
+            self.lane_of(row[d.attribute_position(self.key_attr)])]
         b.append(stream_id, row, timestamp)
         if b.full:
             self.flush()
 
+    def send_columns(self, cols: dict, ts) -> None:
+        """Served columnar ingress: the chunk is routed once (strings
+        coded by one C-level map over the chunk, lanes by code) and
+        slice-copied into the flat batch across as many batches as it
+        spans; a batch seals at its capacity or where a lane would
+        overflow (``LaneBatchBuilder``)."""
+        ts = np.asarray(ts, dtype=np.int64)
+        n = int(ts.shape[0])
+        chunk = self.route_chunk(self.builder.stream_id, cols, n)
+        start = 0
+        while start < n:
+            start += self.builder.append_columns(chunk, ts, start)
+            self._maybe_flush()
+
+    def route_chunk(self, stream_id: str, cols: dict, n: int) -> RoutedChunk:
+        """Encode and route a columnar chunk once: every staged column by
+        its wire key, and every row's lane."""
+        from ..core.columns import DictColumn, encode_dict_column
+        d = self.stream_defs[stream_id]
+        merged = self.compiler.merged
+        si = merged.stream_index[stream_id]
+        used = self.compiler.used_cols
+        chunk = RoutedChunk(cols)
+        chunk.enc = {}
+        key_vals = None
+        for a in d.attributes:
+            wire = f"s{si}_{a.name}"
+            if wire not in used and a.name != self.key_attr:
+                continue
+            col = cols[a.name]
+            if a.type == DataType.STRING:
+                dic = merged.dictionaries[wire]
+                vals = encode_dict_column(col, dic) \
+                    if isinstance(col, DictColumn) \
+                    else dic.encode_list(np.asarray(col).tolist())
+            else:
+                vals = np.asarray(col)
+            chunk.enc[wire] = vals
+            if a.name == self.key_attr:
+                key_vals = vals
+        chunk.lanes = self._lanes_for(stream_id, cols,
+                                      {self.key_attr: key_vals})
+        return chunk
+
+    def _maybe_flush(self) -> None:
+        """The mixin's rule plus the served partition's second seal: a
+        batch that stopped short of its capacity because a lane is full
+        flushes with the cause ``lane_full``."""
+        b = self.builder
+        if b.lane_full and len(b) < b.capacity:
+            self._count_flush("lane_full")
+            self.flush()
+            return
+        super()._maybe_flush()
+
     def encode_columns(self, stream_id: str, cols: dict) -> dict:
         """Dictionary-encode string columns on their DISTINCT values (the
         per-event ``encode`` loop is the measured pack bottleneck)."""
-        from ..query_api.definition import DataType
         d = self.stream_defs[stream_id]
         si = self.compiler.merged.stream_index[stream_id]
         enc = {}
@@ -358,23 +631,12 @@ class PartitionedNFARuntime:
         """Lane array for a bulk send: string keys route via their already-
         computed dictionary CODES (one code→lane table lookup; no second
         string search), other key types via the sorted route cache."""
-        from ..query_api.definition import DataType
         d = self.stream_defs[stream_id]
         if d.attribute_type(self.key_attr) == DataType.STRING and \
                 self.key_attr in enc:
             si = self.compiler.merged.stream_index[stream_id]
             dic = self.compiler.merged.dictionaries[f"s{si}_{self.key_attr}"]
-            tbl = getattr(self, "_lane_by_code", None)
-            if tbl is None:
-                tbl = np.zeros(1, np.int32)
-            if len(tbl) < len(dic):
-                ext = np.fromiter(
-                    ((_hash_key(dic.decode(c)) % self.P)
-                     for c in range(len(tbl), len(dic))),
-                    dtype=np.int32, count=len(dic) - len(tbl))
-                tbl = np.concatenate([tbl, ext])
-                self._lane_by_code = tbl
-            return tbl[enc[self.key_attr]]
+            return self._grow_lane_table(dic)[enc[self.key_attr]]
         return self.route_lanes(cols[self.key_attr])
 
     def partition_columns(self, stream_id: str, cols: dict, timestamps):
@@ -426,6 +688,8 @@ class PartitionedNFARuntime:
         return out if decode else None
 
     def flush(self, decode: bool = False):
+        if self.builder is not None:
+            return self._flush_served()
         # a registered callback implies decode — without this, the
         # auto-flush on a filled lane would silently discard every match
         # row found mid-stream (fuzz regression: match_count advanced while
@@ -449,13 +713,137 @@ class PartitionedNFARuntime:
                                      counts)
         if not decode:
             return ys
-        rows = []
-        for lane in range(self.P):
-            lane_ys = jax.tree_util.tree_map(lambda x: x[lane], ys)
-            rows.extend(self.compiler.decode_outputs(lane_ys).rows())
+        rows = self.decode_stacked(ys).rows()
         if self.callback is not None and rows:
             self.callback(rows)
         return rows
+
+    def decode_stacked(self, ys):
+        """One step's lane-stacked outputs -> ONE ``ColumnsOut``, lanes in
+        order and a lane's rows as its own decode orders them (the blocked
+        kernel: by match event ``j``, then candidate rank), in one pass
+        over the whole: no loop over lanes."""
+        from ..core.columns import ColumnsOut
+        nfa = self.compiler
+        if not nfa.blocked:
+            # the scan kernel's decode is one boolean index over the mask,
+            # whatever its rank: [P, B, 2, C] walks lane, event, source,
+            # candidate in row-major order
+            return nfa.decode_outputs(ys)
+        mask = np.asarray(ys["mask"])
+        idx = np.flatnonzero(mask)              # over [P, M], lane-major
+        if not idx.size:
+            return ColumnsOut.empty(nfa.out_specs, nfa.merged.dictionaries)
+        width = mask.shape[1]
+        j = np.asarray(ys["j"]).reshape(-1)[idx].astype(np.int64)
+        idx = idx[np.argsort((idx // width) * self.lane_batch + j,
+                             kind="stable")]
+        cols = {name: np.asarray(ys[name]).reshape(-1)[idx]
+                for (name, _, t) in nfa.out_specs}
+        return ColumnsOut(None, cols, int(idx.size), nfa.out_specs,
+                          nfa.merged.dictionaries)
+
+    # -- the served interface (what DeviceNFARuntime has) ----------------------
+    def dispatch(self, batch: dict):
+        """Fire-and-forget step of one FLAT batch (arrival order, scalar
+        ``count``, prefix ``valid``, a ``lane`` per event): laid out into
+        ``[P, lane_batch]`` here, on the driver's thread, by one stable
+        argsort by lane (a key's events keep their order), then ``vstep``
+        on donated state. Returns the un-fenced outputs."""
+        t0 = time.perf_counter()
+        with span(f"siddhi:dispatch.route:{self.query_name}"):
+            feed = self._lay_out(batch)
+        batch["_route_s"] = time.perf_counter() - t0
+        self.state, ys = self.vstep(self.state, *feed)
+        return ys
+
+    def _lay_out(self, batch: dict):
+        lanes_n, width = self.P, self.lane_batch
+        n = int(batch["count"])
+        valid = batch["valid"][:n]
+        src = np.arange(n) if valid.all() else np.flatnonzero(valid)
+        lane = batch["lane"][src]
+        order = np.argsort(lane, kind="stable")
+        src, lane = src[order], lane[order]
+        counts = np.bincount(lane, minlength=lanes_n)
+        fullest = int(counts.max()) if n else 0
+        if fullest > width:
+            raise OverflowError(
+                f"lane holds {fullest} events of a batch, capacity {width}")
+        if fullest > self.lane_gauges["fullest_lane_events"]:
+            self.lane_gauges["fullest_lane_events"] = fullest
+        dst = lane * width + (np.arange(src.size)
+                              - (np.cumsum(counts) - counts)[lane])
+
+        def spread(flat):
+            out = np.zeros(lanes_n * width, dtype=flat.dtype)
+            out[dst] = flat[src]
+            return out.reshape(lanes_n, width)
+
+        cols = {k: spread(v) for k, v in batch["cols"].items()}
+        ts_base = np.full(lanes_n, batch["ts_base"], dtype=np.int64)
+        return (cols, spread(batch["tag"]), spread(batch["ts"]), ts_base,
+                counts.astype(np.int32))
+
+    def collect(self, ys):
+        """Egress edge: fence on the mask, then decode the stacked outputs
+        into one ``ColumnsOut`` chunk, its string codes resolved."""
+        self._fence(ys["mask"])
+        with span(f"siddhi:collect.decode:{self.query_name}"):
+            out = self.decode_stacked(ys)
+            out.decoded()
+            return out
+
+    def process(self, batch: dict):
+        """Synchronous step + decode (one dispatch immediately collected)."""
+        return self.collect(self.dispatch(batch))
+
+    def deliver(self, out, emit_ts=None) -> None:
+        fn = self.callback
+        if fn is not None and out:
+            fn(out, emit_ts)
+
+    def _flush_served(self):
+        if len(self.builder) == 0:
+            return None
+        batch = self._emit_batch()
+        if self.driver is not None:
+            self.driver.submit(batch)
+            return None
+        out = self._timed_process(batch)
+        self.deliver(out, batch.get("last_ts"))
+        self.on_drained()
+        return out
+
+    def finalize(self) -> None:
+        """Nothing open at shutdown: a pattern holds no segment."""
+
+    def on_drained(self) -> None:
+        """Drain point (nothing in flight, or every 64th batch under load):
+        the one place that reads device state back. Overflow is warned of,
+        never silent; the gauges say how near the tables are to it."""
+        st = self.state
+        drops = int(np.sum(jax.device_get(st["drops"])))
+        fullest = max((int(np.asarray(t["valid"]).sum(axis=-1).max())
+                       for t in st["tables"].values()), default=0)
+        self.lane_gauges["drops"] = drops
+        self.lane_gauges["fullest_table_share"] = fullest / self.compiler.C
+        if drops > self._warned_drops:
+            log.warning(
+                "query '%s': %d partial matches dropped from full lane "
+                "tables (raise @device(slots=) or lanes=)",
+                self.query_name, drops)
+            self._warned_drops = drops
+
+    def snapshot_state(self):
+        from .batch import device_state_snapshot
+        return device_state_snapshot(self.state, self.compiler.merged)
+
+    def restore_state(self, state) -> None:
+        from .batch import device_state_restore
+        self.state = device_state_restore(state, self.compiler.merged)
+        # a restored dictionary may code the keys anew
+        self._lane_by_code = np.zeros(1, np.int32)
 
     @property
     def match_count(self) -> int:
