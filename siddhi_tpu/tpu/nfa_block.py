@@ -1,10 +1,13 @@
 """Blocked NFA step: batch-level parallel pattern matching.
 
-The round-2 verdict measured the per-event ``lax.scan`` kernel (``nfa.py``) at
-~1.9s per 32k-event batch on a real v5e — 512 sequential scan iterations of
-~300 tiny [C]-wide ops are pure dispatch latency, near-zero MFU. This module
-is the reformulation the north star asks for: sequential depth **S (number of
-NFA states)** instead of **B (events per batch)**.
+The per-event ``lax.scan`` kernel (``nfa.py``) walks a batch one event at a
+time: B sequential iterations of some 300 tiny [C]-wide operations, which is
+launch latency and no arithmetic (its time on a v5e: not measured; no ledger
+line runs it). This module is the reformulation the north star asks for:
+sequential depth **S (number of NFA states)** instead of **B (events per
+batch)**. Its step on the chip is a ledger row: ``step.device_ms_per_batch``
+of ``pattern-chain8-sat`` (C 1,024, B 2,048) and ``partitioned-chain-sat``
+(256 lanes under ``vmap``, C 896, B 320) in ``PERF_LEDGER.jsonl``.
 
 Key insight: for linear chains of *stream* states with ``every`` at the start
 (the dominant pattern shape — BASELINE configs #2/#3/#5), advancement is
@@ -82,7 +85,11 @@ def block_init_state(nfa: "DeviceNFACompiler") -> dict:
     Invariant: table slots are packed in creation order (oldest first) — the
     per-batch survivor pack preserves candidate order, and candidates are
     [old slots (already ordered), creations (born ascending)]. Drop-newest
-    truncation is therefore just "keep the first C survivors"."""
+    truncation is therefore just "keep the first C survivors", which is what
+    ``pack_first`` does: slot c gathers the (c+1)-th surviving candidate, so
+    the order is the candidates' own, empty slots (all at the end) hold the
+    fills of this function, and survivors past the C-th are counted in
+    ``drops`` and gone."""
     C = nfa.C
     has_ew = any(st.within_ms is not None for st in nfa.states)
     tables = {}
@@ -105,6 +112,55 @@ def block_init_state(nfa: "DeviceNFACompiler") -> dict:
         "matches": jnp.array(0, jnp.int64),
         "drops": jnp.array(0, jnp.int64),
     }
+
+
+def _to_words(v):
+    """A [P] leaf as [P, k] int32 words, bit for bit (k = 2 for the 64-bit
+    types, which the TPU holds as word pairs anyway)."""
+    if v.dtype == jnp.bool_:
+        return v.astype(jnp.int32)[:, None]
+    w = jax.lax.bitcast_convert_type(v, jnp.int32)
+    return w if w.ndim == 2 else w[:, None]
+
+
+def _from_words(w, dtype):
+    """[n, k] int32 words back to the [n] leaf they were cut from."""
+    if dtype == jnp.bool_:
+        return w[:, 0] != 0
+    return jax.lax.bitcast_convert_type(w if w.shape[1] > 1 else w[:, 0],
+                                        dtype)
+
+
+def pack_first(mask, n: int, vals, fills):
+    """Order-preserving pack of the rows ``mask`` [P] marks into ``n`` slots:
+    slot c takes the (c+1)-th marked row, slots past the last marked row
+    take ``fills``, marked rows past the n-th drop off and are counted.
+
+    Index once, gather n. ``src[c]``, the position of the (c+1)-th set bit
+    (``P`` where fewer are set), is how many prefix counts lie below c+1: one
+    fused [n, P] compare-and-count, a grid of the kernel's own [B, P] kind
+    that is never materialised. Then the leaves of ``vals`` (a pytree of [P]
+    arrays; ``fills`` the same tree of scalars) go as ONE gather of n rows
+    over their 32-bit words stacked [P, W]: on a v5e a gathered or scattered
+    element costs 7-12 ns whatever the shape, a gathered row hardly more
+    than one element (PERF.md section 6, PR 30). Exact for every dtype the
+    tables hold: words are moved, never computed with.
+    Returns ``(taken [n] bool, packed leaves, dropped i64)``."""
+    P = mask.shape[0]
+    count = jnp.cumsum(mask.astype(jnp.int32))
+    src = jnp.searchsorted(count, jnp.arange(1, n + 1, dtype=jnp.int32),
+                           side="left", method="compare_all")
+    taken = src < P
+    leaves, tree = jax.tree.flatten(vals)
+    words = [_to_words(v) for v in leaves]
+    rows = jnp.concatenate(words, axis=1)[jnp.minimum(src, P - 1)]   # [n, W]
+    parts = jnp.split(rows, np.cumsum([w.shape[1] for w in words])[:-1],
+                      axis=1)
+    packed = [
+        jnp.where(taken, _from_words(part, v.dtype), jnp.asarray(fill, v.dtype))
+        for v, part, fill in zip(leaves, parts, tree.flatten_up_to(fills))]
+    dropped = jnp.maximum(count[-1].astype(jnp.int64) - n, 0)
+    return taken, jax.tree.unflatten(tree, packed), dropped
 
 
 def make_block_step(nfa: "DeviceNFACompiler"):
@@ -194,26 +250,13 @@ def make_block_step(nfa: "DeviceNFACompiler"):
             n = ex.shape[0]
             if K is None or n <= K:
                 return cre, jnp.int64(0)
-            rank = jnp.cumsum(ex.astype(jnp.int32)) - 1
-            tgt = jnp.where(ex, rank, K)
-
-            def cp(vals, fill):
-                return jnp.full((K,), fill, vals.dtype).at[tgt].set(
-                    jnp.where(ex, vals, fill), mode="drop")
-
-            out = {
-                "exists": jnp.zeros((K,), jnp.bool_).at[tgt].set(
-                    ex, mode="drop"),
-                "born": cp(cre["born"], jnp.int32(0)),
-                "vb": cp(cre["vb"], jnp.int32(0)),
-                "first_ts": cp(cre["first_ts"], jnp.int64(-1)),
-                "bind": {k: cp(v, jnp.zeros((), v.dtype))
-                         for k, v in cre["bind"].items()},
-            }
+            vals = {k: v for k, v in cre.items() if k != "exists"}
+            fills = {"born": 0, "vb": 0, "first_ts": -1,
+                     "bind": {k: 0 for k in cre["bind"]}}
             if "last_ts" in cre:
-                out["last_ts"] = cp(cre["last_ts"], jnp.int64(-1))
-            dropped = jnp.maximum(
-                jnp.sum(ex.astype(jnp.int64)) - K, 0)
+                fills["last_ts"] = -1
+            exists, out, dropped = pack_first(ex, K, vals, fills)
+            out["exists"] = exists
             return out, dropped
 
         with jax.named_scope("nfa.admit"):
@@ -335,28 +378,16 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                         # followed
                         surv = surv & (cand_vb == n_valid)
                     # candidates are already in creation order (see
-                    # block_init_state invariant) — pack survivors by rank,
-                    # ranks ≥ C drop off
-                    rank = jnp.cumsum(surv.astype(jnp.int32)) - 1
-                    tgt = jnp.where(surv, rank, C)
-
-                    def pack(vals, fill):
-                        return jnp.full((C,), fill, vals.dtype).at[tgt].set(
-                            jnp.where(surv, vals, fill), mode="drop")
-
-                    ntbl = {
-                        "valid": jnp.zeros((C,), jnp.bool_).at[tgt].set(
-                            surv, mode="drop"),
-                        "first_ts": pack(cand_first, jnp.int64(-1)),
-                    }
+                    # block_init_state invariant): the first C survivors
+                    # are the table, the rest drop off
+                    vals = {"first_ts": cand_first, **cand_bind}
+                    fills = {"first_ts": -1, **{k: 0 for k in cand_bind}}
                     if has_ew:
-                        ntbl["last_ts"] = pack(cand_last, jnp.int64(-1))
-                    for key in binding_keys(s):
-                        ntbl[key] = pack(cand_bind[key],
-                                         jnp.zeros((), key_dtype(key)))
+                        vals["last_ts"], fills["last_ts"] = cand_last, -1
+                    kept, ntbl, dropped = pack_first(surv, C, vals, fills)
+                    ntbl["valid"] = kept
                     tables[f"t{s}"] = ntbl
-                    n_surv = jnp.sum(surv.astype(jnp.int64))
-                    drops = drops + jnp.maximum(n_surv - C, 0)
+                    drops = drops + dropped
 
         new_state = {"tables": tables, "matches": matches, "drops": drops}
         ys = {"mask": out_mask, "j": out_j, "ts": out_ts}

@@ -263,3 +263,142 @@ def test_element_within_on_device():
         from (e1=A[v>1.0] and e2=B[v>1.0]) within 1 sec -> e3=A[v>2.0]
         select e3.v as c insert into O;
         """)
+
+
+# -- the order-preserving pack (counts on the CPU backend, no times) ---------
+
+PACK_P, PACK_N = 300, 40
+PACK_DENSITIES = {"none": 0.0, "sparse": 0.05, "more_than_n": 0.5,
+                  "full": 1.0}
+PACK_DTYPES = ["bool", "int32", "int64", "float32"]
+
+
+@pytest.mark.parametrize("lanes", [0, 5], ids=["single", "vmap"])
+@pytest.mark.parametrize("dtype", PACK_DTYPES)
+@pytest.mark.parametrize("density", list(PACK_DENSITIES))
+def test_pack_first_equals_numpy_reference(density, dtype, lanes):
+    """Slot c holds the (c+1)-th marked row, later slots the fill, marked
+    rows past the n-th are dropped and counted — the table invariant of
+    ``block_init_state``, leaf by leaf, alone and under ``vmap``."""
+    import jax
+    import numpy as np
+
+    from siddhi_tpu.tpu.nfa_block import pack_first
+
+    rng = np.random.default_rng(
+        [lanes, list(PACK_DENSITIES).index(density), PACK_DTYPES.index(dtype)])
+    shape = (max(lanes, 1), PACK_P)
+    mask = rng.random(shape) < PACK_DENSITIES[density]
+    if dtype == "bool":
+        vals, fill = rng.random(shape) < 0.5, False
+    elif dtype == "float32":
+        vals, fill = rng.random(shape).astype(np.float32), 0.0
+    else:
+        # beyond 32 bits where the leaf has them: a timestamp stays exact
+        hi = 2**40 if dtype == "int64" else 2**31 - 1
+        vals, fill = rng.integers(1, hi, shape).astype(dtype), -1
+
+    def pack(m, v):
+        return pack_first(m, PACK_N, {"leaf": v}, {"leaf": fill})
+
+    if lanes:
+        out = jax.jit(jax.vmap(pack))(mask, vals)
+    else:
+        out = jax.tree.map(lambda x: x[None], jax.jit(pack)(mask[0], vals[0]))
+    taken, got, dropped = jax.tree.map(np.asarray, out)
+    assert got["leaf"].dtype == vals.dtype
+    for lane in range(shape[0]):
+        marked = np.flatnonzero(mask[lane])
+        want = np.full(PACK_N, fill, vals.dtype)
+        want[:min(marked.size, PACK_N)] = vals[lane, marked[:PACK_N]]
+        np.testing.assert_array_equal(got["leaf"][lane], want)
+        np.testing.assert_array_equal(
+            taken[lane], np.arange(PACK_N) < marked.size)
+        assert int(dropped[lane]) == max(marked.size - PACK_N, 0)
+    if density == "more_than_n":
+        assert int(dropped.min()) > 0
+
+
+CHAIN4 = """
+define stream S (sym string, v double);
+from every e1=S[v > 20.0] -> e2=S[sym == e1.sym and v > e1.v]
+  -> e3=S[v > e2.v] -> e4=S[v > e3.v] within 5000
+select e1.v as a, e2.v as b, e3.v as c, e4.v as d insert into O;
+"""
+
+
+def blocked_runtime(app, creation_cap=None, **sizes):
+    """A blocked runtime whose step carries the optional creation budget
+    (a compiler argument the one-query runtime does not pass on)."""
+    import jax
+
+    rt = DeviceNFARuntime(app, **sizes)
+    assert rt.compiler.blocked
+    if creation_cap is not None:
+        rt.compiler.creation_cap = creation_cap
+        rt.compiler._step = jax.jit(rt.compiler.make_step(),
+                                    donate_argnums=(0,))
+    return rt
+
+
+@pytest.mark.parametrize("lanes", [0, 4], ids=["single", "vmap_lanes"])
+@pytest.mark.parametrize("creation_cap", [None, 8],
+                         ids=["exact_growth", "creation_budget"])
+def test_blocked_step_compiles_to_no_scatter(lanes, creation_cap):
+    """Every stage's survivor pack (and the creation budget's, where one is
+    set) is index-once, gather-n: the optimized HLO of the jitted step holds
+    gathers and not one scatter, alone and vmapped over lanes."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rt = blocked_runtime(CHAIN4, creation_cap, slot_capacity=16,
+                         batch_capacity=32)
+    nfa = rt.compiler
+    b = rt.builder.emit()
+    args = (nfa.init_state(), b["cols"], b["tag"], b["ts"],
+            jnp.asarray(b["ts_base"]), jnp.asarray(np.int32(b["count"])))
+    step = nfa.make_step()
+    if lanes:
+        step = jax.vmap(step)
+        args = jax.tree.map(
+            lambda x: jnp.broadcast_to(jnp.asarray(x)[None],
+                                       (lanes,) + jnp.shape(x)), args)
+    text = jax.jit(step).lower(*args).compile().as_text()
+    opcodes = re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(",
+                         text, re.M)
+    assert not re.search(r"\bscatter\b", text), sorted(set(opcodes))
+    # the mechanism engaged: one gather at least for every waiting state
+    assert opcodes.count("gather") >= nfa.S - 1
+
+
+def test_creation_budget_counts_what_it_drops_and_keeps_the_oldest():
+    """``creation_cap`` is the same pack at K slots: with room it changes
+    nothing, without it the newest creations of a stage drop and are
+    counted."""
+    app = """
+    define stream S (v double);
+    from every e1=S[v > 0.0] -> e2=S[v > 1000.0] -> e3=S[v > 2000.0]
+    select e1.v as a, e2.v as b, e3.v as c insert into O;
+    """
+    events = [("S", [float(i + 1)], 1000 + i) for i in range(12)] + \
+        [("S", [1500.0], 1100), ("S", [2500.0], 1101)]
+
+    def run(cap):
+        rt = blocked_runtime(app, cap, slot_capacity=32, batch_capacity=16)
+        rows = []
+        rt.add_callback(rows.extend)
+        for sid, row, ts in events:
+            rt.send(sid, row, ts)
+        rt.flush()
+        return sorted(list(r) for r in rows), rt.drop_count
+
+    exact, drops = run(None)
+    assert drops == 0 and len(exact) == 12
+    assert run(14) == (exact, 0)
+    # 12 seeds, then the 1500.0 event itself seeds: the budget of 5 keeps
+    # the five oldest creations of the batch
+    capped, drops = run(5)
+    assert capped == exact[:5] and drops > 0
