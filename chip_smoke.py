@@ -9,10 +9,10 @@ row that comes out with the scalar interpreter (the same app text without
 - S1  served, single stream: BASELINE.json config #1's shape (filter +
       ``window.length(1000)`` + group-by aggregate), 1,000,000 events through
       ``InputHandler.send_columns``;
-- S2  served, pattern: bench.py's 8-state rising chain without the partition
-      wrapper, 200,000 events through per-event ``InputHandler.send`` (the
+- S2  served, pattern: the north star's 8-state rising chain without the
+      partition wrapper, 200,000 events through per-event ``InputHandler.send`` (the
       bridge's only ingress for pattern/join queries today), blocked kernel;
-- S3  the flagship kernel at the bench's shape: ``PartitionedNFARuntime``,
+- S3  the flagship kernel at the north star's shape: ``PartitionedNFARuntime``,
       64 lanes x 2048 x 8 states, 1,000,000 events over 1,024 keys through the
       C++ ingress built in this run;
 - S3b served, partition: ``S3_APP`` with an ``@device(...)`` line deployed
@@ -292,7 +292,7 @@ def stage_s1(cfg, seed, platform, warnings, keep):
 # ---------------------------------------------------------------------------
 
 def rising_chain(first: str, within: int, select_key: bool) -> str:
-    """bench.py's N-state rising chain: e1 over a threshold, every later
+    """The N-state rising chain: e1 over a threshold, every later
     state above the one before it."""
     states = " -> ".join(
         f"e{i}=S[v > e{i - 1}.v]" if i > 1 else f"e1=S[{first}]"
@@ -339,7 +339,7 @@ def stage_s2(cfg, seed, platform, warnings, keep):
 
 
 # ---------------------------------------------------------------------------
-# S3 / S4 — the flagship kernel at the bench's shape
+# S3 / S4 — the flagship kernel at the north star's shape
 # ---------------------------------------------------------------------------
 
 S3_APP = ("define stream S (dev string, v double);\n"

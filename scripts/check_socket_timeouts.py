@@ -3,7 +3,7 @@
 
 The multi-host fault-tolerance layer turns a wedged peer into a DETECTED
 failure — but only if every blocking socket operation carries a timeout
-(the BENCH_r05 smoke deadline was a `recv` with none). This script fails
+(a smoke run once hung on a `recv` with none). This script fails
 on:
 
 - ``socket.create_connection(...)`` / ``create_connection(...)`` calls that

@@ -59,7 +59,7 @@ def main() -> int:
     )
     from siddhi_tpu.core.stream import InputHandler, StreamJunction
     from siddhi_tpu.fleet.group import FleetGroup
-    from siddhi_tpu.flow.adaptive_batch import AdaptiveFlushMixin
+    from siddhi_tpu.tpu.step_runtime import StepRuntime
     from siddhi_tpu.observability import DeviceStepProbe, phase_of_stage
     from siddhi_tpu.resilience.device_guard import DeviceGuard
     from siddhi_tpu.resilience.fleet_guard import FleetGuard
@@ -81,8 +81,8 @@ def main() -> int:
     check("device bridge registers pending traces at packing",
           "probe.pending" in src(DeviceQueryBridge.on_event))
     check("every flush seals its trace group at the emit",
-          "_seal" in src(AdaptiveFlushMixin._maybe_flush)
-          or "step_sealer" in src(AdaptiveFlushMixin._seal))
+          "_seal" in src(StepRuntime._maybe_flush)
+          or "step_sealer" in src(StepRuntime._seal))
     check("driver egress observes every consumed batch (probe drains FIFO)",
           "observe" in src(AsyncDeviceDriver._collect_oldest)
           and "phases" in src(AsyncDeviceDriver._collect_oldest))
@@ -97,10 +97,10 @@ def main() -> int:
               "decode_s", "lock_s", "ring_s", "deliver.lock",
               "deliver.publish"))
           and "ring_wait" in src(AsyncDeviceDriver.submit)
-          and "collect.fence" in src(AdaptiveFlushMixin._fence)
-          and "seal.pack" in src(AdaptiveFlushMixin._emit_batch))
+          and "collect.fence" in src(StepRuntime._fence)
+          and "seal.pack" in src(StepRuntime._emit_batch))
     check("sync path measures the same split",
-          "decode_s" in src(AdaptiveFlushMixin._timed_process))
+          "decode_s" in src(StepRuntime._timed_process))
     check("probe closes fill-wait + device spans per batch",
           "fill-wait" in src(DeviceStepProbe.on_step)
           and "add_span" in src(DeviceStepProbe.on_step))

@@ -297,7 +297,7 @@ class CsvColumnParser:
     malformed-line accounting, built from per-line splits.
 
     ``ts_last=True`` reads a trailing int64 event-time field per line
-    (the bench corpus / DCN convention); otherwise ``ts`` is None and the
+    (the DCN convention); otherwise ``ts`` is None and the
     engine stamps arrival time.
     """
 

@@ -29,7 +29,6 @@ from ..query_api import (
     StateInputStream,
 )
 from ..query_api.annotation import find_annotation
-from ..flow.adaptive_batch import AdaptiveFlushMixin
 from ..observability.profiler import span
 from .egress import ChunkEgress
 from .event import EventType, StreamEvent
@@ -84,8 +83,7 @@ class AsyncDeviceDriver:
         self.depth = max(1, depth)
         # in-flight dispatch window: 2 = double buffering; runtimes whose
         # collect() reads live state (hopping drain) pin it to 1
-        self.window = max(1, window) \
-            if getattr(rt, "pipeline_safe", True) else 1
+        self.window = max(1, window) if rt.pipeline_safe else 1
         self._q = collections.deque()            # packed, undispatched
         self._inflight = collections.deque()     # (batch, token, disp_s, err)
         self._cv = threading.Condition()
@@ -150,13 +148,11 @@ class AsyncDeviceDriver:
         hold the engine lock, and a d2h fetch under _cv would freeze
         ingress for its whole round-trip."""
         self._since_drained = 0
-        drained = getattr(self.rt, "on_drained", None)
-        if drained is not None:
-            try:
-                drained()
-            except Exception:   # noqa: BLE001 — bookkeeping must not kill
-                # the sole device worker
-                log.exception("on_drained failed")
+        try:
+            self.rt.on_drained()
+        except Exception:   # noqa: BLE001 — bookkeeping must not kill the
+            # sole device worker
+            log.exception("on_drained failed")
 
     def _next_action(self):
         with self._cv:
@@ -196,8 +192,8 @@ class AsyncDeviceDriver:
     def _deadline_ms(self):
         """Wall-clock flush deadline for partial batches, or None when no
         latency-mode controller is attached."""
-        c = getattr(self.rt, "batch_controller", None)
-        if c is None or getattr(c, "mode", "throughput") != "latency":
+        c = self.rt.batch_controller
+        if c is None or c.mode != "latency":
             return None
         if not self._builder_staging():
             return None
@@ -296,26 +292,18 @@ class AsyncDeviceDriver:
             # so the phase attribution covers the whole serial waterfall
             # (fill → pack → ring wait → queue → dispatch → fence → decode
             # → lock wait → publish)
-            observe = getattr(rt, "observe_step", None)
-            if observe is not None:
-                t_emit = batch.get("_t_emit")
-                queue_s = max(0.0, t_disp0 - t_emit) \
-                    if t_emit is not None else 0.0
-                queue_s += max(0.0, t0 - (t_disp0 + disp_s))
-                ring_s = batch.get("_ring_wait_s", 0.0)
-                observe(batch.get("count", 0), dt, device_path=ok, phases={
-                    "fill_span_s": batch.get("pack_s", 0.0),
-                    "pack_s": batch.get("pack_exec_s", 0.0),
-                    "ring_s": ring_s,
-                    "queue_s": max(0.0, queue_s - ring_s),
-                    "step_s": disp_s,
-                    "route_s": batch.get("_route_s", 0.0),
-                    "fence_s": fence_s,
-                    "decode_s": collect_s - fence_s,
-                    "lock_s": lock_s,
-                    "publish_s": publish_s,
-                    "cause": batch.get("_cause"),
-                })
+            t_emit = batch.get("_t_emit")
+            queue_s = max(0.0, t_disp0 - t_emit) \
+                if t_emit is not None else 0.0
+            queue_s += max(0.0, t0 - (t_disp0 + disp_s))
+            ring_s = batch.get("_ring_wait_s", 0.0)
+            rt.observe_step(
+                batch.get("count", 0), dt, device_path=ok,
+                phases=rt.step_phases(
+                    batch, queue_s=max(0.0, queue_s - ring_s),
+                    step_s=disp_s, fence_s=fence_s,
+                    decode_s=collect_s - fence_s, ring_s=ring_s,
+                    lock_s=lock_s, publish_s=publish_s))
         except Exception:   # noqa: BLE001 — a raising observer must not
             # kill the sole device worker
             log.exception("step observer failed")
@@ -388,70 +376,6 @@ class AsyncDeviceDriver:
         self._thread.join(timeout=10.0)
 
 
-class _DeviceRTBase(AdaptiveFlushMixin):
-    """Shared packing→step dispatch for bridge runtimes: a full builder is
-    either handed to the async driver (packing overlaps compute) or stepped
-    synchronously.
-
-    The step is two-phase: ``dispatch(batch)`` fires the jitted step without
-    fencing (JAX async dispatch — state advances through donated buffers)
-    and returns the un-fetched output pytree; ``collect(token)`` fences at
-    the egress edge and decodes into one ``ColumnsOut`` chunk.
-    ``process`` is one dispatch immediately collected — the synchronous
-    path, and the shape the DeviceGuard wraps on both phases. Host-sync
-    bookkeeping that would stall the pipeline (counter checks read device
-    scalars) lives in ``on_drained``, which the driver calls whenever the
-    pipeline empties and the sync path calls after every flush."""
-
-    driver = None
-    callback = None
-    pipeline_safe = True    # False → the driver pins the window to 1
-
-    def dispatch(self, batch):
-        """Fire-and-forget device step: advances ``self.state`` and returns
-        the un-fenced output pytree as the egress token."""
-        self.state, out = self.compiled.step(self.state, batch)
-        return out
-
-    def collect(self, out):
-        """Egress fence + decode for one dispatched step: one ``ColumnsOut``
-        chunk (falsy when empty), its string codes already resolved so
-        that ``deliver`` holds the engine lock for the junction alone."""
-        self._fence(out["valid"])
-        with span(f"siddhi:collect.decode:{self.query_name}"):
-            chunk = self.compiled.decode_outputs(out)
-            chunk.decoded()
-            return chunk
-
-    def process(self, batch):
-        """Synchronous step + decode (async: worker thread, no engine lock —
-        device state is worker-owned)."""
-        return self.collect(self.dispatch(batch))
-
-    def on_drained(self):
-        """Called when the pipeline empties — the safe point for host-sync
-        bookkeeping (device_get with nothing in flight)."""
-
-    def deliver(self, out, emit_ts=None):
-        fn = self.callback
-        if fn and out:
-            fn(out, emit_ts)
-
-    def flush(self):
-        if len(self.builder) == 0:
-            return
-        b = self._emit_batch()
-        if self.driver is not None:
-            self.driver.submit(b)
-            return
-        self.deliver(self._timed_process(b), b.get("last_ts"))
-        self.on_drained()
-
-    def finalize(self):
-        """Terminal flush at shutdown (kernels that hold an open segment
-        override this via the runtime's ``finalize``)."""
-
-
 class _LimiterSink:
     """Terminal processor behind the bridge's host-side rate limiter."""
 
@@ -482,8 +406,7 @@ class DeviceQueryBridge(ChunkEgress):
                  output_junction, query_name: str, async_mode: bool = False,
                  output_rate=None, pipeline_window: int = 2):
         self.kind = kind        # 'stream' | 'nfa' | 'join' | 'partition'
-        self.runtime = runtime  # a _DeviceRTBase, DeviceNFARuntime or (kind
-        # 'partition') a served PartitionedNFARuntime
+        self.runtime = runtime  # the kind's StepRuntime (tpu/step_runtime.py)
         self.app_context = app_context
         self.stream_ids = stream_ids
         self.output_junction = output_junction
@@ -600,11 +523,9 @@ class DeviceQueryBridge(ChunkEgress):
 
     def finalize(self) -> None:
         """Shutdown barrier: emit what an open device segment still holds
-        (timeBatch terminal bucket — advisor r3)."""
+        (timeBatch's terminal bucket)."""
         self.flush(cause="final")
-        fin = getattr(self.runtime, "finalize", None)
-        if fin is not None:
-            fin()
+        self.runtime.finalize()
         if self.driver is not None:
             self.driver.flush_sync()
 
@@ -762,9 +683,8 @@ def try_build_device_query(query: Query, app_context, stream_defs: dict,
         target = _audit_device_surface(query, app_context, get_junction)
         ist = query.input_stream
         if isinstance(ist, SingleInputStream):
-            from ..tpu.batch import BatchBuilder
             from ..tpu.query_compile import CompiledStreamQuery
-            from .columns import ColumnsOut
+            from ..tpu.runtime import DeviceStreamRuntime
 
             d = stream_defs.get(ist.stream_id)
             if d is None:
@@ -779,147 +699,7 @@ def try_build_device_query(query: Query, app_context, stream_defs: dict,
                 raise DeviceCompileError(
                     "output rate limiting on windowed queries takes the "
                     "host path")
-
-            class _StreamRT(_DeviceRTBase):
-                def __init__(self):
-                    self.compiled = compiled
-                    self.builder = BatchBuilder(compiled.schema, batch)
-                    # drain steps run on the WORKER thread in async mode —
-                    # they must not touch the producer's live builder
-                    self._drain_builder = BatchBuilder(compiled.schema,
-                                                       batch)
-                    # hopping's collect() reads live state between steps:
-                    # the driver pins its dispatch window to 1
-                    self.pipeline_safe = compiled.window_kind != "hopping"
-                    self.state = compiled.init_state()
-                    # segment clock high-water: arrival ts, or the
-                    # externalTimeBatch attribute column
-                    self._tk_pos = (
-                        d.attribute_position(compiled.time_key)
-                        if compiled.time_key is not None else None)
-                    self._last_clk = None
-
-                def send(self, row, timestamp=0):
-                    clk = timestamp if self._tk_pos is None \
-                        else row[self._tk_pos]
-                    if clk is not None:
-                        self._last_clk = clk if self._last_clk is None \
-                            else max(self._last_clk, clk)
-                    self.builder.append(row, timestamp)
-                    self._maybe_flush()
-
-                def send_columns(self, cols, ts):
-                    """Bulk columnar staging: the chunk slice-copies into
-                    the builder (``append_columns``) across as many
-                    micro-batches as it spans — flush causes and adaptive
-                    thresholds behave exactly as per-event ``send``."""
-                    import numpy as np
-                    ts = np.asarray(ts, dtype=np.int64)
-                    n = int(ts.shape[0])
-                    if n == 0:
-                        return
-                    clk_col = ts if self._tk_pos is None else np.asarray(
-                        cols[compiled.time_key].materialize()
-                        if hasattr(cols[compiled.time_key], "materialize")
-                        else cols[compiled.time_key])
-                    try:
-                        clk = clk_col.max()
-                    except TypeError:    # object column with None values
-                        vals = [v for v in clk_col if v is not None]
-                        clk = max(vals) if vals else None
-                    if clk is not None:
-                        self._last_clk = clk if self._last_clk is None \
-                            else max(self._last_clk, clk)
-                    start = 0
-                    while start < n:
-                        take = self.builder.append_columns(cols, ts, start)
-                        start += take
-                        self._maybe_flush()
-                        if take == 0 and len(self.builder):
-                            # defensive: a full builder _maybe_flush did
-                            # not drain (no controller, capacity race)
-                            self.flush()
-
-                def finalize(self):
-                    """Force-close the open timeBatch bucket at shutdown: a
-                    sentinel event two windows past the last segment-clock
-                    value closes the terminal bucket the way the host's
-                    boundary timer does (advisor r3 — streams that stop
-                    sending must not lose their last bucket). For
-                    externalTimeBatch the sentinel carries the far-future
-                    value in the time ATTRIBUTE (the kernel's clock). The
-                    sentinel lands in its own far-future segment and never
-                    emits. Sessions need no terminal flush on this path:
-                    currents pass through per arrival."""
-                    if self.compiled.window_kind != "timeBatch" or \
-                            self._last_clk is None:
-                        return
-                    self.flush()
-                    sentinel = self._last_clk + \
-                        2 * max(int(self.compiled.window_ms), 1)
-                    row = [None] * len(self.compiled.schema.names)
-                    if self._tk_pos is not None:
-                        row[self._tk_pos] = sentinel
-                    # a guarded builder excludes the sentinel from its
-                    # host-fallback shadow (it is bookkeeping, not an event)
-                    append = getattr(self.builder, "append_sentinel",
-                                     self.builder.append)
-                    append(row, sentinel)
-                    self.flush()
-
-                def collect(self, out):
-                    """Egress fence + decode. Hopping drains deferred
-                    boundary flushes here with empty steps — the runtime is
-                    pipeline-unsafe, so the state read is this step's own —
-                    and their chunks follow the batch's in order."""
-                    self._fence(out["valid"])
-                    with span(f"siddhi:collect.decode:{self.query_name}"):
-                        chunks = [self.compiled.decode_outputs(out)]
-                        if self.compiled.window_kind == "hopping":
-                            from ..tpu.runtime import drain_hop_boundaries
-                            self.state = drain_hop_boundaries(
-                                self.compiled, self.state,
-                                self._drain_builder,
-                                lambda o: chunks.append(
-                                    self.compiled.decode_outputs(o)))
-                        chunk = ColumnsOut.concat(chunks)
-                        chunk.decoded()
-                    return chunk
-
-                def on_drained(self):
-                    # counter checks device_get state scalars — deferred to
-                    # drain points so they never stall the pipeline
-                    self._check_counters()
-
-                def _check_counters(self):
-                    # surface bounded-state overflow instead of silently
-                    # diverging from the host semantics
-                    for key, what in (("window_drops", "alive events evicted "
-                                       "(raise @device(window='N'))"),
-                                      ("ts_regressions", "out-of-order "
-                                       "timestamps clamped"),
-                                      ("group_collisions", "group-by keys "
-                                       "collided in the dense table (raise "
-                                       "@device key capacity)")):
-                        c = self.state.get(key)
-                        if c is None:
-                            continue
-                        c = int(c)
-                        if c > getattr(self, f"_warned_{key}", 0):
-                            log.warning("query '%s': %d %s", name, c, what)
-                            setattr(self, f"_warned_{key}", c)
-
-                def snapshot_state(self):
-                    from ..tpu.batch import device_state_snapshot
-                    return device_state_snapshot(self.state,
-                                                 self.compiled.schema)
-
-                def restore_state(self, st):
-                    from ..tpu.batch import device_state_restore
-                    self.state = device_state_restore(
-                        st, self.compiled.schema)
-
-            rt = _StreamRT()
+            rt = DeviceStreamRuntime(compiled=compiled)
             bridge = DeviceQueryBridge("stream", rt, app_context,
                                        [ist.stream_id], target, name,
                                        async_mode=async_mode,
@@ -928,25 +708,13 @@ def try_build_device_query(query: Query, app_context, stream_defs: dict,
             bridge.output_schema = ([s.name for s in compiled.specs],
                                     [s.dtype for s in compiled.specs])
         elif isinstance(ist, StateInputStream):
-            from ..tpu.nfa import DeviceNFACompiler, DeviceNFARuntime, MergedBatchBuilder
+            from ..tpu.nfa import DeviceNFACompiler, DeviceNFARuntime
 
             compiler = DeviceNFACompiler(query, stream_defs, slots, batch)
-
-            class _NFART(DeviceNFARuntime):
-                def __init__(self):
-                    self.compiler = compiler
-                    self.builder = MergedBatchBuilder(
-                        compiler.merged, batch, stream_defs,
-                        used_cols=compiler.used_cols)
-                    # absent-start seeds arm their clock at the app's start
-                    # time (host: seed placed at start() on the playback
-                    # clock)
-                    self.state = compiler.init_state(
-                        app_context.current_time())
-                    self.callback = None
-                    self.driver = None
-
-            rt = _NFART()
+            # absent-start seeds arm their clock at the app's start time
+            # (host: seed placed at start() on the playback clock)
+            rt = DeviceNFARuntime(compiler=compiler,
+                                  start_time=app_context.current_time())
             bridge = DeviceQueryBridge("nfa", rt, app_context,
                                        compiler.compiled.stream_ids, target,
                                        name, async_mode=async_mode,
@@ -955,49 +723,17 @@ def try_build_device_query(query: Query, app_context, stream_defs: dict,
             bridge.output_schema = ([n for n, _, _ in compiler.out_specs],
                                     [t for _, _, t in compiler.out_specs])
         elif isinstance(ist, JoinInputStream):
-            from ..tpu.join_compile import CompiledJoinQuery
-            from ..tpu.nfa import MergedBatchBuilder
+            from ..tpu.join_compile import (
+                CompiledJoinQuery,
+                DeviceJoinRuntime,
+            )
 
             ring = int(ann.get("ring") or 1024)
             joined = int(ann.get("joined") or 2048)
             compiled = CompiledJoinQuery(
                 query, dict(stream_defs), batch_capacity=batch,
                 ring_capacity=ring, joined_capacity=joined)
-
-            class _JoinRT(_DeviceRTBase):
-                def __init__(self):
-                    self.compiled = compiled
-                    self.builder = MergedBatchBuilder(
-                        compiled.merged, batch, dict(stream_defs))
-                    self.state = compiled.init_state()
-                    self._warned_drops = 0
-
-                def send(self, stream_id, row, timestamp=0):
-                    self.builder.append(stream_id, row, timestamp)
-                    self._maybe_flush()
-
-                def on_drained(self):
-                    # drop counters live in device state: check at drain
-                    # points (device_get would stall the pipeline per-step)
-                    drops = int(self.state["join_drops"]) + \
-                        int(self.state["ring_drops"])
-                    if drops > self._warned_drops:
-                        log.warning(
-                            "query '%s': %d joined rows/ring entries dropped "
-                            "(raise @device(joined=/ring=))", name, drops)
-                        self._warned_drops = drops
-
-                def snapshot_state(self):
-                    from ..tpu.batch import device_state_snapshot
-                    return device_state_snapshot(self.state,
-                                                 self.compiled.merged)
-
-                def restore_state(self, st):
-                    from ..tpu.batch import device_state_restore
-                    self.state = device_state_restore(
-                        st, self.compiled.merged)
-
-            rt = _JoinRT()
+            rt = DeviceJoinRuntime(compiled=compiled)
             bridge = DeviceQueryBridge(
                 "join", rt, app_context,
                 [compiled.left_id, compiled.right_id], target, name,

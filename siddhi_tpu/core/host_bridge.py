@@ -48,7 +48,7 @@ from ..query_api import (
     Variable,
 )
 from ..query_api.annotation import find_annotation
-from ..flow.adaptive_batch import AdaptiveFlushMixin
+from ..tpu.step_runtime import StepRuntime
 from .egress import ChunkEgress
 from .event import EventType, StreamEvent
 
@@ -92,17 +92,16 @@ def host_batch_config(app_annotations) -> Optional[dict]:
     return cfg
 
 
-class _HostRTBase(AdaptiveFlushMixin):
-    """Stage → step → deliver dispatch shared by the host runtimes.
-
-    ``process(batch) -> (ts_list, rows)`` is implemented per engine; rows
-    carry per-row event timestamps end to end."""
-
-    callback = None
-    driver = None               # host path is synchronous (no device queue)
+class _HostRTBase(StepRuntime):
+    """Stage → step → deliver for the host runtimes: ``StepRuntime``'s flush
+    rule, cause bookkeeping and ``observe_step``, with the host tier's own
+    step in place of the two-phase one. ``process(batch)`` is implemented
+    per engine and returns one chunk whose rows carry their own event
+    timestamps end to end, so ``deliver`` stamps nothing; nothing is sealed
+    for a probe and no driver takes the batch."""
 
     def add_callback(self, fn):
-        self.callback = fn
+        self.callback = fn          # fn(chunk)
 
     def deliver(self, out):
         fn = self.callback
@@ -118,6 +117,24 @@ class _HostRTBase(AdaptiveFlushMixin):
 
     def finalize(self):
         self.flush()
+
+    def _timed_process(self, batch: dict):
+        """The whole step is one serial ``host_exec`` segment."""
+        if self.batch_controller is None and self.step_observer is None:
+            return self.process(batch)
+        t0 = time.perf_counter()
+        try:
+            rows = self.process(batch)
+        except BaseException:
+            self.observe_step(batch.get("count", 0),
+                              time.perf_counter() - t0, device_path=False)
+            raise
+        dt = time.perf_counter() - t0
+        self.observe_step(batch.get("count", 0), dt, phases={
+            "fill_span_s": batch.get("pack_s", 0.0),
+            "pack_s": batch.get("pack_exec_s", 0.0),
+            "host_s": dt, "cause": batch.get("_cause")})
+        return rows
 
 
 class HostQueryBridge(ChunkEgress):

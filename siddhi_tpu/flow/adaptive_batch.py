@@ -1,8 +1,7 @@
 """Adaptive device micro-batching: batch size from observed rate + latency.
 
 TiLT (PAPERS.md) motivates adapting batch granularity to the observed
-arrival rate instead of a hand-tuned constant — which is exactly what the
-bench's ``BENCH_LAT_WINDOW``-style env knobs do today. The controller runs
+arrival rate instead of a hand-tuned constant. The controller runs
 AIMD over the *flush threshold* (a soft fill target ≤ the builder's static
 capacity, so jitted shapes never change):
 
@@ -16,9 +15,10 @@ capacity, so jitted shapes never change):
   the operating point.
 
 The chosen size is exported as the ``batch_size`` gauge and read by the
-device bridges' flush check (:class:`AdaptiveFlushMixin`). A flush
-*deadline* rides along: the suggested maximum time a partial batch may wait
-before being flushed, derived from the latency target and the observed
+served runtimes' flush check (``tpu/step_runtime.py``
+``StepRuntime._maybe_flush``, where it plugs in as ``batch_controller``). A
+flush *deadline* rides along: the suggested maximum time a partial batch may
+wait before being flushed, derived from the latency target and the observed
 arrival rate.
 
 **Latency mode** (``@app:adaptive(latency.target.ms='50')``): instead of
@@ -28,9 +28,9 @@ deadline-flush window of W events at arrival rate λ waits up to ``W/λ`` for
 the window to close and then one device step — so the controller sizes W so
 that predicted p99 (fill wait + observed p99 step) stays under the target,
 and the async driver enforces the remaining budget as a wall-clock deadline
-flush on partial batches (``flush_deadline_ms``). This is the knob that
-turns the r3 profile's 2.9s p99 (a queueing artifact of throughput-sized
-windows) into a tail bounded by ~2 step times.
+flush on partial batches (``flush_deadline_ms``): a throughput-sized
+window's queueing tail becomes one bounded by about two step times. Not
+measured on the chip: no benchmark cell carries the annotation.
 """
 
 from __future__ import annotations
@@ -38,10 +38,6 @@ from __future__ import annotations
 import collections
 import time
 from typing import Optional
-
-import numpy as np
-
-from ..observability.profiler import span
 
 
 class AdaptiveBatchController:
@@ -93,10 +89,10 @@ class AdaptiveBatchController:
                 arrival_evps: Optional[float] = None) -> int:
         """Report one stepped batch; returns the (possibly new) threshold.
         ``arrival_evps`` pins the arrival-rate estimate for callers whose
-        feed is not paced like real traffic (the bench's convergence loop
-        steps pre-packed windows back-to-back — its wall clock measures
-        device capacity, not arrivals) and suspends the internal wall-clock
-        estimator for this observation."""
+        feed is not paced like real traffic (pre-packed windows stepped
+        back-to-back: their wall clock measures device capacity, not
+        arrivals) and suspends the internal wall-clock estimator for this
+        observation."""
         self.observations += 1
         lat_ms = max(0.0, float(latency_s) * 1e3)
         self._lat_ms.append(lat_ms)
@@ -225,167 +221,6 @@ class AdaptiveBatchController:
             out["arrival_evps"] = round(self.arrival_evps)
             out["predicted_p99_ms"] = round(self.predicted_p99_ms, 3)
         return out
-
-
-class AdaptiveFlushMixin:
-    """Device-runtime hooks shared by every bridge runtime (stream/join
-    bridges in ``core/device_bridge.py``, the NFA runtime in ``tpu/nfa.py``):
-    flush when the builder hits its hard capacity OR the controller's soft
-    threshold, and feed sync-path step timings to the controller. Expects the
-    host class to provide ``builder`` (with ``full`` and ``__len__``),
-    ``flush()`` and ``process(batch)``."""
-
-    batch_controller = None     # AdaptiveBatchController via @app:adaptive
-    step_observer = None        # DeviceStepProbe.on_step (observability)
-    step_sealer = None          # DeviceStepProbe.seal — closes the probe's
-    # open trace group when a batch is emitted (FIFO group-per-batch)
-    flush_causes = None         # probe's flush-cause counter dict
-    flight = None               # FlightRecorder (observability wiring)
-    flight_site = ""
-    query_name = ""             # the profiler spans' <query> (bridge sets it)
-    fence_s = None              # the last collect's wait for the device,
-    # left by _fence for whoever called collect (driver thread, or the
-    # client on the sync path); None after a collect that never fenced
-    _pending_cause = None       # cause of the flush whose emit comes next
-
-    def _count_flush(self, cause: str) -> None:
-        fc = self.flush_causes
-        if fc is not None:
-            fc[cause] = fc.get(cause, 0) + 1
-        # the emitted batch inherits this cause (phase attribution keys the
-        # deadline-queueing share off it)
-        self._pending_cause = cause
-        f = self.flight
-        if f is not None:
-            # transition-recorded: only a CHANGE of flush cause lands on the
-            # flight timeline (capacity→deadline is the story; ten thousand
-            # capacity flushes are not)
-            f.record_transition("flow", f"flush:{cause}",
-                                site=self.flight_site)
-
-    def _take_cause(self):
-        c = self._pending_cause
-        self._pending_cause = None
-        return c
-
-    def _maybe_flush(self) -> None:
-        """Flush on the hard capacity OR the adaptive soft threshold (jitted
-        shapes stay static at capacity; only the fill level changes)."""
-        c = self.batch_controller
-        if self.builder.full:
-            self._count_flush("capacity")
-            self.flush()
-        elif c is not None and len(self.builder) >= c.current:
-            self._count_flush("adaptive")
-            self.flush()
-
-    def _seal(self) -> None:
-        """Close the probe's open trace group — call immediately before
-        ``builder.emit()`` (every flush implementation does), so trace
-        groups pair 1:1 with emitted batches."""
-        s = self.step_sealer
-        if s is not None:
-            s()
-
-    def _emit_batch(self) -> dict:
-        """Seal and emit the staged batch (every flush's first half): the
-        probe's trace group closes exactly at the emit, and the flush cause
-        rides the batch (phase attribution keys the deadline-queueing share
-        off it)."""
-        self._seal()
-        with span(f"siddhi:seal.pack:{self.query_name}"):
-            batch = self.builder.emit()
-        batch["_cause"] = self._take_cause()
-        return batch
-
-    def _fence(self, first) -> None:
-        """``collect``'s first half, told apart from the decode: wait until
-        the step's outputs are ready on the device, by fetching ``first``,
-        the output the decode reads first (the array keeps its host copy, so
-        the decode's own ``np.asarray`` of it is free). This is the one
-        synchronisation ``collect`` always had, in its place, plus that one
-        copy. A ``block_until_ready`` of the outputs ahead of it is the purer
-        fence and costs a paced query a millisecond of detection latency:
-        the first copy then no longer queues behind the step on the device
-        but waits for the host to wake and ask (PERF.md, PR 25)."""
-        t0 = time.perf_counter()
-        with span(f"siddhi:collect.fence:{self.query_name}"):
-            np.asarray(first)
-        self.fence_s = time.perf_counter() - t0
-
-    def observe_step(self, n_events: int, latency_s: float,
-                     device_path: bool = True,
-                     phases: Optional[dict] = None) -> None:
-        """Feed one stepped batch's latency to the adaptive controller and
-        the observability step probe (the async driver reports its own step
-        timing through this hook). ``device_path=False`` marks a step whose
-        work the resilience layer rerouted to the host interpreter — the
-        controller must not tune on it, but the probe still drains its
-        trace group. ``phases`` carries the batch's measured waterfall
-        segments (X-Ray phase attribution)."""
-        c = self.batch_controller
-        if c is not None and device_path:
-            c.observe(n_events, latency_s)
-        obs = self.step_observer
-        if obs is not None:
-            obs(n_events, latency_s, device_path, phases=phases)
-
-    def _timed_process(self, batch: dict):
-        """Sync-path step, timed for the controller/probe with the
-        dispatch/fence/decode split measured separately (the ``device_step``
-        / ``egress_fence`` / ``egress_decode`` phases; on the sync path
-        there is no ring, so ``ingress_queue`` is the emit→dispatch gap
-        alone)."""
-        if self.batch_controller is None and self.step_observer is None:
-            return self.process(batch)
-        cause = batch.get("_cause")
-        if getattr(self, "dispatch", None) is None:
-            # host-tier runtime (no two-phase step): the whole step is one
-            # serial host_exec segment
-            t0 = time.perf_counter()
-            try:
-                rows = self.process(batch)
-            except BaseException:
-                self.observe_step(batch.get("count", 0),
-                                  time.perf_counter() - t0,
-                                  device_path=False)
-                raise
-            dt = time.perf_counter() - t0
-            self.observe_step(batch.get("count", 0), dt, phases={
-                "fill_span_s": batch.get("pack_s", 0.0),
-                "pack_s": batch.get("pack_exec_s", 0.0),
-                "host_s": dt, "cause": cause})
-            return rows
-        q = self.query_name
-        self.fence_s = None
-        t0 = time.perf_counter()
-        try:
-            with span(f"siddhi:dispatch:{q}"):
-                token = self.dispatch(batch)
-            t1 = time.perf_counter()
-            with span(f"siddhi:collect:{q}"):
-                rows = self.collect(token)
-        except BaseException:
-            # a raising step still consumed its batch: the probe must pop
-            # this batch's trace group or every later device span would be
-            # attributed one batch off, forever
-            self.observe_step(batch.get("count", 0),
-                              time.perf_counter() - t0, device_path=False)
-            raise
-        t2 = time.perf_counter()
-        fence_s = self.fence_s if self.fence_s is not None else t2 - t1
-        t_emit = batch.get("_t_emit")
-        self.observe_step(batch.get("count", 0), t2 - t0, phases={
-            "fill_span_s": batch.get("pack_s", 0.0),
-            "pack_s": batch.get("pack_exec_s", 0.0),
-            "queue_s": max(0.0, t0 - t_emit) if t_emit is not None else 0.0,
-            "step_s": t1 - t0,
-            "route_s": batch.get("_route_s", 0.0),
-            "fence_s": fence_s,
-            "decode_s": t2 - t1 - fence_s,
-            "cause": cause,
-        })
-        return rows
 
 
 def parse_adaptive_annotation(ann) -> dict:

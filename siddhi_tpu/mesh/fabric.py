@@ -417,7 +417,7 @@ class MeshFabric:
     @staticmethod
     def _probe_devices(n: int) -> list:
         """Best-effort device binding: host i steps on jax device i of the
-        mesh (the forced-host CPU mesh in tests/bench, chips on hardware).
+        mesh (the forced-host CPU mesh in tests, chips on hardware).
         Without a live backend the binding stays None — placement and
         migration are device-agnostic."""
         try:

@@ -162,8 +162,8 @@ class PlacementPolicy:
     shapes place largest-population first, and each shape's tenants pack
     onto the fewest hosts — preferring hosts that already hold the shape —
     so per-host compiled-program counts stay near (shapes ÷ hosts) and
-    FleetGroups step wide. ``kind='random'`` is the control arm the bench
-    compares against (seeded shuffle, round-robin over free slots).
+    FleetGroups step wide. ``kind='random'`` is the control arm to
+    compare against (seeded shuffle, round-robin over free slots).
     """
 
     def __init__(self, kind: str = "locality", seed: int = 17):

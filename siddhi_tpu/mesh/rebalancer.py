@@ -35,7 +35,7 @@ _DEF_IMBALANCE = 2.0          # hot = load share > imbalance × fair share
 
 class MeshRebalancer:
     """One fabric's rebalancing loop. Drive :meth:`evaluate` explicitly
-    (tests, bench, an operator cron) or :meth:`start` the background
+    (tests, an operator cron) or :meth:`start` the background
     thread."""
 
     def __init__(self, fabric, interval_s: float = _DEF_INTERVAL_S,

@@ -12,8 +12,7 @@ and counters; PR 3 added the gaps this package closes:
   (``GET /siddhi-apps/{name}/trace``, ``?limit=`` / ``?stream=``);
 - **phase attribution** (``phases.py``) — always-on per-query per-phase
   ``LogHistogram``s whose means reconcile against the end-to-end mean by
-  construction (``GET /siddhi-apps/{name}/latency``, bench
-  ``latency_breakdown``);
+  construction (``GET /siddhi-apps/{name}/latency``);
 - **percentile latency** (``histogram.py``) — log-bucketed histograms
   (p50/p90/p99/p99.9) with OpenMetrics exemplar capture;
 - **exposition** (``prometheus.py``) — ``GET /metrics`` renders every

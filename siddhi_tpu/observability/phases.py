@@ -11,7 +11,7 @@ SUM to the end-to-end mean by construction and the per-phase p99s say
 where a tail came from.
 
 Phases (one vocabulary for span classification, the ``phase.*`` latency
-trackers, and the bench ``latency_breakdown`` line):
+trackers, and the ``/latency`` report):
 
 - ``ingress_parse`` — transport bytes → columns at the edge (CSV/SoA
   parse + dictionary encode in a columnar source, PR 11);

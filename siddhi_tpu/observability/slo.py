@@ -45,7 +45,7 @@ shared-lane fleet tier where "millions of users" actually live:
 
 Compliance is exported as ``siddhi_tpu_slo_*`` gauges and served at
 ``GET /siddhi-apps/{name}/slo``; the chaos soak
-(tests/test_slo.py + bench ``--slo-child``) proves a 10×-share burst
+(tests/test_slo.py) proves a 10×-share burst
 tenant leaves premium p99 in budget while best-effort absorbs the
 shedding.
 """

@@ -204,7 +204,7 @@ _RECV_SEQ: "weakref.WeakKeyDictionary[socket.socket, int]" = \
     weakref.WeakKeyDictionary()
 
 # Process-wide detections (receiver side). A worker surfaces its copy in
-# ``ping``/``evidence`` replies; the parent's copy feeds bench evidence.
+# ``ping``/``evidence`` replies; the parent's copy is its own evidence.
 WIRE_COUNTERS = {"crc_rejected": 0, "dup_frames_dropped": 0}
 
 

@@ -253,7 +253,7 @@ class WorkerServer:
     def op_wedge(self, h: dict, body: bytes):
         """Chaos op: arm (or clear, with 0) the gray-failure stall — every
         subsequent substantive op sleeps ``stall_s`` before dispatch while
-        pings keep answering. The bench gauntlet and tests wedge a LIVE
+        pings keep answering. Tests wedge a LIVE
         worker mid-run with this; production never calls it."""
         self._wedge_s = float(h.get("stall_s", 0) or 0)
         self.flight.record("procmesh", "chaos:wedge", f"w{self.index}",

@@ -16,7 +16,7 @@ can execute two ways:
 plan on the numpy backend) never imports jax — only touching a ``jnp``
 attribute does. That keeps the columnar host engine importable in processes
 that must not claim the chip: a chip belongs to one process, so the parent
-of a process that needs it (bench.py's parent, a procmesh supervisor) and
+of a process that needs it (a procmesh supervisor) and
 workers that only run the NumPy tiers stay clear of PJRT backend init.
 """
 

@@ -85,8 +85,8 @@ class StringDictionary:
         ``searchsorted`` against the known values (O(n log u) C-side string
         compares), with only UNSEEN values taking the Python ``encode``
         path. The per-event ``encode`` loop is the measured ingest
-        bottleneck at 1M ev/s (bench pack phase); ``np.unique`` over the
-        full array is 20× slower than this for low-cardinality streams."""
+        bottleneck; ``np.unique`` over the full array does more work than
+        this for low-cardinality streams."""
         import numpy as np
         arr = np.asarray(values)
         nulls = None
@@ -293,7 +293,7 @@ class BatchBuilder:
 
         Wired end-to-end since the mesh round: single-stream device
         bridges expose ``receive_columns`` (``core/device_bridge.py``
-        ``on_columns_chunk`` → ``_StreamRT.send_columns``), with the
+        ``on_columns_chunk`` → ``DeviceStreamRuntime.send_columns``), with the
         probe/trace FIFO stamped per CHUNK and the DeviceGuard shadow
         captured as lazy column slices — columnar chunks reach the device
         tier with zero per-event appends on the DCN-ingest → device

@@ -1,7 +1,7 @@
 """Where compiled XLA programs persist between processes.
 
 One rule for every entry point that compiles for the chip (``chip_smoke.py``,
-``bench.py``'s device children, ``__graft_entry__``): a cache directory placed
+``benchmark/run.py``, ``__graft_entry__``): a cache directory placed
 from outside (``JAX_COMPILATION_CACHE_DIR``) is the one JAX already reads and
 is left alone; otherwise the cache lives at ``<checkout>/.jax_cache`` — a
 fixed path, because the path is part of how a later process finds the entries

@@ -1365,8 +1365,7 @@ class DCNWorker:
 
 class DCNIngestClient:
     """External bulk-ingest feeder for one DCNWorker's data port — the
-    worker-owned ingest path of the procmesh runtime: a parent process (or
-    a bench feeder) frames rows as ``K_ROWS`` straight into a child's DCN
+    worker-owned ingest path of the procmesh runtime: a parent process frames rows as ``K_ROWS`` straight into a child's DCN
     data plane, never touching the control socket.
 
     Speaks the exact peer wire: ``(sender, group, epoch, seq)`` prefix,

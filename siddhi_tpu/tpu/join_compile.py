@@ -27,6 +27,7 @@ host path for now.
 
 from __future__ import annotations
 
+import logging
 from typing import Callable, Optional
 
 import jax
@@ -45,6 +46,9 @@ from ..query_api.definition import DataType, StreamDefinition
 from .dtypes import JNP as _JNP
 from .expr_compile import DeviceCompileError, compile_expression
 from .nfa import MergedBatchBuilder, MergedBatchSchema
+from .step_runtime import StepRuntime
+
+log = logging.getLogger("siddhi_tpu.device")
 
 _TS_NEG = -(2 ** 62)
 
@@ -467,43 +471,49 @@ def _compact_side(vals, mask, B, fill=0):
         jnp.where(mask, vals, jnp.asarray(fill, vals.dtype)), mode="drop")
 
 
-class DeviceJoinRuntime:
-    """Micro-batching front end over a compiled join (mirrors
-    ``DeviceNFARuntime``)."""
+class DeviceJoinRuntime(StepRuntime):
+    """The windowed stream-join's runtime: a ``MergedBatchBuilder`` of both
+    sides in front of one ``CompiledJoinQuery``. Built from a compiled plan
+    by the bridge (``compiled=``), or from app text when used by itself."""
 
-    def __init__(self, app_or_text, batch_capacity: int = 256,
+    def __init__(self, app_or_text=None, batch_capacity: int = 256,
                  ring_capacity: int = 1024, joined_capacity: int = 2048,
-                 query_index: int = 0):
-        from ..compiler import parse as _parse
-        app = _parse(app_or_text) if isinstance(app_or_text, str) else app_or_text
-        query = app.queries[query_index]
-        self.compiler = CompiledJoinQuery(
-            query, dict(app.stream_definitions), batch_capacity,
-            ring_capacity, joined_capacity)
+                 query_index: int = 0, compiled=None):
+        if compiled is None:
+            from ..compiler import parse as _parse
+            app = _parse(app_or_text) if isinstance(app_or_text, str) \
+                else app_or_text
+            compiled = CompiledJoinQuery(
+                app.queries[query_index], dict(app.stream_definitions),
+                batch_capacity, ring_capacity, joined_capacity)
+        self.compiled = compiled
         self.builder = MergedBatchBuilder(
-            self.compiler.merged, batch_capacity, dict(app.stream_definitions))
-        self.state = self.compiler.init_state()
-        self.callback: Optional[Callable[[list[list]], None]] = None
+            compiled.merged, compiled.B,
+            {compiled.left_id: compiled.left_def,
+             compiled.right_id: compiled.right_def})
+        self.state = compiled.init_state()
+        self._warned_drops = 0
 
-    def add_callback(self, fn) -> None:
-        self.callback = fn
-
-    def send(self, stream_id: str, row: list, timestamp: int) -> None:
+    def send(self, stream_id: str, row: list, timestamp: int = 0) -> None:
         self.builder.append(stream_id, row, timestamp)
-        if self.builder.full:
-            self.flush()
+        self._maybe_flush()
 
-    def flush(self, decode: bool = True):
-        if len(self.builder) == 0:
-            return None
-        batch = self.builder.emit()
-        self.state, out = self.compiler.step(self.state, batch)
-        if decode:
-            rows = self.compiler.decode_outputs(out).rows()
-            if self.callback is not None and rows:
-                self.callback(rows)
-            return rows
+    def dispatch(self, batch: dict):
+        self.state, out = self.compiled.step(self.state, batch)
         return out
+
+    def _decode(self, out):
+        return self.compiled.decode_outputs(out)
+
+    def on_drained(self) -> None:
+        # drop counters live in device state: read at drain points (a
+        # device_get per step would stall the pipeline)
+        drops = self.drop_count + self.ring_drop_count
+        if drops > self._warned_drops:
+            log.warning(
+                "query '%s': %d joined rows/ring entries dropped "
+                "(raise @device(joined=/ring=))", self.query_name, drops)
+            self._warned_drops = drops
 
     @property
     def drop_count(self) -> int:
@@ -515,8 +525,8 @@ class DeviceJoinRuntime:
 
     def snapshot_state(self):
         from .batch import device_state_snapshot
-        return device_state_snapshot(self.state, self.compiler.merged)
+        return device_state_snapshot(self.state, self.compiled.merged)
 
     def restore_state(self, state) -> None:
         from .batch import device_state_restore
-        self.state = device_state_restore(state, self.compiler.merged)
+        self.state = device_state_restore(state, self.compiled.merged)

@@ -50,8 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.pattern import CompiledPattern, PatternCompiler
-from ..flow.adaptive_batch import AdaptiveFlushMixin
-from ..observability.profiler import span
 from ..query_api import (
     Query,
     StateInputStream,
@@ -61,6 +59,7 @@ from ..query_api.definition import DataType, StreamDefinition
 from .batch import StringDictionary
 from .dtypes import JNP as _JNP, NP as _NP
 from .expr_compile import DeviceCompileError, compile_expression
+from .step_runtime import StepRuntime
 
 # Highest statically-referenced occurrence index `e[k]` a count state carries
 # on device. Each referenced k costs one bound column + set flag per slot; the
@@ -1770,7 +1769,7 @@ class DeviceNFACompiler:
         ``(state, cols, tag, ts, ts_base, nvalid) -> (state, ys)`` in the
         wire format (int32 ts deltas + int64 base scalar, validity = prefix
         ``[0, nvalid)``) — the composable surface ``vmap``/``shard_map``
-        wrappers (partition runtime, bench, ``__graft_entry__``) build on.
+        wrappers (partition runtime, ``__graft_entry__``) build on.
         ``self.step`` is the jitted single-lane convenience over the same
         function."""
         return self._make_step()
@@ -1799,80 +1798,43 @@ class DeviceNFACompiler:
                           nulls or None)
 
 
-class DeviceNFARuntime(AdaptiveFlushMixin):
-    """Micro-batching front end over a compiled NFA."""
+class DeviceNFARuntime(StepRuntime):
+    """The pattern / sequence query's runtime: a ``MergedBatchBuilder`` in
+    front of one compiled NFA. Built from a compiler by the bridge
+    (``compiler=``), or from app text when used by itself. NFA state carries
+    no host-sync bookkeeping, so dispatch N+1 overlaps collect N."""
 
-    def __init__(self, app_or_text, slot_capacity: int = 64,
+    fence_key = "mask"
+
+    def __init__(self, app_or_text=None, slot_capacity: int = 64,
                  batch_capacity: int = 1024, query_index: int = 0,
-                 start_time: int = 0):
-        from ..compiler import parse as _parse
-        app = _parse(app_or_text) if isinstance(app_or_text, str) else app_or_text
-        query = app.queries[query_index]
-        self.compiler = DeviceNFACompiler(
-            query, dict(app.stream_definitions), slot_capacity, batch_capacity)
+                 start_time: int = 0, compiler=None):
+        if compiler is None:
+            from ..compiler import parse as _parse
+            app = _parse(app_or_text) if isinstance(app_or_text, str) \
+                else app_or_text
+            compiler = DeviceNFACompiler(
+                app.queries[query_index], dict(app.stream_definitions),
+                slot_capacity, batch_capacity)
+        self.compiler = compiler
         self.builder = MergedBatchBuilder(
-            self.compiler.merged, batch_capacity, dict(app.stream_definitions),
-            used_cols=self.compiler.used_cols)
+            compiler.merged, compiler.B, dict(compiler.stream_defs),
+            used_cols=compiler.used_cols)
         # absent-start patterns arm their non-occurrence clock at the
         # runtime start time (host: seed placed at start() with the playback
         # clock's current value)
-        self.state = self.compiler.init_state(start_time)
-        self.callback: Optional[Callable] = None     # fn(chunk, emit_ts)
-        self.driver = None          # AsyncDeviceDriver when @async device mode
-
-    def add_callback(self, fn) -> None:
-        """``fn(rows)`` per batch, for the runtime used by itself.
-        ``callback`` is what ``deliver`` calls: ``fn(chunk, emit_ts)``."""
-        self.callback = lambda out, emit_ts=None: fn(out.rows())
+        self.state = compiler.init_state(start_time)
 
     def send(self, stream_id: str, row: list, timestamp: int) -> None:
         self.builder.append(stream_id, row, timestamp)
         self._maybe_flush()
 
-    # two-phase step (the async driver's double-buffered pipeline): dispatch
-    # fires the jitted step WITHOUT fencing (JAX async dispatch returns while
-    # the device computes); collect fences, then decodes. NFA state carries
-    # no host-sync bookkeeping, so dispatch N+1 can overlap collect N.
-    pipeline_safe = True
-
     def dispatch(self, batch: dict):
-        """Fire-and-forget device step: advances ``self.state`` (donated
-        buffers — the round-trip allocates nothing) and returns the
-        un-fenced output pytree as the egress token."""
         self.state, ys = self.compiler.step(self.state, batch)
         return ys
 
-    def collect(self, ys):
-        """Egress edge: fence + decode one dispatched step's outputs into
-        one ``ColumnsOut`` chunk, its string codes already resolved."""
-        self._fence(ys["mask"])
-        with span(f"siddhi:collect.decode:{self.query_name}"):
-            out = self.compiler.decode_outputs(ys)
-            out.decoded()
-            return out
-
-    def process(self, batch: dict):
-        """Synchronous step + decode (one dispatch immediately collected)."""
-        return self.collect(self.dispatch(batch))
-
-    def deliver(self, out, emit_ts=None) -> None:
-        fn = self.callback
-        if fn is not None and out:
-            fn(out, emit_ts)
-
-    def flush(self, decode: bool = True):
-        if len(self.builder) == 0:
-            return None
-        batch = self._emit_batch()
-        if self.driver is not None:
-            self.driver.submit(batch)
-            return None
-        if decode:
-            out = self._timed_process(batch)
-            self.deliver(out)
-            return out
-        self.state, ys = self.compiler.step(self.state, batch)
-        return ys
+    def _decode(self, ys):
+        return self.compiler.decode_outputs(ys)
 
     @property
     def match_count(self) -> int:

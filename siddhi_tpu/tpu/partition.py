@@ -26,11 +26,11 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..compiler import parse as _parse
-from ..flow.adaptive_batch import AdaptiveFlushMixin
 from ..observability.profiler import span
 from ..query_api.definition import DataType
 from .expr_compile import DeviceCompileError
 from .nfa import DeviceNFACompiler, MergedBatchBuilder
+from .step_runtime import StepRuntime
 
 log = logging.getLogger("siddhi_tpu.device")
 
@@ -56,8 +56,8 @@ def _inject_key_equality(query, key_attr: str):
     state PER KEY (``PartitionStreamReceiver.java:82-117``). Equivalent
     device semantics: every state after the first carries an implicit
     ``key == e1.key`` filter, so a partial only advances on its own key's
-    events. (Found by the bench oracle cross-check: without this, rising
-    chains stitched across different device ids.)
+    events. (Without this, rising chains stitched across different
+    device ids.)
 
     Sequences (strict continuity is per-key) and patterns whose first state
     binds no alias (absent/logical starts) can't be expressed this way —
@@ -249,7 +249,7 @@ class LaneBatchBuilder(MergedBatchBuilder):
         self.lane_full = False
 
 
-class PartitionedNFARuntime(AdaptiveFlushMixin):
+class PartitionedNFARuntime(StepRuntime):
     """P-lane partitioned pattern matching, optionally sharded over a mesh.
 
     ``partition with (<key> of <stream>)`` over a pattern query: every lane runs
@@ -258,17 +258,15 @@ class PartitionedNFARuntime(AdaptiveFlushMixin):
     Two ways in. **Served** (``batch=`` given; what ``@device`` on a
     ``partition with`` block builds, ``core/device_bridge.py``
     ``try_build_device_partition``): one flat :class:`LaneBatchBuilder` of
-    ``batch`` events, the interface ``DeviceNFARuntime`` has (``send`` /
-    ``send_columns``, ``dispatch`` / ``collect`` / ``process`` / ``deliver``,
-    ``flush``, ``on_drained``, ``snapshot_state`` / ``restore_state``) under
-    the bridge's driver, probe and guard. **Direct** (no ``batch``; the
-    bring-up and multi-host harnesses): one builder a lane in ``builders``,
-    ``send`` / ``send_many`` / ``ingest_csv`` and a synchronous ``flush``.
+    ``batch`` events and ``StepRuntime``'s protocol (``send`` /
+    ``send_columns`` in front, ``snapshot_state`` / ``restore_state``) under
+    the bridge's driver, probe and guard. **Direct** (no ``batch``;
+    ``chip_smoke.py`` S3/S4 and ``tpu/dcn.py``): one builder a lane in
+    ``builders``, ``send`` / ``ingest_csv`` and a synchronous
+    ``flush(decode=)``.
     Both step the same jitted ``vstep`` over ``[P, lane_batch]`` and decode
     its stacked outputs in one pass (``decode_stacked``).
     """
-
-    pipeline_safe = True        # no host-sync state between steps
 
     def __init__(self, app_or_text, num_partitions: int,
                  key_attr: str,
@@ -350,14 +348,13 @@ class PartitionedNFARuntime(AdaptiveFlushMixin):
         else:
             self._sharding = None
         # public jittable step over [P, ...]-stacked lane state and batches
-        # (the API bench/__graft_entry__ drive; donates the carried state)
+        # (what __graft_entry__ drives; donates the carried state)
         self.vstep = jax.jit(vstep, donate_argnums=(0,))
-        self._vstep = self.vstep      # backwards-compat alias
+        self._vstep = self.vstep      # what the direct flush steps
 
         self.state = self.init_state()
-        # direct use: fn(rows); served: the bridge's fn(chunk, emit_ts)
-        self.callback: Optional[Callable] = None
-        self.driver = None          # AsyncDeviceDriver when @device(async)
+        # direct use sets ``callback`` to an fn(rows); served, the bridge
+        # sets it to its fn(chunk, emit_ts)
         # read at drain points only (on_drained), never per step
         self.lane_gauges = {"fullest_table_share": 0.0,
                             "fullest_lane_events": 0, "drops": 0}
@@ -410,7 +407,7 @@ class PartitionedNFARuntime(AdaptiveFlushMixin):
     def enable_native_ingress(self) -> None:
         """Routes raw CSV bytes through the C++ data-loader (no Python in the
         per-event loop): parse → dict-encode → crc32 lane routing → SoA pack.
-        Single-input-stream patterns only (the bench/north-star shape)."""
+        Single-input-stream patterns only (the north-star shape)."""
         from ..native import NativeIngress
 
         if len(self.compiler.merged.stream_ids) != 1:
@@ -450,12 +447,12 @@ class PartitionedNFARuntime(AdaptiveFlushMixin):
                     rows.extend(out)
         return rows
 
-    def emit_native_feed(self) -> dict:
+    def flush_native(self, decode: bool = False):
         """Drains all native lanes into ONE stacked [P, ...] wire feed
-        (cols/tag/ts/ts_base/counts/count) WITHOUT stepping the device —
-        the packing half of ``flush_native``, exposed so a producer thread
-        (bench / AsyncDeviceDriver) can overlap C++ packing with device
-        compute."""
+        (cols/tag/ts/ts_base/counts) and steps it."""
+        decode = decode or self.callback is not None
+        if all(self._ning.lane_len(ln) == 0 for ln in range(self.P)):
+            return [] if decode else None
         batches = [self._ning.emit_lane(ln) for ln in range(self.P)]
         used = self.compiler.used_cols
         cols = {}
@@ -478,23 +475,12 @@ class PartitionedNFARuntime(AdaptiveFlushMixin):
         if over:
             # same loud-overflow policy as MergedBatchBuilder.emit
             self.ts_clamped = getattr(self, "ts_clamped", 0) + over
-            import logging
-            logging.getLogger("siddhi_tpu.device").warning(
-                "native lane ts span exceeds int32 ms; %d clamped",
-                self.ts_clamped)
+            log.warning("native lane ts span exceeds int32 ms; %d clamped",
+                        self.ts_clamped)
         ts = np.clip(deltas, 0, 2**31 - 1).astype(np.int32)
-        return {"cols": cols, "tag": tag, "ts": ts, "ts_base": base,
-                "counts": counts, "count": int(counts.sum())}
-
-    def flush_native(self, decode: bool = False):
-        decode = decode or self.callback is not None
-        if all(self._ning.lane_len(ln) == 0 for ln in range(self.P)):
-            return [] if decode else None
-        b = self.emit_native_feed()
         if decode:
             self._sync_dict_from_native()
-        return self._step_and_decode(b["cols"], b["tag"], b["ts"],
-                                     b["ts_base"], b["counts"], decode)
+        return self._step_and_decode(cols, tag, ts, base, counts, decode)
 
     def _sync_dict_from_native(self) -> None:
         # pull strings the C++ dict minted during ingest into the Python
@@ -571,7 +557,7 @@ class PartitionedNFARuntime(AdaptiveFlushMixin):
         return chunk
 
     def _maybe_flush(self) -> None:
-        """The mixin's rule plus the served partition's second seal: a
+        """``StepRuntime``'s rule plus the served partition's second seal: a
         batch that stopped short of its capacity because a lane is full
         flushes with the cause ``lane_full``."""
         b = self.builder
@@ -580,23 +566,6 @@ class PartitionedNFARuntime(AdaptiveFlushMixin):
             self.flush()
             return
         super()._maybe_flush()
-
-    def encode_columns(self, stream_id: str, cols: dict) -> dict:
-        """Dictionary-encode string columns on their DISTINCT values (the
-        per-event ``encode`` loop is the measured pack bottleneck)."""
-        d = self.stream_defs[stream_id]
-        si = self.compiler.merged.stream_index[stream_id]
-        enc = {}
-        for a in d.attributes:
-            v = cols.get(a.name)
-            if v is None:
-                continue
-            if a.type == DataType.STRING:
-                dic = self.compiler.merged.dictionaries[f"s{si}_{a.name}"]
-                enc[a.name] = dic.encode_array(v)
-            else:
-                enc[a.name] = np.asarray(v)
-        return enc
 
     def route_lanes(self, keys) -> np.ndarray:
         """Vectorized key→lane routing: crc32 runs once per DISTINCT key,
@@ -639,57 +608,12 @@ class PartitionedNFARuntime(AdaptiveFlushMixin):
             return self._grow_lane_table(dic)[enc[self.key_attr]]
         return self.route_lanes(cols[self.key_attr])
 
-    def partition_columns(self, stream_id: str, cols: dict, timestamps):
-        """The vectorized ingest front half: encode strings per distinct
-        value, route all rows with ONE stable argsort, return per-lane
-        column/timestamp views. ``send_many`` and the bench packer share
-        this path (no duplicate routing logic to drift)."""
-        ts = np.asarray(timestamps, dtype=np.int64)
-        enc = self.encode_columns(stream_id, cols)
-        lanes = self._lanes_for(stream_id, cols, enc)
-        order = np.argsort(lanes, kind="stable")
-        lanes_sorted = lanes[order]
-        enc_sorted = {k: v[order] for k, v in enc.items()}
-        ts_sorted = ts[order]
-        bounds = np.searchsorted(lanes_sorted, np.arange(self.P + 1))
-        lane_cols, lane_ts = [], []
-        for lane in range(self.P):
-            lo, hi = int(bounds[lane]), int(bounds[lane + 1])
-            lane_cols.append({k: v[lo:hi] for k, v in enc_sorted.items()})
-            lane_ts.append(ts_sorted[lo:hi])
-        return lane_cols, lane_ts
-
-    def send_many(self, stream_id: str, cols: dict, timestamps,
-                  decode: bool = False):
-        """Bulk ingest: route with ``partition_columns``, bulk-copy per-lane
-        slices into the wire builders, flushing as lanes fill. ``cols`` maps
-        attribute name to an array of values. Replaces the per-event
-        ``send`` loop on the hot path (reference analog:
-        ``StreamJunction.java:279-316``)."""
-        if getattr(self, "_ning", None) is not None:
-            raise RuntimeError(
-                "native ingress enabled: use ingest_csv(), not send_many()")
-        lane_cols, lane_ts = self.partition_columns(
-            stream_id, cols, timestamps)
-        out: list = []
-        for lane in range(self.P):
-            n = len(lane_ts[lane])
-            if n == 0:
-                continue
-            b = self.builders[lane]
-            pos = 0
-            while pos < n:
-                pos += b.append_many(stream_id, lane_cols[lane],
-                                     lane_ts[lane], start=pos)
-                if b.full:
-                    r = self.flush(decode=decode)
-                    if decode and r:
-                        out.extend(r)
-        return out if decode else None
-
     def flush(self, decode: bool = False):
+        """Served: ``StepRuntime.flush``. Direct: every lane's builder
+        emitted, stacked and stepped here, decoded when asked or when a
+        callback waits."""
         if self.builder is not None:
-            return self._flush_served()
+            return super().flush()
         # a registered callback implies decode — without this, the
         # auto-flush on a filled lane would silently discard every match
         # row found mid-stream (fuzz regression: match_count advanced while
@@ -743,7 +667,10 @@ class PartitionedNFARuntime(AdaptiveFlushMixin):
         return ColumnsOut(None, cols, int(idx.size), nfa.out_specs,
                           nfa.merged.dictionaries)
 
-    # -- the served interface (what DeviceNFARuntime has) ----------------------
+    # -- the served interface: StepRuntime's, with these supplied --------------
+    fence_key = "mask"
+    _decode = decode_stacked
+
     def dispatch(self, batch: dict):
         """Fire-and-forget step of one FLAT batch (arrival order, scalar
         ``count``, prefix ``valid``, a ``lane`` per event): laid out into
@@ -784,39 +711,6 @@ class PartitionedNFARuntime(AdaptiveFlushMixin):
         ts_base = np.full(lanes_n, batch["ts_base"], dtype=np.int64)
         return (cols, spread(batch["tag"]), spread(batch["ts"]), ts_base,
                 counts.astype(np.int32))
-
-    def collect(self, ys):
-        """Egress edge: fence on the mask, then decode the stacked outputs
-        into one ``ColumnsOut`` chunk, its string codes resolved."""
-        self._fence(ys["mask"])
-        with span(f"siddhi:collect.decode:{self.query_name}"):
-            out = self.decode_stacked(ys)
-            out.decoded()
-            return out
-
-    def process(self, batch: dict):
-        """Synchronous step + decode (one dispatch immediately collected)."""
-        return self.collect(self.dispatch(batch))
-
-    def deliver(self, out, emit_ts=None) -> None:
-        fn = self.callback
-        if fn is not None and out:
-            fn(out, emit_ts)
-
-    def _flush_served(self):
-        if len(self.builder) == 0:
-            return None
-        batch = self._emit_batch()
-        if self.driver is not None:
-            self.driver.submit(batch)
-            return None
-        out = self._timed_process(batch)
-        self.deliver(out, batch.get("last_ts"))
-        self.on_drained()
-        return out
-
-    def finalize(self) -> None:
-        """Nothing open at shutdown: a pattern holds no segment."""
 
     def on_drained(self) -> None:
         """Drain point (nothing in flight, or every 64th batch under load):

@@ -8,22 +8,25 @@ go to the callback.
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+import logging
 
 import jax
+import numpy as np
 
 from ..compiler import parse as _parse
-from ..query_api import Query, SiddhiApp
+from ..core.columns import ColumnsOut
 from .batch import BatchBuilder
 from .query_compile import CompiledStreamQuery
+from .step_runtime import StepRuntime
+
+log = logging.getLogger("siddhi_tpu.device")
 
 
 def drain_hop_boundaries(compiled, state, drain_builder, on_out):
     """Hopping defers boundary flushes past the per-step flush capacity (a
     long time gap can span more hops than one step covers): step EMPTY
     batches until the next boundary is in the future, handing each step's
-    outputs to ``on_out``. Shared by every hopping call site (sync flush,
-    pipeline collect, bridge runtimes) — returns the advanced state."""
+    outputs to ``on_out``. Returns the advanced state."""
     from .query_compile import _TS_NEG
     while True:
         hop_next, last_ts = (
@@ -36,84 +39,144 @@ def drain_hop_boundaries(compiled, state, drain_builder, on_out):
     return state
 
 
-class DeviceStreamRuntime:
-    def __init__(self, app_or_text, batch_capacity: int = 4096,
-                 group_capacity: int = 1024, query_index: int = 0,
-                 window_capacity: int = 4096):
-        app = _parse(app_or_text) if isinstance(app_or_text, str) else app_or_text
-        queries = app.queries
-        if not queries:
-            raise ValueError("no queries in app")
-        query = queries[query_index]
-        sid = query.input_stream.stream_id
-        if sid not in app.stream_definitions:
-            raise KeyError(f"stream '{sid}' not defined")
-        self.definition = app.stream_definitions[sid]
-        self.compiled = CompiledStreamQuery(
-            query, self.definition, batch_capacity, group_capacity,
-            window_capacity)
-        self.builder = BatchBuilder(self.compiled.schema, batch_capacity)
-        self.state = self.compiled.init_state()
-        self.callback: Optional[Callable[[list[list]], None]] = None
-        self._pending_out = []
-        # hopping steps host-sync on hop boundaries inside collect(): the
-        # pipeline must keep exactly one step in flight (window=1) so the
-        # state collect() reads is the dispatched step's own
-        self.pipeline_safe = self.compiled.window_kind != "hopping"
-        # empty-batch source for hop-boundary drain steps inside collect():
-        # the live builder may hold the NEXT batch's staged rows by then
-        self._drain_builder = BatchBuilder(self.compiled.schema,
-                                           batch_capacity)
+class DeviceStreamRuntime(StepRuntime):
+    """The single-stream query's runtime: a ``BatchBuilder`` in front of one
+    ``CompiledStreamQuery``. Built from a compiled plan by the bridge
+    (``compiled=``), or from app text when used by itself."""
 
-    def add_callback(self, fn: Callable[[list[list]], None]) -> None:
-        self.callback = fn
+    def __init__(self, app_or_text=None, batch_capacity: int = 4096,
+                 group_capacity: int = 1024, query_index: int = 0,
+                 window_capacity: int = 4096, compiled=None):
+        if compiled is None:
+            app = _parse(app_or_text) if isinstance(app_or_text, str) \
+                else app_or_text
+            queries = app.queries
+            if not queries:
+                raise ValueError("no queries in app")
+            query = queries[query_index]
+            sid = query.input_stream.stream_id
+            if sid not in app.stream_definitions:
+                raise KeyError(f"stream '{sid}' not defined")
+            compiled = CompiledStreamQuery(
+                query, app.stream_definitions[sid], batch_capacity,
+                group_capacity, window_capacity)
+        self.compiled = compiled
+        self.definition = compiled.definition
+        self.builder = BatchBuilder(compiled.schema, compiled.B)
+        # hopping's drain steps run inside _decode, on the driver's thread
+        # in async mode: they take their empty batches from a builder of
+        # their own, the live one may hold the NEXT batch's rows by then
+        self._drain_builder = BatchBuilder(compiled.schema, compiled.B)
+        # ... and read live state between steps, so the driver keeps exactly
+        # one step in flight (window=1): the state read is that step's own
+        self.pipeline_safe = compiled.window_kind != "hopping"
+        self.state = compiled.init_state()
+        # segment clock high-water: arrival ts, or the externalTimeBatch
+        # attribute column
+        self._tk_pos = (
+            self.definition.attribute_position(compiled.time_key)
+            if compiled.time_key is not None else None)
+        self._last_clk = None
+        self._warned: dict = {}     # overflow counters already warned of
 
     def send(self, row: list, timestamp: int = 0) -> None:
+        clk = timestamp if self._tk_pos is None else row[self._tk_pos]
+        if clk is not None:
+            self._last_clk = clk if self._last_clk is None \
+                else max(self._last_clk, clk)
         self.builder.append(row, timestamp)
-        if self.builder.full:
-            self.flush()
+        self._maybe_flush()
 
-    def flush(self, decode: bool = True) -> None:
-        if len(self.builder):
-            batch = self.builder.emit()
-            self.state, out = self.compiled.step(self.state, batch)
-            self._deliver(out, decode)
-        if self.compiled.window_kind == "hopping":
-            self.state = drain_hop_boundaries(
-                self.compiled, self.state, self._drain_builder,
-                lambda out: self._deliver(out, decode))
+    def send_columns(self, cols, ts) -> None:
+        """Bulk columnar staging: the chunk slice-copies into the builder
+        (``append_columns``) across as many micro-batches as it spans —
+        flush causes and adaptive thresholds behave exactly as per-event
+        ``send``."""
+        ts = np.asarray(ts, dtype=np.int64)
+        n = int(ts.shape[0])
+        if n == 0:
+            return
+        clk_col = ts
+        if self._tk_pos is not None:
+            col = cols[self.compiled.time_key]
+            clk_col = np.asarray(
+                col.materialize() if hasattr(col, "materialize") else col)
+        try:
+            clk = clk_col.max()
+        except TypeError:    # object column with None values
+            vals = [v for v in clk_col if v is not None]
+            clk = max(vals) if vals else None
+        if clk is not None:
+            self._last_clk = clk if self._last_clk is None \
+                else max(self._last_clk, clk)
+        start = 0
+        while start < n:
+            take = self.builder.append_columns(cols, ts, start)
+            start += take
+            self._maybe_flush()
+            if take == 0 and len(self.builder):
+                # defensive: a full builder _maybe_flush did not drain (no
+                # controller, capacity race)
+                self.flush()
 
-    # -- two-phase step (double-buffered pipeline) ---------------------------
     def dispatch(self, batch: dict):
-        """Fire the jitted step without fencing (JAX async dispatch): device
-        state advances through donated buffers, the un-fetched output pytree
-        is the token ``collect`` later fences at the egress edge."""
         self.state, out = self.compiled.step(self.state, batch)
         return out
 
-    def collect(self, out) -> list[list]:
-        """Egress fence + decode for one dispatched step (the np.asarray in
-        ``decode_outputs`` blocks until the step completed). Hopping windows
-        drain deferred boundary flushes here — pipeline-safe only at
-        window=1 (see ``pipeline_safe``)."""
-        rows = self.compiled.decode_outputs(out).rows()
-        if self.compiled.window_kind == "hopping":
-            self.state = drain_hop_boundaries(
-                self.compiled, self.state, self._drain_builder,
-                lambda o: rows.extend(
-                    self.compiled.decode_outputs(o).rows()))
-        return rows
+    def _decode(self, out):
+        """Hopping drains deferred boundary flushes here with empty steps,
+        and their chunks follow the batch's in order."""
+        chunk = self.compiled.decode_outputs(out)
+        if self.compiled.window_kind != "hopping":
+            return chunk
+        chunks = [chunk]
+        self.state = drain_hop_boundaries(
+            self.compiled, self.state, self._drain_builder,
+            lambda o: chunks.append(self.compiled.decode_outputs(o)))
+        return ColumnsOut.concat(chunks)
 
-    def process(self, batch: dict) -> list[list]:
-        return self.collect(self.dispatch(batch))
+    def finalize(self) -> None:
+        """Force-close the open timeBatch bucket at shutdown: a sentinel
+        event two windows past the last segment-clock value closes the
+        terminal bucket the way the host's boundary timer does (streams that
+        stop sending must not lose their last bucket). For externalTimeBatch
+        the sentinel carries the far-future value in the time ATTRIBUTE (the
+        kernel's clock). The sentinel lands in its own far-future segment
+        and never emits. Sessions need no terminal flush on this path:
+        currents pass through per arrival."""
+        if self.compiled.window_kind != "timeBatch" or \
+                self._last_clk is None:
+            return
+        self.flush()
+        sentinel = self._last_clk + 2 * max(int(self.compiled.window_ms), 1)
+        row = [None] * len(self.compiled.schema.names)
+        if self._tk_pos is not None:
+            row[self._tk_pos] = sentinel
+        # a guarded builder excludes the sentinel from its host-fallback
+        # shadow (it is bookkeeping, not an event)
+        append = getattr(self.builder, "append_sentinel",
+                         self.builder.append)
+        append(row, sentinel)
+        self.flush()
 
-    def _deliver(self, out, decode: bool) -> None:
-        if decode:
-            rows = self.compiled.decode_outputs(out).rows()
-            if self.callback is not None and rows:
-                self.callback(rows)
-        else:
-            self._pending_out.append(out)
+    def on_drained(self) -> None:
+        """Surface bounded-state overflow instead of silently diverging from
+        the host semantics. The counters are device scalars: read at drain
+        points, so they never stall the pipeline."""
+        for key, what in (("window_drops", "alive events evicted "
+                           "(raise @device(window='N'))"),
+                          ("ts_regressions", "out-of-order "
+                           "timestamps clamped"),
+                          ("group_collisions", "group-by keys "
+                           "collided in the dense table (raise "
+                           "@device key capacity)")):
+            c = self.state.get(key)
+            if c is None:
+                continue
+            c = int(c)
+            if c > self._warned.get(key, 0):
+                log.warning("query '%s': %d %s", self.query_name, c, what)
+                self._warned[key] = c
 
     @property
     def group_collision_count(self) -> int:

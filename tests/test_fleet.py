@@ -630,20 +630,3 @@ def test_shape_keys_structure_vs_constants():
     # INT vs DOUBLE constants compile differently ⇒ different key
     assert key_of("from S[n > 5] select v insert into Out;") != \
         key_of("from S[n > 5.5] select v insert into Out;")
-
-
-# ---------------------------------------------------------------------------
-# bench regression guard (BENCH_GUARD-gated, like the host tier's)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-@pytest.mark.skipif(os.environ.get("BENCH_GUARD", "") != "1",
-                    reason="BENCH_GUARD=1 runs the fleet bench guard")
-def test_fleet_bench_guard():
-    from importlib import util as iu
-    spec = iu.spec_from_file_location(
-        "check_bench_regression",
-        os.path.join(REPO, "scripts", "check_bench_regression.py"))
-    mod = iu.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert mod.run_fleet_guard(tol=0.5) == 0

@@ -9,11 +9,9 @@ multisets with f64-scale tolerance (``util_parity``).
 
 Also covers: per-query fallback mixes (one lowering + one interpreter query
 in the same app), the DeviceGuard quarantine fallback engine, snapshot/
-restore of columnar state, host_batch metrics, and the BENCH_GUARD-gated
-bench regression check (scripts/check_bench_regression.py).
+restore of columnar state, and host_batch metrics.
 """
 
-import os
 import random
 
 import pytest
@@ -447,25 +445,3 @@ def test_fuzz_parity(manager, seed):
     ref = run_scalar(manager, app, events)
     got, _ = run_columnar(manager, app, events, chunk, expect_bridges=1)
     assert_rows_match(ref["Out"], got["Out"])
-
-
-# ---------------------------------------------------------------------------
-# bench regression guard (CI hook; skipped unless BENCH_GUARD is set)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.skipif(not os.environ.get("BENCH_GUARD"),
-                    reason="bench regression guard runs only with "
-                           "BENCH_GUARD set")
-def test_bench_regression_guard():
-    import subprocess
-    import sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    # host tier only: the fleet guard has its own BENCH_GUARD-gated test
-    # (tests/test_fleet.py::test_fleet_bench_guard) — running it here too
-    # would double the bench and overrun this subprocess's 600s timeout
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "scripts",
-                                      "check_bench_regression.py")],
-        capture_output=True, text=True, timeout=600,
-        env={**os.environ, "BENCH_GUARD_SKIP_FLEET": "1"})
-    assert p.returncode == 0, f"{p.stdout}\n{p.stderr}"

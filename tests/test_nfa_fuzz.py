@@ -5,7 +5,7 @@ Same rationale as ``test_device_fuzz.py`` for stream queries: the 126-case
 corpus pins known reference behaviors; this sweep samples chain length ×
 predicate thresholds × count states × ``every`` × ``within`` × batch size
 on random data to hunt unknown divergences in the kernel the north-star
-bench rides. Fixed seeds — failures reproduce exactly."""
+query rides. Fixed seeds — failures reproduce exactly."""
 
 import random
 

@@ -1,8 +1,8 @@
-"""Partitioned-NFA differential fuzz — the bench's exact operating shape:
+"""Partitioned-NFA differential fuzz — the north star's operating shape:
 ``partition with (key of S)`` over a single-stream pattern, host oracle vs
 ``PartitionedNFARuntime`` (crc32 lanes → vmapped blocked/scan kernels).
 
-The bench cross-checks ONE workload's match count; this sweep samples chain
+``chip_smoke.py`` S3 checks ONE workload's rows; this sweep samples chain
 length × predicates × every × within × key cardinality × lane counts ×
 batch sizes and compares full match ROWS."""
 

@@ -122,8 +122,8 @@ def test_slo_config_reaches_tenant_and_controller(manager):
 def _run_storm(manager, tenants=8, feed=40_000, chunk=32, burst=10,
                budget_ms=50.0, batch=65536):
     # the opening window is deliberately oversized for the offered rate
-    # (the bench --slo-child protocol): the storm must OPEN in violation
-    # so the test proves the loop closing it
+    # (65,536 slots for 8 tenants' chunks of 32): the storm must OPEN in
+    # violation so the test proves the loop closing it
     """K fleet tenants, last one a best-effort burster at ``burst``× its
     share; returns (apps, group, controller, per-tenant counts)."""
     def klass(i):

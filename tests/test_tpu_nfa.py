@@ -158,8 +158,8 @@ def test_partitioned_per_key_semantics_on_shared_lanes():
     """`partition with` means per-KEY pattern instances. With more keys than
     lanes, a lane sees several keys interleaved — the implicit
     `key == e1.key` constraint must stop chains stitching across keys
-    (found by the bench oracle cross-check: device emitted cross-key
-    matches the host never produced)."""
+    (without it the device emitted cross-key matches the host never
+    produced)."""
     from siddhi_tpu import SiddhiManager, StreamCallback
 
     app = """

@@ -498,7 +498,7 @@ def _columnar_run(manager, name, armed, rows, tss, chunk=512):
 
 
 def test_observability_overhead_pin_on_columnar_micro_corpus(manager):
-    """Acceptance: the columnar bench micro-corpus with tracing at default
+    """Acceptance: the columnar micro-corpus with tracing at default
     sampling (1/16) + the always-on flight recorder armed runs within 5%
     of the disarmed throughput. Measured as PAIRED per-rep ratios with
     alternating order (armed-first on odd reps) so shared-machine noise —
