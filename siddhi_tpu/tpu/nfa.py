@@ -309,6 +309,7 @@ class _NFAResolver:
         self.current = current_state
         self.current_alias = current_alias
         self.touched: list = []        # (state, variant) bound refs resolved
+        self.ev_read: list = []        # ev_ keys resolved (candidate event)
         # backend the compiled predicate/output closures execute on (numpy
         # for the columnar host engine; default lazy jax.numpy)
         xp = getattr(nfa, "xp", None)
@@ -336,6 +337,7 @@ class _NFAResolver:
             if var.attribute not in nfa.compiled.alias_defs[a].attribute_names:
                 raise DeviceCompileError(f"unknown attribute '{var.attribute}'")
             nfa.used_ev_cols.add(key)
+            self.ev_read.append(f"ev_{key}")
             return f"ev_{key}", nfa.merged.columns[key]
         if alias not in nfa.alias_branch:
             raise DeviceCompileError(f"unknown alias '{alias}'")
@@ -382,6 +384,7 @@ class _NFAResolver:
         # fleet per-tenant parameter slots ride the event-column namespace
         # (every cols entry is ev_-prefixed in the step env); they are
         # injected at step time, never staged, so they are NOT used_ev_cols
+        self.ev_read.append(f"ev_{p.key}")
         return f"ev_{p.key}"
 
     def encode_string(self, key: str, value: str) -> int:
@@ -746,9 +749,13 @@ class DeviceNFACompiler:
         # carried boolean flag travels with the partial instead (host parity;
         # formerly a documented divergence)
         self.out_null_deps: list[set] = []
+        # ev_ keys the outputs read off the matching event: all that the
+        # blocked kernel's last stage fetches of it (nfa_block.py)
+        self.out_ev_keys: set[str] = set()
         for oa in attrs:
             resolver = _NFAResolver(self, out_ctx)
             fn, t = compile_expression(oa.expr, resolver)
+            self.out_ev_keys.update(resolver.ev_read)
             deps = set()
             for (q, key) in resolver.touched:
                 if key.startswith(f"b{q}x"):        # logical branch binding

@@ -21,7 +21,12 @@ and the whole batch resolves in S data-parallel stages:
                          & (j > born_p)  & pred_s(event_j, bindings_p)
            j*(p) = first j with grid[j, p]     (vectorized argmax)
            advanced partials become stage s+1's candidates with
-           born' = j*, bindings' = bindings + event_{j*}'s columns.
+           born' = j*, first_ts' = first_ts (the seed event's, carried),
+           bindings' = bindings + what the plan reads of event_{j*}:
+           state s's referenced attributes (plus its time under
+           element-level ``within``, its rank in a sequence, the outputs'
+           columns at the last stage), fetched ONCE a stage
+           (``first_hit``) and nothing else of the event.
 
 Each stage is one [B, P] masked grid — exactly the "candidate×event pairs as
 one grid per state per batch" shape the verdict names. Sequences add the
@@ -131,6 +136,14 @@ def _from_words(w, dtype):
                                         dtype)
 
 
+def _leaves_from_rows(rows, words, leaves):
+    """[n, W] rows of stacked words back to the leaves ``words`` were cut
+    from (``words[i]`` = ``_to_words(leaves[i])``)."""
+    parts = jnp.split(rows, np.cumsum([w.shape[1] for w in words])[:-1],
+                      axis=1)
+    return [_from_words(part, v.dtype) for part, v in zip(parts, leaves)]
+
+
 def pack_first(mask, n: int, vals, fills):
     """Order-preserving pack of the rows ``mask`` [P] marks into ``n`` slots:
     slot c takes the (c+1)-th marked row, slots past the last marked row
@@ -154,13 +167,52 @@ def pack_first(mask, n: int, vals, fills):
     leaves, tree = jax.tree.flatten(vals)
     words = [_to_words(v) for v in leaves]
     rows = jnp.concatenate(words, axis=1)[jnp.minimum(src, P - 1)]   # [n, W]
-    parts = jnp.split(rows, np.cumsum([w.shape[1] for w in words])[:-1],
-                      axis=1)
     packed = [
-        jnp.where(taken, _from_words(part, v.dtype), jnp.asarray(fill, v.dtype))
-        for v, part, fill in zip(leaves, parts, tree.flatten_up_to(fills))]
+        jnp.where(taken, got, jnp.asarray(fill, got.dtype))
+        for got, fill in zip(_leaves_from_rows(rows, words, leaves),
+                             tree.flatten_up_to(fills))]
     dropped = jnp.maximum(count[-1].astype(jnp.int64) - n, 0)
     return taken, jax.tree.unflatten(tree, packed), dropped
+
+
+def first_hit(grid, vals):
+    """What a stage takes from its [B, P] grid, in ONE pass over it:
+    ``adv`` [P] (some event advances the candidate), ``jstar`` [P] i32 (the
+    first that does; 0 where none) and ``{k: vals[k][jstar]}`` for the [B]
+    leaves of ``vals``, exactly as NumPy's ``any`` / ``argmax`` / index
+    would give them.
+
+    No gather: the leaves ride the reduce. It is the argmax's own variadic
+    reduce over the events, the carried tuple ``(j, word, ...)`` with the
+    smaller ``j`` winning, ``j = B`` where the grid is off; a leaf rides as
+    its 32-bit words (moved, never computed with: exact for every dtype, NaN
+    payloads and -0.0 included). On a v5e a gathered element costs 8.6-10.2
+    ns whatever the shape (17.2 ms for ``256 x 6592`` of them) where the
+    whole reduce of a ``256 x 320 x 6592`` grid, ``any`` and argmax
+    included, takes 2.6 ms with one word riding (PERF.md section 6, PR 32)."""
+    B = grid.shape[0]
+    leaves = list(vals.values())
+    words = [_to_words(v) for v in leaves]                 # [B, 1 or 2] each
+    cols = [c for w in words for c in w.T]                 # W of [B]
+    jidx = jnp.arange(B, dtype=jnp.int32)
+
+    def earlier(a, b):
+        first = a[0] <= b[0]
+        return tuple(jnp.where(first, x, y) for x, y in zip(a, b))
+
+    j, *rows = jax.lax.reduce(
+        [jnp.where(grid, jidx[:, None], B)]
+        + [jnp.broadcast_to(c[:, None], grid.shape) for c in cols],
+        [jnp.int32(B)] + [jnp.int32(0)] * len(cols), earlier, (0,))
+    adv = j < B
+    jstar = jnp.where(adv, j, 0)
+    # no event: event 0's words, what ``leaf[argmax]`` reads (never used: the
+    # candidate did not advance)
+    rows = [jnp.where(adv, r, c[0]) for r, c in zip(rows, cols)]
+    if not rows:
+        return adv, jstar, {}
+    return adv, jstar, dict(zip(vals, _leaves_from_rows(
+        jnp.stack(rows, axis=1), words, leaves)))
 
 
 def make_block_step(nfa: "DeviceNFACompiler"):
@@ -168,8 +220,9 @@ def make_block_step(nfa: "DeviceNFACompiler"):
     in the wire format (int32 ts deltas + int64 base, prefix validity).
 
     ys: {"mask": [P] bool, "j": [P] i32 (match event index, for ordering),
-         "ts": [P] i64 (match event timestamp), <out-name>: [P] ...}
-    where P = (S-1)*C + B for S > 1, else B.
+         <out-name>: [P] ...} and nothing else
+    where P = (S-1)*C + B for S > 1, else B. A match's timestamp is the
+    batch's ``ts[j]``, which the host holds: it never leaves the device.
     """
     C, S, B = nfa.C, nfa.S, nfa.B
     states = nfa.states
@@ -177,6 +230,7 @@ def make_block_step(nfa: "DeviceNFACompiler"):
     is_seq = nfa.is_sequence
     referenced = sorted(nfa.referenced)
     out_specs = nfa.out_specs
+    out_ev_keys = sorted(nfa.out_ev_keys)
     # optional creation budget: partials entering a state within one batch
     # are compacted to K entries (order-preserving; overflow counted in
     # `drops`), capping every stage's grid at [B, C+K]. Off by default —
@@ -195,16 +249,16 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                 return _JNP[t]
         raise KeyError(key)
 
-    def new_binding_cols(s: int, cols, idx=None):
-        """Bindings minted when state ``s`` consumes an event: b{s}_attr."""
+    def new_binding_cols(s: int, cols):
+        """Bindings minted when state ``s`` consumes an event: b{s}_attr,
+        as [B] columns of the batch (a stage fetches them by ``jstar``)."""
         out = {}
         sid = nfa.compiled.alias_defs[states[s].alias].id
         for (q, key, t) in referenced:
             if q == s:
                 attr = key[len(f"b{s}_"):]
                 mk = nfa.merged.col_key(sid, attr)
-                col = cols[mk].astype(_JNP[t])
-                out[key] = col if idx is None else col[idx]
+                out[key] = cols[mk].astype(_JNP[t])
         return out
 
     def step(state, cols, tag, ts, ts_base, nvalid):
@@ -231,7 +285,7 @@ def make_block_step(nfa: "DeviceNFACompiler"):
 
         if S == 1:
             # single-state every-pattern: each matching event IS a match
-            out = {"mask": gate0, "j": jidx, "ts": ts}
+            out = {"mask": gate0, "j": jidx}
             emit_env = dict(ev_env)
             for (q, key, t) in referenced:
                 if q == 0:
@@ -251,9 +305,11 @@ def make_block_step(nfa: "DeviceNFACompiler"):
             if K is None or n <= K:
                 return cre, jnp.int64(0)
             vals = {k: v for k, v in cre.items() if k != "exists"}
-            fills = {"born": 0, "vb": 0, "first_ts": -1,
+            fills = {"born": 0, "first_ts": -1,
                      "bind": {k: 0 for k in cre["bind"]}}
-            if "last_ts" in cre:
+            if is_seq:
+                fills["vb"] = 0
+            if has_ew:
                 fills["last_ts"] = -1
             exists, out, dropped = pack_first(ex, K, vals, fills)
             out["exists"] = exists
@@ -264,16 +320,17 @@ def make_block_step(nfa: "DeviceNFACompiler"):
             cre0 = {
                 "exists": gate0,
                 "born": jidx,                                  # batch position
-                "vb": vidx,                                    # vidx[born]
                 "first_ts": ts,
                 "bind": new_binding_cols(0, cols),             # b0_* [B]
             }
+            if is_seq:
+                cre0["vb"] = vidx                              # vidx[born]
             if has_ew:
                 cre0["last_ts"] = ts
             creations, dropped = compact(cre0)
             drops = drops + dropped
 
-        out_mask = out_j = out_ts = None
+        out_mask = out_j = None
         out_cols = {}
 
         for s in range(1, S):
@@ -289,7 +346,8 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                 cand_born = jnp.concatenate(
                     [jnp.full((C,), -1, jnp.int32), creations["born"]])
                 cand_vb = jnp.concatenate(
-                    [jnp.zeros((C,), jnp.int32), creations["vb"]])
+                    [jnp.zeros((C,), jnp.int32), creations["vb"]]) \
+                    if is_seq else None
                 cand_first = jnp.concatenate(
                     [tbl["first_ts"], creations["first_ts"]])
                 cand_last = jnp.concatenate(
@@ -324,40 +382,46 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                 else:
                     grid = grid & (jidx[:, None] > cand_born[None, :])
 
-                adv = jnp.any(grid, axis=0)                    # [P]
-                jstar = jnp.argmax(grid, axis=0).astype(jnp.int32)
+                # ---- what the plan reads of the advancing event ---------
+                # a state's new bindings; its time under element-level
+                # `within`; its rank in a sequence; at the last stage the
+                # columns the outputs read. Nothing else is fetched.
+                live = new_binding_cols(s, cols)               # b{s}_* [B]
+                if s == S - 1:
+                    live.update({k: ev_env[k] for k in out_ev_keys})
+                else:
+                    if has_ew:
+                        live["last_ts"] = ts
+                    if is_seq:
+                        live["vb"] = vidx
+                adv, jstar, got = first_hit(grid, live)        # [P]
+                carried = {**cand_bind, **got}
 
                 if s == S - 1:
                     with jax.named_scope("nfa.emit"):
                         # ---- emission ------------------------------------
                         out_mask = adv
                         out_j = jstar
-                        out_ts = ts[jstar]
-                        emit_env = {k: v[jstar] for k, v in ev_env.items()}
-                        emit_env.update(cand_bind)
-                        emit_env.update(new_binding_cols(s, cols, idx=jstar))
                         for (name, fn, t) in out_specs:
                             out_cols[name] = jnp.broadcast_to(
-                                jnp.asarray(fn(emit_env)), (P,)).astype(
+                                jnp.asarray(fn(carried)), (P,)).astype(
                                     _JNP[t])
                         matches = matches + jnp.sum(adv.astype(jnp.int64))
                 else:
                     # ---- creations for state s+1 -------------------------
-                    nbind = {}
-                    for key in binding_keys(s + 1):
-                        if key in cand_bind:
-                            nbind[key] = cand_bind[key]
-                    nbind.update(new_binding_cols(s, cols, idx=jstar))
+                    # an advanced candidate existed, so it carries its seed
+                    # event's time already: first_ts is the candidate's own
                     cre_n = {
                         "exists": adv,
                         "born": jstar,
-                        "vb": vidx[jstar],
-                        "first_ts": jnp.where(cand_first >= 0, cand_first,
-                                              ts[jstar]),
-                        "bind": nbind,
+                        "first_ts": cand_first,
+                        "bind": {key: carried[key]
+                                 for key in binding_keys(s + 1)},
                     }
+                    if is_seq:
+                        cre_n["vb"] = got["vb"]
                     if has_ew:
-                        cre_n["last_ts"] = ts[jstar]
+                        cre_n["last_ts"] = got["last_ts"]
                     creations, dropped = compact(cre_n)
                     drops = drops + dropped
 
@@ -390,8 +454,7 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                     drops = drops + dropped
 
         new_state = {"tables": tables, "matches": matches, "drops": drops}
-        ys = {"mask": out_mask, "j": out_j, "ts": out_ts}
-        ys.update(out_cols)
+        ys = {"mask": out_mask, "j": out_j, **out_cols}
         return new_state, ys
 
     return step

@@ -370,8 +370,52 @@ def test_blocked_step_compiles_to_no_scatter(lanes, creation_cap):
     opcodes = re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(",
                          text, re.M)
     assert not re.search(r"\bscatter\b", text), sorted(set(opcodes))
-    # the mechanism engaged: one gather at least for every waiting state
-    assert opcodes.count("gather") >= nfa.S - 1
+    # the mechanism engaged: every waiting state's pack is one gather of
+    # rows (the creation budget packs the seeds and each stage's creations
+    # too), and no other gather is left: a stage fetches by jstar inside its
+    # reduce
+    packs = [g for g in _gathers(text) if "nfa.compact" in g[1]]
+    assert len(packs) == nfa.S - 1
+    assert opcodes.count("gather") == \
+        (2 if creation_cap is not None else 1) * (nfa.S - 1)
+
+
+def _gathers(hlo_text):
+    """``(result type, scope)`` of every gather of an optimized HLO."""
+    import re
+    return re.findall(
+        r"^\s*(?:ROOT )?%?[\w.\-]+ = (\S+) gather\(.*?op_name=\"([^\"]*)\"",
+        hlo_text, re.M)
+
+
+@pytest.mark.parametrize("lanes", [0, 4], ids=["single", "vmap_lanes"])
+def test_plain_chain_gathers_nothing_by_jstar(lanes):
+    """A plain keyed chain reads one thing of the event that advances a
+    candidate, the state's new binding (the outputs' column at the last
+    stage), and it rides the stage's reduce: the optimized HLO holds no
+    gather with a 64-bit result (the dead ``ts[jstar]``, which a TPU runs as
+    two) and none at all outside the survivor pack, alone and vmapped."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rt = blocked_runtime(CHAIN4, slot_capacity=16, batch_capacity=32)
+    nfa = rt.compiler
+    assert not nfa.is_sequence
+    assert not any(st.within_ms is not None for st in nfa.states)
+    b = rt.builder.emit()
+    args = (nfa.init_state(), b["cols"], b["tag"], b["ts"],
+            jnp.asarray(b["ts_base"]), jnp.asarray(np.int32(b["count"])))
+    step = nfa.make_step()
+    if lanes:
+        step = jax.vmap(step)
+        args = jax.tree.map(
+            lambda x: jnp.broadcast_to(jnp.asarray(x)[None],
+                                       (lanes,) + jnp.shape(x)), args)
+    gathers = _gathers(jax.jit(step).lower(*args).compile().as_text())
+    assert len(gathers) == nfa.S - 1
+    assert not [g for g in gathers if g[0][1:3] == "64"], gathers
+    assert all("nfa.compact" in g[1] for g in gathers), gathers
 
 
 def test_creation_budget_counts_what_it_drops_and_keeps_the_oldest():
@@ -402,3 +446,155 @@ def test_creation_budget_counts_what_it_drops_and_keeps_the_oldest():
     # the five oldest creations of the batch
     capped, drops = run(5)
     assert capped == exact[:5] and drops > 0
+
+
+# -- what a stage fetches from the advancing event (PR 32) --------------------
+
+FETCH_DTYPES = ["float32", "int32", "int64", "float64", "bool"]
+
+
+def _fetch_leaf(rng, dtype, n):
+    """A [n] leaf of ``dtype`` with the bit patterns a value-level move
+    would lose: NaNs with payloads, -0.0, numbers beyond 32 bits."""
+    import numpy as np
+
+    if dtype == "bool":
+        return rng.random(n) < 0.5
+    if dtype.startswith("float"):
+        bits = np.dtype(dtype).itemsize * 8
+        raw = rng.integers(0, 2**(bits - 1), n, dtype=np.uint64).astype(
+            f"uint{bits}")
+        leaf = raw.view(dtype).copy()
+        leaf[0] = -0.0
+        # a quiet and a signalling NaN, payloads set
+        leaf[1:3] = np.array(
+            [0x7FC00123, 0x7F800456] if bits == 32
+            else [0x7FF8000000000123, 0x7FF0000000000456],
+            f"uint{bits}").view(dtype)
+        return leaf
+    hi = 2**40 if dtype == "int64" else 2**31 - 1
+    return rng.integers(-hi, hi, n).astype(dtype)
+
+
+@pytest.mark.parametrize("lanes", [0, 3], ids=["single", "vmap"])
+@pytest.mark.parametrize("width", [1, 5], ids=["W1", "W5"])
+@pytest.mark.parametrize("dtype", FETCH_DTYPES)
+def test_first_hit_equals_numpy_reference(dtype, width, lanes):
+    """``first_hit`` = NumPy's ``any`` / ``argmax`` over the grid and
+    ``leaf[jstar]`` bit for bit, leaf by leaf, columns with no hit included
+    (they read event 0, as ``leaf[argmax]`` does), alone and under ``vmap``."""
+    import jax
+    import numpy as np
+
+    from siddhi_tpu.tpu.nfa_block import first_hit
+
+    B, P = 24, 70
+    rng = np.random.default_rng([FETCH_DTYPES.index(dtype), width, lanes])
+    L = max(lanes, 1)
+    grid = rng.random((L, B, P)) < 0.08
+    grid[:, :, :5] = False                         # columns with no hit
+    grid[:, 0, 5] = True                           # a hit on event 0
+    # `width` 32-bit words in all: 64-bit leaves are two words each
+    per = 2 if dtype in ("int64", "float64") else 1
+    n_leaves = max(width // per, 1)
+    vals = {f"k{i}": np.stack([_fetch_leaf(rng, dtype, B) for _ in range(L)])
+            for i in range(n_leaves)}
+
+    if lanes:
+        out = jax.jit(jax.vmap(first_hit))(grid, vals)
+    else:
+        out = jax.tree.map(
+            lambda x: x[None],
+            jax.jit(first_hit)(grid[0], {k: v[0] for k, v in vals.items()}))
+    adv, jstar, got = jax.tree.map(np.asarray, out)
+    np.testing.assert_array_equal(adv, grid.any(axis=1))
+    np.testing.assert_array_equal(jstar, grid.argmax(axis=1))
+    assert not adv[:, :5].any() and (jstar[:, :5] == 0).all()
+    uint = {1: np.uint8, 4: np.uint32, 8: np.uint64}
+    for k, v in vals.items():
+        assert got[k].dtype == v.dtype and got[k].shape == (L, P)
+        for lane in range(L):
+            want = v[lane][grid[lane].argmax(axis=0)]
+            u = uint[v.dtype.itemsize]
+            np.testing.assert_array_equal(got[k][lane].view(u), want.view(u))
+
+
+def test_first_hit_without_leaves_fetches_nothing():
+    import jax
+    import numpy as np
+
+    from siddhi_tpu.tpu.nfa_block import first_hit
+
+    grid = np.zeros((6, 9), bool)
+    grid[4, 2] = grid[5, 2] = True
+    adv, jstar, got = jax.jit(lambda g: first_hit(g, {}))(grid)
+    assert got == {} and bool(adv[2]) and int(jstar[2]) == 4
+    text = jax.jit(lambda g: first_hit(g, {})).lower(grid).compile().as_text()
+    assert " gather(" not in text
+
+
+PLAN_FEATURES = {
+    # what the plan states -> what a stage fetches of the advancing event
+    "plain": ("""
+        define stream S (sym string, v double);
+        from every e1=S[v > 20.0] -> e2=S[sym == e1.sym and v > e1.v]
+          -> e3=S[v > e2.v] within 6000
+        select e1.sym as s, e1.v as a, e2.v as b, e3.v as c insert into O;
+        """, dict(seq=False, ew=False)),
+    "sequence": ("""
+        define stream S (sym string, v double);
+        from every e1=S[v > 10.0], e2=S[v > e1.v], e3=S[v > e2.v]
+        select e1.v as a, e2.v as b, e3.v as c insert into O;
+        """, dict(seq=True, ew=False)),
+    "element_within": ("""
+        define stream S (sym string, v double);
+        from every e1=S[v > 20.0] -> e2=S[v > e1.v] within 120
+          -> e3=S[v > e2.v] within 200 -> e4=S[v > e3.v]
+        select e1.v as a, e2.v as b, e3.v as c, e4.v as d insert into O;
+        """, dict(seq=False, ew=True)),
+    "two_attributes_bound": ("""
+        define stream S (sym string, v double);
+        from every e1=S[v > 20.0] -> e2=S[v > e1.v]
+          -> e3=S[sym == e2.sym and v > e2.v] -> e4=S[v > e3.v] within 6000
+        select e1.v as a, e2.sym as s2, e2.v as b, e3.v as c, e4.sym as s4,
+               e4.v + e1.v as d insert into O;
+        """, dict(seq=False, ew=False)),
+    "single_state": ("""
+        define stream S (sym string, v double);
+        from every e1=S[v > 35.0]
+        select e1.sym as s, e1.v as a insert into O;
+        """, dict(seq=False, ew=False)),
+}
+
+
+@pytest.mark.parametrize("batch", [64, 7], ids=["one_batch", "small_batches"])
+@pytest.mark.parametrize("feature", list(PLAN_FEATURES))
+def test_plan_features_match_the_interpreter(feature, batch):
+    """Each thing a stage may fetch by ``jstar`` (a new binding, two of them,
+    the rank in a sequence, the time under element-level `within`, the
+    output's event columns) against the scalar interpreter, and the step's
+    outputs hold ``mask``, ``j`` and the output columns and nothing else."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    app, plan = PLAN_FEATURES[feature]
+    events = gen_one_stream(140, 50 + list(PLAN_FEATURES).index(feature))
+    rows, rt = device(app, events, slot_capacity=64, batch_capacity=batch)
+    nfa = rt.compiler
+    assert nfa.is_sequence == plan["seq"]
+    assert any(st.within_ms is not None for st in nfa.states) == plan["ew"]
+    assert rt.drop_count == 0
+    want = oracle(app, events)
+    assert len(want) > 3
+    assert_rows_match(want, rows)
+
+    # the tables carry what the plan reads and nothing for the rest
+    for tbl in nfa.init_state()["tables"].values():
+        assert ("last_ts" in tbl) == plan["ew"]
+    b = rt.builder.emit()
+    _, ys = nfa.make_step()(
+        nfa.init_state(), b["cols"], b["tag"], b["ts"],
+        jnp.asarray(b["ts_base"]), jnp.asarray(np.int32(b["count"])))
+    assert set(ys) == {"mask", "j"} | {name for name, _, _ in nfa.out_specs}
+    width = (nfa.S - 1) * nfa.C + nfa.B if nfa.S > 1 else nfa.B
+    assert all(v.shape == (width,) for v in ys.values())
