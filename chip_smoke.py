@@ -19,7 +19,9 @@ row that comes out with the scalar interpreter (the same app text without
       through ``SiddhiManager`` (the device branch of a ``partition with``
       block: one bridge, driver, probe and guard over lane-stacked tables),
       S3's oracle prefix through ``send_columns``, rows held to the
-      interpreter's;
+      interpreter's; then the same stream through the Kleene-closure block
+      of ``benchmark/configs/partitioned-kleene.siddhi`` (a count state: the
+      lanes step the per-event scan kernel), held to the interpreter too;
 - S4  S3 again with its lanes sharded over four chips (skipped on one);
 - S5  a compile sweep over every kind the device compilers accept.
 
@@ -60,7 +62,7 @@ FULL = {
     "s3_events": 1_000_000, "s3_keys": 1024, "s3_lanes": 64,
     "s3_lane_batch": 2048, "s3_slots": 512, "s3_oracle": 200_000,
     "s3_min_rows": 1000,
-    "s3b_batch": 32768,
+    "s3b_batch": 32768, "s3b_kleene_events": 100_000,
 }
 # --rehearsal: the same stages and shapes with the stream cut short and the
 # flagship's lane grid shrunk, so a CPU gets through in half a minute.
@@ -70,7 +72,7 @@ REHEARSAL = {
     "s2_events": 8_000, "s2_min_rows": 1,
     "s3_events": 40_000, "s3_keys": 128, "s3_lanes": 8,
     "s3_lane_batch": 256, "s3_oracle": 12_000, "s3_min_rows": 1,
-    "s3b_batch": 1024,
+    "s3b_batch": 1024, "s3b_kleene_events": 6_000,
 }
 N_STATES = 8
 # overflow counters of the device kernels (core/device_bridge.py warns on
@@ -452,49 +454,85 @@ def stage_s3(cfg, seed, platform, warnings, keep):
     return bad, facts
 
 
+# the Kleene-closure block of benchmark/configs/partitioned-kleene.siddhi on
+# S3_APP's stream and partition, at this stage's `within`
+S3B_KLEENE_APP = (
+    "define stream S (dev string, v double);\n"
+    "partition with (dev of S)\nbegin\n"
+    "from every e1=S[v > 50.0] -> e2=S[v > e1.v]<3:> -> e3=S[v < e1.v] "
+    "within 60000\n"
+    "select e1.v as v1, e2[0].v as first, e2[last].v as peak, e3.v as back "
+    "insert into Alerts;\nend;\n")
+
+
 def stage_s3b(cfg, seed, platform, warnings, keep):
     """The served partition branch: S3's app text with an ``@device`` line,
-    S3's oracle prefix as columnar chunks, S3's interpreter rows."""
+    S3's oracle prefix as columnar chunks, S3's interpreter rows; then the
+    Kleene-closure block (the scan kernel under the same bridge) on the
+    head of the same stream, against the interpreter."""
     import numpy as np
 
     if "oracle_rows" not in keep:
         return ["S3 did not run, nothing to compare with"], {}
     events = keep["events"][:cfg["s3_oracle"]]
-    n, chunk = len(events), 8192
+    chunk = 8192
     devs = np.array([e[0] for e in events], dtype=object)
     vs = np.array([e[1] for e in events], dtype=np.float64)
     ts = np.array([e[2] for e in events], dtype=np.int64)
     ann = (f"@device(strict='true', async='true', "
            f"batch='{cfg['s3b_batch']}', slots='{cfg['s3_slots']}', "
            f"lanes='{cfg['s3_lanes']}')")
-    facts = {"events": n}
 
-    def feed(rt):
-        ih = rt.input_handler("S")
-        for s in range(0, n, chunk):
-            ih.send_columns({"dev": devs[s:s + chunk], "v": vs[s:s + chunk]},
-                            ts[s:s + chunk])
+    def served(app, n, kernel, facts):
+        """``app`` with the ``@device`` line on the first ``n`` events:
+        (rows, failures)."""
+        def feed(rt):
+            ih = rt.input_handler("S")
+            for s in range(0, n, chunk):
+                e = min(s + chunk, n)
+                ih.send_columns({"dev": devs[s:e], "v": vs[s:e]}, ts[s:e])
 
-    def check(rt):
-        bad = served_failures(rt, n, platform)
-        if not bad:
-            bridge = rt.device_bridges[0]
-            facts.update(kind=bridge.kind, steps=bridge.probe.steps,
-                         compile_s=round(bridge.probe.compile_seconds, 2),
-                         flush_causes=dict(bridge.probe.flush_causes),
-                         lanes=dict(bridge.runtime.lane_gauges))
-            if bridge.kind != "partition" or bridge.driver is None:
-                bad.append(f"bridge kind '{bridge.kind}', driver "
-                           f"{bridge.driver}: not the served partition")
-        return bad
+        def check(rt):
+            bad = served_failures(rt, n, platform)
+            if not bad:
+                bridge = rt.device_bridges[0]
+                facts.update(kind=bridge.kind, steps=bridge.probe.steps,
+                             compile_s=round(bridge.probe.compile_seconds, 2),
+                             flush_causes=dict(bridge.probe.flush_causes),
+                             lanes=dict(bridge.runtime.lane_gauges),
+                             kernel=bridge.runtime.kernel)
+                if bridge.kind != "partition" or bridge.driver is None:
+                    bad.append(f"bridge kind '{bridge.kind}', driver "
+                               f"{bridge.driver}: not the served partition")
+                if bridge.runtime.kernel != kernel:
+                    bad.append(f"the lanes step the "
+                               f"{bridge.runtime.kernel} kernel, not the "
+                               f"{kernel} one")
+            return bad
 
-    warnings.drain()
-    rows, bad = run_app(S3_APP.replace("begin\n", "begin\n" + ann + "\n"),
-                        "Alerts", feed, check)
-    bad += [f"logged: {w}" for w in warnings.drain()]
+        warnings.drain()
+        rows, bad = run_app(app.replace("begin\n", "begin\n" + ann + "\n"),
+                            "Alerts", feed, check)
+        return rows, bad + [f"logged: {w}" for w in warnings.drain()], feed
+
+    facts = {"events": len(events)}
+    rows, bad, _ = served(S3_APP, len(events), "blocked", facts)
     differ = rows_failures(keep["oracle_rows"], rows, ordered=False)
     facts.update(rows=len(rows), rows_equal=not differ)
-    return bad + differ, facts
+
+    kleene = {"events": min(cfg["s3b_kleene_events"], len(events))}
+    k_rows, k_bad, feed = served(S3B_KLEENE_APP, kleene["events"], "scan",
+                                 kleene)
+    ref, ref_bad = run_app(S3B_KLEENE_APP, "Alerts", feed,
+                           reference_failures)
+    k_differ = rows_failures(ref, k_rows, ordered=False)
+    kleene.update(rows=len(k_rows), rows_equal=not k_differ)
+    if len(ref) < 50:
+        k_bad.append(f"{len(ref)} interpreter rows: the comparison would "
+                     f"prove little")
+    facts["kleene"] = kleene
+    return bad + differ + [f"kleene: {b}"
+                           for b in k_bad + ref_bad + k_differ], facts
 
 
 def stage_s4(cfg, seed, platform, warnings, keep):

@@ -761,11 +761,16 @@ def try_build_device_partition(partition_ast, app_context, stream_defs: dict,
     """The device branch of a ``partition with`` block: ONE bridge (kind
     ``'partition'``) over a served ``PartitionedNFARuntime`` when an inner
     query opts in via ``@device`` and the block is one value partition
-    ``partition with (<attr> of <Stream>)`` holding one blocked-eligible
-    pattern: keys hash to ``@device(lanes=)`` lane-stacked match tables and
-    every lane steps in one vmapped program. None -> the caller's tiers
-    (fleet, host partition, per-key interpreter); ``strict='true'`` raises
-    instead."""
+    ``partition with (<attr> of <Stream>)`` holding one pattern the device
+    NFA compiler takes: keys hash to ``@device(lanes=)`` lane-stacked match
+    tables and every lane steps in one vmapped program, the blocked kernel
+    for a chain of stream states under ``every``, the per-event scan for
+    count (``<m:n>``, a Kleene closure), logical and absent states. What
+    still keeps the caller's tiers (fleet, host partition, per-key
+    interpreter; None here, a raise under ``strict='true'``): sequences
+    (strictness is per key), first states that bind no alias, several
+    queries in the block, multi-stream, range or expression partitions,
+    non-pattern queries, and whatever the NFA compiler itself refuses."""
     from ..query_api import Variable
     from ..tpu.expr_compile import DeviceCompileError
 

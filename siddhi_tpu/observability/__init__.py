@@ -337,6 +337,11 @@ class ObservabilitySubsystem:
                     sm.gauge_tracker(
                         f"device.{q}.lanes_{g}",
                         lambda r=bridge.runtime, g=g: r.lane_gauges[g])
+                # which NFA kernel the lanes step: 1 = the per-event scan
+                # (count / logical / absent states), 0 = the blocked one
+                sm.gauge_tracker(
+                    f"device.{q}.lanes_kernel_scan",
+                    lambda r=bridge.runtime: int(r.kernel == "scan"))
             # egress by shape (core/egress.py): rows over deliveries is the
             # rows a delivery carries — a batch's, not one
             for shape, count in bridge.egress.items():
@@ -416,6 +421,7 @@ class ObservabilitySubsystem:
                 rep["egress"] = bridge.egress_report()
                 if bridge.kind == "partition":
                     rep["lanes"] = dict(bridge.runtime.lane_gauges)
+                    rep["kernel"] = bridge.runtime.kernel
         for q, phases in phase_queries.items():
             if q in out["queries"]:
                 continue

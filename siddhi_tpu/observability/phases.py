@@ -33,7 +33,7 @@ trackers, and the ``/latency`` report):
   the decode reads (the wait plus that one copy);
 - ``egress_decode`` — the rest of ``rt.collect``: the other copies to the
   host and the columns masked into one ``ColumnsOut`` chunk
-  (``decode_outputs`` / ``decode_block_outputs``) with its string codes
+  (``decode_outputs``) with its string codes
   resolved; no row is built here;
 - ``host_exec``     — host-tier execution (interpreter, columnar,
   fleet lanes, shadow replays);

@@ -15,7 +15,12 @@ unbounded cloned ``StateEvent`` lists) becomes:
   twice;
 - ``every`` is a carried seed counter (replenished when its scope completes),
   ``within`` is a timestamp mask that also reclaims expired slots, slot
-  exhaustion is an explicit drop-newest policy with an overflow counter.
+  exhaustion is an explicit drop-newest policy with an overflow counter;
+- the rows a batch emits leave the step as ONE table of static size ``M``
+  (``mask``, ``j`` = the closing event's index, a column per output and per
+  null mask), packed on the device after the scan (``pack_rows``): the
+  per-event ``[2, C]`` emit grids never leave it. The blocked kernel
+  (``nfa_block.py``) hands out the same layout, so one decode serves both.
 
 Scope — 104/104 of the untimed reference pattern corpus compiles and
 matches the host oracle (pinned by ``tests/test_pattern_corpus.py::
@@ -253,6 +258,81 @@ class MergedBatchBuilder:
         self._ts[:n] = snap["ts"]
         if n:                   # restored rows re-arm the flush deadline
             self._pack_t0 = time.perf_counter()
+
+
+# ---------------------------------------------------------------------------
+# the scan kernel's rows, packed on the device
+# ---------------------------------------------------------------------------
+
+_GROUP = 128        # cells of one emit-grid group: a vector register's lanes
+
+
+def pack_rows(mask, cols: dict, n_rows: int):
+    """The rows a scanned batch emitted, out of its emit grids and into one
+    table of ``n_rows``: ``mask`` ``[B, R, C]`` (event, source, candidate;
+    R = the emit sources the plan uses, 1 or 2) marks them, ``cols`` holds a
+    ``[B, R, C]`` grid per output column and null mask. Returns ``({"mask",
+    "j", <col>..: [n_rows]}, lost)``: row r is the (r+1)-th marked cell in
+    row-major order (match event, then source, then candidate: the order a
+    boolean index over the grids walks), ``j`` its event; cells past the
+    table are counted in ``lost``.
+
+    Three levels, each a compare-and-count against running totals, because
+    one ``searchsorted`` over the ``B x RC`` cells would compare every row
+    with every cell (and a two-level one would hold ``n_rows x RC`` running
+    counts a lane: gigabytes at 256 lanes): a row's event from the events'
+    running row counts ``[n_rows, B]``; its group of ``_GROUP`` cells from
+    the event's running group counts ``[n_rows, RC / _GROUP]``; its cell
+    from the group's own cells ``[n_rows, _GROUP]``. The second and third
+    fetch one short row per table row, and so does every column: the
+    cell's group of values, of which a select-and-sum keeps the cell's. On
+    a v5e 442,368 rows of 128 f32 gathered so take 7.7 ms where as many
+    single elements out of the flat grid take 10.3 (PERF.md section 6,
+    PR 33). Nothing is scattered."""
+    B = mask.shape[0]
+    W = mask.shape[1] * mask.shape[2]
+    G = -(-W // _GROUP)
+
+    def groups(grid):
+        """A ``[B, R, C]`` grid as ``[B * G, _GROUP]`` rows of cells."""
+        return jnp.pad(grid.reshape(B, W), ((0, 0), (0, G * _GROUP - W))
+                       ).reshape(B * G, _GROUP)
+
+    cells = groups(mask)
+
+    def locate(rank, totals, size):
+        """Where the (rank+1)-th row falls among ``size`` running totals
+        ``[n_rows, size]`` (as many lie at or below rank as precede it),
+        and its rank inside."""
+        before = totals <= rank[:, None]
+        at = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), size - 1)
+        each = totals - jnp.pad(totals, ((0, 0), (1, 0)))[:, :-1]
+        return at, rank - jnp.sum(jnp.where(before, each, 0), axis=1)
+
+    upto_g = jnp.cumsum(
+        jnp.sum(cells, axis=1, dtype=jnp.int32).reshape(B, G), axis=1)
+    upto = jnp.cumsum(upto_g[:, -1])        # rows of events 0..b, [B]
+    r = jnp.arange(n_rows, dtype=jnp.int32)
+    taken = r < upto[-1]
+    j, rank = locate(r, jnp.broadcast_to(upto, (n_rows, B)), B)
+    g, rank = locate(rank, upto_g[j], G)
+    at = j * G + g                              # the row's group of cells
+    x, _ = locate(rank, jnp.cumsum(cells[at].astype(jnp.int32), axis=1),
+                  _GROUP)
+    here = jnp.arange(_GROUP, dtype=jnp.int32) == x[:, None]    # [n_rows, G]
+    out = {"mask": taken, "j": jnp.where(taken, j, 0)}
+    for name, grid in cols.items():
+        # the cell's group again, then its lane by select-and-sum over the
+        # value's bits (moved, never computed with: exact for every dtype)
+        bits = grid.astype(jnp.uint8) if grid.dtype == jnp.bool_ else \
+            jax.lax.bitcast_convert_type(
+                grid, jnp.dtype(f"uint{8 * grid.dtype.itemsize}"))
+        got = jnp.sum(jnp.where(here & taken[:, None], groups(bits)[at], 0),
+                      axis=1, dtype=bits.dtype)
+        out[name] = got != 0 if grid.dtype == jnp.bool_ else \
+            jax.lax.bitcast_convert_type(got, grid.dtype)
+    lost = jnp.maximum(upto[-1].astype(jnp.int64) - n_rows, 0)
+    return out, lost
 
 
 # ---------------------------------------------------------------------------
@@ -610,6 +690,18 @@ class DeviceNFACompiler:
         # quadratic [B, C+B] (measured: the quadratic term dominates the
         # step at B >= 1024; overflow drops are counted, drop-newest)
         self.creation_cap = creation_cap
+        # which scan-kernel tables can ever hold a partial: ``p0`` where
+        # state 0 keeps its own (a stream start seeds straight into ``p1``),
+        # ``p<s>`` where state s-1 pushes (a count's partials are pulled
+        # out of its own table), and mid-pattern ``every`` targets. A table
+        # that holds nothing is no candidate source (``_make_step``) and
+        # takes no room in the row table (``_row_capacity``).
+        self.table_holds = [
+            (s == 0 and st.kind != "stream")
+            or (s > 0 and self.states[s - 1].kind != "count")
+            or s in self.reseed_targets
+            for s, st in enumerate(self.states)]
+        self.M = None if self.blocked else self._row_capacity()
         if has_element_within and not self.blocked:
             # the blocked kernel masks per-state gaps on its grids; the scan
             # kernel's tables don't carry last-bind times
@@ -626,6 +718,33 @@ class DeviceNFACompiler:
             self._step = None
         else:
             self._step = jax.jit(self._make_step(), donate_argnums=(0,))
+
+    def _row_capacity(self) -> int:
+        """Rows the scan kernel's output table holds for one batch (``M``),
+        from the plan alone: no ``@device`` key sizes it.
+
+        Every emit site consumes the partial it emits (its slot's ``valid``
+        goes, or a start slot is re-armed as a NEW partial), so a batch
+        emits at most one row per partial that existed in it: those alive
+        in a table when the batch began plus those created by its events.
+        A table holds partials only if something inserts into it: ``p0``
+        where state 0 keeps its partial (count, logical, absent starts;
+        a stream start seeds straight into ``p1``), ``p<s>`` where state
+        ``s-1`` pushes (anything but a count, whose partials are pulled
+        from its own table), and mid-pattern ``every`` targets. An event
+        creates at most one partial (the seed, or a start slot's re-arm),
+        two where a pre-placed start can fire in the expiry pre-pass and
+        again in the main pass. So ``live x C + creations x B``:
+        ``C + B`` for ``every A -> B<m:n> -> C``, whose only populated table
+        is the count state's. A mid-pattern ``every`` CLONES a partial at
+        each scope end, so its rows have no bound below the emit grids
+        themselves; it gets room for every partial to emit twice. Whatever
+        the bound, rows past it are counted into ``drops`` (never silent),
+        and the emit grids' own size ``2 x C x B`` caps it."""
+        C, B = self.C, self.B
+        live = sum(self.table_holds)
+        once = max(live, 1) * C + (2 if self.preseeded else 1) * B
+        return min(2 * once if self.reseed_targets else once, 2 * C * B)
 
     def _compile_predicates(self, ist: StateInputStream) -> None:
         # recover filter ASTs from the host compiler's branch filters is not
@@ -835,7 +954,7 @@ class DeviceNFACompiler:
         if self.blocked:
             from .nfa_block import make_block_step
             return make_block_step(self)
-        C, S = self.C, self.S
+        C, S, M = self.C, self.S, self.M
         states = self.states
         within = self.within
         is_seq = self.is_sequence
@@ -898,6 +1017,39 @@ class DeviceNFACompiler:
             inserted = jnp.zeros((C,), jnp.bool_).at[tgt].set(ok, mode="drop")
             return new, dropped, inserted
 
+        def insert_seed(slots: dict, want, values: dict, ts_new,
+                        counts_new=None):
+            """``insert`` for ONE candidate, the event's own seed (``want``
+            a scalar; ``values`` / ``ts_new`` / ``counts_new`` [C] arrays
+            whose entry 0 is the candidate's): it takes the first free slot,
+            which is where ``insert`` ranks candidate 0, found by one
+            argmax and written by selects. Nothing is scattered: with the
+            seed scattered like any candidate, one event's turn of the 256
+            x 1,408 deployment took 43 ms on a v5e (13.8 s a batch), 24 of
+            them the int64 ``first_ts`` alone; like this 0.1 ms (PERF.md
+            section 6, PR 33)."""
+            free = ~slots["valid"]
+            room = jnp.any(free)
+            hit = (jnp.arange(C, dtype=jnp.int32)
+                   == jnp.argmax(free).astype(jnp.int32)) & want & room
+            new = dict(slots)
+            new["valid"] = slots["valid"] | hit
+            new["first_ts"] = jnp.where(hit, ts_new[0], slots["first_ts"])
+            if "count" in slots:
+                new["count"] = jnp.where(
+                    hit, 1 if counts_new is None else counts_new[0],
+                    slots["count"])
+                new["closed"] = slots["closed"] & ~hit
+            for key in slots:
+                if key in ("valid", "first_ts", "count", "closed"):
+                    continue
+                arr = values.get(key)
+                put = jnp.asarray(-1 if key == "arrive_ts" else 0,
+                                  slots[key].dtype) if arr is None \
+                    else arr[0].astype(slots[key].dtype)
+                new[key] = jnp.where(hit, put, slots[key])
+            return new, (want & ~room).astype(jnp.int32), hit
+
         def step_event(carry, ev):
             pend = dict(carry["pending"])
             seeds = carry["seeds"]
@@ -908,8 +1060,8 @@ class DeviceNFACompiler:
             ev_ok = ev["valid"]
 
             # within-expiry reclaims slots
-            if within is not None:
-                for s in range(S):
+            for s in range(S if within is not None else 0):
+                with jax.named_scope("nfa.expire"):
                     slots = dict(pend[f"p{s}"])
                     has_first = slots["first_ts"] >= 0
                     alive = ~(has_first & (ev_ts - slots["first_ts"] > within))
@@ -940,9 +1092,8 @@ class DeviceNFACompiler:
                 has_open = jnp.any(p0["valid"] & ~p0["closed"])
                 want = ev_ok & ~has_open & (
                     jnp.array(True) if always_seed else seeds > 0)
-                ins_mask = jnp.zeros((C,), jnp.bool_).at[0].set(want)
-                new0, dropped0, replenish_ins = insert(
-                    p0, ins_mask, {},
+                new0, dropped0, replenish_ins = insert_seed(
+                    p0, want, {},
                     jnp.full((C,), -1, jnp.int64),
                     jnp.zeros((C,), jnp.int32))
                 pend["p0"] = new0
@@ -960,12 +1111,16 @@ class DeviceNFACompiler:
             # the seed it just returned). Expiry returns (above) ARE visible.
             seeds0 = seeds
 
-            out_mask = jnp.zeros((2, C), jnp.bool_)
-            out_cols = [jnp.zeros((2, C), _JNP[t]) for (_, _, t) in out_specs]
+            # the event's emit grids, one [C] row per emit source the plan
+            # USES (row 0: a state's own table, row 1: the count table before
+            # it), kept by row so that a plan with one source stacks and
+            # packs one: {row: [C]} for the mask and for every column
+            out_mask = {}
+            out_cols = [{} for _ in out_specs]
             # per-output null masks (OR-unmatched side / absent branch /
             # zero-occurrence count refs emit NULL, not the zero value)
-            out_nulls = [jnp.zeros((2, C), jnp.bool_) if out_null_deps[oi]
-                         else None for oi in range(n_out)]
+            out_nulls = [{} if out_null_deps[oi] else None
+                         for oi in range(n_out)]
             touched = {s: jnp.zeros((C,), jnp.bool_) for s in range(S)}
             if replenish_ins is not None:
                 # a partial placed this event is exempt from sequence strict
@@ -974,22 +1129,24 @@ class DeviceNFACompiler:
 
             def emit_rows(out_mask, out_cols, n_match, mask, row, emit_env):
                 """Accumulate matched slots into output row `row`."""
-                out_mask = out_mask.at[row].set(out_mask[row] | mask)
-                for oi, (_, fn, t) in enumerate(out_specs):
-                    val = jnp.broadcast_to(fn(emit_env), (C,)).astype(
-                        out_cols[oi].dtype)
-                    out_cols[oi] = out_cols[oi].at[row].set(
-                        jnp.where(mask, val, out_cols[oi][row]))
-                    if out_null_deps[oi]:
-                        nm = jnp.zeros((C,), jnp.bool_)
-                        for (q, flag) in sorted(out_null_deps[oi]):
-                            got = emit_env.get(flag)
-                            if got is None:      # flag not carried → unbound
-                                nm = jnp.ones((C,), jnp.bool_)
-                            else:
-                                nm = nm | ~jnp.broadcast_to(got, (C,))
-                        out_nulls[oi] = out_nulls[oi].at[row].set(
-                            jnp.where(mask, nm, out_nulls[oi][row]))
+                with jax.named_scope("nfa.emit"):
+                    out_mask[row] = out_mask.get(row, False) | mask
+                    for oi, (_, fn, t) in enumerate(out_specs):
+                        val = jnp.broadcast_to(fn(emit_env), (C,)).astype(
+                            _JNP[t])
+                        out_cols[oi][row] = jnp.where(
+                            mask, val,
+                            out_cols[oi].get(row, jnp.zeros((), _JNP[t])))
+                        if out_null_deps[oi]:
+                            nm = jnp.zeros((C,), jnp.bool_)
+                            for (q, flag) in sorted(out_null_deps[oi]):
+                                got = emit_env.get(flag)
+                                if got is None:      # flag not carried → unbound
+                                    nm = jnp.ones((C,), jnp.bool_)
+                                else:
+                                    nm = nm | ~jnp.broadcast_to(got, (C,))
+                            out_nulls[oi][row] = jnp.where(
+                                mask, nm, out_nulls[oi].get(row, False))
                 return out_mask, out_cols, \
                     n_match + jnp.sum(mask.astype(jnp.int64))
 
@@ -1002,9 +1159,8 @@ class DeviceNFACompiler:
             # advance); several establishments inside ONE inter-event gap
             # collapse to a single advance per event (documented divergence:
             # the host fires one timer per `for` interval).
-            for s in [i for i, stx in enumerate(states)
-                      if stx.kind == "absent" or
-                      (stx.kind == "logical" and stx.waiting_ms is not None)]:
+            def expire_state(s):
+                nonlocal seeds, drops, n_match, out_mask, out_cols
                 st = states[s]
                 slots = pend[f"p{s}"]
                 estab = slots["valid"] & ev_ok & (slots["arrive_ts"] >= 0) & \
@@ -1069,6 +1225,10 @@ class DeviceNFACompiler:
                     drops = drops + dropped.astype(jnp.int64)
                 if every_end == s:
                     seeds = seeds + n_adv
+
+            for s in [i for i, stx in enumerate(states) if _clocked(stx)]:
+                with jax.named_scope("nfa.expire"):
+                    expire_state(s)
 
             def env_for(level: int, ev):
                 env = {f"ev_{k}": ev["cols"][k] for k in ev["cols"]}
@@ -1348,25 +1508,21 @@ class DeviceNFACompiler:
                                 out_mask, out_cols, n_match, ins0, 0,
                                 emit_env)
                         else:
-                            insc_mask = jnp.zeros((C,), jnp.bool_).at[0].set(
-                                seed_done)
                             cvals = {key: seed_vals[key]
                                      for key in seed_vals
                                      if not key.startswith("done")}
                             if _clocked(states[1]):
                                 cvals["arrive_ts"] = jnp.broadcast_to(
                                     ev_ts, (C,)).astype(jnp.int64)
-                            newc, droppedc, insertedc = insert(
-                                pend["p1"], insc_mask, cvals,
+                            newc, droppedc, insertedc = insert_seed(
+                                pend["p1"], seed_done, cvals,
                                 jnp.broadcast_to(ev_ts, (C,)),
                                 jnp.zeros((C,), jnp.int32))
                             pend["p1"] = newc
                             touched[1] = touched[1] | insertedc
                             drops = drops + droppedc.astype(jnp.int64)
-                        ins_mask = jnp.zeros((C,), jnp.bool_).at[0].set(
-                            ins_pend)
-                        new0, dropped, inserted = insert(
-                            pend["p0"], ins_mask, seed_vals,
+                        new0, dropped, inserted = insert_seed(
+                            pend["p0"], ins_pend, seed_vals,
                             jnp.broadcast_to(ev_ts, (C,)))
                         pend["p0"] = new0
                         touched[0] = touched[0] | inserted
@@ -1389,13 +1545,11 @@ class DeviceNFACompiler:
                             out_mask, out_cols, n_match = emit_rows(
                                 out_mask, out_cols, n_match, ins0, 0, emit_env)
                         else:
-                            ins_mask = jnp.zeros((C,), jnp.bool_).at[0].set(
-                                can_any)
                             if _clocked(states[1]):
                                 seed_vals["arrive_ts"] = jnp.broadcast_to(
                                     ev_ts, (C,)).astype(jnp.int64)
-                            new1, dropped, inserted = insert(
-                                pend["p1"], ins_mask, seed_vals,
+                            new1, dropped, inserted = insert_seed(
+                                pend["p1"], can_any, seed_vals,
                                 jnp.broadcast_to(ev_ts, (C,)),
                                 jnp.zeros((C,), jnp.int32))
                             pend["p1"] = new1
@@ -1414,7 +1568,8 @@ class DeviceNFACompiler:
                 p0pre = pend["p0"]
                 count0_open_pre = jnp.any(p0pre["valid"] & ~p0pre["closed"])
 
-            for s in range(S - 1, -1, -1):
+            def advance_state(s):
+                nonlocal pend, seeds, drops, n_match, out_mask, out_cols
                 st = states[s]
                 if st.kind == "absent":
                     # expiry ran in the pre-pass; here the forbidden event
@@ -1436,13 +1591,13 @@ class DeviceNFACompiler:
                         ns["valid"] = ns["valid"] & ~kill
                     pend[f"p{s}"] = ns
                     touched[s] = touched[s] | kill
-                    continue
+                    return
                 if st.kind == "logical":
                     (pend, seeds, drops, n_match, out_mask, out_cols) = \
                         logical_state(s, st, pend, seeds, drops, n_match,
                                       out_mask, out_cols, touched, ev, ev_ts,
                                       ev_tag, ev_ok, env_for)
-                    continue
+                    return
                 gate = ev_ok & (ev_tag == st.stream_idx)
                 # ---- candidate source A: pending[s]
                 slots = pend[f"p{s}"]
@@ -1522,10 +1677,14 @@ class DeviceNFACompiler:
                     # stream state: sources = pending[s] and (if prev is count)
                     # its eligible slots; freshly re-placed scope clones are
                     # invisible this event
-                    cand = slots["valid"] & pred & gate
-                    if "fresh" in slots:
-                        cand = cand & ~slots["fresh"]
-                    sources = [(s, cand)]
+                    # (emit row, table, candidates); a table nothing ever
+                    # inserts into (``table_holds``) is no source
+                    sources = []
+                    if self.table_holds[s]:
+                        cand = slots["valid"] & pred & gate
+                        if "fresh" in slots:
+                            cand = cand & ~slots["fresh"]
+                        sources.append((0, s, cand))
                     if s > 0 and states[s - 1].kind == "count":
                         prev = pend[f"p{s-1}"]
                         env_p = env_for(s - 1, ev)
@@ -1533,9 +1692,9 @@ class DeviceNFACompiler:
                             else jnp.broadcast_to(st.predicate(env_p), (C,))
                         elig = prev["valid"] & (
                             prev["count"] >= states[s - 1].min_count)
-                        sources.append((s - 1, elig & pred_p & gate))
+                        sources.append((1, s - 1, elig & pred_p & gate))
 
-                    for src_i, (lvl, matched) in enumerate(sources):
+                    for (src_i, lvl, matched) in sources:
                         src = pend[f"p{lvl}"]
                         touched[lvl] = touched[lvl] | matched
                         # gather advanced values: all bound cols + new binding
@@ -1685,8 +1844,8 @@ class DeviceNFACompiler:
                                 out_mask, out_cols, n_match, ins_mask, 0,
                                 emit_env)
                         else:
-                            new0, dropped, inserted = insert(
-                                pend["p0"], ins_mask, seed_vals,
+                            new0, dropped, inserted = insert_seed(
+                                pend["p0"], can_seed, seed_vals,
                                 jnp.broadcast_to(ev_ts, (C,)),
                                 jnp.ones((C,), jnp.int32))
                             pend["p0"] = new0
@@ -1719,8 +1878,8 @@ class DeviceNFACompiler:
                             if _clocked(states[1]):
                                 seed_vals["arrive_ts"] = jnp.broadcast_to(
                                     ev_ts, (C,)).astype(jnp.int64)
-                            new1, dropped, inserted = insert(
-                                pend["p1"], ins_mask, seed_vals,
+                            new1, dropped, inserted = insert_seed(
+                                pend["p1"], can_seed, seed_vals,
                                 jnp.broadcast_to(ev_ts, (C,)),
                                 jnp.zeros((C,), jnp.int32))
                             pend["p1"] = new1
@@ -1728,6 +1887,12 @@ class DeviceNFACompiler:
                             drops = drops + dropped.astype(jnp.int64)
                     if not always_seed:
                         seeds = seeds - can_seed.astype(jnp.int64)
+
+            # states in reverse order, so one event can't advance a partial
+            # twice
+            for s in range(S - 1, -1, -1):
+                with jax.named_scope(f"nfa.state{s}"):
+                    advance_state(s)
 
             # scope clones become visible from the next event on
             for r in self.reseed_targets:
@@ -1745,11 +1910,17 @@ class DeviceNFACompiler:
 
             new_carry = {"pending": pend, "seeds": seeds, "drops": drops,
                          "matches": n_match}
-            ys = {"mask": out_mask, "ts": ev_ts}
-            for oi, (name, _, _) in enumerate(out_specs):
-                ys[name] = out_cols[oi]
+            rows = sorted(out_mask)     # static: the plan's emit sources
+
+            def grid(by_row, dtype):
+                return jnp.stack([by_row[r] for r in rows]) if rows \
+                    else jnp.zeros((1, C), dtype)
+
+            ys = {"mask": grid(out_mask, jnp.bool_)}
+            for oi, (name, _, t) in enumerate(out_specs):
+                ys[name] = grid(out_cols[oi], _JNP[t])
                 if out_nulls[oi] is not None:
-                    ys[f"null__{name}"] = out_nulls[oi]
+                    ys[f"null__{name}"] = grid(out_nulls[oi], jnp.bool_)
             return new_carry, ys
 
         def step(state, cols, tag, ts, ts_base, nvalid):
@@ -1765,7 +1936,11 @@ class DeviceNFACompiler:
 
             xs = {f"c_{k}": v for k, v in cols.items()}
             xs.update({"tag": tag, "ts": ts64, "valid": valid})
-            state, ys = jax.lax.scan(body, state, xs)
+            with jax.named_scope("nfa.scan"):
+                state, grids = jax.lax.scan(body, state, xs)
+            with jax.named_scope("nfa.compact"):
+                ys, lost = pack_rows(grids.pop("mask"), grids, M)
+            state["drops"] = state["drops"] + lost
             return state, ys
 
         return step
@@ -1785,24 +1960,32 @@ class DeviceNFACompiler:
         return self._step(state, batch["cols"], batch["tag"], batch["ts"],
                           batch["ts_base"], np.int32(batch["count"]))
 
-    def decode_outputs(self, ys):
-        """One step's outputs → a :class:`~siddhi_tpu.core.columns.ColumnsOut`
-        (string codes stay codes; NULL cells ride as masks)."""
-        if self.blocked:
-            from .nfa_block import decode_block_outputs
-            return decode_block_outputs(self, ys)
+    def decode_outputs(self, ys, lane_batch: Optional[int] = None):
+        """One step's row table → a :class:`~siddhi_tpu.core.columns.
+        ColumnsOut` (string codes stay codes; NULL cells ride as masks).
+        Both kernels hand out the same table: ``mask`` and ``j`` (the match
+        event's index in its batch) ``[M]`` with a column per output and
+        per null mask; rows go out by match event, a match event's rows in
+        table order (the scan kernel: source, then candidate; the blocked
+        kernel: candidate rank). ``lane_batch`` given, the table is
+        lane-stacked ``[P, M]`` and decoded in one pass, lanes in order:
+        no loop over lanes."""
         from ..core.columns import ColumnsOut
-        mask = np.asarray(ys["mask"])              # [B, 2, C]
-        # a boolean index walks [b, src, c] in row-major order: match
-        # event, then source, then candidate
-        cols = {name: np.asarray(ys[name])[mask]
+        mask = np.asarray(ys["mask"])
+        idx = np.flatnonzero(mask)
+        if not idx.size:
+            return ColumnsOut.empty(self.out_specs, self.merged.dictionaries)
+        j = np.asarray(ys["j"]).reshape(-1)[idx].astype(np.int64)
+        if lane_batch is not None:
+            j += (idx // mask.shape[-1]) * lane_batch
+        idx = idx[np.argsort(j, kind="stable")]
+        cols = {name: np.asarray(ys[name]).reshape(-1)[idx]
                 for (name, _, t) in self.out_specs}
-        nulls = {name: np.asarray(ys[f"null__{name}"])[mask]
+        nulls = {name: np.asarray(ys[f"null__{name}"]).reshape(-1)[idx]
                  for (name, _, t) in self.out_specs
                  if f"null__{name}" in ys}
-        return ColumnsOut(None, cols, int(np.count_nonzero(mask)),
-                          self.out_specs, self.merged.dictionaries,
-                          nulls or None)
+        return ColumnsOut(None, cols, int(idx.size), self.out_specs,
+                          self.merged.dictionaries, nulls or None)
 
 
 class DeviceNFARuntime(StepRuntime):

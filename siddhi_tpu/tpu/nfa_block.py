@@ -2,8 +2,9 @@
 
 The per-event ``lax.scan`` kernel (``nfa.py``) walks a batch one event at a
 time: B sequential iterations of some 300 tiny [C]-wide operations, which is
-launch latency and no arithmetic (its time on a v5e: not measured; no ledger
-line runs it). This module is the reformulation the north star asks for:
+launch latency and no arithmetic (its time on a v5e is a ledger row since
+PR 33: ``partitioned-kleene-sat``, 256 lanes under ``vmap``, C 1,408, 320
+events deep). This module is the reformulation the north star asks for:
 sequential depth **S (number of NFA states)** instead of **B (events per
 batch)**. Its step on the chip is a ledger row: ``step.device_ms_per_batch``
 of ``pattern-chain8-sat`` (C 1,024, B 2,048) and ``partitioned-chain-sat``
@@ -69,7 +70,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..query_api.definition import DataType
 from .dtypes import JNP as _JNP
 
 if TYPE_CHECKING:
@@ -221,8 +221,10 @@ def make_block_step(nfa: "DeviceNFACompiler"):
 
     ys: {"mask": [P] bool, "j": [P] i32 (match event index, for ordering),
          <out-name>: [P] ...} and nothing else
-    where P = (S-1)*C + B for S > 1, else B. A match's timestamp is the
-    batch's ``ts[j]``, which the host holds: it never leaves the device.
+    where P = (S-1)*C + B for S > 1, else B: the row table
+    ``DeviceNFACompiler.decode_outputs`` reads, which the scan kernel hands
+    out too. A match's timestamp is the batch's ``ts[j]``, which the host
+    holds: it never leaves the device.
     """
     C, S, B = nfa.C, nfa.S, nfa.B
     states = nfa.states
@@ -458,17 +460,3 @@ def make_block_step(nfa: "DeviceNFACompiler"):
         return new_state, ys
 
     return step
-
-
-def decode_block_outputs(nfa: "DeviceNFACompiler", ys):
-    """ys → a ``ColumnsOut``, ordered by match event (j), then candidate
-    rank."""
-    from ..core.columns import ColumnsOut
-    idx = np.flatnonzero(np.asarray(ys["mask"]))
-    if not idx.size:
-        return ColumnsOut.empty(nfa.out_specs, nfa.merged.dictionaries)
-    j = np.asarray(ys["j"])[idx]
-    idx = idx[np.argsort(j, kind="stable")]
-    cols = {name: np.asarray(ys[name])[idx] for (name, _, t) in nfa.out_specs}
-    return ColumnsOut(None, cols, int(idx.size), nfa.out_specs,
-                      nfa.merged.dictionaries)

@@ -265,7 +265,11 @@ class PartitionedNFARuntime(StepRuntime):
     ``builders``, ``send`` / ``ingest_csv`` and a synchronous
     ``flush(decode=)``.
     Both step the same jitted ``vstep`` over ``[P, lane_batch]`` and decode
-    its stacked outputs in one pass (``decode_stacked``).
+    its stacked outputs in one pass (``decode_stacked``). The step is
+    whichever kernel the compiler chose for the pattern (``kernel``): the
+    blocked one for chains of stream states under ``every``, the per-event
+    scan for count (``<m:n>``), logical and absent states; both hand out
+    one ``[P, M]`` row table.
     """
 
     def __init__(self, app_or_text, num_partitions: int,
@@ -324,10 +328,6 @@ class PartitionedNFARuntime(StepRuntime):
             if len(merged.stream_ids) != 1:
                 raise DeviceCompileError(
                     "a served partition routes one input stream")
-            if not self.compiler.blocked:
-                raise DeviceCompileError(
-                    "a served partition steps the blocked kernel: count, "
-                    "logical and absent states keep the host tiers")
             sid = merged.stream_ids[0]
             self._key_pos = self.stream_defs[sid].attribute_position(key_attr)
             self.builder = LaneBatchBuilder(
@@ -643,29 +643,16 @@ class PartitionedNFARuntime(StepRuntime):
         return rows
 
     def decode_stacked(self, ys):
-        """One step's lane-stacked outputs -> ONE ``ColumnsOut``, lanes in
-        order and a lane's rows as its own decode orders them (the blocked
-        kernel: by match event ``j``, then candidate rank), in one pass
-        over the whole: no loop over lanes."""
-        from ..core.columns import ColumnsOut
-        nfa = self.compiler
-        if not nfa.blocked:
-            # the scan kernel's decode is one boolean index over the mask,
-            # whatever its rank: [P, B, 2, C] walks lane, event, source,
-            # candidate in row-major order
-            return nfa.decode_outputs(ys)
-        mask = np.asarray(ys["mask"])
-        idx = np.flatnonzero(mask)              # over [P, M], lane-major
-        if not idx.size:
-            return ColumnsOut.empty(nfa.out_specs, nfa.merged.dictionaries)
-        width = mask.shape[1]
-        j = np.asarray(ys["j"]).reshape(-1)[idx].astype(np.int64)
-        idx = idx[np.argsort((idx // width) * self.lane_batch + j,
-                             kind="stable")]
-        cols = {name: np.asarray(ys[name]).reshape(-1)[idx]
-                for (name, _, t) in nfa.out_specs}
-        return ColumnsOut(None, cols, int(idx.size), nfa.out_specs,
-                          nfa.merged.dictionaries)
+        """One step's lane-stacked row tables ``[P, M]`` -> ONE
+        ``ColumnsOut``, lanes in order and a lane's rows as its own decode
+        orders them (by match event ``j``, then table order), in one pass
+        over the whole: no loop over lanes, whichever kernel stepped."""
+        return self.compiler.decode_outputs(ys, lane_batch=self.lane_batch)
+
+    @property
+    def kernel(self) -> str:
+        """Which NFA kernel the lanes step: ``'blocked'`` or ``'scan'``."""
+        return "blocked" if self.compiler.blocked else "scan"
 
     # -- the served interface: StepRuntime's, with these supplied --------------
     fence_key = "mask"
@@ -718,8 +705,10 @@ class PartitionedNFARuntime(StepRuntime):
         never silent; the gauges say how near the tables are to it."""
         st = self.state
         drops = int(np.sum(jax.device_get(st["drops"])))
+        # the waiting tables: the blocked kernel's or the scan kernel's
+        tables = st["tables"] if "tables" in st else st["pending"]
         fullest = max((int(np.asarray(t["valid"]).sum(axis=-1).max())
-                       for t in st["tables"].values()), default=0)
+                       for t in tables.values()), default=0)
         self.lane_gauges["drops"] = drops
         self.lane_gauges["fullest_table_share"] = fullest / self.compiler.C
         if drops > self._warned_drops:

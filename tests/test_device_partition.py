@@ -18,7 +18,6 @@ from siddhi_tpu import InMemoryPersistenceStore, SiddhiManager, StreamCallback
 from siddhi_tpu.core.columns import ColumnsOut
 from siddhi_tpu.tpu import partition as tpu_partition
 from siddhi_tpu.tpu.expr_compile import DeviceCompileError
-from siddhi_tpu.tpu.nfa_block import decode_block_outputs
 from siddhi_tpu.tpu.partition import (
     LaneBatchBuilder,
     PartitionedNFARuntime,
@@ -305,20 +304,20 @@ def test_strict_raises_for_a_block_of_two_queries():
         m.shutdown()
 
 
-COUNT_STATE = """
+SEQUENCE = """
 define stream S (dev string, v double);
 partition with (dev of S) begin
 @device(strict='{strict}', batch='64', slots='64', lanes='4')
-from every e1=S[v > 50.0] -> e2=S[v > e1.v]<2:3> within 4000
-select e1.v as v1, e2[0].v as v2 insert into Alerts;
+from every e1=S[v > 50.0], e2=S[v > e1.v] within 4000
+select e1.v as v1, e2.v as v2 insert into Alerts;
 end;
 """
 
 
 @pytest.mark.parametrize("text, why", [
     (TWO_QUERIES.format(strict="false"), "two queries"),
-    (COUNT_STATE.format(strict="false"), "a count state"),
-], ids=["two-queries", "count-state"])
+    (SEQUENCE.format(strict="false"), "a sequence"),
+], ids=["two-queries", "sequence"])
 def test_a_block_that_does_not_lower_keeps_the_host_tiers(text, why):
     m = SiddhiManager()
     try:
@@ -329,11 +328,14 @@ def test_a_block_that_does_not_lower_keeps_the_host_tiers(text, why):
         m.shutdown()
 
 
-def test_strict_raises_for_a_count_state():
+def test_strict_raises_for_a_sequence():
+    """What a count state was until the served partition stepped the scan
+    kernel too: a block that still does not lower (strictness is per key,
+    a lane holds many keys)."""
     m = SiddhiManager()
     try:
-        with pytest.raises(DeviceCompileError, match="blocked kernel"):
-            m.create_siddhi_app_runtime(COUNT_STATE.format(strict="true"),
+        with pytest.raises(DeviceCompileError, match="per-key strictness"):
+            m.create_siddhi_app_runtime(SEQUENCE.format(strict="true"),
                                         playback=True)
     finally:
         m.shutdown()
@@ -366,10 +368,12 @@ def test_one_compile_and_one_chunk_a_step_and_no_call_per_event(monkeypatch):
     monkeypatch.setattr(tpu_partition, "_hash_key", lambda v: (
         hashed.append(v), zlib.crc32(str(v).encode()) & 0x7FFFFFFF)[1])
     lane_decodes: list = []
+    inner_decode = type(r.compiler).decode_outputs
     monkeypatch.setattr(
         type(r.compiler), "decode_outputs",
-        lambda self, ys: lane_decodes.append(1) or decode_block_outputs(
-            self, ys))
+        lambda self, ys, lane_batch=None: (
+            lane_decodes.append(1) if lane_batch is None else None,
+            inner_decode(self, ys, lane_batch))[1])
     try:
         # first pass: one crc32 a DISTINCT key, not an event
         _send(rt, devs[:500], vs[:500], True, chunk=100)
@@ -425,7 +429,7 @@ def test_the_stacked_decode_equals_the_per_lane_decode():
         old = []
         for lane in range(6):
             lane_ys = jax.tree_util.tree_map(lambda x: x[lane], ys)
-            old.extend(decode_block_outputs(nfa, lane_ys).rows())
+            old.extend(nfa.decode_outputs(lane_ys).rows())
         got = rt.decode_stacked(ys)
         assert isinstance(got, ColumnsOut)
         assert got.rows() == old
@@ -507,9 +511,11 @@ def test_the_route_tracker_and_the_gauges_are_reported():
         assert entry["reconciliation_ratio"] == pytest.approx(1.0, abs=1e-6)
         assert set(entry["lanes"]) == {"fullest_table_share",
                                        "fullest_lane_events", "drops"}
+        assert entry["kernel"] == "blocked"
         gauges = rt.ctx.statistics_manager.report()
-        assert any(k.endswith("lanes_fullest_table_share")
-                   for k in gauges.get("gauges", gauges))
+        gauges = gauges.get("gauges", gauges)
+        assert any(k.endswith("lanes_fullest_table_share") for k in gauges)
+        assert any(k.endswith("lanes_kernel_scan") for k in gauges)
     finally:
         m.shutdown()
 
@@ -522,5 +528,234 @@ def test_table_overflow_is_warned_of_not_silent(caplog):
         assert rt.device_bridges[0].runtime.lane_gauges["drops"] > 0
         assert any("dropped from full lane tables" in r.getMessage()
                    for r in caplog.records)
+    finally:
+        m.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# a count state in the block: the lanes step the per-event scan kernel
+# (ISSUE 33), held to the same interpreter
+# ---------------------------------------------------------------------------
+
+KLEENE = ("from every e1=S[v > 50.0] -> e2=S[v > e1.v]{count} -> "
+          "e3=S[v < e1.v] within {within}\n"
+          "select e1.v as v1, {select}, e3.v as back insert into Alerts;")
+KLEENE_SELECT = "e2[0].v as first, e2[last].v as peak"
+
+
+def _kleene(device: str = "", count: str = "<3:>", within: int = 4000,
+            select: str = KLEENE_SELECT, head: str = "") -> str:
+    return (f"{head}define stream S (dev string, v double);\n"
+            f"partition with (dev of S) begin\n{device}\n"
+            + KLEENE.format(count=count, within=within, select=select)
+            + "\nend;\n")
+
+
+def _null_f32(rows) -> list:
+    """``_f32`` for rows that may hold NULL (``e2[k]`` never reached)."""
+    return sorted(tuple(-1.0 if x is None else float(np.float32(x))
+                        for x in r) for r in rows)
+
+
+def _kleene_interpreter(devs, vs, **kw) -> list:
+    rt, m, rows = _run(_kleene(**kw), devs, vs)
+    assert not rt.device_bridges and len(rt.partition_runtimes) == 1
+    m.shutdown()
+    return _null_f32(rows)
+
+
+@pytest.mark.parametrize("columns", [False, True], ids=["send", "columns"])
+@pytest.mark.parametrize("zipf", [False, True], ids=["uniform", "zipf"])
+def test_served_count_state_rows_equal_the_interpreters(columns, zipf):
+    devs, vs = _events(3000, keys=40, zipf=zipf)
+    want = _kleene_interpreter(devs, vs)
+    assert len(want) > 100
+    rt, m, rows = _run(_kleene(_device()), devs, vs, columns=columns)
+    try:
+        assert _null_f32(rows) == want
+        assert len(rt.device_bridges) == 1
+        assert not (rt.partition_runtimes or rt.host_bridges
+                    or rt.fleet_bridges or rt.query_runtimes)
+        bridge = rt.device_bridges[0]
+        assert bridge.kind == "partition" and bridge.guard is not None
+        r = bridge.runtime
+        assert r.kernel == "scan" and not r.compiler.blocked
+        assert [s.kind for s in r.compiler.states] == ["stream", "count",
+                                                       "stream"]
+        assert bridge.probe.events == 3000
+        assert bridge.guard.report()["failures"] == 0
+        assert r.lane_gauges["drops"] == 0
+        assert 0.0 < r.lane_gauges["fullest_table_share"] < 1.0
+        rep = rt.observability.latency_report()["queries"]
+        assert [v["kernel"] for v in rep.values() if "lanes" in v] == ["scan"]
+    finally:
+        m.shutdown()
+
+
+@pytest.mark.parametrize("count, select", [
+    ("<3:>", KLEENE_SELECT),
+    ("<2:5>", KLEENE_SELECT),
+    ("<3:>", "e2[0].v as first, e2[1].v as second, e2[last].v as peak"),
+    ("<2:5>", "e2[1].v as second, e2[3].v as fourth"),
+], ids=["3-unbounded", "2-to-5", "occurrence-1", "occurrence-3-may-be-null"])
+def test_count_bounds_and_occurrence_indexes(count, select):
+    devs, vs = _events(2500, keys=20, seed=7)
+    want = _kleene_interpreter(devs, vs, count=count, select=select)
+    assert len(want) > 50
+    rt, m, rows = _run(_kleene(_device(), count=count, select=select), devs,
+                       vs, columns=True)
+    try:
+        assert _null_f32(rows) == want
+        if "e2[3]" in select:       # a closure of two or three: NULL
+            assert any(r[2] is None for r in rows)
+            assert any(r[2] is not None for r in rows)
+    finally:
+        m.shutdown()
+
+
+def test_count_state_more_keys_than_lanes_and_a_closure_over_three_batches():
+    """64 keys over 4 lanes in batches of 96: a key sees about 1.5 events a
+    batch, so every closure of three or more spans three batches at least."""
+    devs, vs = _events(2400, keys=64, seed=5)
+    want = _kleene_interpreter(devs, vs)
+    assert len(want) > 50
+    rt, m, rows = _run(_kleene(_device(batch=96, lanes=4, slots=512)), devs,
+                       vs, columns=True, chunk=50)
+    try:
+        assert _null_f32(rows) == want
+        assert rt.device_bridges[0].probe.steps >= 25
+        assert rt.device_bridges[0].runtime.lane_gauges["drops"] == 0
+    finally:
+        m.shutdown()
+
+
+def test_count_state_within_expires_across_batches():
+    devs, vs = _events(2500, keys=10, seed=13)
+    want = _kleene_interpreter(devs, vs, within=60)
+    loose = _kleene_interpreter(devs, vs, within=4000)
+    assert 0 < len(want) < len(loose)
+    rt, m, rows = _run(_kleene(_device(batch=64, lanes=4), within=60), devs,
+                       vs, columns=True, chunk=64)
+    try:
+        assert _null_f32(rows) == want
+    finally:
+        m.shutdown()
+
+
+@pytest.mark.parametrize("columns", [False, True], ids=["send", "columns"])
+def test_count_state_a_full_lane_seals_the_batch(columns):
+    n = 1200
+    rng = np.random.default_rng(21)
+    hot = rng.uniform(size=n) < 0.7
+    devs = np.where(hot, "hot", np.array(
+        [f"dev{k}" for k in rng.integers(0, 30, n)], dtype=object))
+    devs = devs.astype(object)
+    vs = np.round(rng.uniform(0.0, 100.0, n), 3)
+    want = _kleene_interpreter(devs, vs)
+    rt, m, rows = _run(_kleene(_device(batch=256, lanes=8, slots=512)), devs,
+                       vs, columns=columns, chunk=200)
+    try:
+        bridge = rt.device_bridges[0]
+        assert bridge.probe.flush_causes.get("lane_full", 0) >= 4
+        assert bridge.probe.events == n
+        assert _null_f32(rows) == want
+    finally:
+        m.shutdown()
+
+
+@pytest.mark.parametrize("async_", ["false", "true"])
+def test_count_state_snapshot_then_restore_mid_closure(async_):
+    devs, vs = _events(2400, keys=20, seed=17)
+    text = _kleene(_device(batch=128, async_=async_),
+                   head="@app:name('SnapK')\n")
+    rt0, m0, whole = _run(text, devs, vs, columns=True)
+    m0.shutdown()
+    cut = 1111
+    m1 = SiddhiManager()
+    store = InMemoryPersistenceStore()
+    m1.set_persistence_store(store)
+    rt1, _, first = _run(text, devs[:cut], vs[:cut], columns=True,
+                         manager=m1)
+    # closures are open at the cut: partials collecting, some already at 3
+    pend = rt1.device_bridges[0].runtime.state["pending"]["p1"]
+    open_counts = np.asarray(pend["count"])[np.asarray(pend["valid"])]
+    assert (open_counts >= 3).any() and (open_counts < 3).any()
+    rt1.persist()
+    m1.shutdown()
+    m2 = SiddhiManager()
+    m2.set_persistence_store(store)
+    rest: list = []
+    rt2 = m2.create_siddhi_app_runtime(text, playback=True)
+    rt2.add_callback("Alerts", StreamCallback(
+        lambda evs: rest.extend(tuple(e.data) for e in evs)))
+    rt2.start()
+    rt2.restore_last_revision()
+    _send(rt2, devs[cut:], vs[cut:], True, start=cut)
+    rt2.flush_device()
+    try:
+        assert _null_f32(first + rest) == _null_f32(whole)
+        assert len(rest) > 0
+    finally:
+        m2.shutdown()
+
+
+def test_count_state_a_failed_step_replays_through_the_host_partition():
+    devs, vs = _events(900, keys=12, seed=19)
+    want = _kleene_interpreter(devs, vs)
+    rt, m, rows = _run(_kleene(_device(), head=CHAOS.format(p="1.0")), devs,
+                       vs, columns=True)
+    try:
+        rep = rt.device_bridges[0].guard.report()
+        assert rep["failures"] >= 7
+        assert rep["fallback_events"] == 900 and rep["lost_events"] == 0
+        assert rep["fallback_engine"] == "scalar"
+        assert _null_f32(rows) == want
+        assert not rt.partition_runtimes
+    finally:
+        m.shutdown()
+
+
+def test_count_state_one_compile_one_chunk_and_one_row_table_a_step():
+    """What one step hands to ``decode``: the lane-stacked row table,
+    ``(2 + outputs + null masks) x P x M`` elements with ``M = C + B`` for
+    this query, and nothing of the ``[B, 2, C]`` emit grids."""
+    devs, vs = _events(1000, keys=30, seed=29)
+    m = SiddhiManager()
+    rt = m.create_siddhi_app_runtime(
+        _kleene(_device(batch=128, lanes=8, slots=64)), playback=True)
+    rows: list = []
+    rt.add_callback("Alerts", StreamCallback(
+        lambda evs: rows.extend(tuple(e.data) for e in evs)))
+    rt.start()
+    r = rt.device_bridges[0].runtime
+    chunks, handed = [], []
+    inner_collect, inner_decode = r.collect, r._decode
+
+    def collect(token):
+        out = inner_collect(token)
+        chunks.append(out)
+        return out
+
+    def decode(ys):
+        handed.append({k: tuple(v.shape) for k, v in ys.items()})
+        return inner_decode(ys)
+
+    r.collect, r._decode = collect, decode
+    try:
+        _send(rt, devs, vs, True, chunk=100)
+        rt.flush_device()
+        lanes, slots, lane_batch = 8, 64, lane_capacity_for(128, 8)
+        rows_m = slots + lane_batch
+        assert r.compiler.M == rows_m
+        assert r.vstep._cache_size() == 1
+        assert len(chunks) == rt.device_bridges[0].probe.steps == 8
+        assert all(isinstance(c, ColumnsOut) for c in chunks)
+        assert sum(len(c) for c in chunks) == len(rows) > 0
+        # mask, j and the four outputs (none can be NULL here): [P, M] each
+        for ys in handed:
+            assert set(ys) == {"mask", "j", "v1", "first", "peak", "back"}
+            assert set(ys.values()) == {(lanes, rows_m)}
+        assert sum(int(np.prod(shape)) for shape in handed[0].values()) \
+            == (2 + 4 + 0) * lanes * rows_m
     finally:
         m.shutdown()

@@ -117,3 +117,65 @@ def test_nfa_fuzz_device_coverage_share():
         except DeviceCompileError:
             pass
     assert compiled / total >= 0.6, f"device coverage {compiled}/{total}"
+
+
+# ---------------------------------------------------------------------------
+# the scan kernel's row table never overflows where a partial emits once
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("seed", range(6))
+def test_a_batch_that_closes_every_partial_fits_the_row_table(seed):
+    """``every A -> B<m:> -> C`` with its table at the brim when a batch
+    begins; the batch closes EVERY partial at once, then seeds, collects
+    and closes again, round after round: a partial emits once, so the rows
+    of a batch are at most the C alive when it began plus one an event,
+    ``M = C + B``. Nothing is counted into ``drops`` and the rows are the
+    host's."""
+    from siddhi_tpu.tpu.nfa import DeviceNFARuntime
+    rng = random.Random(4000 + seed)
+    C, B = 16, 64
+    m = rng.choice([1, 2, 3])
+    app = ("define stream A (k string, v long);\n"
+           f"from every e1=A[k == 'x' and v > 50] -> "
+           f"e2=A[k == 'y' and v > e1.v]<{m}:> -> e3=A[k == 'z']\n"
+           "select e1.v as v1, e2[0].v as f, e2[last].v as l, e3.v as z "
+           "insert into OutputStream;\n")
+    ts, events = START, []
+
+    def send(k, v):
+        nonlocal ts
+        ts += 1
+        events.append(("A", [k, v], ts))
+
+    def seed_and_collect(n):
+        for _ in range(n):
+            send("x", rng.randrange(51, 90))
+        for i in range(m):
+            send("y", 95 + i)
+
+    seed_and_collect(C)             # earlier batches: the table at its brim
+    while len(events) % B:
+        send("w", 0)                # neither opens, collects nor closes
+    first = len(events)
+    closed = C
+    send("z", 1)                    # the batch: every partial closes..
+    while len(events) - first < B - (m + 2):
+        n = rng.randrange(1, min(C, B - (len(events) - first) - m - 1) + 1)
+        seed_and_collect(n)         # ..and as many again as fit, each round
+        send("z", 2)
+        closed += n
+    while len(events) % B:
+        send("w", 0)
+    assert len(events) == first + B and closed > C + B // 2
+    rt = DeviceNFARuntime(app, slot_capacity=C, batch_capacity=B,
+                          start_time=START)
+    assert rt.compiler.M == C + B
+    rows = []
+    rt.add_callback(rows.extend)
+    for sid, row, t in events:
+        rt.send(sid, list(row), t)
+    rt.flush()
+    assert rt.drop_count == 0
+    expected = _host(app, events)
+    assert len(expected) == closed <= C + B
+    assert sorted(map(tuple, expected)) == sorted(map(tuple, rows))

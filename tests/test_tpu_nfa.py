@@ -447,3 +447,188 @@ def test_within_expires_partial_seeded_at_timestamp_zero():
     assert_match_parity(app, evs)
     evs2 = [("A", [7], 0), ("B", [9], 50)]      # inside window: match
     assert_match_parity(app, evs2)
+
+
+# ---------------------------------------------------------------------------
+# the scan kernel's rows, packed on the device (ISSUE 33): the row table
+# against the boolean index over the per-event emit grids it replaced
+# ---------------------------------------------------------------------------
+
+APP_COUNT_KLEENE = """
+define stream S (k string, v double);
+from every e1=S[v > 10.0] -> e2=S[k == e1.k and v > e1.v]<2:> -> e3=S[k == e1.k and v < e1.v] within 40
+select e1.v as a, e2[0].v as f, e2[1].v as g, e2[last].v as l, e3.v as z
+insert into O;
+"""
+APP_ZERO_MIN = """
+define stream A (v long); define stream B (v long);
+from every e1=A[v > 3] -> e2=B[v > e1.v]<0:2> -> e3=A[v < e1.v]
+select e1.v as a, e2[0].v as f, e2[last].v as l, e3.v as z insert into O;
+"""
+APP_OR_NULLS = """
+define stream A (v long);
+define stream B (v long);
+define stream C (v long);
+from every e1=A[v > 5] -> e2=B[v > 10] or e3=C[v > 20]
+select e1.v as a, e2.v as b, e3.v as c insert into O;
+"""
+
+
+def _abc_events(seed, n=300):
+    rng = random.Random(seed)
+    return [(rng.choice(["A", "B", "C"]), [rng.randrange(40)], 1000 + i * 7)
+            for i in range(n)]
+
+
+def _ab_events(seed, n=300):
+    rng = random.Random(seed)
+    return [(rng.choice(["A", "B"]), [rng.randrange(12)], 1000 + i)
+            for i in range(n)]
+
+
+def _kleene_events(seed, n=400):
+    rng = random.Random(seed)
+    return [("S", [rng.choice("xyz"), round(rng.uniform(0, 40), 1)],
+             1000 + i) for i in range(n)]
+
+
+def _absent_events(seed, n=250):
+    rng = random.Random(seed)
+    evs, ts = [], 1000
+    for _ in range(n):
+        ts += rng.choice([10, 30, 60, 150])
+        evs.append((rng.choice(["A", "B", "C"]), [rng.randrange(20)], ts))
+    return evs
+
+
+SCAN_CORPUS = {
+    "count-kleene": (APP_COUNT_KLEENE, _kleene_events(31)),
+    "count-zero-min-nulls": (APP_ZERO_MIN, _ab_events(32)),
+    "logical-and": (APP_AND_CHAIN, _abc_events(21)),
+    "logical-or-nulls": (APP_OR_NULLS, _abc_events(22)),
+    "absent": (APP_ABSENT_CHAIN, _absent_events(23)),
+}
+
+
+def _grid_step(compiler, args, monkeypatch):
+    """The scan step with its pack stubbed out, compiled for ``args``: what
+    it emitted before the rows were packed on the device, ``[B, 2, C]``
+    grids."""
+    from siddhi_tpu.tpu import nfa as nfa_mod
+    with monkeypatch.context() as mp:
+        mp.setattr(nfa_mod, "pack_rows",
+                   lambda mask, cols, n: ({"mask": mask, **cols},
+                                          np.int64(0)))
+        return jax.jit(compiler.make_step()).lower(
+            compiler.init_state(), *args).compile()
+
+
+@pytest.mark.parametrize("name", list(SCAN_CORPUS))
+def test_the_row_table_holds_the_rows_the_boolean_index_gave(name,
+                                                             monkeypatch):
+    """Same rows, same order (match event, source, candidate), NULL masks
+    kept, batch after batch from the same carried state."""
+    app, events = SCAN_CORPUS[name]
+    rt = DeviceNFARuntime(app, slot_capacity=32, batch_capacity=48)
+    nfa = rt.compiler
+    assert not nfa.blocked and nfa.M <= 2 * 32 * 48
+    grid_step = None
+    state_g = nfa.init_state()
+    n_rows = n_null = 0
+    for sid, row, ts in events:
+        rt.builder.append(sid, row, ts)
+        if not rt.builder.full and (sid, row, ts) != events[-1]:
+            continue
+        b = rt.builder.emit()
+        args = (b["cols"], b["tag"], b["ts"], b["ts_base"],
+                np.int32(b["count"]))
+        grid_step = grid_step or _grid_step(nfa, args, monkeypatch)
+        state_g, grids = grid_step(state_g, *args)
+        assert grids["mask"].shape[0::2] == (48, 32)
+        rt.state, table = nfa._step(rt.state, *args)
+        assert set(table) == {"mask", "j"} | (set(grids) - {"mask"})
+        assert all(v.shape == (nfa.M,) for v in table.values())
+        mask = np.asarray(grids["mask"])                    # [B, 2, C]
+        got = nfa.decode_outputs(table)
+        assert len(got) == int(mask.sum())
+        for (col, _, _) in nfa.out_specs:
+            assert np.array_equal(np.asarray(grids[col])[mask],
+                                  got.cols[col] if len(got) else []), col
+            if f"null__{col}" in grids and len(got):
+                want = np.asarray(grids[f"null__{col}"])[mask]
+                assert np.array_equal(want, got.nulls[col]), col
+                n_null += int(want.sum())
+        j = np.asarray(table["j"])[np.asarray(table["mask"])]
+        assert np.array_equal(j, np.nonzero(mask)[0])       # the events
+        n_rows += len(got)
+        # the carried state is the same state
+        for a, b_ in zip(jax.tree_util.tree_leaves(state_g),
+                         jax.tree_util.tree_leaves(rt.state)):
+            assert np.array_equal(np.asarray(a), np.asarray(b_))
+    assert n_rows > 10
+    assert (n_null > 0) == name.endswith("nulls")
+    assert rt.drop_count == 0
+
+
+def test_rows_past_the_table_are_counted_into_drops():
+    """A table too small for what a batch emits: the first ``M`` rows in
+    order, the rest counted, never silent."""
+    from siddhi_tpu.tpu.nfa import pack_rows
+    rng = np.random.default_rng(5)
+    mask = rng.uniform(size=(16, 2, 40)) < 0.2
+    vals = rng.uniform(size=(16, 2, 40)).astype(np.float32)
+    want = vals[mask]
+    for n in (8, len(want), len(want) + 9):
+        out, lost = pack_rows(mask, {"x": vals}, n)
+        kept = min(n, len(want))
+        assert int(lost) == len(want) - kept
+        assert int(np.asarray(out["mask"]).sum()) == kept
+        assert np.array_equal(np.asarray(out["x"])[:kept], want[:kept])
+        assert np.array_equal(np.asarray(out["j"])[:kept],
+                              np.nonzero(mask)[0][:kept])
+        assert not np.asarray(out["x"])[kept:].any()
+
+
+@pytest.mark.parametrize("app, live, per_event", [
+    (APP_COUNT_KLEENE, 1, 1),       # only the count state's table fills
+    (APP_AND_CHAIN, 1, 1),          # p1 holds, the logical state waits there
+    (APP_ABSENT_CHAIN, 2, 1),       # p1 (absent, clocked) and p2
+    ("define stream A (v long); define stream B (v long);\n"
+     "from not A for 100 -> e2=B select e2.v as b insert into O;", 2, 2),
+], ids=["count", "logical", "absent", "absent-start"])
+def test_the_row_tables_size_is_read_from_the_plan(app, live, per_event):
+    rt = DeviceNFARuntime(app, slot_capacity=32, batch_capacity=48)
+    assert rt.compiler.M == live * 32 + per_event * 48
+
+
+def test_the_stacked_scan_decode_equals_the_per_lane_decode():
+    """As ``test_device_partition`` has it for the blocked kernel: on seeded
+    lane-stacked row tables of a count-state plan (NULL masks included),
+    the one-pass decode gives the rows a loop over lanes gives."""
+    app = """
+    define stream S (dev string, v double);
+    from every e1=S[v > 50.0] -> e2=S[v > e1.v]<2:5> -> e3=S[v < e1.v]
+    select e1.v as v1, e2[3].v as fourth, e3.v as back insert into Alerts;
+    """
+    rt = PartitionedNFARuntime(app, num_partitions=6, key_attr="dev",
+                               slot_capacity=16, lane_batch=32)
+    nfa = rt.compiler
+    assert rt.kernel == "scan" and nfa.M == 16 + 32
+    rng = np.random.default_rng(31)
+    for density in (0.0, 0.05, 0.4, 1.0):
+        mask = rng.uniform(size=(6, nfa.M)) < density
+        mask[4] = False
+        ys = {"mask": mask,
+              "j": np.sort(rng.integers(0, 32, (6, nfa.M)), axis=1).astype(
+                  np.int32),
+              "null__fourth": rng.uniform(size=(6, nfa.M)) < 0.5}
+        for name, _, _ in nfa.out_specs:
+            ys[name] = rng.uniform(0, 100, (6, nfa.M)).astype(np.float32)
+        old = []
+        for lane in range(6):
+            old.extend(nfa.decode_outputs(
+                jax.tree_util.tree_map(lambda x: x[lane], ys)).rows())
+        got = rt.decode_stacked(ys)
+        assert got.rows() == old and len(got) == int(mask.sum())
+        if density == 1.0:
+            assert any(r[1] is None for r in old)

@@ -895,6 +895,40 @@ insert into O;
 """
 
 
+SCOPED_SCAN_APP = """
+define stream S (dev string, v double);
+@device(batch='64', slots='16')
+from every a=S[v > 50.0] -> b=S[v > a.v]<3:> -> c=S[v < a.v] within 1000
+select a.v as v1, b[0].v as first, b[last].v as peak, c.v as back
+insert into M;
+"""
+# the blocked kernel's programs at the sizes of this file's fixtures, the
+# benchmark's two chain queries (`pattern-chain8` plain, `partitioned-chain`
+# under `vmap`): their optimized HLO on this backend at commit 8baf157,
+# before the scan kernel's rows were packed on the device (ISSUE 33)
+CHAIN8 = ("from every e1=S[v > 50.0] -> e2=S[v > e1.v] -> e3=S[v > e2.v] "
+          "-> e4=S[v > e3.v] -> e5=S[v > e4.v] -> e6=S[v > e5.v] "
+          "-> e7=S[v > e6.v] -> e8=S[v > e7.v] within 4000\n"
+          "select e1.v as v1, e2.v as v2, e3.v as v3, e4.v as v4, "
+          "e5.v as v5, e6.v as v6, e7.v as v7, e8.v as v8 "
+          "insert into Alerts;")
+BLOCKED_PROGRAMS = {
+    "pattern-chain8": (
+        "define stream S (dev string, v double);\n"
+        "@device(batch='64', slots='16')\n" + CHAIN8, 2461),
+    "partitioned-chain": (
+        "define stream S (dev string, v double);\n"
+        "partition with (dev of S) begin\n"
+        "@device(batch='256', slots='16', lanes='4')\n" + CHAIN8
+        + "\nend;", 2431),
+}
+
+
+def _instructions(text):
+    import re
+    return len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", text, re.M))
+
+
 def _compiled_step_text(app_text):
     """Optimized HLO of the app's one jitted device step."""
     import numpy as np
@@ -903,7 +937,9 @@ def _compiled_step_text(app_text):
         r = m.create_siddhi_app_runtime(
             app_text, playback=True).device_bridges[0].runtime
         b = r.builder.emit()
-        if hasattr(r, "compiler"):
+        if hasattr(r, "vstep"):         # a served partition: lanes stacked
+            low = r.vstep.lower(r.state, *r._lay_out(b))
+        elif hasattr(r, "compiler"):
             low = r.compiler._step.lower(
                 r.state, b["cols"], b["tag"], b["ts"], b["ts_base"],
                 np.int32(b["count"]))
@@ -920,7 +956,9 @@ def _compiled_step_text(app_text):
                           "nfa.emit", "nfa.compact")),
     (SCOPED_STREAM_APP, ("filter", "compact", "window.length", "groupby",
                          "select")),
-], ids=["nfa_block", "stream_query"])
+    (SCOPED_SCAN_APP, ("nfa.scan", "nfa.expire", "nfa.state0", "nfa.state1",
+                       "nfa.state2", "nfa.emit", "nfa.compact")),
+], ids=["nfa_block", "stream_query", "nfa_scan"])
 def test_jitted_stages_are_named_and_the_names_cost_no_operation(
         monkeypatch, app_text, scopes):
     import contextlib
@@ -928,8 +966,7 @@ def test_jitted_stages_are_named_and_the_names_cost_no_operation(
 
     import jax
 
-    def instructions(text):
-        return len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", text, re.M))
+    instructions = _instructions
 
     named = _compiled_step_text(app_text)
     op_names = re.findall(r'op_name="([^"]*)"', named)
@@ -941,3 +978,13 @@ def test_jitted_stages_are_named_and_the_names_cost_no_operation(
     assert not any(s in n.split("/") for s in scopes
                    for n in re.findall(r'op_name="([^"]*)"', bare))
     assert instructions(bare) == instructions(named) > 50
+
+
+@pytest.mark.parametrize("name", list(BLOCKED_PROGRAMS))
+def test_the_blocked_programs_are_the_programs_they_were(name):
+    """ISSUE 33 packs the SCAN kernel's rows on the device and gives both
+    kernels one decode; the blocked kernel's step must come out of it the
+    same program: the instruction count of its optimized HLO as at the
+    parent commit."""
+    app_text, at_parent = BLOCKED_PROGRAMS[name]
+    assert _instructions(_compiled_step_text(app_text)) == at_parent
