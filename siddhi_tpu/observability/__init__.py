@@ -135,9 +135,9 @@ class DeviceStepProbe:
         carries the measured serial segments of this batch's waterfall,
         keyed as :meth:`PhaseBreakdown.record_batch` names them
         (``fill_span_s``, ``pack_s``, ``ring_s``, ``queue_s``, ``step_s``,
-        ``fence_s``, ``decode_s``, ``lock_s``, ``publish_s``, ``host_s``,
-        ``cause``) — recorded event-weighted into the per-phase
-        histograms."""
+        ``fence_s``, ``decode_s``, ``decode_full_s``, ``lock_s``,
+        ``publish_s``, ``host_s``, ``cause``) — recorded event-weighted
+        into the per-phase histograms."""
         if device_path:
             self.steps += 1
             self.events += int(n_events)

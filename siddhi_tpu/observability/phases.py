@@ -47,10 +47,14 @@ trackers, and the ``/latency`` report):
   process-per-host fabric (dispatch wall-clock → child apply, including
   any lost-ack retry delay).
 
-One part of a phase is told apart without joining the serial sum
+Two parts of a phase are told apart without joining the serial sum
 (``NESTED``): ``route`` — a served partition's lane layout of the flat
 batch, inside ``device_step`` (``tpu/partition.py`` ``dispatch``; span
-``siddhi:dispatch.route``).
+``siddhi:dispatch.route``); ``decode_full`` — the blocked NFA's decode of
+its WHOLE candidate table, inside ``egress_decode``, which runs only for a
+batch in which a lane emitted more rows than the packed row table holds
+(``tpu/nfa.py`` ``decode_rows``; span ``siddhi:collect.decode.full``): its
+count over ``egress_decode``'s says how often that was.
 
 The driver's segments are also spans on the profiler's clock
 (``profiler.py``): ``siddhi:seal.pack`` = ``pack``, ``submit.ring_wait`` =
@@ -69,7 +73,7 @@ PHASES = ("ingress_parse", "ingress_queue", "ring_wait", "fill_wait", "pack",
 
 # a part of a phase told apart: recorded like a phase, outside the serial
 # sum (its parent already carries the time)
-NESTED = {"route": "device_step"}
+NESTED = {"route": "device_step", "decode_full": "egress_decode"}
 
 # span stage → phase (unknown stages are host work by default: every
 # host-side processor span nests inside the query chain)
@@ -121,7 +125,7 @@ class PhaseBreakdown:
                      publish_s: float = 0.0, host_s: float = 0.0,
                      parse_s: float = 0.0, ring_s: float = 0.0,
                      decode_s: float = 0.0, lock_s: float = 0.0,
-                     route_s: float = 0.0,
+                     route_s: float = 0.0, decode_full_s: float = 0.0,
                      cause: Optional[str] = None,
                      exemplar=None) -> None:
         if n <= 0:
@@ -138,9 +142,10 @@ class PhaseBreakdown:
             if v > 0.0:
                 self.trackers[phase].record_seconds(v, n, exemplar=exemplar)
                 total += v
-        if route_s > 0.0:       # inside device_step: not a segment
-            self.trackers["route"].record_seconds(route_s, n,
-                                                  exemplar=exemplar)
+        # inside device_step / egress_decode: not segments
+        for part, v in (("route", route_s), ("decode_full", decode_full_s)):
+            if v > 0.0:
+                self.trackers[part].record_seconds(v, n, exemplar=exemplar)
         self.end_to_end.record_seconds(total, n, exemplar=exemplar)
         self.e2e_sum += total * n
         if cause is not None:

@@ -27,6 +27,10 @@ span                             thread    opens / closes
 ``siddhi:collect.decode:<q>``    driver    the rest of ``collect``: the
                                            other copies out, the mask,
                                            string codes resolved
+``siddhi:collect.decode.full``   driver    inside it, only for a batch of
+``:<q>``                                   the blocked NFA whose rows pass
+                                           its packed table: the decode of
+                                           the whole candidate table
 ``siddhi:deliver:<q>``           driver    from asking for the engine lock
                                            to ``rt.deliver`` returning
 ``siddhi:deliver.lock:<q>``      driver    asking for the engine lock until

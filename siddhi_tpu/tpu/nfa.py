@@ -20,7 +20,8 @@ unbounded cloned ``StateEvent`` lists) becomes:
   (``mask``, ``j`` = the closing event's index, a column per output and per
   null mask), packed on the device after the scan (``pack_rows``): the
   per-event ``[2, C]`` emit grids never leave it. The blocked kernel
-  (``nfa_block.py``) hands out the same layout, so one decode serves both.
+  (``nfa_block.py``) packs its rows into the same layout, so one decode
+  (``decode_outputs``, one ``device_get`` a batch) serves both.
 
 Scope — 104/104 of the untimed reference pattern corpus compiles and
 matches the host oracle (pinned by ``tests/test_pattern_corpus.py::
@@ -55,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.pattern import CompiledPattern, PatternCompiler
+from ..observability.profiler import span
 from ..query_api import (
     Query,
     StateInputStream,
@@ -701,7 +703,11 @@ class DeviceNFACompiler:
             or (s > 0 and self.states[s - 1].kind != "count")
             or s in self.reseed_targets
             for s, st in enumerate(self.states)]
-        self.M = None if self.blocked else self._row_capacity()
+        # rows of the table a step hands out: the scan kernel's is a bound
+        # on what a batch can emit; the blocked kernel packs to the batch's
+        # event capacity and keeps its whole candidate table beside it for
+        # the batch that emits more (``decode_rows``)
+        self.M = self.B if self.blocked else self._row_capacity()
         if has_element_within and not self.blocked:
             # the blocked kernel masks per-state gaps on its grids; the scan
             # kernel's tables don't carry last-bind times
@@ -1960,6 +1966,13 @@ class DeviceNFACompiler:
         return self._step(state, batch["cols"], batch["tag"], batch["ts"],
                           batch["ts_base"], np.int32(batch["count"]))
 
+    @property
+    def fence_key(self) -> str:
+        """The step output the decode reads first, which ``StepRuntime.
+        _fence`` fetches: the blocked kernel's row count ``n`` (4 bytes a
+        lane), the scan kernel's ``mask``."""
+        return "n" if self.blocked else "mask"
+
     def decode_outputs(self, ys, lane_batch: Optional[int] = None):
         """One step's row table → a :class:`~siddhi_tpu.core.columns.
         ColumnsOut` (string codes stay codes; NULL cells ride as masks).
@@ -1967,25 +1980,53 @@ class DeviceNFACompiler:
         event's index in its batch) ``[M]`` with a column per output and
         per null mask; rows go out by match event, a match event's rows in
         table order (the scan kernel: source, then candidate; the blocked
-        kernel: candidate rank). ``lane_batch`` given, the table is
+        kernel: candidate rank, in its packed table and in its whole
+        candidate table ``full`` alike). ``lane_batch`` given, the table is
         lane-stacked ``[P, M]`` and decoded in one pass, lanes in order:
-        no loop over lanes."""
+        no loop over lanes. The table's leaves cross to the host in ONE
+        ``jax.device_get``: every copy is started before any is waited
+        for; whatever else ``ys`` holds stays where it is."""
         from ..core.columns import ColumnsOut
-        mask = np.asarray(ys["mask"])
+        names = [name for (name, _, _) in self.out_specs]
+        keys = ["mask", "j", *names,
+                *(f"null__{name}" for name in names
+                  if f"null__{name}" in ys)]
+        host = dict(zip(keys, jax.device_get([ys[k] for k in keys])))
+        mask = host["mask"]
         idx = np.flatnonzero(mask)
         if not idx.size:
             return ColumnsOut.empty(self.out_specs, self.merged.dictionaries)
-        j = np.asarray(ys["j"]).reshape(-1)[idx].astype(np.int64)
+        j = host["j"].reshape(-1)[idx].astype(np.int64)
         if lane_batch is not None:
             j += (idx // mask.shape[-1]) * lane_batch
         idx = idx[np.argsort(j, kind="stable")]
-        cols = {name: np.asarray(ys[name]).reshape(-1)[idx]
-                for (name, _, t) in self.out_specs}
-        nulls = {name: np.asarray(ys[f"null__{name}"]).reshape(-1)[idx]
-                 for (name, _, t) in self.out_specs
-                 if f"null__{name}" in ys}
+        cols = {name: host[name].reshape(-1)[idx] for name in names}
+        nulls = {name: host[f"null__{name}"].reshape(-1)[idx]
+                 for name in names if f"null__{name}" in host}
         return ColumnsOut(None, cols, int(idx.size), self.out_specs,
                           self.merged.dictionaries, nulls or None)
+
+
+def decode_rows(rt, ys, lane_batch: Optional[int] = None):
+    """``_decode`` of both NFA runtimes: one step's outputs as one
+    ``ColumnsOut``, through ``decode_outputs`` whichever table is read. The
+    scan kernel hands out one table. The blocked kernel hands out its rows
+    packed into ``M`` a lane and the count ``n`` (the fence has fetched
+    it): where no lane emitted more than ``M``, the packed table holds
+    every row; else the whole candidate table ``full`` is decoded, as every
+    batch was before PR 34: no row is lost and none is counted as a drop.
+    That decode is timed apart (``rt.decode_full_s``, the ``decode_full``
+    tracker; span ``siddhi:collect.decode.full``): how often it runs is
+    what the packed table's size is judged by."""
+    nfa = rt.compiler
+    full = ys.get("full")
+    if full is None or int(np.max(jax.device_get(ys["n"]))) <= nfa.M:
+        return nfa.decode_outputs(ys, lane_batch)
+    t0 = time.perf_counter()
+    with span(f"siddhi:collect.decode.full:{rt.query_name}"):
+        out = nfa.decode_outputs(full, lane_batch)
+    rt.decode_full_s = time.perf_counter() - t0
+    return out
 
 
 class DeviceNFARuntime(StepRuntime):
@@ -1993,8 +2034,6 @@ class DeviceNFARuntime(StepRuntime):
     front of one compiled NFA. Built from a compiler by the bridge
     (``compiler=``), or from app text when used by itself. NFA state carries
     no host-sync bookkeeping, so dispatch N+1 overlaps collect N."""
-
-    fence_key = "mask"
 
     def __init__(self, app_or_text=None, slot_capacity: int = 64,
                  batch_capacity: int = 1024, query_index: int = 0,
@@ -2007,6 +2046,7 @@ class DeviceNFARuntime(StepRuntime):
                 app.queries[query_index], dict(app.stream_definitions),
                 slot_capacity, batch_capacity)
         self.compiler = compiler
+        self.fence_key = compiler.fence_key
         self.builder = MergedBatchBuilder(
             compiler.merged, compiler.B, dict(compiler.stream_defs),
             used_cols=compiler.used_cols)
@@ -2024,7 +2064,7 @@ class DeviceNFARuntime(StepRuntime):
         return ys
 
     def _decode(self, ys):
-        return self.compiler.decode_outputs(ys)
+        return decode_rows(self, ys)
 
     @property
     def match_count(self) -> int:

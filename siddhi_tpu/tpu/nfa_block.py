@@ -34,6 +34,17 @@ one grid per state per batch" shape the verdict names. Sequences add the
 strict-continuity constraint ``vidx[j] == vidx[born]+1`` (``vidx`` = running
 count of valid events); ``within`` is a timestamp mask on the grid.
 
+What leaves the step: the last stage's candidates that advanced are the
+batch's rows. They are packed on the device (``pack_first``, the survivor
+pack's tool) to the front of a row table of ``M = B`` rows, the layout the
+scan kernel hands out (``mask``, ``j``, a column per output), with their
+count ``n``: that table and the count are what the host fetches, ``B x`` a
+row's bytes a batch instead of ``P x``. Every one of the ``P`` candidates may
+emit in one batch, so no table under ``P`` rows is a bound: the whole
+candidate table stays among the outputs (``full``; on the device, it costs no
+copy), and a batch whose ``n`` passes ``M`` is decoded from it
+(``nfa.decode_rows``). No emitted row is ever dropped or merely counted.
+
 Capacity semantics (documented divergence from the per-event kernel): within
 a batch the partial population grows exactly (static shapes, ``sC + B``; an
 optional ``creation_cap`` budget compacts each stage to ``[B, C+K]`` for very
@@ -219,12 +230,18 @@ def make_block_step(nfa: "DeviceNFACompiler"):
     """Returns step(state, cols, tag, ts, ts_base, nvalid) -> (state, ys)
     in the wire format (int32 ts deltas + int64 base, prefix validity).
 
-    ys: {"mask": [P] bool, "j": [P] i32 (match event index, for ordering),
-         <out-name>: [P] ...} and nothing else
-    where P = (S-1)*C + B for S > 1, else B: the row table
+    ys: {"n": i32, the rows this batch emitted,
+         "mask": [M] bool, "j": [M] i32 (match event index, for ordering),
+         <out-name>: [M] ...,
+         "full": {"mask", "j", <out-name>: [P]}        (S > 1 only)}
+    ``mask`` / ``j`` / the columns are the row table
     ``DeviceNFACompiler.decode_outputs`` reads, which the scan kernel hands
-    out too. A match's timestamp is the batch's ``ts[j]``, which the host
-    holds: it never leaves the device.
+    out too: the emitted rows packed to the front in candidate order,
+    ``M = B``. ``full`` is the last stage's whole candidate table in the same
+    layout, ``P = (S-1)*C + B``, read only for a batch with ``n > M`` (the
+    module text says why). ``S == 1`` emits ``[B]`` as it is. A match's
+    timestamp is the batch's ``ts[j]``, which the host holds: it never
+    leaves the device.
     """
     C, S, B = nfa.C, nfa.S, nfa.B
     states = nfa.states
@@ -287,7 +304,8 @@ def make_block_step(nfa: "DeviceNFACompiler"):
 
         if S == 1:
             # single-state every-pattern: each matching event IS a match
-            out = {"mask": gate0, "j": jidx}
+            n = jnp.sum(gate0, dtype=jnp.int32)
+            out = {"n": n, "mask": gate0, "j": jidx}
             emit_env = dict(ev_env)
             for (q, key, t) in referenced:
                 if q == 0:
@@ -296,7 +314,7 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                 out[name] = jnp.broadcast_to(
                     jnp.asarray(fn(emit_env)), (B,)).astype(_JNP[t])
             new_state = {"tables": tables, "drops": drops,
-                         "matches": matches + jnp.sum(gate0.astype(jnp.int64))}
+                         "matches": matches + n.astype(jnp.int64)}
             return new_state, out
 
         def compact(cre):
@@ -332,8 +350,7 @@ def make_block_step(nfa: "DeviceNFACompiler"):
             creations, dropped = compact(cre0)
             drops = drops + dropped
 
-        out_mask = out_j = None
-        out_cols = {}
+        ys = None
 
         for s in range(1, S):
             with jax.named_scope(f"nfa.stage{s}"):
@@ -402,13 +419,20 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                 if s == S - 1:
                     with jax.named_scope("nfa.emit"):
                         # ---- emission ------------------------------------
-                        out_mask = adv
-                        out_j = jstar
+                        rows = {"j": jstar}
                         for (name, fn, t) in out_specs:
-                            out_cols[name] = jnp.broadcast_to(
+                            rows[name] = jnp.broadcast_to(
                                 jnp.asarray(fn(carried)), (P,)).astype(
                                     _JNP[t])
-                        matches = matches + jnp.sum(adv.astype(jnp.int64))
+                        n = jnp.sum(adv, dtype=jnp.int32)
+                        matches = matches + n.astype(jnp.int64)
+                        # the rows that matched, packed to the front of a
+                        # [B] table; rows past it are NOT dropped: the
+                        # decode reads ``full`` when n says there are any
+                        taken, packed, _ = pack_first(
+                            adv, B, rows, {k: 0 for k in rows})
+                        ys = {"n": n, "mask": taken, **packed,
+                              "full": {"mask": adv, **rows}}
                 else:
                     # ---- creations for state s+1 -------------------------
                     # an advanced candidate existed, so it carries its seed
@@ -456,7 +480,6 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                     drops = drops + dropped
 
         new_state = {"tables": tables, "matches": matches, "drops": drops}
-        ys = {"mask": out_mask, "j": out_j, **out_cols}
         return new_state, ys
 
     return step

@@ -29,7 +29,7 @@ from ..compiler import parse as _parse
 from ..observability.profiler import span
 from ..query_api.definition import DataType
 from .expr_compile import DeviceCompileError
-from .nfa import DeviceNFACompiler, MergedBatchBuilder
+from .nfa import DeviceNFACompiler, MergedBatchBuilder, decode_rows
 from .step_runtime import StepRuntime
 
 log = logging.getLogger("siddhi_tpu.device")
@@ -269,7 +269,10 @@ class PartitionedNFARuntime(StepRuntime):
     whichever kernel the compiler chose for the pattern (``kernel``): the
     blocked one for chains of stream states under ``every``, the per-event
     scan for count (``<m:n>``), logical and absent states; both hand out
-    one ``[P, M]`` row table.
+    one ``[P, M]`` row table, and that table is what a batch's decode
+    fetches (the blocked kernel keeps its whole ``[P, (S-1)C + B]``
+    candidate table on the device beside it, read only for a batch in which
+    a lane emitted more than ``M`` rows).
     """
 
     def __init__(self, app_or_text, num_partitions: int,
@@ -306,6 +309,7 @@ class PartitionedNFARuntime(StepRuntime):
         self.compiler = DeviceNFACompiler(
             query, self.stream_defs, slot_capacity, lane_batch,
             creation_cap=creation_cap)
+        self.fence_key = self.compiler.fence_key
         merged = self.compiler.merged
         # the dictionary a string key is coded in (ONE shared by every
         # string column of the merged schema); None for other key types
@@ -646,8 +650,10 @@ class PartitionedNFARuntime(StepRuntime):
         """One step's lane-stacked row tables ``[P, M]`` -> ONE
         ``ColumnsOut``, lanes in order and a lane's rows as its own decode
         orders them (by match event ``j``, then table order), in one pass
-        over the whole: no loop over lanes, whichever kernel stepped."""
-        return self.compiler.decode_outputs(ys, lane_batch=self.lane_batch)
+        over the whole: no loop over lanes, whichever kernel stepped (the
+        blocked kernel's whole candidate tables where a lane emitted more
+        than ``M`` rows: ``decode_rows``)."""
+        return decode_rows(self, ys, lane_batch=self.lane_batch)
 
     @property
     def kernel(self) -> str:
@@ -655,7 +661,6 @@ class PartitionedNFARuntime(StepRuntime):
         return "blocked" if self.compiler.blocked else "scan"
 
     # -- the served interface: StepRuntime's, with these supplied --------------
-    fence_key = "mask"
     _decode = decode_stacked
 
     def dispatch(self, batch: dict):

@@ -42,6 +42,8 @@ class StepRuntime:
     fence_s = None              # the last collect's wait for the device,
     # left by _fence for whoever called collect (driver thread, or the
     # client on the sync path); None after a collect that never fenced
+    decode_full_s = None        # left by a decode that read the blocked
+    # NFA's whole candidate table (nfa.decode_rows); step_phases takes it
     _pending_cause = None       # cause of the flush whose emit comes next
     driver = None               # AsyncDeviceDriver when the bridge pipelines
     callback = None             # deliver()'s fn(chunk, emit_ts)
@@ -196,13 +198,14 @@ class StepRuntime:
         if obs is not None:
             obs(n_events, latency_s, device_path, phases=phases)
 
-    @staticmethod
-    def step_phases(batch: dict, queue_s: float, step_s: float,
+    def step_phases(self, batch: dict, queue_s: float, step_s: float,
                     fence_s: float, decode_s: float, **driver_s) -> dict:
         """One device batch's waterfall as ``PhaseBreakdown.record_batch``
         names it: what the batch carries (fill span, pack, route, cause),
-        what whoever stepped it measured, and in ``driver_s`` what only the
-        async driver has (``ring_s``, ``lock_s``, ``publish_s``)."""
+        what whoever stepped it measured, what its decode left on the
+        runtime (``decode_full_s``, taken here) and in ``driver_s`` what
+        only the async driver has (``ring_s``, ``lock_s``, ``publish_s``)."""
+        full_s, self.decode_full_s = self.decode_full_s, None
         return {
             "fill_span_s": batch.get("pack_s", 0.0),
             "pack_s": batch.get("pack_exec_s", 0.0),
@@ -211,6 +214,7 @@ class StepRuntime:
             "route_s": batch.get("_route_s", 0.0),
             "fence_s": fence_s,
             "decode_s": decode_s,
+            "decode_full_s": full_s or 0.0,
             "cause": batch.get("_cause"),
             **driver_s,
         }

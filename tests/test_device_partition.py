@@ -23,6 +23,7 @@ from siddhi_tpu.tpu.partition import (
     PartitionedNFARuntime,
     lane_capacity_for,
 )
+from util_parity import assert_same_chunk
 
 CHAIN = ("from every e1=S[v > 50.0] -> e2=S[v > e1.v] -> e3=S[v > e2.v] "
          "within {within}\n"
@@ -434,6 +435,197 @@ def test_the_stacked_decode_equals_the_per_lane_decode():
         assert isinstance(got, ColumnsOut)
         assert got.rows() == old
         assert len(got) == int(mask.sum())
+
+
+# ---------------------------------------------------------------------------
+# the lanes' rows, packed on the device (PR 34): what the host fetches
+# ---------------------------------------------------------------------------
+
+def _tap_decode(r, on_step):
+    """Calls ``on_step(ys)`` with every step's un-decoded outputs."""
+    inner = r._decode
+
+    def decode(ys):
+        on_step(ys)
+        return inner(ys)
+
+    r._decode = decode
+
+
+@pytest.mark.parametrize("zipf", [False, True], ids=["uniform", "zipf"])
+@pytest.mark.parametrize("served", [True, False], ids=["served", "direct"])
+def test_the_packed_lane_tables_hold_the_full_tables_rows(served, zipf):
+    """The partition fuzz's streams, step after step: the rows decoded from
+    the lanes' packed ``[P, M]`` tables are the rows decoded from their
+    whole candidate tables, element for element and in order, ``n`` counts
+    them a lane, and the whole-table decode never ran."""
+    devs, vs = _events(1500, keys=48, seed=7, zipf=zipf)
+    want = _interpreter(devs, vs)
+    steps: list = []
+    m = None
+    if served:
+        m = SiddhiManager()
+        rt = m.create_siddhi_app_runtime(
+            _app(_device(batch=128, slots=96, lanes=4)), playback=True)
+        rows: list = []
+        rt.add_callback("Alerts", StreamCallback(
+            lambda evs: rows.extend(tuple(e.data) for e in evs)))
+        rt.start()
+        r = rt.device_bridges[0].runtime
+    else:
+        r = PartitionedNFARuntime(_app(), num_partitions=4, key_attr="dev",
+                                  slot_capacity=96, lane_batch=64)
+        rows = []
+        r.callback = rows.extend
+    nfa = r.compiler
+
+    def on_step(ys):
+        assert set(ys) == {"n", "mask", "j", "v1", "v2", "v3", "full"}
+        packed = nfa.decode_outputs(ys, lane_batch=r.lane_batch)
+        full = nfa.decode_outputs(ys["full"], lane_batch=r.lane_batch)
+        assert_same_chunk(packed, full)
+        n = np.asarray(ys["n"])
+        assert n.shape == (4,) and int(n.sum()) == len(full)
+        assert np.array_equal(np.asarray(ys["mask"]).sum(axis=1), n)
+        steps.append(len(full))
+
+    if served:
+        _tap_decode(r, on_step)
+    else:
+        inner = r.decode_stacked
+        r.decode_stacked = lambda ys: (on_step(ys), inner(ys))[1]
+    try:
+        if served:
+            _send(rt, devs, vs, True, chunk=100)
+            rt.flush_device()
+        else:
+            for i in range(len(vs)):
+                r.send("S", [devs[i], float(vs[i])], 1000 + i)
+            r.flush()
+        assert nfa.M == r.lane_batch
+        assert len(steps) > 5 and sum(steps) == len(rows) > 20
+        assert r.decode_full_s is None and r.drop_count == 0
+        assert _f32(rows) == want
+        if served:
+            phases = rt.device_bridges[0].probe.phases
+            assert phases.trackers["decode_full"].hist.count == 0
+            assert phases.trackers["egress_decode"].hist.count == len(vs)
+    finally:
+        if m is not None:
+            m.shutdown()
+
+
+def _one_lane_closes_many(n_wait=80):
+    """A stream in which ONE key closes ``n_wait`` partials with one event:
+    they wait two states deep (two batches in the making), then all emit
+    in one batch, in one lane."""
+    devs, vs = _events(240, keys=12, seed=43)
+    hot = np.array(["hot"] * (n_wait + 2), dtype=object)
+    hot_v = np.array([52.0 - i / 1000 for i in range(n_wait)] + [60.0, 70.0])
+    return (np.concatenate([devs[:120], hot, devs[120:]]),
+            np.concatenate([vs[:120], hot_v, vs[120:]]))
+
+
+@pytest.mark.parametrize("columns", [False, True], ids=["send", "columns"])
+def test_a_lane_that_emits_more_than_its_packed_table_loses_no_row(columns):
+    """``M`` (a lane's event capacity, 64 here) is no bound on a lane's rows:
+    80 waiting partials close on one event. The decode reads the whole
+    candidate tables for that batch and for no other: every row is
+    delivered and equals the interpreter's, nothing is a drop, one
+    ``decode_full`` is counted."""
+    devs, vs = _one_lane_closes_many()
+    want = _interpreter(devs, vs)
+    m = SiddhiManager()
+    rt = m.create_siddhi_app_runtime(
+        _app(_device(batch=64, slots=128, lanes=4)), playback=True)
+    rows: list = []
+    rt.add_callback("Alerts", StreamCallback(
+        lambda evs: rows.extend(tuple(e.data) for e in evs)))
+    rt.start()
+    r = rt.device_bridges[0].runtime
+    most: list = []
+    _tap_decode(r, lambda ys: most.append(int(np.asarray(ys["n"]).max())))
+    try:
+        _send(rt, devs, vs, columns, chunk=50)
+        rt.flush_device()
+        assert r.compiler.M == 64
+        assert sum(n > 64 for n in most) == 1 and max(most) >= 80
+        assert _f32(rows) == want and len(rows) >= 80
+        assert r.drop_count == 0 and r.lane_gauges["drops"] == 0
+        phases = rt.device_bridges[0].probe.phases
+        # event-weighted like every phase: that one batch's events
+        assert 0 < phases.trackers["decode_full"].hist.count <= 64
+        assert phases.trackers["egress_decode"].hist.count == len(vs)
+        assert rt.device_bridges[0].probe.steps == len(most)
+        rep = rt.observability.latency_report()["queries"]
+        (entry,) = [v for v in rep.values() if "lanes" in v]
+        assert "decode_full" in entry["phases"]
+        assert entry["reconciliation_ratio"] == pytest.approx(1.0, abs=1e-6)
+    finally:
+        m.shutdown()
+
+
+def test_one_compile_and_only_the_packed_tables_cross_to_the_host(
+        monkeypatch):
+    """What one step of a served chain partition brings to the host: the
+    fence fetches ``n`` (a count a lane), the decode ``(2 + outputs) x lanes
+    x M`` elements in ONE ``device_get``, and nothing ``[.., P]`` wide (the
+    whole candidate tables stay on the device); one compile of ``vstep``."""
+    import jax
+
+    devs, vs = _events(1000, keys=30, seed=29)
+    m = SiddhiManager()
+    rt = m.create_siddhi_app_runtime(
+        _app(_device(batch=128, lanes=8, slots=64)), playback=True)
+    rows: list = []
+    rt.add_callback("Alerts", StreamCallback(
+        lambda evs: rows.extend(tuple(e.data) for e in evs)))
+    rt.start()
+    r = rt.device_bridges[0].runtime
+    lanes, slots, rows_m = 8, 64, lane_capacity_for(128, 8)
+    wide = 2 * slots + rows_m                   # P of the 3-state chain
+    fenced, fetched, handed = [], [], []
+    inner_fence, inner_decode = r._fence, r._decode
+    real_get = jax.device_get
+    in_decode = [False]
+
+    def device_get(x):
+        if in_decode[0]:
+            fetched[-1].append([tuple(a.shape) for a in jax.tree.leaves(x)])
+        return real_get(x)
+
+    def decode(ys):
+        handed.append(jax.tree.map(lambda a: tuple(a.shape), ys))
+        fetched.append([])
+        in_decode[0] = True
+        try:
+            return inner_decode(ys)
+        finally:
+            in_decode[0] = False
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    r._fence = lambda first: (fenced.append(tuple(first.shape)),
+                              inner_fence(first))[1]
+    r._decode = decode
+    try:
+        _send(rt, devs, vs, True, chunk=100)
+        rt.flush_device()
+        assert r.compiler.M == rows_m and r.fence_key == "n"
+        assert r.vstep._cache_size() == 1
+        assert len(handed) == rt.device_bridges[0].probe.steps == 8
+        assert rows
+        table = {k: (lanes, rows_m) for k in ("mask", "j", "v1", "v2", "v3")}
+        for ys in handed:       # on the device: both tables and the count
+            assert ys == {"n": (lanes,), **table,
+                          "full": {k: (lanes, wide) for k in table}}
+        assert fenced == [(lanes,)] * 8
+        for step in fetched:    # to the host: n again (cached), ONE table
+            assert step == [[(lanes,)], [(lanes, rows_m)] * 5]
+            assert sum(int(np.prod(s)) for s in step[1]) \
+                == (2 + 3) * lanes * rows_m
+        assert r.decode_full_s is None
+    finally:
+        m.shutdown()
 
 
 # ---------------------------------------------------------------------------

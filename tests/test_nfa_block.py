@@ -1,13 +1,14 @@
 """Blocked NFA kernel (nfa_block.py): parity vs the host oracle AND vs the
 per-event scan kernel, kernel-selection logic, capacity semantics."""
 
+import functools
 import random
 
 import pytest
 
 from siddhi_tpu import SiddhiManager, StreamCallback
 from siddhi_tpu.tpu.nfa import DeviceNFACompiler, DeviceNFARuntime
-from util_parity import assert_rows_match
+from util_parity import assert_rows_match, assert_same_chunk
 
 
 def oracle(app, events, out="O"):
@@ -346,8 +347,9 @@ def blocked_runtime(app, creation_cap=None, **sizes):
                          ids=["exact_growth", "creation_budget"])
 def test_blocked_step_compiles_to_no_scatter(lanes, creation_cap):
     """Every stage's survivor pack (and the creation budget's, where one is
-    set) is index-once, gather-n: the optimized HLO of the jitted step holds
-    gathers and not one scatter, alone and vmapped over lanes."""
+    set) and the emitted rows' pack into the ``[B]`` row table (PR 34) are
+    index-once, gather-n: the optimized HLO of the jitted step holds gathers
+    and not one scatter, alone and vmapped over lanes."""
     import re
 
     import jax
@@ -372,12 +374,14 @@ def test_blocked_step_compiles_to_no_scatter(lanes, creation_cap):
     assert not re.search(r"\bscatter\b", text), sorted(set(opcodes))
     # the mechanism engaged: every waiting state's pack is one gather of
     # rows (the creation budget packs the seeds and each stage's creations
-    # too), and no other gather is left: a stage fetches by jstar inside its
-    # reduce
+    # too), the last stage packs its emitted rows by one more, and no other
+    # gather is left: a stage fetches by jstar inside its reduce
     packs = [g for g in _gathers(text) if "nfa.compact" in g[1]]
     assert len(packs) == nfa.S - 1
+    emits = [g for g in _gathers(text) if "nfa.emit" in g[1]]
+    assert len(emits) == 1 and f"nfa.stage{nfa.S - 1}" in emits[0][1]
     assert opcodes.count("gather") == \
-        (2 if creation_cap is not None else 1) * (nfa.S - 1)
+        (2 if creation_cap is not None else 1) * (nfa.S - 1) + 1
 
 
 def _gathers(hlo_text):
@@ -394,7 +398,8 @@ def test_plain_chain_gathers_nothing_by_jstar(lanes):
     candidate, the state's new binding (the outputs' column at the last
     stage), and it rides the stage's reduce: the optimized HLO holds no
     gather with a 64-bit result (the dead ``ts[jstar]``, which a TPU runs as
-    two) and none at all outside the survivor pack, alone and vmapped."""
+    two) and none at all outside the survivor packs and the emitted rows'
+    pack, alone and vmapped."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -413,9 +418,10 @@ def test_plain_chain_gathers_nothing_by_jstar(lanes):
             lambda x: jnp.broadcast_to(jnp.asarray(x)[None],
                                        (lanes,) + jnp.shape(x)), args)
     gathers = _gathers(jax.jit(step).lower(*args).compile().as_text())
-    assert len(gathers) == nfa.S - 1
+    assert len(gathers) == nfa.S
     assert not [g for g in gathers if g[0][1:3] == "64"], gathers
-    assert all("nfa.compact" in g[1] for g in gathers), gathers
+    assert all("nfa.compact" in g[1] or "nfa.emit" in g[1]
+               for g in gathers), gathers
 
 
 def test_creation_budget_counts_what_it_drops_and_keeps_the_oldest():
@@ -573,7 +579,9 @@ def test_plan_features_match_the_interpreter(feature, batch):
     """Each thing a stage may fetch by ``jstar`` (a new binding, two of them,
     the rank in a sequence, the time under element-level `within`, the
     output's event columns) against the scalar interpreter, and the step's
-    outputs hold ``mask``, ``j`` and the output columns and nothing else."""
+    outputs hold the count ``n`` and ``mask``, ``j`` and the output columns
+    twice, packed ``[B]`` and whole ``[P]`` (once where one state emits
+    ``[B]`` as it is), and nothing else."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -595,6 +603,221 @@ def test_plan_features_match_the_interpreter(feature, batch):
     _, ys = nfa.make_step()(
         nfa.init_state(), b["cols"], b["tag"], b["ts"],
         jnp.asarray(b["ts_base"]), jnp.asarray(np.int32(b["count"])))
-    assert set(ys) == {"mask", "j"} | {name for name, _, _ in nfa.out_specs}
-    width = (nfa.S - 1) * nfa.C + nfa.B if nfa.S > 1 else nfa.B
-    assert all(v.shape == (width,) for v in ys.values())
+    table = {"mask", "j"} | {name for name, _, _ in nfa.out_specs}
+    full = ys.pop("full", None)
+    assert set(ys) == {"n"} | table
+    assert ys.pop("n").shape == () and nfa.M == nfa.B
+    assert all(v.shape == (nfa.B,) for v in ys.values())
+    assert (full is None) == (nfa.S == 1)
+    if full is not None:
+        assert set(full) == table
+        assert all(v.shape == ((nfa.S - 1) * nfa.C + nfa.B,)
+                   for v in full.values())
+
+
+# -- the emitted rows, packed on the device (PR 34) ---------------------------
+# The step hands out its rows twice: packed to the front of a [B] table (what
+# the host fetches) and as the last stage's whole [P] candidate table (what it
+# fetched until PR 34, read now only for a batch that emitted more than B).
+
+def _seq_events(n, seed):
+    rng = random.Random(seed)
+    return [("S", [round(rng.uniform(0, 30), 1)], 1000 + i * 50)
+            for i in range(n)]
+
+
+PACKED_CORPUS = {
+    "chain3": (CHAIN3, gen_one_stream),
+    "chain4": (CHAIN4, gen_one_stream),
+    "two_stream": (TWO_STREAM, gen_two_stream),
+    "sequence2": (SEQ2, _seq_events),
+    **{f"plan_{k}": (app, gen_one_stream)
+       for k, (app, _) in PLAN_FEATURES.items()},
+}
+
+
+def _lane_batches(app, events_by_lane, slots, batch):
+    """``(compiler, [[wire batch of lane l] for each step])``: one builder a
+    lane, every lane the same number of events, so the lanes' batches line
+    up step for step (the last one partial)."""
+    rts = [DeviceNFARuntime(app, slot_capacity=slots, batch_capacity=batch)
+           for _ in events_by_lane]
+    steps = []
+    n = len(events_by_lane[0])
+    for i in range(n):
+        for rt, events in zip(rts, events_by_lane):
+            rt.builder.append(*events[i])
+        if rts[0].builder.full or i == n - 1:
+            steps.append([rt.builder.emit() for rt in rts])
+    return rts[0], steps
+
+
+def _wire(b):
+    import numpy as np
+    return (b["cols"], b["tag"], b["ts"], np.int64(b["ts_base"]),
+            np.int32(b["count"]))
+
+
+def _check_tables(rt, ys, lane_batch=None):
+    """One step's outputs: the whole candidate table's decode (the step's
+    own ``[B]`` where one state emits it as it is), after holding the other
+    readings to it: ``n`` counts its rows (a lane); ``decode_rows`` gives
+    them whichever table it reads; the packed table holds them all, at its
+    front and in order, wherever no lane emitted more than ``M``, and the
+    first ``M`` of a lane that did."""
+    import numpy as np
+    from siddhi_tpu.tpu.nfa import decode_rows
+
+    nfa = rt.compiler
+    full = nfa.decode_outputs(ys.get("full", ys), lane_batch)
+    n = np.asarray(ys["n"])
+    assert n.dtype == np.int32 and int(n.sum()) == len(full)
+    assert_same_chunk(full, decode_rows(rt, ys, lane_batch))
+    packed = nfa.decode_outputs(ys, lane_batch)
+    if n.max() <= nfa.M:
+        assert_same_chunk(full, packed)
+    if "full" in ys:
+        mask = np.asarray(ys["mask"])
+        assert mask.shape[-1] == nfa.M
+        taken = np.minimum(n, nfa.M)
+        assert np.array_equal(
+            mask, np.arange(nfa.M) < np.expand_dims(taken, -1))
+    return full
+
+
+@pytest.mark.parametrize("batch", [32, 7], ids=["B32", "B7"])
+@pytest.mark.parametrize("name", list(PACKED_CORPUS))
+def test_the_packed_rows_are_the_full_tables_rows(name, batch):
+    """Batch after batch from the same carried state, one lane alone and
+    three lanes stacked under ``vmap``: the rows decoded from the packed
+    ``[B]`` table are the rows decoded from the whole ``[P]`` candidate
+    table, element for element and in order; ``n`` counts them; the stacked
+    decode is the lanes' decodes one after another. Batches of 7 events
+    emit more than 7 rows now and then: those are read from the whole
+    table, and only those."""
+    import jax
+    import numpy as np
+
+    app, gen = PACKED_CORPUS[name]
+    lanes = [gen(100, 60 + lane) for lane in range(3)]
+    rt, steps = _lane_batches(app, lanes, slots=24, batch=batch)
+    nfa = rt.compiler
+    assert nfa.blocked and nfa.M == nfa.B == batch
+    vstep = jax.jit(jax.vmap(nfa.make_step()))
+    stacked = jax.tree.map(lambda *xs: np.stack(xs),
+                           *[nfa.init_state() for _ in lanes])
+    single = [nfa.init_state() for _ in lanes]
+    n_rows = n_over = 0
+    for batches in steps:
+        by_lane = []
+        for lane, b in enumerate(batches):
+            single[lane], ys = nfa._step(single[lane], *_wire(b))
+            rt.decode_full_s = None
+            by_lane.append(_check_tables(rt, ys))
+            over = "full" in ys and len(by_lane[-1]) > nfa.M
+            assert (rt.decode_full_s is not None) == over
+            n_over += over
+        stacked, ys = vstep(stacked, *jax.tree.map(
+            lambda *xs: np.stack(xs), *[_wire(b) for b in batches]))
+        assert np.asarray(ys["n"]).tolist() == [len(c) for c in by_lane]
+        rows = _check_tables(rt, ys, lane_batch=nfa.B)
+        assert rows.rows() == [r for c in by_lane for r in c.rows()]
+        n_rows += len(rows)
+    assert n_rows > 5
+    assert not (n_over and batch == 32)
+
+
+@functools.lru_cache(maxsize=None)
+def _blocked_shapes(n):
+    """Seeded random chains of ``test_nfa_fuzz`` that take the blocked
+    kernel (patterns and sequences, one or two streams, ``within``)."""
+    from test_nfa_fuzz import START, _chain, _events
+    from siddhi_tpu.tpu.expr_compile import DeviceCompileError
+    out, seed = [], 0
+    while len(out) < n:
+        rng = random.Random(3400 + seed)
+        seed += 1
+        app, two = _chain(rng)
+        try:
+            rt = DeviceNFARuntime(app, slot_capacity=8, batch_capacity=8,
+                                  start_time=START)
+        except DeviceCompileError:
+            continue
+        if rt.compiler.blocked:
+            out.append((app, _events(rng, 60, two), rng.choice([8, 16, 32])))
+    return out
+
+
+@pytest.mark.parametrize("case", range(10))
+def test_the_packed_rows_are_the_full_tables_rows_seeded_sweep(case):
+    """The same over random blocked shapes: whichever table a batch's rows
+    are read from (``decode_rows`` chooses by ``n``), they are the whole
+    candidate table's rows in its order, and the packed table's wherever it
+    holds them all."""
+    app, events, batch = _blocked_shapes(10)[case]
+    rt, steps = _lane_batches(app, [events], slots=64, batch=batch)
+    nfa = rt.compiler
+    state = nfa.init_state()
+    for (b,) in steps:
+        state, ys = nfa._step(state, *_wire(b))
+        _check_tables(rt, ys)
+    assert int(state["drops"]) == 0
+
+
+OVERFLOW = """
+define stream S (v double);
+{device}
+from every e1=S[v > 0.0] -> e2=S[v > 1000.0]
+select e1.v as a, e2.v as b insert into O;
+"""
+
+
+def _overflow_events():
+    # 30 partials wait in a table of 32; ONE event closes them all, in a
+    # batch of 8: 30 rows where the packed table holds 8
+    return [("S", [float(i + 1)], 1000 + i) for i in range(30)] + \
+        [("S", [1500.0], 1100)] + \
+        [("S", [float(i + 1)], 1200 + i) for i in range(5)]
+
+
+def test_a_batch_that_emits_more_than_the_packed_table_reads_the_full_one():
+    """No static table under ``P`` rows is a bound (every candidate may
+    emit in one batch): the decode sees ``n > M`` and reads the whole
+    candidate table, every row delivered and equal to the interpreter's,
+    nothing counted as a drop, one ``decode_full`` recorded."""
+    events = _overflow_events()
+    want = oracle(OVERFLOW.format(device=""), events)
+    assert len(want) == 30
+
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(OVERFLOW.format(
+            device="@device(strict='true', batch='8', slots='32')"),
+            playback=True)
+        got = []
+        rt.add_callback("O", StreamCallback(
+            lambda evs: got.extend(e.data for e in evs)))
+        rt.start()
+        r = rt.device_bridges[0].runtime
+        counts = []
+        inner = r._decode
+        r._decode = lambda ys: (counts.append(int(ys["n"])), inner(ys))[1]
+        for sid, row, ts in events:
+            rt.input_handler(sid).send(row, timestamp=ts)
+        rt.flush_device()
+        assert r.compiler.M == 8 and max(counts) == 30
+        assert sum(c > 8 for c in counts) == 1
+        assert_rows_match(want, got)
+        assert [tuple(r_) for r_ in got] == [tuple(w) for w in want]
+        assert r.drop_count == 0 and r.match_count == 30
+        phases = rt.device_bridges[0].probe.phases
+        full, decode = (phases.trackers[k].hist.count
+                        for k in ("decode_full", "egress_decode"))
+        # event-weighted, like every phase: the one batch's 8 events
+        assert full == 8 and decode == len(events)
+        rep = rt.observability.latency_report()["queries"]
+        (entry,) = rep.values()
+        assert "decode_full" in entry["phases"]
+        assert entry["reconciliation_ratio"] == pytest.approx(1.0, abs=1e-6)
+    finally:
+        m.shutdown()

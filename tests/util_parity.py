@@ -50,3 +50,17 @@ def assert_rows_match(expected, actual, rel=1e-5, abs_=1e-5):
         else:
             raise AssertionError(f"oracle row {e} has no device match; "
                                  f"unmatched device rows: {remaining[:5]}")
+
+
+def assert_same_chunk(a, b):
+    """Two ``ColumnsOut`` chunks element for element, in order, dtypes and
+    NULL masks included."""
+    import numpy as np
+    assert len(a) == len(b) and list(a.cols) == list(b.cols)
+    for name in a.cols:
+        if len(a):
+            assert a.cols[name].dtype == b.cols[name].dtype, name
+        assert np.array_equal(a.cols[name], b.cols[name]), name
+    assert (a.nulls is None) == (b.nulls is None)
+    for name in a.nulls or {}:
+        assert np.array_equal(a.nulls[name], b.nulls[name]), name
