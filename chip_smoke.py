@@ -23,7 +23,11 @@ row that comes out with the scalar interpreter (the same app text without
       of ``benchmark/configs/partitioned-kleene.siddhi`` (a count state: the
       lanes step the per-event scan kernel), held to the interpreter too;
 - S4  S3 again with its lanes sharded over four chips (skipped on one);
-- S5  a compile sweep over every kind the device compilers accept.
+- S5  a compile sweep over every kind the device compilers accept;
+- S6  served, grouped hopping window with the selector's tail: NEXmark
+      Query 5 (``benchmark/configs/nexmark-q5.small.siddhi``'s query),
+      100,000 bids of 4,096 auctions (ids above 2^33) through
+      ``send_columns``: one row a boundary, the top auction by count.
 
 The served stages also read what a fallback would hide: the DeviceGuard's
 counters, where the state lives, the probe's step and event counts, the
@@ -63,6 +67,7 @@ FULL = {
     "s3_lane_batch": 2048, "s3_slots": 512, "s3_oracle": 200_000,
     "s3_min_rows": 1000,
     "s3b_batch": 32768, "s3b_kleene_events": 100_000,
+    "s6_events": 100_000,
 }
 # --rehearsal: the same stages and shapes with the stream cut short and the
 # flagship's lane grid shrunk, so a CPU gets through in half a minute.
@@ -73,6 +78,7 @@ REHEARSAL = {
     "s3_events": 40_000, "s3_keys": 128, "s3_lanes": 8,
     "s3_lane_batch": 256, "s3_oracle": 12_000, "s3_min_rows": 1,
     "s3b_batch": 1024, "s3b_kleene_events": 6_000,
+    "s6_events": 30_000,
 }
 N_STATES = 8
 # overflow counters of the device kernels (core/device_bridge.py warns on
@@ -644,6 +650,10 @@ select sym, vol, sum(vol) as s, count() as c insert into O;"""),
     ("window.hopping", "S", _S + """{device}
 from S#window.hopping(1 sec, 400)
 select sum(price) as total, count() as c, max(price) as hi insert into O;"""),
+    ("window.hopping group-by + order by / limit", "S", _S + """{device}
+from S#window.hopping(1 sec, 400)
+select sym, sum(vol) as s, count() as c, max(price) as hi
+group by sym order by c desc, s limit 2 insert into O;"""),
     ("stdDev running", "S", _S + """{device}
 from S select sym, stdDev(price) as sd, count() as c insert into O;"""),
     ("stdDev window.length", "S", _S + """{device}
@@ -781,8 +791,57 @@ def stage_s5(cfg, seed, platform, warnings, keep):
 # main
 # ---------------------------------------------------------------------------
 
+# ---------------------------------------------------------------------------
+# S6 — served, grouped hopping flush + order by / limit (NEXmark Query 5)
+# ---------------------------------------------------------------------------
+
+S6_APP = """
+define stream Bid (auction long, bidder long, price long);
+{device}
+from Bid#window.hopping(20000, 4000)
+select auction, count() as num
+group by auction
+order by num desc
+limit 1
+insert into HotItems;
+"""
+
+
+def stage_s6(cfg, seed, platform, warnings, keep):
+    import numpy as np
+
+    n, chunk = cfg["s6_events"], 8192
+    rng = np.random.default_rng(seed + 6)
+    # half the bids on a hot auction that moves on every 1,536 bids, ids
+    # past 32 bits: a key folded to its low word would meet another
+    auction = ((rng.zipf(1.7, n) - 1 + np.arange(n) // 1536 * 100) % 4096
+               + 2 ** 33).astype(np.int64)
+    cols = {"auction": auction,
+            "bidder": rng.integers(0, 100_000, n).astype(np.int64),
+            "price": rng.integers(100, 1_000_000, n).astype(np.int64)}
+    ts = 1_000_000 + np.arange(n, dtype=np.int64)
+
+    def feed(rt):
+        ih = rt.input_handler("Bid")
+        for s in range(0, n, chunk):
+            ih.send_columns({k: v[s:s + chunk] for k, v in cols.items()},
+                            ts[s:s + chunk])
+
+    def also(rt):
+        live = rt.device_bridges[0].runtime.window_gauges["window_live_keys"]
+        return [] if live > 100 else [f"window_live_keys reads {live}"]
+
+    ann = "@device(strict='true', async='true', batch='2048', window='22528')"
+    bad, facts = run_pair(S6_APP, ann, "HotItems", feed, {"Bid": n},
+                          platform, True, warnings, also=also)
+    if facts.get("rows") != (n - 1) // 4000:
+        bad.append(f"{facts.get('rows')} rows, a boundary every 4,000 of {n} "
+                   f"events makes {(n - 1) // 4000}")
+    return bad, facts
+
+
 STAGES = {"S1": stage_s1, "S2": stage_s2, "S3": stage_s3, "S3B": stage_s3b,
-          "S4": stage_s4, "S5": stage_s5}
+          "S4": stage_s4, "S5": stage_s5, "S6": stage_s6}
 
 
 def main(argv=None) -> int:

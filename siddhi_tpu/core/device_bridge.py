@@ -580,18 +580,20 @@ def _audit_device_surface(query: Query, app_context, get_junction):
     """The full Query-surface audit: anything the device compilers do not
     model raises ``DeviceCompileError`` (-> host fallback), never silently
     drops semantics (reference surface: Query.java — selector
-    order-by/limit/offset QuerySelector.java:44, output_rate
+    order-by/limit/offset QuerySelector.java:44, served on a grouped
+    hopping flush only, ``query_compile.selector_tail_refusal``; output_rate
     OutputRateLimiter.java:43, fault/inner streams, events_for). Returns
     the output junction."""
     from ..tpu.expr_compile import DeviceCompileError
 
     sel = query.selector
-    if sel is not None and (sel.order_by or sel.limit is not None
-                            or sel.offset is not None):
-        raise DeviceCompileError(
-            "order by / limit / offset take the host path (device "
-            "micro-batch chunking would change their per-chunk "
-            "semantics)")
+    # order by / limit / offset apply to a CHUNK: served where the window
+    # flushes chunks of its own and is compiled with the tail (a grouped
+    # hopping flush); everywhere else the refusal says which
+    from ..tpu.query_compile import selector_tail_refusal
+    refusal = selector_tail_refusal(query)
+    if refusal is not None:
+        raise DeviceCompileError(refusal)
     if query.output_rate is not None:
         from ..query_api import EventOutputRate
         if not isinstance(query.output_rate, EventOutputRate):

@@ -118,7 +118,10 @@ BUILTIN_LIBRARY: list[ExtensionMeta] = [
            optional=True)]),
     _m("hopping", "window",
        "Fixed-length window emitted every hop interval (overlapping "
-       "tumbling buckets).",
+       "tumbling buckets). Served from the chip with aggregates; with "
+       "`group by` a boundary emits one row per key live in the window "
+       "(exact, no bucket grid), and the selector's `order by` / `offset` "
+       "/ `limit` run on that flush chunk on the device.",
        [_p("window.time", [DataType.INT, DataType.LONG], "window length"),
         _p("hop.time", [DataType.INT, DataType.LONG], "emission interval")]),
     _m("cron", "window", "Batch window flushed on a cron schedule.",

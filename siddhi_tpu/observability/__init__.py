@@ -135,7 +135,8 @@ class DeviceStepProbe:
         carries the measured serial segments of this batch's waterfall,
         keyed as :meth:`PhaseBreakdown.record_batch` names them
         (``fill_span_s``, ``pack_s``, ``ring_s``, ``queue_s``, ``step_s``,
-        ``fence_s``, ``decode_s``, ``decode_full_s``, ``lock_s``,
+        ``fence_s``, ``decode_s``, ``decode_full_s``, ``hop_drain_s``,
+        ``hop_flush_s``, ``lock_s``,
         ``publish_s``, ``host_s``, ``cause``) — recorded event-weighted
         into the per-phase histograms."""
         if device_path:
@@ -342,6 +343,12 @@ class ObservabilitySubsystem:
                 sm.gauge_tracker(
                     f"device.{q}.lanes_kernel_scan",
                     lambda r=bridge.runtime: int(r.kernel == "scan"))
+            # a grouped hopping flush's window, as its drain points last
+            # read it (tpu/runtime.py on_drained)
+            for g in getattr(bridge.runtime, "window_gauges", {}):
+                sm.gauge_tracker(
+                    f"device.{q}.{g}",
+                    lambda r=bridge.runtime, g=g: r.window_gauges[g])
             # egress by shape (core/egress.py): rows over deliveries is the
             # rows a delivery carries — a batch's, not one
             for shape, count in bridge.egress.items():
@@ -422,6 +429,8 @@ class ObservabilitySubsystem:
                 if bridge.kind == "partition":
                     rep["lanes"] = dict(bridge.runtime.lane_gauges)
                     rep["kernel"] = bridge.runtime.kernel
+                if getattr(bridge.runtime, "window_gauges", None):
+                    rep["window"] = dict(bridge.runtime.window_gauges)
         for q, phases in phase_queries.items():
             if q in out["queries"]:
                 continue

@@ -54,7 +54,14 @@ batch, inside ``device_step`` (``tpu/partition.py`` ``dispatch``; span
 its WHOLE candidate table, inside ``egress_decode``, which runs only for a
 batch in which a lane emitted more rows than the packed row table holds
 (``tpu/nfa.py`` ``decode_rows``; span ``siddhi:collect.decode.full``): its
-count over ``egress_decode``'s says how often that was.
+count over ``egress_decode``'s says how often that was; ``hop_drain`` — a
+hopping window's drain after every batch, inside ``egress_decode``: the read
+of ``hop_next`` / ``last_ts`` out of the live state and any empty steps for
+deferred boundaries (``tpu/runtime.py`` ``_decode``; span
+``siddhi:collect.decode.hop_drain``); ``hop_flush`` — the decode of a batch
+whose step fired a boundary with rows (span
+``siddhi:collect.decode.hop_flush``): its count over ``egress_decode``'s is
+the share of batches that carried a boundary.
 
 The driver's segments are also spans on the profiler's clock
 (``profiler.py``): ``siddhi:seal.pack`` = ``pack``, ``submit.ring_wait`` =
@@ -73,7 +80,8 @@ PHASES = ("ingress_parse", "ingress_queue", "ring_wait", "fill_wait", "pack",
 
 # a part of a phase told apart: recorded like a phase, outside the serial
 # sum (its parent already carries the time)
-NESTED = {"route": "device_step", "decode_full": "egress_decode"}
+NESTED = {"route": "device_step", "decode_full": "egress_decode",
+          "hop_drain": "egress_decode", "hop_flush": "egress_decode"}
 
 # span stage → phase (unknown stages are host work by default: every
 # host-side processor span nests inside the query chain)
@@ -126,6 +134,7 @@ class PhaseBreakdown:
                      parse_s: float = 0.0, ring_s: float = 0.0,
                      decode_s: float = 0.0, lock_s: float = 0.0,
                      route_s: float = 0.0, decode_full_s: float = 0.0,
+                     hop_drain_s: float = 0.0, hop_flush_s: float = 0.0,
                      cause: Optional[str] = None,
                      exemplar=None) -> None:
         if n <= 0:
@@ -143,7 +152,9 @@ class PhaseBreakdown:
                 self.trackers[phase].record_seconds(v, n, exemplar=exemplar)
                 total += v
         # inside device_step / egress_decode: not segments
-        for part, v in (("route", route_s), ("decode_full", decode_full_s)):
+        for part, v in (("route", route_s), ("decode_full", decode_full_s),
+                        ("hop_drain", hop_drain_s),
+                        ("hop_flush", hop_flush_s)):
             if v > 0.0:
                 self.trackers[part].record_seconds(v, n, exemplar=exemplar)
         self.end_to_end.record_seconds(total, n, exemplar=exemplar)

@@ -31,6 +31,13 @@ span                             thread    opens / closes
 ``:<q>``                                   the blocked NFA whose rows pass
                                            its packed table: the decode of
                                            the whole candidate table
+``siddhi:collect.decode``        driver    inside it, a hopping window only:
+``.hop_flush:<q>``                         the decode of a batch whose step
+                                           fired a boundary with rows
+``siddhi:collect.decode``        driver    inside it, a hopping window only:
+``.hop_drain:<q>``                         the read of ``hop_next`` out of
+                                           the live state and any empty
+                                           steps for deferred boundaries
 ``siddhi:deliver:<q>``           driver    from asking for the engine lock
                                            to ``rt.deliver`` returning
 ``siddhi:deliver.lock:<q>``      driver    asking for the engine lock until

@@ -20,10 +20,25 @@ The TPU-native replacement for the hot path the reference interprets per event
   args *and* projected columns) as a remainder buffer.
 - Group-by (multi-key: codes mixed into one bucket id mod K) uses one-hot
   [B,K] cumulative contributions with carried dense per-key state [K].
+- ``hopping(D, H)`` with group-by keeps the window as EVENTS and reduces it
+  by key at a boundary (``_hopping_grouped``): one sort of the time-ordered
+  concat by (keys, lane), suffix reductions over the sorted segments, rows
+  in first-seen key order; exact for as many keys as the window holds
+  events, nothing reads ``group_capacity``. The selector's tail (``order
+  by`` / ``offset`` / ``limit``) runs on that flush chunk, on the device.
 - ``having`` compiles over the materialized output columns and masks
   emission (reference ``QuerySelector`` having executor).
 - Masked events (filter rejections, padding) are *compacted* with a stable
   scatter so window semantics see only accepted events.
+
+What keeps the host path (``DeviceCompileError``, never a silent
+difference): ``order by`` / ``limit`` / ``offset`` anywhere but on a grouped
+hopping flush (``selector_tail_refusal`` says which: a sliding window, no
+window, an ungrouped hopping flush, a join or a pattern); group-by with
+``lengthBatch`` / ``timeBatch`` / ``session`` / ``batch`` / ``sort`` /
+``frequent``; ``having`` or ``stdDev`` on a grouped hopping flush, ``order
+by`` a string; group-by with sliding-window min / max / stdDev;
+``distinctCount``; hopping without aggregates; stream functions.
 
 Numeric policy (dtypes.py): integer-argument sums/avgs accumulate in int64 —
 exact, like the reference's Java longs — float aggregates in float32 with
@@ -49,7 +64,7 @@ from ..query_api import (
 )
 from ..query_api.definition import DataType, StreamDefinition
 from .batch import BatchSchema
-from .dtypes import FACC, JNP as _JNP_DTYPES
+from .dtypes import FACC, JNP as _JNP_DTYPES, NP as _NP_DTYPES
 from .expr_compile import ColumnResolver, DeviceCompileError, compile_expression
 
 # event-time sentinels bounding every real timestamp (keep searchsorted input
@@ -111,6 +126,40 @@ def _range_reduce(z, lo, j, is_min: bool):
     kk = 31 - jax.lax.clz(m)                           # floor(log2 m)
     p2 = (1 << kk).astype(jnp.int32)
     return red(T[kk, j], T[kk, jnp.clip(lo + p2 - 1, 0, M - 1)])
+
+
+def selector_tail_refusal(query: Query) -> Optional[str]:
+    """Why this query's ``order by`` / ``limit`` / ``offset`` keep the host
+    path, or None where the device serves them (or there are none). The
+    selector orders and limits a CHUNK: a batching window's flush is a chunk
+    of its own whatever the micro-batch, so a grouped ``hopping`` flush is
+    compiled with the tail (``_hopping_grouped``); everywhere else the
+    device's chunk is the micro-batch, which is not the selector's."""
+    sel = query.selector
+    if sel is None or not (sel.order_by or sel.limit is not None
+                           or sel.offset is not None):
+        return None
+    ist = query.input_stream
+    windows = [h.name for h in getattr(ist, "handlers", [])
+               if isinstance(h, Window)]
+    if isinstance(ist, SingleInputStream) and windows == ["hopping"] \
+            and sel.group_by:
+        return None
+    if not isinstance(ist, SingleInputStream):
+        where = "a join or a pattern"
+    elif not windows or windows == [""]:
+        where = "a query without a window"
+    elif windows == ["hopping"]:
+        where = "an ungrouped hopping flush (one row a chunk)"
+    elif windows[0] in ("timeBatch", "lengthBatch", "externalTimeBatch",
+                        "session", "batch", "sort"):
+        where = f"window '{windows[0]}' (no grouped device flush yet)"
+    else:
+        where = f"sliding window '{windows[0]}'"
+    return (f"order by / limit / offset on {where} take the host path: the "
+            f"device serves them on a grouped hopping flush only, a chunk "
+            f"of the window's own; elsewhere device micro-batch chunking "
+            f"would change their per-chunk semantics")
 
 
 class _OutputResolver:
@@ -389,10 +438,17 @@ class CompiledStreamQuery:
             self.group_key_types.append(kt)
         if self.group_keys and self.window_kind in (
                 "lengthBatch", "timeBatch", "session", "batch", "sort",
-                "hopping", "frequent", "lossyFrequent"):
+                "frequent", "lossyFrequent"):
             raise DeviceCompileError(
                 f"group-by with {self.window_kind} windows takes the host "
-                f"path")
+                f"path (a grouped flush is served for hopping only)")
+        # a hopping flush reduced BY KEY (``_hopping_grouped``): exact for as
+        # many keys as the window holds events, no bucket grid
+        self.grouped_flush = self.window_kind == "hopping" and \
+            bool(self.group_keys)
+        refusal = selector_tail_refusal(query)
+        if refusal is not None:
+            raise DeviceCompileError(refusal)
 
         # select list
         self.specs: list[_Spec] = []
@@ -457,11 +513,13 @@ class CompiledStreamQuery:
                          if s.kind == "stdDev"]
         self.agg_idx = [i for i, s in enumerate(self.specs) if s.kind != "value"]
         if self.group_keys and self.window_kind is not None and \
-                (self.magg_idx or self.sagg_idx):
+                (self.sagg_idx or (self.magg_idx and not self.grouped_flush)):
             # per-key windowed min/max/stdDev would need a [M,K] sparse table
-            # per lane — not worth the HBM; host path covers it
+            # per lane — not worth the HBM; host path covers it (a grouped
+            # hopping flush reduces sorted segments, so min/max are served)
             raise DeviceCompileError(
-                "group-by with windowed min/max/stdDev takes the host path")
+                "group-by with windowed stdDev (and, on a sliding window, "
+                "min/max) takes the host path")
         if self.window_kind == "delay" and (self.agg_idx or self.group_keys):
             # the delay kernel re-times value projections only; aggregates
             # over a delayed stream keep host semantics
@@ -484,6 +542,8 @@ class CompiledStreamQuery:
         # having: post-filter over materialized output columns (reference
         # ``QuerySelector``'s havingConditionExecutor)
         self.having_fn: Optional[Callable] = None
+        if self.grouped_flush:
+            self._plan_grouped_flush(query, resolver)
         if query.selector.having is not None:
             hres = _OutputResolver(self.specs, self.schema)
             if self.xp is not None:
@@ -491,6 +551,54 @@ class CompiledStreamQuery:
             self.having_fn, _ = compile_expression(query.selector.having, hres)
         self._step = None if backend == "numpy" \
             else jax.jit(self._make_step(), donate_argnums=(0,))
+
+    def _plan_grouped_flush(self, query: Query, resolver) -> None:
+        """The static plan of a grouped hopping flush: which value columns
+        ARE group keys (read from the sorted keys, no payload), the
+        selector's tail, the boundaries a step resolves and the rows a
+        boundary may emit."""
+        sel = query.selector
+        if sel.having is not None:
+            # the interpreter tests `having` on every event's RUNNING row
+            # and collapses to the last one that passed; a flush has the
+            # final rows only
+            raise DeviceCompileError(
+                "having on a grouped hopping flush takes the host path")
+        attrs = sel.attributes
+        # value column -> position among the group keys, where it is one
+        self.key_of_value: dict[int, int] = {}
+        if not sel.select_all and attrs:
+            for i in self.value_idx:
+                e = attrs[i].expr
+                if isinstance(e, Variable) and e.stream_id is None:
+                    key, _ = resolver.resolve(e)
+                    if key in self.group_keys:
+                        self.key_of_value[i] = self.group_keys.index(key)
+        names = [sp.name for sp in self.specs]
+        self.order_by: list[tuple[int, bool]] = []
+        from ..query_api import OrderByOrder
+        for ob in sel.order_by:
+            if ob.variable.attribute not in names:
+                raise DeviceCompileError(
+                    f"order by unknown output attribute "
+                    f"'{ob.variable.attribute}'")
+            i = names.index(ob.variable.attribute)
+            if self.specs[i].dtype not in (DataType.INT, DataType.LONG,
+                                           DataType.FLOAT, DataType.DOUBLE):
+                # dictionary codes are arrival-ordered, not collated
+                raise DeviceCompileError(
+                    "order by a non-numeric attribute takes the host path")
+            self.order_by.append((i, ob.order == OrderByOrder.DESC))
+        M = max(self.window_n, 1) + self.B
+        self.limit = sel.limit
+        self.offset = min(sel.offset or 0, M)
+        # a step resolves the boundaries its own events can fire at one
+        # tick an event, plus the one carried in; later ones are deferred
+        # to the next step (drained by empty steps at a flush)
+        self.flush_cap = -(-self.B // self.hop_ms) + 1
+        # rows a boundary emits: the limit, else every key of the window
+        self.flush_rows = M if self.limit is None \
+            else max(1, min(self.limit, M))
 
     def _mdtype(self, i: int):
         return _JNP_DTYPES[self.specs[i].dtype]
@@ -501,6 +609,31 @@ class CompiledStreamQuery:
         AF, AI = len(self.fagg_idx), len(self.iagg_idx)
         AS = len(self.sagg_idx)
         state: dict[str, Any] = {}
+        if self.grouped_flush:
+            # the window kept as events: timestamp, key(s) and aggregate
+            # arguments of the newest N, reduced by key at a boundary
+            state["tail_ts"] = jnp.full((N,), _TS_NEG, dtype=jnp.int64)
+            for n, t in enumerate(self.group_key_types):
+                state[f"tail_gk{n}"] = jnp.zeros((N,), _JNP_DTYPES[t])
+            state["tail_fvals"] = jnp.zeros((AF, N), dtype=FACC)
+            state["tail_ivals"] = jnp.zeros((AI, N), dtype=_IACC)
+            for i in self.magg_idx:
+                dt = self._mdtype(i)
+                state[f"tail_m{i}"] = jnp.full(
+                    (N,), _ident(dt, self.specs[i].kind == "min"), dt)
+            for i in self.value_idx:
+                if i not in self.key_of_value:
+                    state[f"tail_proj_{i}"] = jnp.zeros(
+                        (N,), dtype=_JNP_DTYPES[self.specs[i].dtype])
+            state["hop_next"] = jnp.asarray(_TS_NEG, dtype=jnp.int64)
+            state["last_ts"] = jnp.asarray(_TS_NEG, dtype=jnp.int64)
+            state["window_drops"] = jnp.zeros((), dtype=jnp.int64)
+            state["ts_regressions"] = jnp.zeros((), dtype=jnp.int64)
+            # gauges, read at drain points: events held, and the distinct
+            # keys of the newest boundary's window
+            state["window_held"] = jnp.zeros((), dtype=jnp.int32)
+            state["window_live_keys"] = jnp.zeros((), dtype=jnp.int32)
+            return state
         windowed = self.window_kind in ("length", "lengthBatch", "time",
                                         "timeBatch", "session", "timeLength",
                                         "hopping")
@@ -632,6 +765,7 @@ class CompiledStreamQuery:
         mdt = {i: self._mdtype(i) for i in magg_idx}
         m_ident = {i: _ident(mdt[i], specs[i].kind == "min") for i in magg_idx}
         m_ismin = {i: specs[i].kind == "min" for i in magg_idx}
+        grouped_flush = self.grouped_flush
         kernel_scope = f"window.{window_kind}" if window_kind is not None \
             else "groupby" if group_keys else "aggregate"
 
@@ -719,6 +853,13 @@ class CompiledStreamQuery:
                             ovalid.shape)
                 return state, {"out": out, "valid": ovalid, "ts": ots,
                                "count": k if count is None else count}
+
+            def hop_clock():
+                """The newest timestamp of the batch, filtered events
+                included: the interpreter's boundary timer fires on the
+                playback clock, which every event of the stream advances,
+                whether or not it passes the filter into the window."""
+                return jnp.max(jnp.where(valid, ts, _TS_NEG))
 
             # one scope per kernel: the window, else the dense group-by table,
             # else the running aggregates (scopes are metadata on the
@@ -859,12 +1000,20 @@ class CompiledStreamQuery:
                         m_ismin, k, N, B)
                     return finish(new_state, sums_f, sums_i, cnts, mins, svars)
 
+                if grouped_flush:
+                    wts = compact(ts, fill=jnp.asarray(_TS_POS, jnp.int64))
+                    gk_c = [compact(cols[gk].astype(_JNP_DTYPES[t]))
+                            for gk, t in zip(group_keys, group_key_types)]
+                    return _hopping_grouped(
+                        self, state, gk_c, av_f, av_i, av_m, m_ismin,
+                        m_ident, proj_c, wts, k, hop_clock())
+
                 if window_kind == "hopping":
                     wts = compact(ts, fill=jnp.asarray(_TS_POS, jnp.int64))
                     return _hopping_flushes(
                         state, value_idx, av_f, av_i, av_s, av_m, magg_idx,
                         m_ismin, ones_c, proj_c, wts, k, N, B,
-                        window_ms, hop_ms, finish)
+                        window_ms, hop_ms, finish, hop_clock())
 
                 if window_kind in ("frequent", "lossyFrequent"):
                     k64 = [compact(cols[kk].astype(jnp.int64))
@@ -1125,6 +1274,23 @@ class CompiledStreamQuery:
         NumPy index each (string codes stay codes until ``decoded()`` /
         ``rows()``)."""
         from ..core.columns import ColumnsOut
+        if self.grouped_flush:
+            # rows sit at the front of each boundary's slot: the row counts
+            # first (the fence has fetched them), and the columns are copied
+            # only where a boundary left rows, whole: [flush_cap, limit]
+            # each, no program of its own to slice them on the device
+            nrows = np.asarray(out["nrows"])
+            fired = np.flatnonzero(nrows)
+            cols = {}
+            for s in self.specs:
+                if fired.size:
+                    host = np.asarray(out["out"][s.name])
+                    cols[s.name] = np.concatenate(
+                        [host[f, :nrows[f]] for f in fired])
+                else:
+                    cols[s.name] = np.zeros((0,), _NP_DTYPES[s.dtype])
+            return ColumnsOut(None, cols, int(nrows.sum()), self.out_specs,
+                              self.schema.dictionaries)
         idx = np.flatnonzero(np.asarray(out["valid"]))
         cols = {s.name: np.asarray(out["out"][s.name])[idx]
                 for s in self.specs}
@@ -1212,15 +1378,23 @@ def _length_concat(state, av_f, av_i, av_s, av_m, magg_idx, ones_c):
     return z_f, z_i, z_s, zo, zm
 
 
+def _monotone_ts(wts, k, B, last_ts):
+    """Event times of the accepted sub-batch clamped to be non-decreasing
+    (from ``last_ts`` on), padding at ``_TS_POS``; and how many were
+    clamped (``ts_regressions``)."""
+    valid = jnp.arange(B) < k
+    raw = jnp.where(valid, wts, _TS_POS)
+    mono = jnp.maximum(jax.lax.cummax(raw), last_ts)
+    regressed = jnp.sum(jnp.where(valid & (raw < mono), 1, 0)) \
+        .astype(jnp.int64)
+    return jnp.where(valid, mono, _TS_POS), mono, regressed
+
+
 def _time_window_bounds(state, av_f, av_i, av_s, av_m, magg_idx, ones_c,
                         wts, k, N, B, D):
     """Time-window variant: monotonicity clamp, searchsorted lower bounds,
     overflow accounting. Returns concat lanes + (j, lo) ranges + new state."""
-    valid = jnp.arange(B) < k
-    raw = jnp.where(valid, wts, _TS_POS)
-    mono = jnp.maximum(jax.lax.cummax(raw), state["last_ts"])
-    regressed = jnp.sum(jnp.where(valid & (raw < mono), 1, 0)).astype(jnp.int64)
-    wts_s = jnp.where(valid, mono, _TS_POS)
+    wts_s, mono, regressed = _monotone_ts(wts, k, B, state["last_ts"])
     z_f, z_i, z_s, zo, zm = _length_concat(
         state, av_f, av_i, av_s, av_m, magg_idx, ones_c)
     zts = jnp.concatenate([state["tail_ts"], wts_s])               # [N+B]
@@ -1564,7 +1738,8 @@ def _sort_window(state, skey_c, av_f, av_i, av_s, av_m, magg_idx, m_ismin,
 
 
 def _hopping_flushes(state, value_idx, av_f, av_i, av_s, av_m, magg_idx,
-                     m_ismin, ones_c, proj_c, wts, k, N, B, D, H, finish):
+                     m_ismin, ones_c, proj_c, wts, k, N, B, D, H, finish,
+                     clock):
     """hopping(duration D, hop H) — overlapping tumbling buckets (reference
     ``HopingWindowProcessor``): every H ms emit ONE aggregated row over the
     events of the last D ms (strictly before the boundary; an arrival AT the
@@ -1576,12 +1751,7 @@ def _hopping_flushes(state, value_idx, av_f, av_i, av_s, av_m, magg_idx,
     Kernel: time-sorted concat [tail(N) + batch(B)] lanes; the f-th flush
     boundary reads its bucket (t_f - D, t_f) as cumsum/sparse-table range
     reductions — all flushes in the batch resolve in parallel."""
-    valid = jnp.arange(B) < k
-    raw = jnp.where(valid, wts, _TS_POS)
-    mono = jnp.maximum(jax.lax.cummax(raw), state["last_ts"])
-    regressed = jnp.sum(jnp.where(valid & (raw < mono), 1, 0)) \
-        .astype(jnp.int64)
-    wts_s = jnp.where(valid, mono, _TS_POS)
+    wts_s, _mono, regressed = _monotone_ts(wts, k, B, state["last_ts"])
     zts = jnp.concatenate([state["tail_ts"], wts_s])                # [N+B]
     zo = jnp.concatenate([state["tail_ones"], ones_c])
     z_f = jnp.concatenate([state["tail_fvals"], av_f], axis=1)
@@ -1592,8 +1762,10 @@ def _hopping_flushes(state, value_idx, av_f, av_i, av_s, av_m, magg_idx,
     zproj = {i: jnp.concatenate([state[f"tail_proj_{i}"], proj_c[i]])
              for i in value_idx}
 
-    newest = jnp.where(k > 0, zts[jnp.maximum(N + k - 1, N)],
-                       state["last_ts"])
+    # a boundary fires on the stream's clock (``clock``: filtered events
+    # advance it too), whatever the window was handed
+    newest = jnp.maximum(jnp.where(k > 0, zts[jnp.maximum(N + k - 1, N)],
+                                   state["last_ts"]), clock)
     armed = state["hop_next"] > _TS_NEG
     # unarmed ⇒ empty tail ⇒ the first real event sits at slot N
     b0 = jnp.where(armed, state["hop_next"], zts[N] + H)
@@ -1655,6 +1827,215 @@ def _hopping_flushes(state, value_idx, av_f, av_i, av_s, av_m, magg_idx,
     return finish(new_state, sums_f, sums_i, cnts, mins, svars,
                   ovalid=ovalid, ots=t_f, proj=proj_fl,
                   count=jnp.sum(ovalid.astype(jnp.int32)))
+
+
+def _seg_suffix(end, vals, ops):
+    """Per-segment suffix reductions of several lanes in one pass: position
+    ``i`` reads ``op`` over its lane from ``i`` to the end of its segment
+    (``end`` flags a segment's last position). A reverse segmented
+    ``associative_scan``: no prefix-sum differences, so float sums do not
+    cancel, and min / max / "the later one" ride the same pass."""
+    def comb(later, here):
+        f_l, v_l = later
+        f_h, v_h = here
+        return f_l | f_h, tuple(jnp.where(f_h, h, op(l, h))
+                                for l, h, op in zip(v_l, v_h, ops))
+    return jax.lax.associative_scan(comb, (end, tuple(vals)),
+                                    reverse=True)[1]
+
+
+def _hopping_grouped(plan, state, gk_c, av_f, av_i, av_m, m_ismin, m_ident,
+                     proj_c, wts, k, clock):
+    """hopping(D, H) with ``group by``: at a boundary ``t`` one row per key
+    live in (t - D, t), in the order of each key's first event there, a
+    non-aggregate column reading the key's last event; then the selector's
+    tail (``order by`` / ``offset`` / ``limit``) on that chunk, as the
+    interpreter's selector applies it to a flush chunk.
+
+    The window is kept as events (the newest N: timestamp, keys, aggregate
+    arguments). A boundary sorts the time-ordered concat [tail(N) +
+    batch(B)] by (keys, lane): a key's events become one segment, its live
+    ones a contiguous run inside it because lanes ascend with time; suffix
+    reductions over the segments leave every key's totals at its first live
+    lane, which is the row. Exact for every key (full-width compares, no
+    bucket); a window of N events holds at most N keys, the only bound.
+    Boundaries resolve one after another in a loop that runs as often as
+    boundaries fired (a batch without one pays nothing), at most
+    ``flush_cap`` a step; windows known to be empty are skipped by
+    arithmetic, so a gap of many hops costs no step."""
+    B, N = plan.B, max(plan.window_n, 1)
+    M = N + B
+    D, H = jnp.int64(plan.window_ms), jnp.int64(plan.hop_ms)
+    F, R = plan.flush_cap, plan.flush_rows
+    specs, value_idx = plan.specs, plan.value_idx
+    fagg_idx, iagg_idx, magg_idx = plan.fagg_idx, plan.iagg_idx, plan.magg_idx
+    key_of_value = plan.key_of_value
+    carried = [i for i in value_idx if i not in key_of_value]
+    nk = len(gk_c)
+
+    wts_s, _mono, regressed = _monotone_ts(wts, k, B, state["last_ts"])
+    zts = jnp.concatenate([state["tail_ts"], wts_s])                # [M]
+    zk = [jnp.concatenate([state[f"tail_gk{n}"], gk_c[n]])
+          for n in range(nk)]
+    z_f = jnp.concatenate([state["tail_fvals"], av_f], axis=1)
+    z_i = jnp.concatenate([state["tail_ivals"], av_i], axis=1)
+    zm = {i: jnp.concatenate([state[f"tail_m{i}"], av_m[i]])
+          for i in magg_idx}
+    zproj = {i: jnp.concatenate([state[f"tail_proj_{i}"], proj_c[i]])
+             for i in carried}
+
+    # a boundary fires on the stream's clock (``clock``: filtered events
+    # advance it too), whatever the window was handed
+    newest = jnp.maximum(jnp.where(k > 0, zts[jnp.maximum(N + k - 1, N)],
+                                   state["last_ts"]), clock)
+    armed = state["hop_next"] > _TS_NEG
+    # unarmed ⇒ empty tail ⇒ the first real event sits at slot N
+    b0 = jnp.where(armed, state["hop_next"], zts[N] + H)
+    has_any = armed | (k > 0)
+    lane = jnp.arange(M, dtype=jnp.int32)
+    big = jnp.int32(2 ** 31 - 1)
+
+    def next_live(t):
+        """``t``, or where its window is empty the first boundary that may
+        hold something: past the next event, or past ``newest`` where no
+        event is left. The boundaries between have fired and hold nothing
+        (every event up to ``newest`` is in the concat)."""
+        nxt = jnp.searchsorted(zts, t - D, side="right")
+        e = jnp.where(nxt < M, zts[jnp.minimum(nxt, M - 1)], _TS_POS)
+        e = jnp.where(e < _TS_POS, e, jnp.maximum(newest, t - 1))
+        return jnp.where(e >= t, t + ((e - t) // H + 1) * H, t)
+
+    def flush(t):
+        """(output columns [R], rows, distinct keys) of boundary ``t``."""
+        lo = jnp.searchsorted(zts, t - D, side="right").astype(jnp.int32)
+        hi = jnp.searchsorted(zts, t, side="left").astype(jnp.int32) - 1
+        with jax.named_scope("groupby.sort"):
+            payload = [z_f[a] for a in range(len(fagg_idx))] \
+                + [z_i[a] for a in range(len(iagg_idx))] \
+                + [zm[i] for i in magg_idx]
+            srt = jax.lax.sort((*zk, lane, *payload), num_keys=nk + 1)
+            sk, sidx, rest = srt[:nk], srt[nk], list(srt[nk + 1:])
+        with jax.named_scope("groupby.reduce"):
+            live = (sidx >= lo) & (sidx <= hi)
+            differs = sk[0][1:] != sk[0][:-1]
+            for n in range(1, nk):
+                differs = differs | (sk[n][1:] != sk[n][:-1])
+            one = jnp.ones((1,), jnp.bool_)
+            first = jnp.concatenate([one, differs])     # of its key
+            last = jnp.concatenate([differs, one])
+            # lanes ascend inside a key, so its live events are one run:
+            # the row sits at the run's first position
+            row = live & (first | ~jnp.concatenate([~one, live[:-1]]))
+            vals = [live.astype(jnp.int32)]
+            ops = [jnp.add]
+            n_sums = len(fagg_idx) + len(iagg_idx)
+            for v in rest[:n_sums]:
+                vals.append(jnp.where(live, v, jnp.zeros((), v.dtype)))
+                ops.append(jnp.add)
+            for i, v in zip(magg_idx, rest[n_sums:]):
+                vals.append(jnp.where(live, v, m_ident[i]))
+                ops.append(jnp.minimum if m_ismin[i] else jnp.maximum)
+            if carried:
+                # a carried column reads the key's LAST live event: its
+                # lane, then the column itself in time order
+                vals.append(jnp.where(live, sidx, -1))
+                ops.append(jnp.maximum)
+            red = iter(_seg_suffix(last, vals, ops))
+            cnts = next(red).astype(jnp.int64)
+            sums_f = [next(red) for _ in fagg_idx]
+            sums_i = [next(red) for _ in iagg_idx]
+            mins = {i: next(red) for i in magg_idx}
+            proj_last = {}
+            if carried:
+                last_lane = jnp.maximum(next(red), 0)
+                proj_last = {i: zproj[i][last_lane] for i in carried}
+            proj = {i: sk[key_of_value[i]] if i in key_of_value
+                    else proj_last[i] for i in value_idx}
+            cols_m = _materialize(specs, value_idx, fagg_idx, iagg_idx,
+                                  magg_idx, [], proj, sums_f, sums_i, cnts,
+                                  mins, [])
+            n_keys = jnp.sum(row, dtype=jnp.int32)
+        with jax.named_scope("select.order"):
+            if plan.order_by and plan.limit == 1 and not plan.offset:
+                # `limit 1` is the argmax it is: the best of each order key
+                # in turn among those still tied, then the first seen
+                cand = row
+                for i, desc in plan.order_by:
+                    v = cols_m[specs[i].name]
+                    worst = _ident(v.dtype, not desc)
+                    pick = jnp.max if desc else jnp.min
+                    cand = cand & (v == pick(jnp.where(cand, v, worst)))
+                take = jnp.argmin(jnp.where(cand, sidx, big))[None]
+            else:
+                # a stable order: dead lanes last, the order keys, then the
+                # first-seen lane (unique among rows)
+                okeys = []
+                for i, desc in plan.order_by:
+                    v = cols_m[specs[i].name]
+                    if desc:
+                        v = -v if jnp.issubdtype(v.dtype, jnp.floating) \
+                            else ~v
+                    okeys.append(v)
+                order = jax.lax.sort(
+                    ((~row).astype(jnp.int32), *okeys, sidx, lane),
+                    num_keys=2 + len(okeys))[-1]
+                take = jnp.concatenate(
+                    [order, jnp.zeros((R,), jnp.int32)]
+                )[plan.offset:plan.offset + R]
+        with jax.named_scope("select.limit"):
+            n_rows = jnp.maximum(n_keys - jnp.int32(plan.offset), 0)
+            if plan.limit is not None:
+                n_rows = jnp.minimum(n_rows, jnp.int32(plan.limit))
+            return ({name: c[take] for name, c in cols_m.items()},
+                    n_rows, n_keys)
+
+    zero_cols = {s.name: jnp.zeros((F, R), _JNP_DTYPES[s.dtype])
+                 for s in specs}
+
+    def fire(c):
+        t, f = c["t"], c["f"]
+        rows, n_rows, n_keys = flush(t)
+        out = {name: jax.lax.dynamic_update_slice(
+            c["out"][name], rows[name].astype(c["out"][name].dtype)[None],
+            (f, jnp.int32(0))) for name in c["out"]}
+        return {"t": next_live(t + H), "f": f + 1, "out": out,
+                "nrows": c["nrows"].at[f].set(n_rows), "keys": n_keys}
+
+    done = jax.lax.while_loop(
+        lambda c: (c["f"] < F) & has_any & (c["t"] <= newest), fire,
+        {"t": next_live(b0), "f": jnp.int32(0), "out": zero_cols,
+         "nrows": jnp.zeros((F,), jnp.int32),
+         "keys": state["window_live_keys"]})
+
+    # an event pushed out by the slide is lost only if a boundary still to
+    # fire would have read it
+    hop_next = jnp.where(has_any, done["t"], jnp.int64(_TS_NEG))
+    sliced = (lane < k) & (zts > _TS_NEG)
+    drops = jnp.sum((sliced & (zts > hop_next - D)).astype(jnp.int64))
+    take_n = lambda z: jax.lax.dynamic_slice(z, (k,), (N,))
+    new_state = {
+        **state,
+        "tail_ts": take_n(zts),
+        "tail_fvals": jax.vmap(take_n)(z_f) if z_f.shape[0]
+        else state["tail_fvals"],
+        "tail_ivals": jax.vmap(take_n)(z_i) if z_i.shape[0]
+        else state["tail_ivals"],
+        "hop_next": hop_next,
+        "last_ts": jnp.maximum(state["last_ts"], newest),
+        "window_drops": state["window_drops"] + drops,
+        "ts_regressions": state["ts_regressions"] + regressed,
+        "window_held": jnp.minimum(state["window_held"] + k, N)
+        .astype(jnp.int32),
+        "window_live_keys": done["keys"],
+    }
+    for n in range(nk):
+        new_state[f"tail_gk{n}"] = take_n(zk[n])
+    for i in magg_idx:
+        new_state[f"tail_m{i}"] = take_n(zm[i])
+    for i in carried:
+        new_state[f"tail_proj_{i}"] = take_n(zproj[i])
+    # rows sit at the front of each boundary's slot, `nrows` of them
+    return new_state, {"out": done["out"], "nrows": done["nrows"]}
 
 
 def _heavy_hitters(state, kcode, av_f, av_i, k, C, B, lossy, support, error):

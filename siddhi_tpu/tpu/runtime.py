@@ -8,13 +8,16 @@ go to the callback.
 
 from __future__ import annotations
 
+import contextlib
 import logging
+import time
 
 import jax
 import numpy as np
 
 from ..compiler import parse as _parse
 from ..core.columns import ColumnsOut
+from ..observability.profiler import span
 from .batch import BatchBuilder
 from .query_compile import CompiledStreamQuery
 from .step_runtime import StepRuntime
@@ -70,6 +73,14 @@ class DeviceStreamRuntime(StepRuntime):
         # ... and read live state between steps, so the driver keeps exactly
         # one step in flight (window=1): the state read is that step's own
         self.pipeline_safe = compiled.window_kind != "hopping"
+        # a grouped hopping flush hands out its rows compacted a boundary,
+        # and the row counts are what the decode reads first; its window's
+        # gauges are read at drain points (on_drained)
+        self.window_gauges: dict = {}
+        if compiled.grouped_flush:
+            self.fence_key = "nrows"
+            self.window_gauges = {"window_live_keys": 0,
+                                  "window_fill_share": 0.0}
         self.state = compiled.init_state()
         # segment clock high-water: arrival ts, or the externalTimeBatch
         # attribute column
@@ -125,14 +136,27 @@ class DeviceStreamRuntime(StepRuntime):
 
     def _decode(self, out):
         """Hopping drains deferred boundary flushes here with empty steps,
-        and their chunks follow the batch's in order."""
-        chunk = self.compiled.decode_outputs(out)
+        and their chunks follow the batch's in order. Both are timed apart,
+        inside ``egress_decode``: the decode of a batch whose step fired a
+        boundary (``hop_flush``) and the drain, which reads live state back
+        (``hop_drain``: why a hopping runtime keeps one step in flight)."""
         if self.compiled.window_kind != "hopping":
-            return chunk
-        chunks = [chunk]
-        self.state = drain_hop_boundaries(
-            self.compiled, self.state, self._drain_builder,
-            lambda o: chunks.append(self.compiled.decode_outputs(o)))
+            return self.compiled.decode_outputs(out)
+        q = self.query_name
+        t0 = time.perf_counter()
+        # the fence has fetched this output: rows out = a boundary fired
+        fired = np.asarray(out[self.fence_key]).any()
+        with span(f"siddhi:collect.decode.hop_flush:{q}") if fired \
+                else contextlib.nullcontext():
+            chunks = [self.compiled.decode_outputs(out)]
+        t1 = time.perf_counter()
+        if fired:
+            self.hop_flush_s = t1 - t0
+        with span(f"siddhi:collect.decode.hop_drain:{q}"):
+            self.state = drain_hop_boundaries(
+                self.compiled, self.state, self._drain_builder,
+                lambda o: chunks.append(self.compiled.decode_outputs(o)))
+        self.hop_drain_s = time.perf_counter() - t1
         return ColumnsOut.concat(chunks)
 
     def finalize(self) -> None:
@@ -177,6 +201,12 @@ class DeviceStreamRuntime(StepRuntime):
             if c > self._warned.get(key, 0):
                 log.warning("query '%s': %d %s", self.query_name, c, what)
                 self._warned[key] = c
+        if self.window_gauges:
+            keys, held = jax.device_get((self.state["window_live_keys"],
+                                         self.state["window_held"]))
+            self.window_gauges["window_live_keys"] = int(keys)
+            self.window_gauges["window_fill_share"] = \
+                int(held) / max(self.compiled.window_n, 1)
 
     @property
     def group_collision_count(self) -> int:

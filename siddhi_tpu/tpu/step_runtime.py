@@ -44,6 +44,10 @@ class StepRuntime:
     # client on the sync path); None after a collect that never fenced
     decode_full_s = None        # left by a decode that read the blocked
     # NFA's whole candidate table (nfa.decode_rows); step_phases takes it
+    hop_drain_s = None          # left by a hopping decode: its drain (the
+    # state read and any empty steps), and, where the step fired a
+    # boundary, the decode of its rows; step_phases takes both
+    hop_flush_s = None
     _pending_cause = None       # cause of the flush whose emit comes next
     driver = None               # AsyncDeviceDriver when the bridge pipelines
     callback = None             # deliver()'s fn(chunk, emit_ts)
@@ -203,9 +207,12 @@ class StepRuntime:
         """One device batch's waterfall as ``PhaseBreakdown.record_batch``
         names it: what the batch carries (fill span, pack, route, cause),
         what whoever stepped it measured, what its decode left on the
-        runtime (``decode_full_s``, taken here) and in ``driver_s`` what
-        only the async driver has (``ring_s``, ``lock_s``, ``publish_s``)."""
+        runtime (``decode_full_s``, ``hop_drain_s``, ``hop_flush_s``, taken
+        here) and in ``driver_s`` what only the async driver has
+        (``ring_s``, ``lock_s``, ``publish_s``)."""
         full_s, self.decode_full_s = self.decode_full_s, None
+        drain_s, self.hop_drain_s = self.hop_drain_s, None
+        flush_s, self.hop_flush_s = self.hop_flush_s, None
         return {
             "fill_span_s": batch.get("pack_s", 0.0),
             "pack_s": batch.get("pack_exec_s", 0.0),
@@ -215,6 +222,8 @@ class StepRuntime:
             "fence_s": fence_s,
             "decode_s": decode_s,
             "decode_full_s": full_s or 0.0,
+            "hop_drain_s": drain_s or 0.0,
+            "hop_flush_s": flush_s or 0.0,
             "cause": batch.get("_cause"),
             **driver_s,
         }

@@ -124,7 +124,8 @@ def test_rehearsal_runs_green_on_cpu(tmp_path):
     lines = p.stdout.strip().splitlines()
     assert all(ln.startswith("[REHEARSAL reduced size on cpu") for ln in lines)
     assert not any('"ok"' in ln for ln in lines)
-    for stage in ("S1: ok", "S2: ok", "S3: ok", "S4: ok", "S5: ok"):
+    for stage in ("S1: ok", "S2: ok", "S3: ok", "S4: ok", "S5: ok",
+                  "S6: ok"):
         assert any(stage in ln for ln in lines), (stage, p.stdout[-4000:])
     assert any('"ingress": "native"' in ln for ln in lines)
     assert os.listdir(cache)

@@ -44,8 +44,6 @@ def _shape(rng):
         hop=rng.choice([20, 50]))
     aggs = rng.choice(AGG_SETS)
     filt = rng.choice(FILTERS)
-    if "hopping" in win and "sym" in aggs:
-        aggs = "sum(v) as s, count() as c"
     if ("sort" in win or "frequent" in win) and ("min(" in aggs
                                                  or "stdDev" in aggs):
         aggs = "sum(v) as s, count() as c"   # host-only combos, keep density
@@ -101,6 +99,44 @@ def test_differential_fuzz(seed):
         actual = _device(app, events, cap)
     except DeviceCompileError:
         pytest.skip(f"host-only shape: {app.strip().splitlines()[1]}")
+    expected = _host(app, events)
+    assert len(expected) == len(actual), \
+        f"row count {len(expected)} != {len(actual)} for app: {app}"
+    for e, a in zip(expected, actual):
+        assert rows_equal(e, a, rel=2e-3, abs_=2e-3), (app, e, a)
+
+
+HOP_AGG_SETS = [a for a in AGG_SETS if "stdDev" not in a]
+HOP_TAILS = ["", "", "order by {o} desc", "order by {o}", "limit 2",
+             "order by {o} desc limit 1", "order by {o} limit 2 offset 1",
+             "offset 1"]
+
+
+def _grouped_hopping_shape(rng):
+    """`hopping` meets `group by`: a grouped flush, and on it the selector's
+    tail drawn from `order by` (the first aggregate, asc / desc), `limit`
+    and `offset`."""
+    aggs = rng.choice(HOP_AGG_SETS)
+    group = rng.choice(["sym", "sym", "v", "sym, v"])
+    tail = rng.choice(HOP_TAILS).format(o=aggs.split(" as ")[1].split(",")[0])
+    win = f"hopping({rng.choice([40, 90, 200])}, {rng.choice([20, 50])})"
+    return f"""
+    define stream S (sym string, p double, v long);
+    from S{rng.choice(FILTERS)}#window.{win}
+    select {group}, {aggs}
+    group by {group}
+    {tail}
+    insert into O;
+    """
+
+
+@pytest.mark.parametrize("seed", range(16))
+def test_differential_fuzz_grouped_hopping(seed):
+    rng = random.Random(7000 + seed)
+    app = _grouped_hopping_shape(rng)
+    events = _events(rng, rng.choice([40, 90]))
+    cap = rng.choice([4, 8, 16, 64])
+    actual = _device(app, events, cap)      # every drawn shape is served
     expected = _host(app, events)
     assert len(expected) == len(actual), \
         f"row count {len(expected)} != {len(actual)} for app: {app}"

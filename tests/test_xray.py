@@ -902,6 +902,13 @@ from every a=S[v > 50.0] -> b=S[v > a.v]<3:> -> c=S[v < a.v] within 1000
 select a.v as v1, b[0].v as first, b[last].v as peak, c.v as back
 insert into M;
 """
+SCOPED_HOPPING_APP = """
+define stream S (k long, v long);
+@device(batch='64', window='256')
+from S#window.hopping(200, 40)
+select k, count() as c, sum(v) as t group by k order by c desc, t limit 2
+insert into O;
+"""
 # the blocked kernel's programs at the sizes of this file's fixtures, the
 # benchmark's two chain queries (`pattern-chain8` plain, `partitioned-chain`
 # under `vmap`): their optimized HLO on this backend as PR 34 left it (the
@@ -958,7 +965,10 @@ def _compiled_step_text(app_text):
                          "select")),
     (SCOPED_SCAN_APP, ("nfa.scan", "nfa.expire", "nfa.state0", "nfa.state1",
                        "nfa.state2", "nfa.emit", "nfa.compact")),
-], ids=["nfa_block", "stream_query", "nfa_scan"])
+    (SCOPED_HOPPING_APP, ("filter", "compact", "window.hopping",
+                          "groupby.sort", "groupby.reduce", "select.order",
+                          "select.limit")),
+], ids=["nfa_block", "stream_query", "nfa_scan", "hopping_grouped"])
 def test_jitted_stages_are_named_and_the_names_cost_no_operation(
         monkeypatch, app_text, scopes):
     import contextlib
