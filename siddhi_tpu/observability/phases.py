@@ -50,11 +50,14 @@ trackers, and the ``/latency`` report):
 Two parts of a phase are told apart without joining the serial sum
 (``NESTED``): ``route`` — a served partition's lane layout of the flat
 batch, inside ``device_step`` (``tpu/partition.py`` ``dispatch``; span
-``siddhi:dispatch.route``); ``decode_full`` — the blocked NFA's decode of
-its WHOLE candidate table, inside ``egress_decode``, which runs only for a
-batch in which a lane emitted more rows than the packed row table holds
-(``tpu/nfa.py`` ``decode_rows``; span ``siddhi:collect.decode.full``): its
-count over ``egress_decode``'s says how often that was; ``hop_drain`` — a
+``siddhi:dispatch.route``); ``decode_full`` — an NFA's decode of its
+``full`` table (the blocked kernel's whole candidate table, the scan
+kernel's table of the plan's bound), inside ``egress_decode``, which runs
+only for a batch in which a lane emitted more rows than the packed row
+table holds (``tpu/nfa.py`` ``decode_rows``; span
+``siddhi:collect.decode.full``): its count over ``egress_decode``'s says how
+often that was, and for the scan kernel how often the step took its whole
+pack; ``hop_drain`` — a
 hopping window's drain after every batch, inside ``egress_decode``: the read
 of ``hop_next`` / ``last_ts`` out of the live state and any empty steps for
 deferred boundaries (``tpu/runtime.py`` ``_decode``; span

@@ -28,9 +28,9 @@ span                             thread    opens / closes
                                            other copies out, the mask,
                                            string codes resolved
 ``siddhi:collect.decode.full``   driver    inside it, only for a batch of
-``:<q>``                                   the blocked NFA whose rows pass
-                                           its packed table: the decode of
-                                           the whole candidate table
+``:<q>``                                   an NFA whose rows pass its packed
+                                           table in some lane: the decode
+                                           of its ``full`` table
 ``siddhi:collect.decode``        driver    inside it, a hopping window only:
 ``.hop_flush:<q>``                         the decode of a batch whose step
                                            fired a boundary with rows

@@ -16,12 +16,15 @@ unbounded cloned ``StateEvent`` lists) becomes:
 - ``every`` is a carried seed counter (replenished when its scope completes),
   ``within`` is a timestamp mask that also reclaims expired slots, slot
   exhaustion is an explicit drop-newest policy with an overflow counter;
-- the rows a batch emits leave the step as ONE table of static size ``M``
-  (``mask``, ``j`` = the closing event's index, a column per output and per
-  null mask), packed on the device after the scan (``pack_rows``): the
-  per-event ``[2, C]`` emit grids never leave it. The blocked kernel
-  (``nfa_block.py``) packs its rows into the same layout, so one decode
-  (``decode_outputs``, one ``device_get`` a batch) serves both.
+- the rows a batch emits leave the step as their count ``n`` and ONE table
+  of ``M`` = the batch's event capacity rows (``mask``, ``j`` = the closing
+  event's index, a column per output and per null mask), packed on the
+  device after the scan (``pack_rows``): the per-event ``[2, C]`` emit grids
+  never leave it. A batch in which a lane emitted more is packed at the
+  size of the plan's bound, into ``full`` beside it (``_row_capacity``);
+  which of the two packs runs is one branch on the device, for all lanes. The
+  blocked kernel (``nfa_block.py``) hands out the same three, so one decode
+  (``decode_rows``, one ``device_get`` a batch) serves both.
 
 Scope — 104/104 of the untimed reference pattern corpus compiles and
 matches the host oracle (pinned by ``tests/test_pattern_corpus.py::
@@ -267,74 +270,110 @@ class MergedBatchBuilder:
 # ---------------------------------------------------------------------------
 
 _GROUP = 128        # cells of one emit-grid group: a vector register's lanes
+_TILE = 8           # lanes of one tile of a scanned grid: its sublanes
 
 
-def pack_rows(mask, cols: dict, n_rows: int):
-    """The rows a scanned batch emitted, out of its emit grids and into one
-    table of ``n_rows``: ``mask`` ``[B, R, C]`` (event, source, candidate;
-    R = the emit sources the plan uses, 1 or 2) marks them, ``cols`` holds a
-    ``[B, R, C]`` grid per output column and null mask. Returns ``({"mask",
-    "j", <col>..: [n_rows]}, lost)``: row r is the (r+1)-th marked cell in
-    row-major order (match event, then source, then candidate: the order a
-    boolean index over the grids walks), ``j`` its event; cells past the
-    table are counted in ``lost``.
+def pack_rows(mask, cols: dict):
+    """The rows a scanned batch emitted, out of its emit grids and into a
+    table a lane: ``mask`` ``[B, P, R, C]`` (event, lane, source,
+    candidate: the order the scanned, lane-mapped body leaves them in; R =
+    the emit sources the plan uses, 1 or 2) marks them, ``cols`` holds a
+    ``[B, P, R, C]`` grid per output column and null mask. Returns ``(n,
+    table)``: ``n`` ``[P]`` the rows each lane emitted, known from the
+    mask's counts before any row is located, and ``table(n_rows)`` ->
+    ``{"mask", "j", <col>..: [P, n_rows]}``, where a lane's row r is its
+    (r+1)-th marked cell in row-major order (match event, then source, then
+    candidate: the order a boolean index over the lane's grids walks) and
+    ``j`` its event. Cells past ``n_rows`` are in no row: ``n`` says how
+    many.
 
-    Three levels, each a compare-and-count against running totals, because
-    one ``searchsorted`` over the ``B x RC`` cells would compare every row
-    with every cell (and a two-level one would hold ``n_rows x RC`` running
+    Every level of ``table`` costs by ``n_rows``, not by the rows there
+    are, so the caller sizes it by ``n`` (``_make_step``: the batch's event
+    capacity where no lane emitted more, else the plan's bound). Three
+    levels, each a compare-and-count against running totals, because one
+    ``searchsorted`` over the ``B x RC`` cells would compare every row with
+    every cell (and a two-level one would hold ``n_rows x RC`` running
     counts a lane: gigabytes at 256 lanes): a row's event from the events'
-    running row counts ``[n_rows, B]``; its group of ``_GROUP`` cells from
-    the event's running group counts ``[n_rows, RC / _GROUP]``; its cell
-    from the group's own cells ``[n_rows, _GROUP]``. The second and third
-    fetch one short row per table row, and so does every column: the
-    cell's group of values, of which a select-and-sum keeps the cell's. On
-    a v5e 442,368 rows of 128 f32 gathered so take 7.7 ms where as many
-    single elements out of the flat grid take 10.3 (PERF.md section 6,
-    PR 33). Nothing is scattered."""
-    B = mask.shape[0]
-    W = mask.shape[1] * mask.shape[2]
-    G = -(-W // _GROUP)
+    running row counts ``[n_rows, B]``; its group of ``_GROUP`` cells (of
+    one source) from the event's running group counts ``[n_rows, R x G]``;
+    its cell from the group's own cells ``[n_rows, _GROUP]``. The second
+    and third fetch one short row per table row, and so does every column:
+    the cell's group of values, of which a select-and-sum keeps the cell's.
+    On a v5e a gathered row of 128 f32 costs 17 ns where a single element
+    out of the flat grid costs 23 (PERF.md section 6, PR 33). Nothing is
+    scattered.
+
+    Nothing passes over a whole grid but the mask's count, either: the
+    groups are fetched from where the scan left them. The scanned,
+    lane-mapped body writes a grid event by event, and a v5e keeps each
+    event's ``[P, C]`` slab of a source in tiles of ``_TILE`` lanes by
+    ``_GROUP`` candidates, a lane's group one contiguous row of a tile. So
+    ``groups`` numbers the rows in that order (event, source, tile of
+    lanes, group, lane in the tile), which the compiler takes for the same
+    bytes, where the order (event, lane, group) cost a copy of every grid
+    (16.8 ms of PR 33's step). A lane count that ``_TILE`` does not divide
+    (one lane) is numbered plainly; the rows are the same rows either way.
+    """
+    B, P, R, C = mask.shape
+    G = -(-C // _GROUP)             # groups of one source's cells
+    T = _TILE if P % _TILE == 0 else 1
+    pad = ((0, 0), (0, 0), (0, 0), (0, G * _GROUP - C))
 
     def groups(grid):
-        """A ``[B, R, C]`` grid as ``[B * G, _GROUP]`` rows of cells."""
-        return jnp.pad(grid.reshape(B, W), ((0, 0), (0, G * _GROUP - W))
-                       ).reshape(B * G, _GROUP)
-
-    cells = groups(mask)
+        """A ``[B, P, R, C]`` grid as rows of ``_GROUP`` cells: group ``g``
+        of source ``r`` of event ``j`` in lane ``p`` is row ``((((j * R + r)
+        * P/T + p // T) * G + g) * T + p % T``."""
+        return jnp.pad(grid, pad).reshape(
+            B, P // T, T, R, G, _GROUP).transpose(0, 3, 1, 4, 2, 5).reshape(
+                -1, _GROUP)
 
     def locate(rank, totals, size):
         """Where the (rank+1)-th row falls among ``size`` running totals
-        ``[n_rows, size]`` (as many lie at or below rank as precede it),
-        and its rank inside."""
-        before = totals <= rank[:, None]
-        at = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), size - 1)
-        each = totals - jnp.pad(totals, ((0, 0), (1, 0)))[:, :-1]
-        return at, rank - jnp.sum(jnp.where(before, each, 0), axis=1)
+        ``[P, n_rows | 1, size]`` (as many lie at or below rank as precede
+        it), and its rank inside."""
+        before = totals <= rank[..., None]
+        at = jnp.minimum(jnp.sum(before, axis=-1, dtype=jnp.int32), size - 1)
+        each = totals - jnp.pad(totals, ((0, 0), (0, 0), (1, 0)))[..., :-1]
+        return at, rank - jnp.sum(jnp.where(before, each, 0), axis=-1)
 
+    cells = groups(mask)
+    # rows of the groups 0..g of an event in its lane: [B * P, R * G]
     upto_g = jnp.cumsum(
-        jnp.sum(cells, axis=1, dtype=jnp.int32).reshape(B, G), axis=1)
-    upto = jnp.cumsum(upto_g[:, -1])        # rows of events 0..b, [B]
-    r = jnp.arange(n_rows, dtype=jnp.int32)
-    taken = r < upto[-1]
-    j, rank = locate(r, jnp.broadcast_to(upto, (n_rows, B)), B)
-    g, rank = locate(rank, upto_g[j], G)
-    at = j * G + g                              # the row's group of cells
-    x, _ = locate(rank, jnp.cumsum(cells[at].astype(jnp.int32), axis=1),
-                  _GROUP)
-    here = jnp.arange(_GROUP, dtype=jnp.int32) == x[:, None]    # [n_rows, G]
-    out = {"mask": taken, "j": jnp.where(taken, j, 0)}
-    for name, grid in cols.items():
-        # the cell's group again, then its lane by select-and-sum over the
-        # value's bits (moved, never computed with: exact for every dtype)
-        bits = grid.astype(jnp.uint8) if grid.dtype == jnp.bool_ else \
-            jax.lax.bitcast_convert_type(
-                grid, jnp.dtype(f"uint{8 * grid.dtype.itemsize}"))
-        got = jnp.sum(jnp.where(here & taken[:, None], groups(bits)[at], 0),
-                      axis=1, dtype=bits.dtype)
-        out[name] = got != 0 if grid.dtype == jnp.bool_ else \
-            jax.lax.bitcast_convert_type(got, grid.dtype)
-    lost = jnp.maximum(upto[-1].astype(jnp.int64) - n_rows, 0)
-    return out, lost
+        jnp.sum(cells, axis=1, dtype=jnp.int32).reshape(
+            B, R, P // T, G, T).transpose(0, 2, 4, 1, 3).reshape(
+                B * P, R * G), axis=1)
+    # rows of events 0..b, lane by lane: [P, B]
+    upto = jnp.cumsum(upto_g[:, -1].reshape(B, P), axis=0).T
+    n = upto[:, -1]
+    lane = jnp.arange(P, dtype=jnp.int32)[:, None]
+
+    def table(n_rows: int) -> dict:
+        r = jnp.broadcast_to(jnp.arange(n_rows, dtype=jnp.int32), (P, n_rows))
+        taken = r < n[:, None]
+        j, rank = locate(r, upto[:, None, :], B)
+        g, rank = locate(rank, upto_g[j * P + lane], R * G)
+        at = ((((j * R + g // G) * (P // T) + lane // T) * G + g % G) * T
+              + lane % T)                           # the row's group of cells
+        x, _ = locate(rank, jnp.cumsum(cells[at].astype(jnp.int32), axis=-1),
+                      _GROUP)
+        here = (jnp.arange(_GROUP, dtype=jnp.int32) == x[..., None]) \
+            & taken[..., None]                      # [P, n_rows, _GROUP]
+        out = {"mask": taken, "j": jnp.where(taken, j, 0)}
+        for name, grid in cols.items():
+            # the cell's group again, then its lane by select-and-sum over
+            # the value's bits (moved, never computed with: exact for every
+            # dtype); the rows fetched are turned into bits, not the grid
+            near = groups(grid)[at]
+            bits = near.astype(jnp.uint8) if grid.dtype == jnp.bool_ else \
+                jax.lax.bitcast_convert_type(
+                    near, jnp.dtype(f"uint{8 * grid.dtype.itemsize}"))
+            got = jnp.sum(jnp.where(here, bits, 0), axis=-1,
+                          dtype=bits.dtype)
+            out[name] = got != 0 if grid.dtype == jnp.bool_ else \
+                jax.lax.bitcast_convert_type(got, grid.dtype)
+        return out
+
+    return n, table
 
 
 # ---------------------------------------------------------------------------
@@ -703,11 +742,12 @@ class DeviceNFACompiler:
             or (s > 0 and self.states[s - 1].kind != "count")
             or s in self.reseed_targets
             for s, st in enumerate(self.states)]
-        # rows of the table a step hands out: the scan kernel's is a bound
-        # on what a batch can emit; the blocked kernel packs to the batch's
-        # event capacity and keeps its whole candidate table beside it for
-        # the batch that emits more (``decode_rows``)
-        self.M = self.B if self.blocked else self._row_capacity()
+        # rows of the table a step hands out: the batch's event capacity,
+        # whichever kernel. The batch that emits more in some lane is read
+        # from ``full`` beside it (``decode_rows``): the blocked kernel's
+        # whole candidate table, the scan kernel's table of
+        # ``_row_capacity`` rows
+        self.M = self.B
         if has_element_within and not self.blocked:
             # the blocked kernel masks per-state gaps on its grids; the scan
             # kernel's tables don't carry last-bind times
@@ -726,8 +766,12 @@ class DeviceNFACompiler:
             self._step = jax.jit(self._make_step(), donate_argnums=(0,))
 
     def _row_capacity(self) -> int:
-        """Rows the scan kernel's output table holds for one batch (``M``),
-        from the plan alone: no ``@device`` key sizes it.
+        """Rows of the scan kernel's ``full`` table, a bound on what one
+        batch can emit, from the plan alone: no ``@device`` key sizes it.
+        The pack runs at this size, and the decode reads ``full``, only
+        for a batch in which some lane emitted more than the ``M`` rows of
+        the packed table (``_make_step`` ``hand_out``); the blocked kernel's
+        ``full`` is its whole candidate table.
 
         Every emit site consumes the partial it emits (its slot's ``valid``
         goes, or a start slot is re-armed as a NEW partial), so a batch
@@ -956,11 +1000,15 @@ class DeviceNFACompiler:
         }
 
     # ------------------------------------------------------------------- step
-    def _make_step(self):
+    def _make_step(self, form: str = "step"):
+        """``form``: ``'step'`` one lane's step, ``'stacked'`` the same over
+        ``[P, ...]``-stacked lanes, ``'scan'`` the scan kernel's first half
+        alone (``make_step`` / ``make_scan`` say what each hands out)."""
         if self.blocked:
             from .nfa_block import make_block_step
-            return make_block_step(self)
-        C, S, M = self.C, self.S, self.M
+            step = make_block_step(self)
+            return jax.vmap(step) if form == "stacked" else step
+        C, S, B, M_full = self.C, self.S, self.B, self._row_capacity()
         states = self.states
         within = self.within
         is_seq = self.is_sequence
@@ -1929,7 +1977,7 @@ class DeviceNFACompiler:
                     ys[f"null__{name}"] = grid(out_nulls[oi], jnp.bool_)
             return new_carry, ys
 
-        def step(state, cols, tag, ts, ts_base, nvalid):
+        def scan(state, cols, tag, ts, ts_base, nvalid):
             # wire format: int32 ts deltas + per-batch base, prefix validity
             nB = ts.shape[0]
             ts64 = ts_base.astype(jnp.int64) + ts.astype(jnp.int64)
@@ -1943,35 +1991,78 @@ class DeviceNFACompiler:
             xs = {f"c_{k}": v for k, v in cols.items()}
             xs.update({"tag": tag, "ts": ts64, "valid": valid})
             with jax.named_scope("nfa.scan"):
-                state, grids = jax.lax.scan(body, state, xs)
+                return jax.lax.scan(body, state, xs)
+
+        def hand_out(grids):
+            """The lanes' emit grids ``[B, P, R, C]`` -> ``(ys, lost)``:
+            the rows packed by what the batch emitted. ONE branch for all
+            the lanes stepped together, on one scalar (a branch a lane
+            would lower to a select under ``vmap`` and run both packs).
+            ``full`` holds every lane's rows whichever pack ran (the packed
+            table's, padded out, where that sufficed): under a mesh the
+            shards branch apart and the decode reads ``full`` for all."""
             with jax.named_scope("nfa.compact"):
-                ys, lost = pack_rows(grids.pop("mask"), grids, M)
+                n, table = pack_rows(grids.pop("mask"), grids)
+
+                def packed():
+                    rows = table(B)
+                    return rows, {k: jnp.pad(v, ((0, 0), (0, M_full - B)))
+                                  for k, v in rows.items()}
+
+                def whole():
+                    rows = table(M_full)
+                    return {k: v[:, :B] for k, v in rows.items()}, rows
+
+                rows, full = jax.lax.cond(jnp.max(n) <= B, packed, whole)
+                lost = jnp.maximum(n.astype(jnp.int64) - M_full, 0)
+            return {"n": n, **rows, "full": full}, lost
+
+        def step(state, *feed):
+            state, grids = scan(state, *feed)
+            ys, lost = hand_out({k: v[:, None] for k, v in grids.items()})
+            state["drops"] = state["drops"] + lost[0]
+            return state, jax.tree_util.tree_map(lambda x: x[0], ys)
+
+        def stacked_step(state, *feed):
+            # the grids stay in the scan's own order, the lane second
+            state, grids = jax.vmap(scan, out_axes=(0, 1))(state, *feed)
+            ys, lost = hand_out(grids)
             state["drops"] = state["drops"] + lost
             return state, ys
 
-        return step
+        return {"scan": scan, "step": step, "stacked": stacked_step}[form]
 
     # -------------------------------------------------------------- execution
-    def make_step(self):
-        """Public builder for the un-jitted single-lane step function
-        ``(state, cols, tag, ts, ts_base, nvalid) -> (state, ys)`` in the
-        wire format (int32 ts deltas + int64 base scalar, validity = prefix
-        ``[0, nvalid)``) — the composable surface ``vmap``/``shard_map``
-        wrappers (partition runtime, ``__graft_entry__``) build on.
-        ``self.step`` is the jitted single-lane convenience over the same
-        function."""
-        return self._make_step()
+    def make_step(self, stacked: bool = False):
+        """Public builder for the un-jitted step function ``(state, cols,
+        tag, ts, ts_base, nvalid) -> (state, ys)`` in the wire format (int32
+        ts deltas + int64 base scalar, validity = prefix ``[0, nvalid)``):
+        one lane's, the composable surface of ``__graft_entry__`` (and of
+        ``self.step``, the jitted convenience over it), or with
+        ``stacked`` the same step over ``[P, ...]``-stacked lanes, which the
+        partition runtime jits and ``shard_map``s. Both kernels hand out
+        ``ys = {"n", "mask", "j", <col>.., "full": {..}}`` (``decode_rows``).
+        The stacked scan step is NOT ``jax.vmap`` of the lane's: it maps the
+        scan and packs the rows of all lanes after ONE ``lax.cond`` on the
+        largest ``n`` among them (under ``shard_map``, among the shard's),
+        where a ``cond`` a lane lowers to a select that runs both packs."""
+        return self._make_step("stacked" if stacked else "step")
+
+    def make_scan(self):
+        """The scan kernel's first half alone, one lane: ``(state, cols,
+        tag, ts, ts_base, nvalid) -> (state, grids)`` with the emit grids
+        ``{"mask", <col>..: [B, R, C]}`` the pack reads (event, the emit
+        sources the plan uses, candidate). The carried state is whole after
+        it but for the ``drops`` of rows past ``full``."""
+        return self._make_step("scan")
 
     def step(self, state, batch: dict):
         return self._step(state, batch["cols"], batch["tag"], batch["ts"],
                           batch["ts_base"], np.int32(batch["count"]))
 
-    @property
-    def fence_key(self) -> str:
-        """The step output the decode reads first, which ``StepRuntime.
-        _fence`` fetches: the blocked kernel's row count ``n`` (4 bytes a
-        lane), the scan kernel's ``mask``."""
-        return "n" if self.blocked else "mask"
+    # the step output the decode reads first, which ``StepRuntime._fence``
+    # fetches: the row count ``n``, 4 bytes a lane, whichever kernel stepped
+    fence_key = "n"
 
     def decode_outputs(self, ys, lane_batch: Optional[int] = None):
         """One step's row table → a :class:`~siddhi_tpu.core.columns.
@@ -2009,15 +2100,19 @@ class DeviceNFACompiler:
 
 def decode_rows(rt, ys, lane_batch: Optional[int] = None):
     """``_decode`` of both NFA runtimes: one step's outputs as one
-    ``ColumnsOut``, through ``decode_outputs`` whichever table is read. The
-    scan kernel hands out one table. The blocked kernel hands out its rows
-    packed into ``M`` a lane and the count ``n`` (the fence has fetched
-    it): where no lane emitted more than ``M``, the packed table holds
-    every row; else the whole candidate table ``full`` is decoded, as every
-    batch was before PR 34: no row is lost and none is counted as a drop.
-    That decode is timed apart (``rt.decode_full_s``, the ``decode_full``
-    tracker; span ``siddhi:collect.decode.full``): how often it runs is
-    what the packed table's size is judged by."""
+    ``ColumnsOut``, through ``decode_outputs`` whichever table is read.
+    Both kernels hand out their rows packed into ``M`` a lane and the count
+    ``n`` (the fence has fetched it): where no lane emitted more than
+    ``M``, the packed table holds every row; else ``full`` is decoded (the
+    blocked kernel's whole candidate table, as every batch was before
+    PR 34; the scan kernel's table of ``_row_capacity`` rows, which its
+    step packs at that size for such a batch alone and else fills with
+    the packed table's rows): no row is lost, and none is counted as a
+    drop that was not one before. That decode is timed apart
+    (``rt.decode_full_s``, the ``decode_full`` tracker; span
+    ``siddhi:collect.decode.full``): how often it runs is what the packed
+    table's size is judged by, and for the scan kernel how often the step
+    took the whole pack. A single-state blocked plan has no ``full``."""
     nfa = rt.compiler
     full = ys.get("full")
     if full is None or int(np.max(jax.device_get(ys["n"]))) <= nfa.M:

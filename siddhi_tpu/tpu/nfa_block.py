@@ -234,14 +234,15 @@ def make_block_step(nfa: "DeviceNFACompiler"):
          "mask": [M] bool, "j": [M] i32 (match event index, for ordering),
          <out-name>: [M] ...,
          "full": {"mask", "j", <out-name>: [P]}        (S > 1 only)}
-    ``mask`` / ``j`` / the columns are the row table
-    ``DeviceNFACompiler.decode_outputs`` reads, which the scan kernel hands
-    out too: the emitted rows packed to the front in candidate order,
-    ``M = B``. ``full`` is the last stage's whole candidate table in the same
-    layout, ``P = (S-1)*C + B``, read only for a batch with ``n > M`` (the
-    module text says why). ``S == 1`` emits ``[B]`` as it is. A match's
-    timestamp is the batch's ``ts[j]``, which the host holds: it never
-    leaves the device.
+    ``n``, the row table and ``full`` are what the scan kernel hands out
+    too (``nfa.py`` ``_make_step``), and what ``nfa.decode_rows`` reads:
+    ``mask`` / ``j`` / the columns hold the emitted rows packed to the front
+    in candidate order, ``M = B``; ``full`` is read only for a batch with
+    ``n > M``. Here it is the last stage's whole candidate table in the same
+    layout, ``P = (S-1)*C + B``, which costs no copy (the module text says
+    why; the scan kernel packs at the size of its ``full`` for such a batch
+    alone). ``S == 1`` emits ``[B]`` as it is. A match's timestamp is the
+    batch's ``ts[j]``, which the host holds: it never leaves the device.
     """
     C, S, B = nfa.C, nfa.S, nfa.B
     states = nfa.states

@@ -269,10 +269,11 @@ class PartitionedNFARuntime(StepRuntime):
     whichever kernel the compiler chose for the pattern (``kernel``): the
     blocked one for chains of stream states under ``every``, the per-event
     scan for count (``<m:n>``), logical and absent states; both hand out
-    one ``[P, M]`` row table, and that table is what a batch's decode
-    fetches (the blocked kernel keeps its whole ``[P, (S-1)C + B]``
-    candidate table on the device beside it, read only for a batch in which
-    a lane emitted more than ``M`` rows).
+    the lanes' row counts and one ``[P, M]`` row table, and that table is
+    what a batch's decode fetches (beside it on the device, read only for a
+    batch in which a lane emitted more than ``M`` rows: the blocked
+    kernel's whole ``[P, (S-1)C + B]`` candidate table, the scan kernel's
+    table of the plan's bound, packed at that size for such a batch alone).
     """
 
     def __init__(self, app_or_text, num_partitions: int,
@@ -339,9 +340,8 @@ class PartitionedNFARuntime(StepRuntime):
                 sid, num_partitions, lane_batch,
                 route_row=self._lane_of_row, route_chunk=self.route_chunk)
 
-        # vmap the single-lane step over the lane axis
-        step = self.compiler.make_step()
-        vstep = jax.vmap(step, in_axes=(0, 0, 0, 0, 0, 0))
+        # the step over the lane axis (under a mesh, over a shard's lanes)
+        vstep = self.compiler.make_step(stacked=True)
         if mesh is not None:
             spec = P(axis)
             specs6 = (spec, spec, spec, spec, spec, spec)
@@ -650,9 +650,9 @@ class PartitionedNFARuntime(StepRuntime):
         """One step's lane-stacked row tables ``[P, M]`` -> ONE
         ``ColumnsOut``, lanes in order and a lane's rows as its own decode
         orders them (by match event ``j``, then table order), in one pass
-        over the whole: no loop over lanes, whichever kernel stepped (the
-        blocked kernel's whole candidate tables where a lane emitted more
-        than ``M`` rows: ``decode_rows``)."""
+        over the whole: no loop over lanes, whichever kernel stepped (its
+        ``full`` tables where a lane emitted more than ``M`` rows:
+        ``decode_rows``)."""
         return decode_rows(self, ys, lane_batch=self.lane_batch)
 
     @property

@@ -42,8 +42,8 @@ class StepRuntime:
     fence_s = None              # the last collect's wait for the device,
     # left by _fence for whoever called collect (driver thread, or the
     # client on the sync path); None after a collect that never fenced
-    decode_full_s = None        # left by a decode that read the blocked
-    # NFA's whole candidate table (nfa.decode_rows); step_phases takes it
+    decode_full_s = None        # left by a decode that read an NFA's
+    # ``full`` table (nfa.decode_rows); step_phases takes it
     hop_drain_s = None          # left by a hopping decode: its drain (the
     # state read and any empty steps), and, where the step fired a
     # boundary, the decode of its rows; step_phases takes both

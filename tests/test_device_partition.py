@@ -907,10 +907,16 @@ def test_count_state_a_failed_step_replays_through_the_host_partition():
         m.shutdown()
 
 
-def test_count_state_one_compile_one_chunk_and_one_row_table_a_step():
-    """What one step hands to ``decode``: the lane-stacked row table,
-    ``(2 + outputs + null masks) x P x M`` elements with ``M = C + B`` for
-    this query, and nothing of the ``[B, 2, C]`` emit grids."""
+def test_count_state_one_compile_one_chunk_and_one_row_table_a_step(
+        monkeypatch):
+    """What one step of a served count-state partition brings to the host,
+    as the chain's does: the fence fetches ``n`` (a count a lane), the
+    decode ``(2 + outputs + null masks) x P x M`` elements in ONE
+    ``device_get`` with ``M`` the lane's event capacity; ``full``, ``C + B``
+    rows a lane for this query, stays on the device, and nothing of the
+    ``[B, P, 1, C]`` emit grids leaves the step."""
+    import jax
+
     devs, vs = _events(1000, keys=30, seed=29)
     m = SiddhiManager()
     rt = m.create_siddhi_app_runtime(
@@ -920,34 +926,118 @@ def test_count_state_one_compile_one_chunk_and_one_row_table_a_step():
         lambda evs: rows.extend(tuple(e.data) for e in evs)))
     rt.start()
     r = rt.device_bridges[0].runtime
-    chunks, handed = [], []
-    inner_collect, inner_decode = r.collect, r._decode
+    chunks, fenced, fetched, handed = [], [], [], []
+    inner_collect, inner_decode, inner_fence = r.collect, r._decode, r._fence
+    real_get = jax.device_get
+    in_decode = [False]
 
     def collect(token):
         out = inner_collect(token)
         chunks.append(out)
         return out
 
-    def decode(ys):
-        handed.append({k: tuple(v.shape) for k, v in ys.items()})
-        return inner_decode(ys)
+    def device_get(x):
+        if in_decode[0]:
+            fetched[-1].append([tuple(a.shape) for a in jax.tree.leaves(x)])
+        return real_get(x)
 
+    def decode(ys):
+        handed.append(jax.tree.map(lambda a: tuple(a.shape), ys))
+        fetched.append([])
+        in_decode[0] = True
+        try:
+            return inner_decode(ys)
+        finally:
+            in_decode[0] = False
+
+    monkeypatch.setattr(jax, "device_get", device_get)
+    r._fence = lambda first: (fenced.append(tuple(first.shape)),
+                              inner_fence(first))[1]
     r.collect, r._decode = collect, decode
     try:
         _send(rt, devs, vs, True, chunk=100)
         rt.flush_device()
-        lanes, slots, lane_batch = 8, 64, lane_capacity_for(128, 8)
-        rows_m = slots + lane_batch
-        assert r.compiler.M == rows_m
+        lanes, slots, rows_m = 8, 64, lane_capacity_for(128, 8)
+        assert r.compiler.M == rows_m and r.fence_key == "n"
+        assert r.compiler._row_capacity() == slots + rows_m
         assert r.vstep._cache_size() == 1
         assert len(chunks) == rt.device_bridges[0].probe.steps == 8
         assert all(isinstance(c, ColumnsOut) for c in chunks)
         assert sum(len(c) for c in chunks) == len(rows) > 0
-        # mask, j and the four outputs (none can be NULL here): [P, M] each
-        for ys in handed:
-            assert set(ys) == {"mask", "j", "v1", "first", "peak", "back"}
-            assert set(ys.values()) == {(lanes, rows_m)}
-        assert sum(int(np.prod(shape)) for shape in handed[0].values()) \
-            == (2 + 4 + 0) * lanes * rows_m
+        # mask, j and the four outputs (none can be NULL here)
+        table = {k: (lanes, rows_m)
+                 for k in ("mask", "j", "v1", "first", "peak", "back")}
+        for ys in handed:       # on the device: both tables and the count
+            assert ys == {"n": (lanes,), **table, "full": {
+                k: (lanes, slots + rows_m) for k in table}}
+        assert fenced == [(lanes,)] * 8
+        for step in fetched:    # to the host: n again (cached), ONE table
+            assert step == [[(lanes,)], [(lanes, rows_m)] * 6]
+            assert sum(int(np.prod(shape)) for shape in step[1]) \
+                == (2 + 4 + 0) * lanes * rows_m
+        assert r.decode_full_s is None
+    finally:
+        m.shutdown()
+
+
+def _one_lane_closes_many_closures(n_wait=80):
+    """A stream in which ONE key closes ``n_wait`` Kleene closures with one
+    event: rising readings open a closure each and collect every later one,
+    the reading back under the first closes all that hold three."""
+    devs, vs = _events(240, keys=12, seed=43)
+    hot = np.array(["hot"] * (n_wait + 4), dtype=object)
+    hot_v = np.array([52.0 + i / 1000 for i in range(n_wait + 3)] + [10.0])
+    return (np.concatenate([devs[:120], hot, devs[120:]]),
+            np.concatenate([vs[:120], hot_v, vs[120:]]))
+
+
+@pytest.mark.parametrize("columns", [False, True], ids=["send", "columns"])
+def test_count_state_a_lane_that_emits_more_than_its_packed_table_loses_no_row(
+        columns):
+    """The scan kernel's ``M`` (a lane's event capacity, 64 here) is no bound
+    on a lane's rows either: 80 closures of one key close on one event. In
+    that batch exactly one lane passes ``M``, so the step packs at the size
+    of ``full`` for all lanes and the decode reads ``full``, for that batch
+    and for no other: every lane's rows equal the interpreter's, nothing is
+    a drop, one ``decode_full`` is counted."""
+    devs, vs = _one_lane_closes_many_closures()
+    want = _kleene_interpreter(devs, vs)
+    m = SiddhiManager()
+    rt = m.create_siddhi_app_runtime(
+        _kleene(_device(batch=64, slots=128, lanes=4)), playback=True)
+    rows: list = []
+    rt.add_callback("Alerts", StreamCallback(
+        lambda evs: rows.extend(tuple(e.data) for e in evs)))
+    rt.start()
+    r = rt.device_bridges[0].runtime
+    counts: list = []
+
+    def on_step(ys):
+        n = np.asarray(ys["n"])
+        counts.append(n)
+        # ``full`` holds every lane's rows, whichever pack ran
+        assert np.array_equal(np.asarray(ys["full"]["mask"]).sum(axis=1), n)
+        assert np.array_equal(np.asarray(ys["mask"]).sum(axis=1),
+                              np.minimum(n, 64))
+
+    _tap_decode(r, on_step)
+    try:
+        _send(rt, devs, vs, columns, chunk=50)
+        rt.flush_device()
+        assert r.kernel == "scan" and r.compiler.M == 64
+        over = [n for n in counts if n.max() > 64]
+        assert len(over) == 1 and over[0].max() >= 80
+        assert int((over[0] > 64).sum()) == 1           # exactly one lane
+        assert _null_f32(rows) == want and len(rows) >= 80
+        assert r.drop_count == 0 and r.lane_gauges["drops"] == 0
+        phases = rt.device_bridges[0].probe.phases
+        # event-weighted like every phase: that one batch's events
+        assert 0 < phases.trackers["decode_full"].hist.count <= 64
+        assert phases.trackers["egress_decode"].hist.count == len(vs)
+        assert rt.device_bridges[0].probe.steps == len(counts)
+        rep = rt.observability.latency_report()["queries"]
+        (entry,) = [v for v in rep.values() if "lanes" in v]
+        assert "decode_full" in entry["phases"]
+        assert entry["reconciliation_ratio"] == pytest.approx(1.0, abs=1e-6)
     finally:
         m.shutdown()

@@ -129,8 +129,9 @@ def test_a_batch_that_closes_every_partial_fits_the_row_table(seed):
     begins; the batch closes EVERY partial at once, then seeds, collects
     and closes again, round after round: a partial emits once, so the rows
     of a batch are at most the C alive when it began plus one an event,
-    ``M = C + B``. Nothing is counted into ``drops`` and the rows are the
-    host's."""
+    ``C + B``: the rows of ``full``, which such a batch (more rows than its
+    ``M = B`` events) is read from. Nothing is counted into ``drops`` and
+    the rows are the host's."""
     from siddhi_tpu.tpu.nfa import DeviceNFARuntime
     rng = random.Random(4000 + seed)
     C, B = 16, 64
@@ -169,7 +170,7 @@ def test_a_batch_that_closes_every_partial_fits_the_row_table(seed):
     assert len(events) == first + B and closed > C + B // 2
     rt = DeviceNFARuntime(app, slot_capacity=C, batch_capacity=B,
                           start_time=START)
-    assert rt.compiler.M == C + B
+    assert rt.compiler.M == B and rt.compiler._row_capacity() == C + B
     rows = []
     rt.add_callback(rows.extend)
     for sid, row, t in events:

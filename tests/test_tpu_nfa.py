@@ -5,6 +5,7 @@ BASELINE.json configs exercised: #2 (A→B sequence-style pattern with within),
 virtual devices (conftest).
 """
 
+import functools
 import random
 
 import jax
@@ -152,6 +153,46 @@ def test_partitioned_mesh_parity():
     rt.flush()
     assert rt.drop_count == 0
     assert rt.match_count == len(oracle(app, events))
+
+
+def test_scan_kernel_on_a_mesh_each_shard_takes_its_own_pack():
+    """The count-state step sharded over four devices, two lanes a shard:
+    one key closes 40 closures on one event, more than its lane's 32 events
+    of a batch, so that batch's rows pass the packed table in ONE shard.
+    Every shard branches on the largest row count of its own lanes; the
+    rows, lane for lane, are those of the same runtime without a mesh."""
+    app = """
+    define stream S (dev string, v double);
+    from every e1=S[v > 50.0] -> e2=S[dev == e1.dev and v > e1.v]<3:>
+        -> e3=S[dev == e1.dev and v < e1.v]
+    select e1.v as v1, e2[0].v as first, e2[last].v as peak, e3.v as back
+    insert into O;
+    """
+    rng = random.Random(16)
+    events = [("S", [f"dev{rng.randrange(12)}",
+                     round(rng.uniform(0, 100), 1)], 1000 + i)
+              for i in range(160)]
+    hot = [("S", ["hot", 52.0 + i / 100], 2000 + i) for i in range(43)]
+    events[80:80] = hot + [("S", ["hot", 10.0], 2100)]
+    got = {}
+    for mesh in (None, Mesh(np.array(jax.devices()[:4]), ("p",))):
+        rt = PartitionedNFARuntime(app, num_partitions=8, key_attr="dev",
+                                   slot_capacity=64, lane_batch=32,
+                                   mesh=mesh)
+        assert rt.kernel == "scan" and rt.compiler.M == 32
+        rows, most = [], []
+        rt.callback = rows.extend
+        inner = rt.decode_stacked
+        rt.decode_stacked = lambda ys, inner=inner, most=most: (
+            most.append(np.asarray(ys["n"])), inner(ys))[1]
+        for sid, row, ts in events:
+            rt.send(sid, list(row), ts)
+        rt.flush()
+        assert rt.drop_count == 0 and len(rows) >= 40
+        over = [n for n in most if n.max() > 32]
+        assert len(over) == 1 and int((over[0] > 32).sum()) == 1
+        got[mesh is None] = rows
+    assert got[True] == got[False]
 
 
 def test_partitioned_per_key_semantics_on_shared_lanes():
@@ -510,83 +551,180 @@ SCAN_CORPUS = {
 }
 
 
-def _grid_step(compiler, args, monkeypatch):
-    """The scan step with its pack stubbed out, compiled for ``args``: what
-    it emitted before the rows were packed on the device, ``[B, 2, C]``
-    grids."""
-    from siddhi_tpu.tpu import nfa as nfa_mod
-    with monkeypatch.context() as mp:
-        mp.setattr(nfa_mod, "pack_rows",
-                   lambda mask, cols, n: ({"mask": mask, **cols},
-                                          np.int64(0)))
-        return jax.jit(compiler.make_step()).lower(
-            compiler.init_state(), *args).compile()
-
-
-@pytest.mark.parametrize("name", list(SCAN_CORPUS))
-def test_the_row_table_holds_the_rows_the_boolean_index_gave(name,
-                                                             monkeypatch):
-    """Same rows, same order (match event, source, candidate), NULL masks
-    kept, batch after batch from the same carried state."""
-    app, events = SCAN_CORPUS[name]
-    rt = DeviceNFARuntime(app, slot_capacity=32, batch_capacity=48)
-    nfa = rt.compiler
-    assert not nfa.blocked and nfa.M <= 2 * 32 * 48
-    grid_step = None
-    state_g = nfa.init_state()
-    n_rows = n_null = 0
+def _walk_batches(rt, events):
+    """``events`` through ``rt``'s builder: the step's arguments, a batch at
+    a time."""
     for sid, row, ts in events:
         rt.builder.append(sid, row, ts)
-        if not rt.builder.full and (sid, row, ts) != events[-1]:
-            continue
-        b = rt.builder.emit()
-        args = (b["cols"], b["tag"], b["ts"], b["ts_base"],
-                np.int32(b["count"]))
-        grid_step = grid_step or _grid_step(nfa, args, monkeypatch)
-        state_g, grids = grid_step(state_g, *args)
-        assert grids["mask"].shape[0::2] == (48, 32)
-        rt.state, table = nfa._step(rt.state, *args)
-        assert set(table) == {"mask", "j"} | (set(grids) - {"mask"})
-        assert all(v.shape == (nfa.M,) for v in table.values())
-        mask = np.asarray(grids["mask"])                    # [B, 2, C]
-        got = nfa.decode_outputs(table)
-        assert len(got) == int(mask.sum())
-        for (col, _, _) in nfa.out_specs:
-            assert np.array_equal(np.asarray(grids[col])[mask],
-                                  got.cols[col] if len(got) else []), col
-            if f"null__{col}" in grids and len(got):
-                want = np.asarray(grids[f"null__{col}"])[mask]
-                assert np.array_equal(want, got.nulls[col]), col
-                n_null += int(want.sum())
-        j = np.asarray(table["j"])[np.asarray(table["mask"])]
-        assert np.array_equal(j, np.nonzero(mask)[0])       # the events
-        n_rows += len(got)
+        if rt.builder.full or (sid, row, ts) == events[-1]:
+            b = rt.builder.emit()
+            yield (b["cols"], b["tag"], b["ts"], b["ts_base"],
+                   np.int32(b["count"]))
+
+
+def _assert_table_is_the_index(nfa, table, grids, upto=None):
+    """``table``'s rows are the boolean index over the emit grids (its
+    first ``upto``): rows, order, ``j`` and NULL masks. Returns how many NULL
+    cells were compared."""
+    mask = np.asarray(grids["mask"])                        # [B, R, C]
+    got = nfa.decode_outputs(table)
+    kept = int(mask.sum()) if upto is None else upto
+    assert len(got) == kept
+    n_null = 0
+    for (col, _, _) in nfa.out_specs:
+        assert np.array_equal(np.asarray(grids[col])[mask][:kept],
+                              got.cols[col] if kept else []), col
+        if f"null__{col}" in grids and kept:
+            want = np.asarray(grids[f"null__{col}"])[mask][:kept]
+            assert np.array_equal(want, got.nulls[col]), col
+            n_null += int(want.sum())
+    j = np.asarray(table["j"])[np.asarray(table["mask"])]
+    assert np.array_equal(j, np.nonzero(mask)[0][:kept])    # the events
+    return n_null
+
+
+@pytest.mark.parametrize("batch", [48, 4], ids=["packed", "past-the-batch"])
+@pytest.mark.parametrize("name", list(SCAN_CORPUS))
+def test_the_row_table_holds_the_rows_the_boolean_index_gave(name, batch):
+    """Same rows, same order (match event, source, candidate), NULL masks
+    kept, batch after batch from the same carried state: in ``full``
+    always, and in the packed ``[B]`` table where the batch emitted at most
+    ``B`` rows (else it holds the first ``B``). With batches of 4 events
+    some batch of every plan emits more
+    rows than it has events. The state the step carries is the state the
+    scan alone leaves, bit for bit."""
+    app, events = SCAN_CORPUS[name]
+    rt = DeviceNFARuntime(app, slot_capacity=32, batch_capacity=batch)
+    nfa = rt.compiler
+    assert not nfa.blocked and nfa.M == nfa.B == batch
+    rows_full = nfa._row_capacity()
+    assert batch < rows_full <= 2 * 32 * batch
+    scan = jax.jit(nfa.make_scan())
+    state_g = nfa.init_state()
+    n_rows = n_null = n_over = 0
+    for args in _walk_batches(rt, events):
+        state_g, grids = scan(state_g, *args)
+        assert grids["mask"].shape[0::2] == (batch, 32)
+        rt.state, ys = nfa._step(rt.state, *args)
+        n, full = int(ys.pop("n")), ys.pop("full")
+        assert set(ys) == set(full) == {"mask", "j"} | (set(grids) - {"mask"})
+        assert all(v.shape == (batch,) for v in ys.values())
+        assert all(v.shape == (rows_full,) for v in full.values())
+        assert n == int(np.asarray(grids["mask"]).sum()) <= rows_full
+        n_null += _assert_table_is_the_index(nfa, full, grids)
+        _assert_table_is_the_index(nfa, ys, grids, upto=min(n, batch))
+        n_over += n > batch
+        n_rows += n
         # the carried state is the same state
         for a, b_ in zip(jax.tree_util.tree_leaves(state_g),
                          jax.tree_util.tree_leaves(rt.state)):
             assert np.array_equal(np.asarray(a), np.asarray(b_))
     assert n_rows > 10
+    assert (n_over > 0) == (batch == 4)
     assert (n_null > 0) == name.endswith("nulls")
     assert rt.drop_count == 0
 
 
-def test_rows_past_the_table_are_counted_into_drops():
-    """A table too small for what a batch emits: the first ``M`` rows in
-    order, the rest counted, never silent."""
+@pytest.mark.parametrize("lanes, cands", [(3, 40), (8, 40), (16, 300)],
+                         ids=["plain", "one-tile", "tiles-and-groups"])
+def test_rows_past_the_table_are_counted_into_drops(lanes, cands):
+    """A table too small for what a batch emits holds the first rows in
+    order and ``n`` counts them all (``pack_rows``), whether the lanes are
+    numbered plainly or by tiles of 8 and whether a source is one group of
+    cells or three; the step counts the rows past its ``full`` table into
+    ``drops`` (the next test), never silent."""
     from siddhi_tpu.tpu.nfa import pack_rows
     rng = np.random.default_rng(5)
-    mask = rng.uniform(size=(16, 2, 40)) < 0.2
-    vals = rng.uniform(size=(16, 2, 40)).astype(np.float32)
-    want = vals[mask]
-    for n in (8, len(want), len(want) + 9):
-        out, lost = pack_rows(mask, {"x": vals}, n)
-        kept = min(n, len(want))
-        assert int(lost) == len(want) - kept
-        assert int(np.asarray(out["mask"]).sum()) == kept
-        assert np.array_equal(np.asarray(out["x"])[:kept], want[:kept])
-        assert np.array_equal(np.asarray(out["j"])[:kept],
-                              np.nonzero(mask)[0][:kept])
-        assert not np.asarray(out["x"])[kept:].any()
+    mask = rng.uniform(size=(16, lanes, 2, cands)) < 0.2
+    vals = rng.uniform(size=(16, lanes, 2, cands)).astype(np.float32)
+    most = int(mask.sum(axis=(0, 2, 3)).max())
+
+    @functools.partial(jax.jit, static_argnums=2)
+    def pack(mask, vals, n_rows):
+        n, table = pack_rows(mask, {"x": vals})
+        return n, table(n_rows)
+
+    for n_rows in (8, most // 2, most + 9):
+        n, out = jax.tree_util.tree_map(np.asarray, pack(mask, vals, n_rows))
+        for lane in range(lanes):
+            want = vals[:, lane][mask[:, lane]]
+            kept = min(n_rows, len(want))
+            assert int(n[lane]) == len(want)
+            assert int(out["mask"][lane].sum()) == kept
+            assert np.array_equal(out["x"][lane, :kept], want[:kept])
+            assert np.array_equal(out["j"][lane, :kept],
+                                  np.nonzero(mask[:, lane])[0][:kept])
+            assert not out["x"][lane, kept:].any()
+
+
+def test_rows_past_the_full_table_are_counted_into_the_steps_drops(
+        monkeypatch):
+    """The step with a ``full`` table smaller than what a batch emits:
+    ``full`` holds the first rows in order, the packed table its first
+    ``B``, ``n`` counts them all and ``drops`` the rows past ``full``."""
+    from siddhi_tpu.tpu.nfa import DeviceNFACompiler
+    monkeypatch.setattr(DeviceNFACompiler, "_row_capacity", lambda self: 6)
+    app, events = SCAN_CORPUS["count-kleene"]
+    rt = DeviceNFARuntime(app, slot_capacity=32, batch_capacity=4)
+    nfa = rt.compiler
+    scan = jax.jit(nfa.make_scan())
+    state_g = nfa.init_state()
+    lost = 0
+    for args in _walk_batches(rt, events):
+        state_g, grids = scan(state_g, *args)
+        rt.state, ys = nfa._step(rt.state, *args)
+        n, full = int(ys.pop("n")), ys.pop("full")
+        assert n == int(np.asarray(grids["mask"]).sum())
+        assert full["mask"].shape == (6,) and ys["mask"].shape == (4,)
+        _assert_table_is_the_index(nfa, full, grids, upto=min(n, 6))
+        _assert_table_is_the_index(nfa, ys, grids, upto=min(n, 4))
+        lost += max(n - 6, 0)
+        assert rt.drop_count == lost
+    assert lost > 0
+
+
+def test_the_stacked_step_packs_under_one_scalar_branch():
+    """The optimized HLO of the lane-stacked scan step holds ONE
+    ``conditional``, its predicate one scalar for all lanes, and a pack
+    (its row gathers) in either branch: the packed table's and the whole
+    one's. A branch a lane (``vmap`` of the lane's step) lowers to a select
+    with both packs on every batch, and holds none."""
+    import re
+    rt = PartitionedNFARuntime(
+        SCAN_CORPUS["count-kleene"][0], num_partitions=6, key_attr="k",
+        slot_capacity=16, lane_batch=32)
+    nfa = rt.compiler
+    assert rt.kernel == "scan"
+    b = rt.builders[0].emit()
+    feed = jax.tree_util.tree_map(
+        lambda x: np.stack([x] * 6),
+        (b["cols"], b["tag"], b["ts"], b["ts_base"], np.int32(0)))
+
+    def hlo(step):
+        return jax.jit(step).lower(rt.init_state(), *feed).compile().as_text()
+
+    def gathers(text, where):
+        return [shape for shape, scope in re.findall(
+            r"^\s*(?:ROOT )?%?[\w.\-]+ = (\S+) gather\(.*?op_name="
+            r"\"([^\"]*)\"", text, re.M) if where in scope]
+
+    text = hlo(nfa.make_step(stacked=True))
+    conds = re.findall(r"^.* conditional\(%?([\w.\-]+),.*$", text, re.M)
+    assert len(conds) == 1
+    pred = re.search(rf"^\s*%?{re.escape(conds[0])} = (\S+) ", text, re.M)
+    assert pred.group(1) in ("pred[]", "s32[]")
+    n_cols = len(nfa.out_specs) + 1         # e2[1] may be NULL: its mask
+    for branch, n_rows in (("branch_1_fun", nfa.B),
+                           ("branch_0_fun", nfa._row_capacity())):
+        got = gathers(text, f"nfa.compact/cond/{branch}/")
+        # the running group counts, the cells, a gather a column
+        assert len(got) == 2 + n_cols, got
+        assert all(g.split("[")[1].startswith(f"{6 * n_rows},")
+                   for g in got), got
+    assert not gathers(text.replace("nfa.compact/cond/", ""), "nfa.compact")
+    alone = hlo(jax.vmap(nfa.make_step()))
+    assert " conditional(" not in alone
+    assert len(gathers(alone, "nfa.compact")) == 2 * (2 + n_cols)
 
 
 @pytest.mark.parametrize("app, live, per_event", [
@@ -597,8 +735,17 @@ def test_rows_past_the_table_are_counted_into_drops():
      "from not A for 100 -> e2=B select e2.v as b insert into O;", 2, 2),
 ], ids=["count", "logical", "absent", "absent-start"])
 def test_the_row_tables_size_is_read_from_the_plan(app, live, per_event):
+    """The packed table has a row an event of the batch, the blocked
+    kernel's rule; ``full`` beside it has the plan's bound."""
     rt = DeviceNFARuntime(app, slot_capacity=32, batch_capacity=48)
-    assert rt.compiler.M == live * 32 + per_event * 48
+    nfa = rt.compiler
+    assert nfa.M == nfa.B == 48 and rt.fence_key == "n"
+    assert nfa._row_capacity() == live * 32 + per_event * 48
+    b = rt.builder.emit()                   # a batch of no events
+    _, ys = nfa._step(rt.state, b["cols"], b["tag"], b["ts"], b["ts_base"],
+                      np.int32(b["count"]))
+    assert ys["n"].shape == () and ys["mask"].shape == (48,)
+    assert ys["full"]["mask"].shape == (nfa._row_capacity(),)
 
 
 def test_the_stacked_scan_decode_equals_the_per_lane_decode():
@@ -613,7 +760,7 @@ def test_the_stacked_scan_decode_equals_the_per_lane_decode():
     rt = PartitionedNFARuntime(app, num_partitions=6, key_attr="dev",
                                slot_capacity=16, lane_batch=32)
     nfa = rt.compiler
-    assert rt.kernel == "scan" and nfa.M == 16 + 32
+    assert rt.kernel == "scan" and nfa.M == 32
     rng = np.random.default_rng(31)
     for density in (0.0, 0.05, 0.4, 1.0):
         mask = rng.uniform(size=(6, nfa.M)) < density
