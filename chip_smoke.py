@@ -40,7 +40,9 @@ Any of them off fails the stage even when the rows are right.
                                                 no result line is printed
     python3 chip_smoke.py --only S3,S5          a partial run is never a pass
 
-It states counts and set-up (compile) seconds, never a rate or a latency.
+It states counts and each stage's first step in seconds (`first_step_s`: the
+trace and compile, or the program's load from the compile cache), never a
+rate or a latency.
 Exit status: 0 only when every stage passed on a TPU (or, with --rehearsal,
 on the platform found). The last line of a passing run is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
@@ -241,7 +243,7 @@ def run_pair(app: str, device_ann: str, out_stream: str, feed, sent: dict,
         if rt.device_bridges and rt.device_bridges[0].probe is not None:
             probe = rt.device_bridges[0].probe
             facts["steps"] = probe.steps
-            facts["compile_s"] = round(probe.compile_seconds, 2)
+            facts["first_step_s"] = round(probe.first_step_seconds, 2)
         return bad + (also(rt) if also is not None else [])
 
     warnings.drain()
@@ -404,7 +406,7 @@ def run_flagship(cfg, events, mesh=None):
     tail = rt.ingest_csv(csv(events[cut:]), ts_last=True, decode=True)
     tail += rt.flush_native(decode=True) or []
     facts = {"events": len(events), "rows": len(head) + len(tail),
-             "steps": stepped["n"], "compile_s": stepped["first_s"],
+             "steps": stepped["n"], "first_step_s": stepped["first_s"],
              "ingress": "native", "drops": rt.drop_count,
              "parse_errors": rt._ning.parse_errors,
              "state_bytes": sum(x.nbytes for x in
@@ -503,7 +505,8 @@ def stage_s3b(cfg, seed, platform, warnings, keep):
             if not bad:
                 bridge = rt.device_bridges[0]
                 facts.update(kind=bridge.kind, steps=bridge.probe.steps,
-                             compile_s=round(bridge.probe.compile_seconds, 2),
+                             first_step_s=round(
+                                 bridge.probe.first_step_seconds, 2),
                              flush_causes=dict(bridge.probe.flush_causes),
                              lanes=dict(bridge.runtime.lane_gauges),
                              kernel=bridge.runtime.kernel)
@@ -764,7 +767,7 @@ def stage_s5(cfg, seed, platform, warnings, keep):
                 if not engaged:
                     bad.append("the device reducer was not engaged")
                 bad += [f"logged: {w}" for w in warnings.drain()]
-                facts = {"rows": len(dev), "compile_s": None}
+                facts = {"rows": len(dev), "first_step_s": None}
             else:
                 # one stream emits in arrival order on both engines; rows
                 # a pattern or join emits on one event may swap places
@@ -773,9 +776,9 @@ def stage_s5(cfg, seed, platform, warnings, keep):
         except Exception as e:  # noqa: BLE001 — one kind the chip's compiler
             # refuses must not hide what it does to the kinds after it
             bad = [f"{type(e).__name__}: {str(e)[:600]}"]
-            facts = {"rows": 0, "compile_s": None}
+            facts = {"rows": 0, "first_step_s": None}
         verdict = "FAILED" if bad else "ok"
-        say(f"S5 {kind}: {verdict} compile_s={facts.get('compile_s')} "
+        say(f"S5 {kind}: {verdict} first_step_s={facts.get('first_step_s')} "
             f"rows={facts.get('rows')} "
             f"total_s={time.perf_counter() - t0:.1f}")
         for b in bad:

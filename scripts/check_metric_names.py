@@ -349,7 +349,7 @@ def check(text: str) -> list[str]:
             problems.append(
                 f"{family}{dict(series)}: +Inf bucket {buckets[-1][1]} "
                 f"!= _count {total}")
-    from siddhi_tpu.observability.phases import NESTED, PHASES
+    from siddhi_tpu.observability.phases import NESTED, PHASES, THREAD_CLOCKS
     for (family, label), values in label_values.items():
         if len(values) > MAX_LABEL_VALUES:
             problems.append(
@@ -359,7 +359,8 @@ def check(text: str) -> list[str]:
         if label == "phase":
             # one vocabulary: a phase label outside observability.phases
             # PHASES is a tracker somebody named by hand
-            stray = values - set(PHASES) - set(NESTED) - {"end_to_end"}
+            stray = values - set(PHASES) - set(NESTED) \
+                - set(THREAD_CLOCKS) - {"end_to_end"}
             if stray:
                 problems.append(
                     f"{family}: phase label values {sorted(stray)} are "

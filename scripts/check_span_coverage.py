@@ -94,13 +94,21 @@ def main() -> int:
     check("driver egress measures the split and puts it on the profiler's "
           "clock",
           all(k in src(AsyncDeviceDriver._collect_oldest) for k in (
-              "decode_s", "lock_s", "ring_s", "deliver.lock",
+              "collect_s", "lock_s", "ring_s", "deliver.lock",
               "deliver.publish"))
+          and "decode_s" in src(StepRuntime.step_phases)
           and "ring_wait" in src(AsyncDeviceDriver.submit)
           and "collect.fence" in src(StepRuntime._fence)
           and "seal.pack" in src(StepRuntime._emit_batch))
     check("sync path measures the same split",
-          "decode_s" in src(StepRuntime._timed_process))
+          "collect_s" in src(StepRuntime._timed_process)
+          and "step_phases" in src(StepRuntime._timed_process))
+    check("every segment a thread works in reads the thread's CPU clock "
+          "beside the wall clock",
+          all("thread_time" in src(f) for f in (
+              AsyncDeviceDriver._dispatch, AsyncDeviceDriver._collect_oldest,
+              StepRuntime._fence, StepRuntime._timed_process,
+              StepRuntime._emit_batch)))
     check("probe closes fill-wait + device spans per batch",
           "fill-wait" in src(DeviceStepProbe.on_step)
           and "add_span" in src(DeviceStepProbe.on_step))

@@ -701,7 +701,7 @@ class SiddhiAppRuntime:
             raise KeyError(f"stream '{stream_id}' is not defined")
         j = self.ctx.stream_junctions[stream_id]
         j.subscribe(_StreamCallbackReceiver(
-            callback, j.definition.attribute_names))
+            callback, j.definition.attribute_names, stream_id))
 
     def add_rows_callback(self, stream_id: str, fn) -> None:
         """Columns-capable subscription: ``fn(cols, ts, n)`` receives whole

@@ -85,7 +85,8 @@ class AsyncDeviceDriver:
         # collect() reads live state (hopping drain) pin it to 1
         self.window = max(1, window) if rt.pipeline_safe else 1
         self._q = collections.deque()            # packed, undispatched
-        self._inflight = collections.deque()     # (batch, token, disp_s, err)
+        self._inflight = collections.deque()     # (batch, token, t_disp0,
+        # disp_s, disp_cpu_s, err)
         self._cv = threading.Condition()
         self._busy = False          # dispatch/collect/delivery in flight
         self._paused = False
@@ -129,6 +130,10 @@ class AsyncDeviceDriver:
 
     # -- worker ---------------------------------------------------------------
     def _run(self) -> None:
+        # this thread's CPU clock as the last batch last read it (behind
+        # its collect, or its publishing): the difference from batch to
+        # batch is ``driver_cpu``
+        self._cpu_mark = time.thread_time()
         while True:
             action, batch = self._next_action()
             if action == "stop":
@@ -222,7 +227,7 @@ class AsyncDeviceDriver:
             self.rt.flush()
 
     def _dispatch(self, batch) -> None:
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         err = None
         token = None
         try:
@@ -233,18 +238,19 @@ class AsyncDeviceDriver:
             # batch is consumed (counted at its egress slot)
             log.exception("device dispatch failed")
             err = e
-        disp_s = time.perf_counter() - t0
+        disp_s, disp_cpu_s = time.perf_counter() - t0, time.thread_time() - c0
         with self._cv:
-            self._inflight.append((batch, token, t0, disp_s, err))
+            self._inflight.append((batch, token, t0, disp_s, disp_cpu_s, err))
             self._cv.notify_all()
 
     def _collect_oldest(self) -> None:
         with self._cv:
-            batch, token, t_disp0, disp_s, err = self._inflight.popleft()
+            batch, token, t_disp0, disp_s, disp_cpu_s, err = \
+                self._inflight.popleft()
         rt = self.rt
         rt.fence_s = None       # the runtime's own collect leaves its wait
         # for the device here; a guard replay, which fences nothing, none
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         rows = []
         ok = False
         try:
@@ -258,19 +264,19 @@ class AsyncDeviceDriver:
             # host path before this can trigger
             log.exception("device step failed")
             rows = []
-        collect_s = time.perf_counter() - t0
-        fence_s = rt.fence_s if rt.fence_s is not None else collect_s
+        cpu_now = time.thread_time()
+        collect_s, collect_cpu_s = time.perf_counter() - t0, cpu_now - c0
         dt = disp_s + collect_s
         self.step_seconds += dt
         self.batches_stepped += 1
-        lock_s = publish_s = 0.0
+        lock_s = publish_s = publish_cpu_s = 0.0
         if rows:
             lock = self.app_context.root_lock
             tp0 = time.perf_counter()
             with span(self._spans["deliver"]):
                 with span(self._spans["deliver.lock"]):
                     lock.acquire()
-                tp1 = time.perf_counter()
+                tp1, cp1 = time.perf_counter(), time.thread_time()
                 try:
                     # stamp outputs with the batch's own last event time —
                     # the producer-side _out_ts has already advanced to
@@ -286,6 +292,8 @@ class AsyncDeviceDriver:
                     lock.release()
             lock_s = tp1 - tp0
             publish_s = time.perf_counter() - tp1
+            cpu_now = time.thread_time()
+            publish_cpu_s = cpu_now - cp1
         try:
             # the probe must see EVERY consumed batch (success or not) or
             # its FIFO trace groups desynchronize; observed AFTER delivery
@@ -297,13 +305,18 @@ class AsyncDeviceDriver:
                 if t_emit is not None else 0.0
             queue_s += max(0.0, t0 - (t_disp0 + disp_s))
             ring_s = batch.get("_ring_wait_s", 0.0)
+            # all this thread's CPU since the last batch's last reading:
+            # the recording below, on_drained and _next_action included
+            mark, self._cpu_mark = self._cpu_mark, cpu_now
             rt.observe_step(
                 batch.get("count", 0), dt, device_path=ok,
                 phases=rt.step_phases(
                     batch, queue_s=max(0.0, queue_s - ring_s),
-                    step_s=disp_s, fence_s=fence_s,
-                    decode_s=collect_s - fence_s, ring_s=ring_s,
-                    lock_s=lock_s, publish_s=publish_s))
+                    step_s=disp_s, step_cpu_s=disp_cpu_s,
+                    collect_s=collect_s, collect_cpu_s=collect_cpu_s,
+                    ring_s=ring_s, lock_s=lock_s, publish_s=publish_s,
+                    publish_cpu_s=publish_cpu_s,
+                    driver_cpu_s=self._cpu_mark - mark))
         except Exception:   # noqa: BLE001 — a raising observer must not
             # kill the sole device worker
             log.exception("step observer failed")

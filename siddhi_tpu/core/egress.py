@@ -18,24 +18,51 @@ from what is in front of it, per chunk:
   batch.
 
 There is no per-row path. ``egress`` counts deliveries and rows by shape, so
-rows per delivery can be read (``egress_report``).
+rows per delivery can be read (``egress_report``). What the ENGINE builds for
+a subscriber that takes events is timed apart from what the subscriber does
+with it: on the second path the timestamps, the rows, a ``StreamEvent`` a
+row and the ``Event`` list of a query callback (span
+``siddhi:deliver.publish.build:<query>``); on the first the ``Event`` list a
+``StreamCallback``'s receiver builds from the columns
+(``core/stream.py`` ``receive_columns``, ``built_s``; the same span, named by
+the stream). The seconds are left on the runtime for its ``phases`` record,
+``publish_build_s`` (``observability/phases.py`` ``publish_build``, inside
+``sink_publish``): 0 for a subscriber that takes the columns as they are.
 """
 
 from __future__ import annotations
 
+import contextlib
+import time
+
 import numpy as np
 
+from ..observability.profiler import span
 from .event import Event, EventType, StreamEvent
 
 
 class ChunkEgress:
-    """Mixin of the bridges (``output_junction``, ``query_callbacks``)."""
+    """Mixin of the bridges (``output_junction``, ``query_callbacks``,
+    ``runtime``, ``query_name``)."""
 
     rate_limiter = None
 
     def _init_egress(self) -> None:
         # shape -> [deliveries, rows]
         self.egress = {"columns": [0, 0], "events": [0, 0]}
+
+    @contextlib.contextmanager
+    def _building(self, first: bool):
+        """Round what the engine builds for a subscriber that takes events.
+        The seconds are left on the runtime for its ``step_phases``: a
+        chunk's ``first`` build sets them, the ``Event`` list of its query
+        callbacks adds to them."""
+        rt = self.runtime
+        t0 = time.perf_counter()
+        with span(f"siddhi:deliver.publish.build:{self.query_name}"):
+            yield
+        dt = time.perf_counter() - t0
+        rt.publish_build_s = dt if first else (rt.publish_build_s or 0.0) + dt
 
     def _on_out(self, out) -> None:
         """``out`` is a stamped :class:`~siddhi_tpu.core.columns.ColumnsOut`
@@ -63,11 +90,16 @@ class ChunkEgress:
         count[0] += 1
         count[1] += out.n
         oj.deliver_columns(cols, np.asarray(out.ts, dtype=np.int64), out.n)
+        # a StreamCallback asked for events: its receiver built them from
+        # the columns and says what that took
+        self.runtime.publish_build_s = sum(
+            getattr(r, "built_s", 0.0) for r in oj.receivers)
 
     def _deliver_events_out(self, out) -> None:
         cur = EventType.CURRENT
-        events = [StreamEvent(ts, row, cur)
-                  for ts, row in zip(out.ts_list(), out.rows())]
+        with self._building(first=True):
+            events = [StreamEvent(ts, row, cur)
+                      for ts, row in zip(out.ts_list(), out.rows())]
         if self.rate_limiter is not None:
             self.rate_limiter.process(events)   # → _publish_events
         else:
@@ -82,7 +114,8 @@ class ChunkEgress:
         count[0] += 1
         count[1] += len(events)
         if self.query_callbacks:
-            evs = [Event(e.timestamp, e.data) for e in events]
+            with self._building(first=False):
+                evs = [Event(e.timestamp, e.data) for e in events]
             for cb in self.query_callbacks:
                 cb.receive(events[-1].timestamp, evs, None)
         if self.output_junction is not None:

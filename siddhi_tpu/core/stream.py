@@ -15,6 +15,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from ..observability.profiler import span
 from ..query_api.definition import AbstractDefinition
 from .event import Event, EventType, StreamEvent
 
@@ -594,10 +595,15 @@ class _StreamCallbackReceiver:
     ``receive`` per delivery, whatever its shape (an event, a chunk of
     events, a columnar chunk)."""
 
+    built_s = 0.0   # what the last columnar delivery spent on this
+    # subscriber's ``Event`` list, the user's function not included: the
+    # bridge that delivered reads it (``core/egress.py``, ``publish_build``)
+
     def __init__(self, callback: StreamCallback,
-                 names: Optional[list] = None):
+                 names: Optional[list] = None, stream_id: str = ""):
         self.callback = callback
         self.names = names      # the stream's attribute names, in order
+        self._build_span = f"siddhi:deliver.publish.build:{stream_id}"
 
     def receive(self, event: StreamEvent) -> None:
         if event.type in (EventType.CURRENT, EventType.EXPIRED):
@@ -616,14 +622,19 @@ class _StreamCallbackReceiver:
     def receive_columns(self, cols: dict, ts, n: int) -> None:
         """One ``receive`` for a columnar chunk (``deliver_columns``): the
         ``Event`` list is built straight from the columns, whole columns
-        through ``tolist()``, with no ``StreamEvent`` in between."""
+        through ``tolist()``, with no ``StreamEvent`` in between; the build
+        is timed apart from the user's function (``built_s`` and a span on
+        the profiler's clock, named by the stream)."""
         from .columns import columns_to_rows
-        rows = columns_to_rows(cols, self.names or list(cols), n)
-        if rows:
+        t0 = time.perf_counter()
+        with span(self._build_span):
+            rows = columns_to_rows(cols, self.names or list(cols), n)
             own = Event._own
-            self.callback.receive(
-                [own(t, row)
-                 for t, row in zip(np.asarray(ts).tolist(), rows)])
+            events = [own(t, row)
+                      for t, row in zip(np.asarray(ts).tolist(), rows)]
+        self.built_s = time.perf_counter() - t0
+        if events:
+            self.callback.receive(events)
 
 
 class RowsCallback:

@@ -80,9 +80,10 @@ FLUSH_CAUSES = ("capacity", "adaptive", "deadline", "drain", "final",
 
 class DeviceStepProbe:
     """Per-bridge device-path accounting, fed by ``observe_step`` on both
-    the sync flush path and the async driver. ``compile_*`` is a proxy:
-    batch shapes are static, so the first step's wall time is the one that
-    pays jit trace + XLA compile."""
+    the sync flush path and the async driver. ``first_step_seconds`` is the
+    first step's dispatch + collect on the host's clock: batch shapes are
+    static, so it is the one step that pays the jit trace and the XLA
+    compile, or, from a persistent compile cache, the program's load."""
 
     # sealed groups beyond this are stale (emit sites the probe does not
     # seal, e.g. shutdown finalize) — close their spans rather than grow
@@ -101,9 +102,7 @@ class DeviceStepProbe:
         self.driver = None      # AsyncDeviceDriver when the bridge pipelines
         self.steps = 0
         self.events = 0
-        self.busy_seconds = 0.0
-        self.compile_count = 0
-        self.compile_seconds = 0.0
+        self.first_step_seconds = 0.0
         self.flush_causes: dict[str, int] = {}
         # (trace, arrival perf_counter_ns) registered at packing time into
         # the OPEN group; seal() closes the group when its batch is emitted
@@ -136,16 +135,17 @@ class DeviceStepProbe:
         keyed as :meth:`PhaseBreakdown.record_batch` names them
         (``fill_span_s``, ``pack_s``, ``ring_s``, ``queue_s``, ``step_s``,
         ``fence_s``, ``decode_s``, ``decode_full_s``, ``hop_drain_s``,
-        ``hop_flush_s``, ``lock_s``,
-        ``publish_s``, ``host_s``, ``cause``) — recorded event-weighted
-        into the per-phase histograms."""
+        ``hop_flush_s``, ``lock_s``, ``publish_s``, ``publish_build_s``,
+        ``host_s``, ``cause``; the threads' CPU clocks ``step_cpu_s``,
+        ``route_cpu_s``, ``fence_cpu_s``, ``decode_cpu_s``,
+        ``publish_cpu_s``, ``client_cycle_s``, ``client_cpu_s``,
+        ``driver_cpu_s``) — recorded event-weighted into the per-phase
+        histograms."""
         if device_path:
             self.steps += 1
             self.events += int(n_events)
-            self.busy_seconds += latency_s
             if self.steps == 1:
-                self.compile_count = 1
-                self.compile_seconds = latency_s
+                self.first_step_seconds = latency_s
         # a host-fallback step (device_path=False) still consumed its batch:
         # drain its trace group so spans close and nothing accumulates
         # during a quarantine
@@ -317,12 +317,8 @@ class ObservabilitySubsystem:
                 ctrl.site = q
             sm.gauge_tracker(f"device.{q}.steps_total",
                              lambda p=probe: p.steps)
-            sm.gauge_tracker(f"device.{q}.busy_seconds_total",
-                             lambda p=probe: p.busy_seconds)
-            sm.gauge_tracker(f"device.{q}.compile_count",
-                             lambda p=probe: p.compile_count)
-            sm.gauge_tracker(f"device.{q}.compile_seconds",
-                             lambda p=probe: p.compile_seconds)
+            sm.gauge_tracker(f"device.{q}.first_step_seconds",
+                             lambda p=probe: p.first_step_seconds)
             sm.gauge_tracker(f"device.{q}.pad_ratio",
                              lambda p=probe: round(p.pad_ratio, 4))
             sm.gauge_tracker(f"device.{q}.pipeline_depth",

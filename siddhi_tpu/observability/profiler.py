@@ -44,12 +44,23 @@ span                             thread    opens / closes
                                            it is held
 ``siddhi:deliver.publish:<q>``   driver    ``rt.deliver(chunk)``: the chunk
                                            to the junction, callbacks
+``siddhi:deliver.publish``       driver    inside it, what the engine builds
+``.build:<q | stream>``                    for a subscriber that takes
+                                           events: rows and ``StreamEvent``s
+                                           in the bridge (``<q>``), or the
+                                           ``Event`` list a
+                                           ``StreamCallback``'s receiver
+                                           builds from a columnar chunk
+                                           (named by the ``<stream>``); the
+                                           rest is the subscriber's function
 ===============================  ========  ================================
 
 (On the synchronous path the driver's spans open on the client thread, and
 there is no ring, no lock wait and no ``deliver`` span.) The same boundaries
 feed the ``phase.*`` trackers (``phases.py``), so an untraced run sees them
-too. ``@app:profile(dir='/tmp/jaxtrace')`` captures a full profiler trace
+too; beside the wall clock the trackers read the thread's own CPU clock at
+the same places (``phases.THREAD_CLOCKS``), which a span cannot carry.
+``@app:profile(dir='/tmp/jaxtrace')`` captures a full profiler trace
 between ``start()`` and ``shutdown()``. Everything degrades to a no-op when
 ``jax.profiler`` is unavailable — profiling must never take an app down.
 """

@@ -669,10 +669,11 @@ class PartitionedNFARuntime(StepRuntime):
         ``[P, lane_batch]`` here, on the driver's thread, by one stable
         argsort by lane (a key's events keep their order), then ``vstep``
         on donated state. Returns the un-fenced outputs."""
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         with span(f"siddhi:dispatch.route:{self.query_name}"):
             feed = self._lay_out(batch)
         batch["_route_s"] = time.perf_counter() - t0
+        batch["_route_cpu_s"] = time.thread_time() - c0
         self.state, ys = self.vstep(self.state, *feed)
         return ys
 

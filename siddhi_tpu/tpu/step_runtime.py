@@ -22,6 +22,7 @@ resolves both through ``self``.
 
 from __future__ import annotations
 
+import threading
 import time
 from typing import Optional
 
@@ -42,12 +43,17 @@ class StepRuntime:
     fence_s = None              # the last collect's wait for the device,
     # left by _fence for whoever called collect (driver thread, or the
     # client on the sync path); None after a collect that never fenced
+    fence_cpu_s = None          # that thread's CPU seconds over the same wait
     decode_full_s = None        # left by a decode that read an NFA's
     # ``full`` table (nfa.decode_rows); step_phases takes it
     hop_drain_s = None          # left by a hopping decode: its drain (the
     # state read and any empty steps), and, where the step fired a
     # boundary, the decode of its rows; step_phases takes both
     hop_flush_s = None
+    publish_build_s = None      # left by the bridge's egress while deliver
+    # ran: what the engine built for a subscriber that takes events
+    # (core/egress.py); step_phases takes it
+    _sealed = None              # (thread, wall, CPU) at the last seal
     _pending_cause = None       # cause of the flush whose emit comes next
     driver = None               # AsyncDeviceDriver when the bridge pipelines
     callback = None             # deliver()'s fn(chunk, emit_ts)
@@ -104,11 +110,21 @@ class StepRuntime:
         """Seal and emit the staged batch (every flush's first half): the
         probe's trace group closes exactly at the emit, and the flush cause
         rides the batch (phase attribution keys the deadline-queueing share
-        off it)."""
+        off it). Where the thread that seals this batch sealed the one
+        before it, the batch also carries that thread's wall and CPU seconds
+        since then (``client_cycle`` / ``client_cpu``): everything the
+        client's thread did for one batch; a seal by another thread (a
+        deadline flush, a ``flush_sync``) breaks the chain for two batches."""
+        here = (threading.get_ident(), time.perf_counter(),
+                time.thread_time())
+        last, self._sealed = self._sealed, here
         self._seal()
         with span(f"siddhi:seal.pack:{self.query_name}"):
             batch = self.builder.emit()
         batch["_cause"] = self._take_cause()
+        if last is not None and last[0] == here[0]:
+            batch["_client_cycle_s"] = here[1] - last[1]
+            batch["_client_cpu_s"] = here[2] - last[2]
         return batch
 
     # -- dispatch → fence → decode ---------------------------------------------
@@ -132,10 +148,11 @@ class StepRuntime:
         fence and costs a paced query a millisecond of detection latency:
         the first copy then no longer queues behind the step on the device
         but waits for the host to wake and ask (PERF.md, PR 25)."""
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         with span(f"siddhi:collect.fence:{self.query_name}"):
             np.asarray(first)
         self.fence_s = time.perf_counter() - t0
+        self.fence_cpu_s = time.thread_time() - c0
 
     def collect(self, out):
         """Egress fence + decode for one dispatched step: one ``ColumnsOut``
@@ -203,27 +220,43 @@ class StepRuntime:
             obs(n_events, latency_s, device_path, phases=phases)
 
     def step_phases(self, batch: dict, queue_s: float, step_s: float,
-                    fence_s: float, decode_s: float, **driver_s) -> dict:
+                    step_cpu_s: float, collect_s: float,
+                    collect_cpu_s: float, **driver_s) -> dict:
         """One device batch's waterfall as ``PhaseBreakdown.record_batch``
-        names it: what the batch carries (fill span, pack, route, cause),
-        what whoever stepped it measured, what its decode left on the
-        runtime (``decode_full_s``, ``hop_drain_s``, ``hop_flush_s``, taken
-        here) and in ``driver_s`` what only the async driver has
-        (``ring_s``, ``lock_s``, ``publish_s``)."""
+        names it: what the batch carries (fill span, pack, route, cause,
+        the client's cycle), what whoever stepped it measured on the wall
+        clock and, beside it, on its thread's CPU clock (``collect`` is cut
+        here into the fence its ``_fence`` left on the runtime and the
+        decode; a collect that never fenced is all wait), what its decode
+        and its delivery left on the runtime (``decode_full_s``,
+        ``hop_drain_s``, ``hop_flush_s``, ``publish_build_s``, taken here)
+        and in ``driver_s`` what only the async driver has (``ring_s``,
+        ``lock_s``, ``publish_s``, ``publish_cpu_s``, ``driver_cpu_s``)."""
         full_s, self.decode_full_s = self.decode_full_s, None
         drain_s, self.hop_drain_s = self.hop_drain_s, None
         flush_s, self.hop_flush_s = self.hop_flush_s, None
+        build_s, self.publish_build_s = self.publish_build_s, None
+        fence_s, fence_cpu_s = self.fence_s, self.fence_cpu_s
+        if fence_s is None:
+            fence_s, fence_cpu_s = collect_s, collect_cpu_s
         return {
             "fill_span_s": batch.get("pack_s", 0.0),
             "pack_s": batch.get("pack_exec_s", 0.0),
             "queue_s": queue_s,
             "step_s": step_s,
+            "step_cpu_s": step_cpu_s,
             "route_s": batch.get("_route_s", 0.0),
+            "route_cpu_s": batch.get("_route_cpu_s"),
             "fence_s": fence_s,
-            "decode_s": decode_s,
+            "fence_cpu_s": fence_cpu_s,
+            "decode_s": collect_s - fence_s,
+            "decode_cpu_s": collect_cpu_s - fence_cpu_s,
             "decode_full_s": full_s or 0.0,
             "hop_drain_s": drain_s or 0.0,
             "hop_flush_s": flush_s or 0.0,
+            "publish_build_s": build_s or 0.0,
+            "client_cycle_s": batch.get("_client_cycle_s"),
+            "client_cpu_s": batch.get("_client_cpu_s"),
             "cause": batch.get("_cause"),
             **driver_s,
         }
@@ -231,18 +264,18 @@ class StepRuntime:
     def _timed_process(self, batch: dict):
         """Sync-path step, timed for the controller/probe with the
         dispatch/fence/decode split measured separately (the ``device_step``
-        / ``egress_fence`` / ``egress_decode`` phases; on the sync path
-        there is no ring, so ``ingress_queue`` is the emit→dispatch gap
-        alone)."""
+        / ``egress_fence`` / ``egress_decode`` phases, each with the
+        client thread's CPU beside it; on the sync path there is no ring,
+        so ``ingress_queue`` is the emit→dispatch gap alone)."""
         if self.batch_controller is None and self.step_observer is None:
             return self.process(batch)
         q = self.query_name
         self.fence_s = None
-        t0 = time.perf_counter()
+        t0, c0 = time.perf_counter(), time.thread_time()
         try:
             with span(f"siddhi:dispatch:{q}"):
                 token = self.dispatch(batch)
-            t1 = time.perf_counter()
+            t1, c1 = time.perf_counter(), time.thread_time()
             with span(f"siddhi:collect:{q}"):
                 rows = self.collect(token)
         except BaseException:
@@ -252,12 +285,12 @@ class StepRuntime:
             self.observe_step(batch.get("count", 0),
                               time.perf_counter() - t0, device_path=False)
             raise
-        t2 = time.perf_counter()
-        fence_s = self.fence_s if self.fence_s is not None else t2 - t1
+        t2, c2 = time.perf_counter(), time.thread_time()
         t_emit = batch.get("_t_emit")
         phases = self.step_phases(
             batch,
             queue_s=max(0.0, t0 - t_emit) if t_emit is not None else 0.0,
-            step_s=t1 - t0, fence_s=fence_s, decode_s=t2 - t1 - fence_s)
+            step_s=t1 - t0, step_cpu_s=c1 - c0,
+            collect_s=t2 - t1, collect_cpu_s=c2 - c1)
         self.observe_step(batch.get("count", 0), t2 - t0, phases=phases)
         return rows

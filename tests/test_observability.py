@@ -368,7 +368,7 @@ def test_device_step_probe_counts_and_histogram():
     assert probe.steps >= 2
     assert probe.events == 40
     assert 0.0 <= probe.pad_ratio < 1.0
-    assert probe.compile_count == 1 and probe.compile_seconds > 0
+    assert probe.first_step_seconds > 0
     assert probe.flush_causes.get("capacity", 0) >= 1
     assert probe.flush_causes.get("drain", 0) >= 1
     sm = rt.ctx.statistics_manager
