@@ -292,9 +292,17 @@ def stage_s1(cfg, seed, platform, warnings, keep):
             ih.send_columns({k: v[s:s + chunk] for k, v in cols.items()},
                             ts[s:s + chunk])
 
+    def also(rt):
+        # the filter rejects a tenth of every batch: each step's compaction
+        # has rows to move, and says so
+        bridge = rt.device_bridges[0]
+        moves = bridge.runtime.step_gauges["compact_moves"]
+        return [] if moves == bridge.probe.steps else [
+            f"compact_moves reads {moves} of {bridge.probe.steps} steps"]
+
     ann = f"@device(strict='true', batch='{cfg['s1_batch']}')"
     return run_pair(S1_APP, ann, "Stats", feed, {"Bids": n}, platform, True,
-                    warnings)
+                    warnings, also=also)
 
 
 # ---------------------------------------------------------------------------
@@ -831,8 +839,11 @@ def stage_s6(cfg, seed, platform, warnings, keep):
                             ts[s:s + chunk])
 
     def also(rt):
-        live = rt.device_bridges[0].runtime.window_gauges["window_live_keys"]
-        return [] if live > 100 else [f"window_live_keys reads {live}"]
+        gauges = rt.device_bridges[0].runtime.step_gauges
+        live, moves = gauges["window_live_keys"], gauges["compact_moves"]
+        # no filter: the batch's `valid` is a prefix and nothing is moved
+        return ([] if live > 100 else [f"window_live_keys reads {live}"]) \
+            + ([] if moves == 0 else [f"compact_moves reads {moves}"])
 
     ann = "@device(strict='true', async='true', batch='2048', window='22528')"
     bad, facts = run_pair(S6_APP, ann, "HotItems", feed, {"Bid": n},

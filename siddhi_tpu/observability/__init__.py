@@ -339,12 +339,13 @@ class ObservabilitySubsystem:
                 sm.gauge_tracker(
                     f"device.{q}.lanes_kernel_scan",
                     lambda r=bridge.runtime: int(r.kernel == "scan"))
-            # a grouped hopping flush's window, as its drain points last
-            # read it (tpu/runtime.py on_drained)
-            for g in getattr(bridge.runtime, "window_gauges", {}):
+            # a single-stream step's own gauges (the batches its compaction
+            # moved, a grouped hopping flush's window), as its drain points
+            # last read them (tpu/runtime.py on_drained)
+            for g in getattr(bridge.runtime, "step_gauges", {}):
                 sm.gauge_tracker(
                     f"device.{q}.{g}",
-                    lambda r=bridge.runtime, g=g: r.window_gauges[g])
+                    lambda r=bridge.runtime, g=g: r.step_gauges[g])
             # egress by shape (core/egress.py): rows over deliveries is the
             # rows a delivery carries — a batch's, not one
             for shape, count in bridge.egress.items():
@@ -425,8 +426,8 @@ class ObservabilitySubsystem:
                 if bridge.kind == "partition":
                     rep["lanes"] = dict(bridge.runtime.lane_gauges)
                     rep["kernel"] = bridge.runtime.kernel
-                if getattr(bridge.runtime, "window_gauges", None):
-                    rep["window"] = dict(bridge.runtime.window_gauges)
+                if getattr(bridge.runtime, "step_gauges", None):
+                    rep["step"] = dict(bridge.runtime.step_gauges)
         for q, phases in phase_queries.items():
             if q in out["queries"]:
                 continue

@@ -79,9 +79,9 @@ from typing import TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .dtypes import JNP as _JNP
+from .rowpack import leaves_from_rows, pack_first, to_words
 
 if TYPE_CHECKING:
     from .nfa import DeviceNFACompiler
@@ -130,62 +130,6 @@ def block_init_state(nfa: "DeviceNFACompiler") -> dict:
     }
 
 
-def _to_words(v):
-    """A [P] leaf as [P, k] int32 words, bit for bit (k = 2 for the 64-bit
-    types, which the TPU holds as word pairs anyway)."""
-    if v.dtype == jnp.bool_:
-        return v.astype(jnp.int32)[:, None]
-    w = jax.lax.bitcast_convert_type(v, jnp.int32)
-    return w if w.ndim == 2 else w[:, None]
-
-
-def _from_words(w, dtype):
-    """[n, k] int32 words back to the [n] leaf they were cut from."""
-    if dtype == jnp.bool_:
-        return w[:, 0] != 0
-    return jax.lax.bitcast_convert_type(w if w.shape[1] > 1 else w[:, 0],
-                                        dtype)
-
-
-def _leaves_from_rows(rows, words, leaves):
-    """[n, W] rows of stacked words back to the leaves ``words`` were cut
-    from (``words[i]`` = ``_to_words(leaves[i])``)."""
-    parts = jnp.split(rows, np.cumsum([w.shape[1] for w in words])[:-1],
-                      axis=1)
-    return [_from_words(part, v.dtype) for part, v in zip(parts, leaves)]
-
-
-def pack_first(mask, n: int, vals, fills):
-    """Order-preserving pack of the rows ``mask`` [P] marks into ``n`` slots:
-    slot c takes the (c+1)-th marked row, slots past the last marked row
-    take ``fills``, marked rows past the n-th drop off and are counted.
-
-    Index once, gather n. ``src[c]``, the position of the (c+1)-th set bit
-    (``P`` where fewer are set), is how many prefix counts lie below c+1: one
-    fused [n, P] compare-and-count, a grid of the kernel's own [B, P] kind
-    that is never materialised. Then the leaves of ``vals`` (a pytree of [P]
-    arrays; ``fills`` the same tree of scalars) go as ONE gather of n rows
-    over their 32-bit words stacked [P, W]: on a v5e a gathered or scattered
-    element costs 7-12 ns whatever the shape, a gathered row hardly more
-    than one element (PERF.md section 6, PR 30). Exact for every dtype the
-    tables hold: words are moved, never computed with.
-    Returns ``(taken [n] bool, packed leaves, dropped i64)``."""
-    P = mask.shape[0]
-    count = jnp.cumsum(mask.astype(jnp.int32))
-    src = jnp.searchsorted(count, jnp.arange(1, n + 1, dtype=jnp.int32),
-                           side="left", method="compare_all")
-    taken = src < P
-    leaves, tree = jax.tree.flatten(vals)
-    words = [_to_words(v) for v in leaves]
-    rows = jnp.concatenate(words, axis=1)[jnp.minimum(src, P - 1)]   # [n, W]
-    packed = [
-        jnp.where(taken, got, jnp.asarray(fill, got.dtype))
-        for got, fill in zip(_leaves_from_rows(rows, words, leaves),
-                             tree.flatten_up_to(fills))]
-    dropped = jnp.maximum(count[-1].astype(jnp.int64) - n, 0)
-    return taken, jax.tree.unflatten(tree, packed), dropped
-
-
 def first_hit(grid, vals):
     """What a stage takes from its [B, P] grid, in ONE pass over it:
     ``adv`` [P] (some event advances the candidate), ``jstar`` [P] i32 (the
@@ -203,7 +147,7 @@ def first_hit(grid, vals):
     included, takes 2.6 ms with one word riding (PERF.md section 6, PR 32)."""
     B = grid.shape[0]
     leaves = list(vals.values())
-    words = [_to_words(v) for v in leaves]                 # [B, 1 or 2] each
+    words = [to_words(v) for v in leaves]                  # [B, 1 or 2] each
     cols = [c for w in words for c in w.T]                 # W of [B]
     jidx = jnp.arange(B, dtype=jnp.int32)
 
@@ -222,7 +166,7 @@ def first_hit(grid, vals):
     rows = [jnp.where(adv, r, c[0]) for r, c in zip(rows, cols)]
     if not rows:
         return adv, jstar, {}
-    return adv, jstar, dict(zip(vals, _leaves_from_rows(
+    return adv, jstar, dict(zip(vals, leaves_from_rows(
         jnp.stack(rows, axis=1), words, leaves)))
 
 

@@ -28,8 +28,13 @@ The TPU-native replacement for the hot path the reference interprets per event
   by`` / ``offset`` / ``limit``) runs on that flush chunk, on the device.
 - ``having`` compiles over the materialized output columns and masks
   emission (reference ``QuerySelector`` having executor).
-- Masked events (filter rejections, padding) are *compacted* with a stable
-  scatter so window semantics see only accepted events.
+- Masked events (filter rejections, padding) are *compacted* so window
+  semantics see only accepted events, front-packed in their order: every
+  column a kind reads goes through ONE ``rowpack.compact_front`` at the head
+  of the step, which looks at the mask it is handed: a prefix (nothing was
+  filtered: the bridge's ``valid``) is masked where it stands, anything else
+  moves as one gather of rows of 32-bit words; no scatter either way. The
+  state scalar ``compact_moves`` counts the steps that moved.
 
 What keeps the host path (``DeviceCompileError``, never a silent
 difference): ``order by`` / ``limit`` / ``offset`` anywhere but on a grouped
@@ -66,6 +71,7 @@ from ..query_api.definition import DataType, StreamDefinition
 from .batch import BatchSchema
 from .dtypes import FACC, JNP as _JNP_DTYPES, NP as _NP_DTYPES
 from .expr_compile import ColumnResolver, DeviceCompileError, compile_expression
+from .rowpack import compact_front
 
 # event-time sentinels bounding every real timestamp (keep searchsorted input
 # sorted: empty tail slots sit at the front, batch padding at the back)
@@ -608,7 +614,9 @@ class CompiledStreamQuery:
         N = max(self.window_n, 1)
         AF, AI = len(self.fagg_idx), len(self.iagg_idx)
         AS = len(self.sagg_idx)
-        state: dict[str, Any] = {}
+        # steps whose batch the compaction had to move (every kind's step
+        # runs it; `rowpack.compact_front`)
+        state: dict[str, Any] = {"compact_moves": jnp.zeros((), jnp.int64)}
         if self.grouped_flush:
             # the window kept as events: timestamp, key(s) and aggregate
             # arguments of the newest N, reduced by key at a boundary
@@ -776,36 +784,77 @@ class CompiledStreamQuery:
                 mask = valid
                 for fn in filter_fns:
                     mask = jnp.logical_and(mask, fn(cols))
-                k = jnp.sum(mask.astype(jnp.int32))
 
-                # stable compaction: accepted event i → slot rank_i; rejected
-                # rows all target slot B-1 with value 0 — that slot only
-                # holds a real event when k == B, in which case nothing was
-                # rejected
-                rank = jnp.cumsum(mask.astype(jnp.int32)) - 1
-                pos = jnp.where(mask, rank, B - 1)
-
-            def compact(x, fill=None):
-                f = jnp.zeros((), x.dtype) if fill is None else fill
-                out = jnp.full((B,), f, dtype=x.dtype)
-                return out.at[pos].set(jnp.where(mask, x, f), mode="drop")
-
-            with jax.named_scope("compact"):
-                cts = compact(ts)
-                proj_c = {i: compact(specs[i].fn(cols)) for i in value_idx}
+            # what the kernels read compacted (window semantics see accepted
+            # events only, front-packed in their order): every column of
+            # every kind is collected here, so that ONE `compact_front`
+            # moves them all, and only where the mask has something to move
+            if grouped_flush:
+                gk = [cols[g].astype(_JNP_DTYPES[t])
+                      for g, t in zip(group_keys, group_key_types)]
+            else:
+                # the dense table's packed key, a heavy-hitter window's
+                gk = [cols[g].astype(jnp.int64)
+                      for g in group_keys or hh_keys]
+            raw = {
+                "ts": ts,
+                "proj": {i: specs[i].fn(cols) for i in value_idx},
                 # fleet per-tenant parameter columns (injected by the caller,
                 # not part of the schema): compacted so having programs over
                 # hoisted constants stay row-aligned with the output columns
-                pcols = {kk: compact(cols[kk]) for kk in cols
-                         if kk.startswith("__fleet_p")}
+                "p": {kk: cols[kk] for kk in cols
+                      if kk.startswith("__fleet_p")},
+                "gk": gk,
+                "f": [specs[i].fn(cols).astype(FACC) for i in fagg_idx],
+                "i": [specs[i].fn(cols).astype(_IACC) for i in iagg_idx],
+                "s": [specs[i].fn(cols).astype(FACC) for i in sagg_idx],
+                "m": {i: specs[i].fn(cols).astype(mdt[i]) for i in magg_idx},
+            }
+            if window_kind in ("time", "timeLength", "timeBatch", "session",
+                               "hopping"):
+                # the window's clock (externalTime / externalTimeBatch read
+                # it from a column)
+                raw["wts"] = cols[time_key].astype(jnp.int64) if time_key \
+                    else ts
+            if window_kind == "sort":
+                kv = cols[sort_key].astype(sort_kdt)
+                if sort_desc:
+                    # stored negated: ascending order IS the sort order and
+                    # the evicted slot (N-1) is the per-order worst; int
+                    # min would wrap under negation (it has no positive
+                    # counterpart), so clamp it one up first
+                    if not jnp.issubdtype(sort_kdt, jnp.floating):
+                        lowest = jnp.iinfo(sort_kdt).min
+                        kv = jnp.where(kv == lowest, lowest + 1, kv)
+                    kv = -kv
+                raw["skey"] = kv
+            # behind the accepted events: zero, but the reduction's identity
+            # for min / max, and what sorts behind every real time or key
+            fills = {**jax.tree.map(lambda _: 0, raw), "m": m_ident}
+            if "wts" in raw:
+                fills["wts"] = _TS_POS
+            if "skey" in raw:
+                fills["skey"] = _ident(sort_kdt, True)
+
+            with jax.named_scope("compact"):
+                front, k, moved = compact_front(mask, raw, fills)
+                cts, proj_c, pcols = front["ts"], front["proj"], front["p"]
+                av_m = front["m"]
+
+                def stack(rows, dt):
+                    return jnp.stack(rows) if rows else jnp.zeros((0, B), dt)
+
+                av_f, av_i = stack(front["f"], FACC), stack(front["i"], _IACC)
+                av_s = stack(front["s"], FACC)                # raw values
+                out_valid = jnp.arange(B) < k
+                ones_c = out_valid.astype(jnp.int32)
 
             def make_keys():
                 """Bucket id [B] + exact packed key [B] for the group-by
                 columns (compacted). Single narrow keys (dictionary codes /
                 small ints) mod K directly — collision-free while #groups<=K;
                 wider combinations avalanche-mix."""
-                k64 = [compact(cols[gk].astype(jnp.int64))
-                       for gk in group_keys]
+                k64 = front["gk"]
                 narrow = all(t in (DataType.STRING, DataType.INT)
                              for t in group_key_types)
                 if len(group_keys) == 1:
@@ -824,22 +873,6 @@ class CompiledStreamQuery:
                         packed = packed * jnp.int64(0x100000001B3) ^ kx
                     keys = (_avalanche(packed) % K).astype(jnp.int32)
                 return keys, packed
-
-            def agg_stack(idx, dt):
-                rows = []
-                for i in idx:
-                    v = specs[i].fn(cols).astype(dt)
-                    rows.append(compact(jnp.where(mask, v, jnp.zeros((), dt))))
-                return jnp.stack(rows) if rows else jnp.zeros((0, B), dt)
-
-            with jax.named_scope("compact"):
-                av_f = agg_stack(fagg_idx, FACC)
-                av_i = agg_stack(iagg_idx, _IACC)
-                av_s = agg_stack(sagg_idx, FACC)          # raw values
-                av_m = {i: compact(specs[i].fn(cols).astype(mdt[i]),
-                                   fill=m_ident[i]) for i in magg_idx}
-                ones_c = compact(mask.astype(jnp.int32))
-                out_valid = jnp.arange(B) < k
 
             def finish(state, sums_f, sums_i, cnts, mins, svars,
                        ovalid=out_valid, ots=cts, proj=proj_c, count=None):
@@ -861,10 +894,7 @@ class CompiledStreamQuery:
                 whether or not it passes the filter into the window."""
                 return jnp.max(jnp.where(valid, ts, _TS_NEG))
 
-            # one scope per kernel: the window, else the dense group-by table,
-            # else the running aggregates (scopes are metadata on the
-            # compiled operations; a trace names device time by them)
-            with jax.named_scope(kernel_scope):
+            def kernel():
                 if window_kind in ("length", "time", "timeLength"):
                     if window_kind == "length":
                         z_f, z_i, z_s, zo, zm = _length_concat(
@@ -875,13 +905,10 @@ class CompiledStreamQuery:
                         new_state = _slide_tails(state, z_f, z_i, z_s, zo, zm,
                                                  k, N)
                     else:
-                        wts = compact(cols[time_key].astype(jnp.int64),
-                                      fill=jnp.asarray(_TS_POS, jnp.int64)) \
-                            if time_key else compact(
-                                ts, fill=jnp.asarray(_TS_POS, jnp.int64))
                         (z_f, z_i, z_s, zo, zm, j, lo, new_state) = \
                             _time_window_bounds(state, av_f, av_i, av_s, av_m,
-                                                magg_idx, ones_c, wts, k, N, B,
+                                                magg_idx, ones_c,
+                                                front["wts"], k, N, B,
                                                 window_ms)
                         if window_kind == "timeLength":
                             # the live range is ALSO bounded by the newest
@@ -949,16 +976,10 @@ class CompiledStreamQuery:
                                          agg_collapse=has_agg)
 
                 if window_kind in ("timeBatch", "session"):
-                    # externalTimeBatch reads the segment clock from a column
-                    cts_pos = compact(
-                        cols[time_key].astype(jnp.int64),
-                        fill=jnp.asarray(_TS_POS, jnp.int64)) \
-                        if time_key else compact(
-                            ts, fill=jnp.asarray(_TS_POS, jnp.int64))
                     return _segmented_batch(state, value_idx, fagg_idx, iagg_idx,
                                             magg_idx, sagg_idx, m_ismin, proj_c,
                                             av_f, av_i, av_s, av_m, ones_c,
-                                            cts_pos, k, N, B, finish,
+                                            front["wts"], k, N, B, finish,
                                             window_kind, window_ms,
                                             agg_collapse=has_agg)
 
@@ -984,40 +1005,24 @@ class CompiledStreamQuery:
                                   count=jnp.sum(ovalid.astype(jnp.int32)))
 
                 if window_kind == "sort":
-                    kv = cols[sort_key].astype(sort_kdt)
-                    if sort_desc:
-                        # stored negated: ascending order IS the sort order and
-                        # the evicted slot (N-1) is the per-order worst; int
-                        # min would wrap under negation (it has no positive
-                        # counterpart), so clamp it one up first
-                        if not jnp.issubdtype(sort_kdt, jnp.floating):
-                            lowest = jnp.iinfo(sort_kdt).min
-                            kv = jnp.where(kv == lowest, lowest + 1, kv)
-                        kv = -kv
-                    skey_c = compact(kv, fill=_ident(sort_kdt, True))
                     new_state, sums_f, sums_i, cnts, mins, svars = _sort_window(
-                        state, skey_c, av_f, av_i, av_s, av_m, magg_idx,
-                        m_ismin, k, N, B)
+                        state, front["skey"], av_f, av_i, av_s, av_m,
+                        magg_idx, m_ismin, k, N, B)
                     return finish(new_state, sums_f, sums_i, cnts, mins, svars)
 
                 if grouped_flush:
-                    wts = compact(ts, fill=jnp.asarray(_TS_POS, jnp.int64))
-                    gk_c = [compact(cols[gk].astype(_JNP_DTYPES[t]))
-                            for gk, t in zip(group_keys, group_key_types)]
                     return _hopping_grouped(
-                        self, state, gk_c, av_f, av_i, av_m, m_ismin,
-                        m_ident, proj_c, wts, k, hop_clock())
+                        self, state, front["gk"], av_f, av_i, av_m, m_ismin,
+                        m_ident, proj_c, front["wts"], k, hop_clock())
 
                 if window_kind == "hopping":
-                    wts = compact(ts, fill=jnp.asarray(_TS_POS, jnp.int64))
                     return _hopping_flushes(
                         state, value_idx, av_f, av_i, av_s, av_m, magg_idx,
-                        m_ismin, ones_c, proj_c, wts, k, N, B,
+                        m_ismin, ones_c, proj_c, front["wts"], k, N, B,
                         window_ms, hop_ms, finish, hop_clock())
 
                 if window_kind in ("frequent", "lossyFrequent"):
-                    k64 = [compact(cols[kk].astype(jnp.int64))
-                           for kk in hh_keys]
+                    k64 = front["gk"]
                     if len(k64) == 2:
                         kcode = (k64[0] << 32) | (k64[1] & 0xFFFFFFFF)
                     else:
@@ -1258,6 +1263,18 @@ class CompiledStreamQuery:
                         jnp.maximum(m2_new, 0.0))
                     new_state["run_scnt"] = new_state["run_scnt"].at[si].set(n_new)
                 return finish(new_state, sums_f, sums_i, cnts, mins, svars)
+
+            # one scope per kernel: the window, else the dense group-by table,
+            # else the running aggregates (scopes are metadata on the
+            # compiled operations; a trace names device time by them)
+            with jax.named_scope(kernel_scope):
+                new_state, out = kernel()
+            # steps whose mask was not a prefix, so that rows were moved: a
+            # state scalar like the overflow counters, read at drain points
+            # (a snapshot from before PR 38 restores without it)
+            moves = state.get("compact_moves", jnp.zeros((), jnp.int64))
+            return {**new_state,
+                    "compact_moves": moves + moved.astype(jnp.int64)}, out
 
         return step
 

@@ -73,14 +73,17 @@ class DeviceStreamRuntime(StepRuntime):
         # ... and read live state between steps, so the driver keeps exactly
         # one step in flight (window=1): the state read is that step's own
         self.pipeline_safe = compiled.window_kind != "hopping"
+        # the step's own gauges, state scalars read at drain points
+        # (on_drained): the steps whose batch the compaction had to move
+        # (over the probe's `steps`: the share of batches a filter cut into)
+        self.step_gauges: dict = {"compact_moves": 0}
         # a grouped hopping flush hands out its rows compacted a boundary,
         # and the row counts are what the decode reads first; its window's
-        # gauges are read at drain points (on_drained)
-        self.window_gauges: dict = {}
+        # gauges join the step's
         if compiled.grouped_flush:
             self.fence_key = "nrows"
-            self.window_gauges = {"window_live_keys": 0,
-                                  "window_fill_share": 0.0}
+            self.step_gauges.update(window_live_keys=0,
+                                    window_fill_share=0.0)
         self.state = compiled.init_state()
         # segment clock high-water: arrival ts, or the externalTimeBatch
         # attribute column
@@ -185,8 +188,14 @@ class DeviceStreamRuntime(StepRuntime):
 
     def on_drained(self) -> None:
         """Surface bounded-state overflow instead of silently diverging from
-        the host semantics. The counters are device scalars: read at drain
-        points, so they never stall the pipeline."""
+        the host semantics, and refresh the step's gauges. The counters are
+        device scalars: read at drain points, in one copy, so they never
+        stall the pipeline."""
+        read = jax.device_get({
+            key: self.state[key] for key in (
+                "window_drops", "ts_regressions", "group_collisions",
+                "compact_moves", "window_live_keys", "window_held")
+            if key in self.state})
         for key, what in (("window_drops", "alive events evicted "
                            "(raise @device(window='N'))"),
                           ("ts_regressions", "out-of-order "
@@ -194,19 +203,16 @@ class DeviceStreamRuntime(StepRuntime):
                           ("group_collisions", "group-by keys "
                            "collided in the dense table (raise "
                            "@device key capacity)")):
-            c = self.state.get(key)
-            if c is None:
-                continue
-            c = int(c)
+            c = int(read.get(key, 0))
             if c > self._warned.get(key, 0):
                 log.warning("query '%s': %d %s", self.query_name, c, what)
                 self._warned[key] = c
-        if self.window_gauges:
-            keys, held = jax.device_get((self.state["window_live_keys"],
-                                         self.state["window_held"]))
-            self.window_gauges["window_live_keys"] = int(keys)
-            self.window_gauges["window_fill_share"] = \
-                int(held) / max(self.compiled.window_n, 1)
+        self.step_gauges["compact_moves"] = int(read.get("compact_moves", 0))
+        if "window_held" in read:
+            self.step_gauges["window_live_keys"] = \
+                int(read["window_live_keys"])
+            self.step_gauges["window_fill_share"] = \
+                int(read["window_held"]) / max(self.compiled.window_n, 1)
 
     @property
     def group_collision_count(self) -> int:

@@ -303,11 +303,11 @@ def test_the_deployment_is_served_strict_with_one_bridge_and_no_host_tier(
         assert len(got) == 14 and got == alone
         # the window's gauges, read at drain points, and the two nested
         # trackers, in /latency and in the statistics manager
-        assert bridge.runtime.window_gauges["window_fill_share"] == 1.0
-        assert 0 < bridge.runtime.window_gauges["window_live_keys"] <= 20
+        assert bridge.runtime.step_gauges["window_fill_share"] == 1.0
+        assert 0 < bridge.runtime.step_gauges["window_live_keys"] <= 20
         entry = rt.observability.latency_report()["queries"][
             bridge.query_name]
-        assert entry["window"] == bridge.runtime.window_gauges
+        assert entry["step"] == bridge.runtime.step_gauges
         assert {"hop_drain", "hop_flush", "egress_decode"} <= set(
             entry["phases"])
         trackers = bridge.probe.phases.trackers

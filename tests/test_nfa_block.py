@@ -284,7 +284,7 @@ def test_pack_first_equals_numpy_reference(density, dtype, lanes):
     import jax
     import numpy as np
 
-    from siddhi_tpu.tpu.nfa_block import pack_first
+    from siddhi_tpu.tpu.rowpack import pack_first
 
     rng = np.random.default_rng(
         [lanes, list(PACK_DENSITIES).index(density), PACK_DTYPES.index(dtype)])

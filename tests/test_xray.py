@@ -961,13 +961,14 @@ def _compiled_step_text(app_text):
 @pytest.mark.parametrize("app_text,scopes", [
     (SCOPED_PATTERN_APP, ("nfa.admit", "nfa.stage1", "nfa.stage2",
                           "nfa.emit", "nfa.compact")),
-    (SCOPED_STREAM_APP, ("filter", "compact", "window.length", "groupby",
-                         "select")),
+    (SCOPED_STREAM_APP, ("filter", "compact", "compact.keep", "compact.move",
+                         "window.length", "groupby", "select")),
     (SCOPED_SCAN_APP, ("nfa.scan", "nfa.expire", "nfa.state0", "nfa.state1",
                        "nfa.state2", "nfa.emit", "nfa.compact")),
-    (SCOPED_HOPPING_APP, ("filter", "compact", "window.hopping",
-                          "groupby.sort", "groupby.reduce", "select.order",
-                          "select.limit")),
+    # no filter: nothing is left in that scope
+    (SCOPED_HOPPING_APP, ("compact", "compact.keep", "compact.move",
+                          "window.hopping", "groupby.sort", "groupby.reduce",
+                          "select.order", "select.limit")),
 ], ids=["nfa_block", "stream_query", "nfa_scan", "hopping_grouped"])
 def test_jitted_stages_are_named_and_the_names_cost_no_operation(
         monkeypatch, app_text, scopes):
