@@ -27,7 +27,13 @@ row that comes out with the scalar interpreter (the same app text without
 - S6  served, grouped hopping window with the selector's tail: NEXmark
       Query 5 (``benchmark/configs/nexmark-q5.small.siddhi``'s query),
       100,000 bids of 4,096 auctions (ids above 2^33) through
-      ``send_columns``: one row a boundary, the top auction by count.
+      ``send_columns``: one row a boundary, the top auction by count;
+- S7  served, keyed sliding window: the Siddhi guide's value partition
+      (``partition with (deviceID of TempStream)`` round
+      ``window.length(10)`` + ``max(temp)``, the benchmark's ``having``),
+      100,000 readings of 4,096 devices (ids above 2^40) through
+      ``send_columns``; the table's gauges ``keyed_live_keys`` and
+      ``key_table_fill_share`` are printed.
 
 The served stages also read what a fallback would hide: the DeviceGuard's
 counters, where the state lives, the probe's step and event counts, the
@@ -69,7 +75,7 @@ FULL = {
     "s3_lane_batch": 2048, "s3_slots": 512, "s3_oracle": 200_000,
     "s3_min_rows": 1000,
     "s3b_batch": 32768, "s3b_kleene_events": 100_000,
-    "s6_events": 100_000,
+    "s6_events": 100_000, "s7_events": 100_000,
 }
 # --rehearsal: the same stages and shapes with the stream cut short and the
 # flagship's lane grid shrunk, so a CPU gets through in half a minute.
@@ -80,7 +86,7 @@ REHEARSAL = {
     "s3_events": 40_000, "s3_keys": 128, "s3_lanes": 8,
     "s3_lane_batch": 256, "s3_oracle": 12_000, "s3_min_rows": 1,
     "s3b_batch": 1024, "s3b_kleene_events": 6_000,
-    "s6_events": 30_000,
+    "s6_events": 30_000, "s7_events": 30_000,
 }
 N_STATES = 8
 # overflow counters of the device kernels (core/device_bridge.py warns on
@@ -854,8 +860,62 @@ def stage_s6(cfg, seed, platform, warnings, keep):
     return bad, facts
 
 
+# ---------------------------------------------------------------------------
+# S7 — served, keyed sliding window (the guide's value partition)
+# ---------------------------------------------------------------------------
+
+S7_APP = """
+define stream TempStream (deviceID long, roomNo int, temp double);
+partition with (deviceID of TempStream) begin
+{device}
+from TempStream#window.length(10)
+select roomNo, deviceID, max(temp) as maxTemp
+having maxTemp > 99.9
+insert into DeviceTempStream;
+end;
+"""
+
+
+def stage_s7(cfg, seed, platform, warnings, keep):
+    import numpy as np
+
+    n, chunk = cfg["s7_events"], 8192
+    rng = np.random.default_rng(seed + 7)
+    p = np.arange(1, 4097, dtype=np.float64) ** -0.6
+    cols = {"deviceID": (rng.choice(4096, size=n, p=p / p.sum())
+                         * 1_000_003 + 2 ** 40).astype(np.int64),
+            "roomNo": rng.integers(0, 1000, n).astype(np.int32),
+            "temp": np.round(rng.uniform(0.0, 100.0, n), 3)}
+    ts = 1_000_000 + np.arange(n, dtype=np.int64)
+
+    def feed(rt):
+        ih = rt.input_handler("TempStream")
+        for s in range(0, n, chunk):
+            ih.send_columns({k: v[s:s + chunk] for k, v in cols.items()},
+                            ts[s:s + chunk])
+
+    def also(rt):
+        gauges = rt.device_bridges[0].runtime.step_gauges
+        facts.update(gauges)
+        live = gauges["keyed_live_keys"]
+        want = len(np.unique(cols["deviceID"]))
+        return [] if live == want else \
+            [f"keyed_live_keys reads {live}, the stream holds {want} ids"]
+
+    facts: dict = {}
+    ann = "@device(strict='true', async='true', batch='2048', keys='8192')"
+    bad, got = run_pair(S7_APP, ann, "DeviceTempStream", feed,
+                        {"TempStream": n}, platform, False, warnings,
+                        also=also)
+    facts.update(got)
+    if facts.get("rows", 0) < n // 400:
+        bad.append(f"{facts.get('rows')} rows of {n} readings: the stage "
+                   f"checks too little")
+    return bad, facts
+
+
 STAGES = {"S1": stage_s1, "S2": stage_s2, "S3": stage_s3, "S3B": stage_s3b,
-          "S4": stage_s4, "S5": stage_s5, "S6": stage_s6}
+          "S4": stage_s4, "S5": stage_s5, "S6": stage_s6, "S7": stage_s7}
 
 
 def main(argv=None) -> int:

@@ -105,6 +105,17 @@ define stream F (sym string, v double);
 from F[v > 1.0] select sym, v insert into FO;
 """
 
+# a served keyed window (PR 39): the phase of its key lookup and the gauges
+# of its table render, so their names are linted on every run
+KEYED_APP = """
+@app(name='LintKeyed', statistics='true')
+define stream K (dev long, v double);
+partition with (dev of K) begin
+@device(batch='16', keys='8')
+from K#window.length(4) select dev, max(v) as m insert into KO;
+end;
+"""
+
 
 MESH_TENANT = """
 @app(name='lint-mesh-{i}')
@@ -132,6 +143,12 @@ def build_exposition() -> str:
     fh = srt.input_handler("F")
     for i in range(20):
         fh.send([f"s{i % 3}", float(i)], timestamp=1000 + i)
+    krt = m.create_siddhi_app_runtime(KEYED_APP, playback=True)
+    krt.start()
+    kh = krt.input_handler("K")
+    for i in range(40):
+        kh.send([(i % 3) << 40, float(i)], timestamp=1000 + i)
+    krt.flush_device()
     rt.drain_async()
     rt.flush_device()
     srt.flush_host()
@@ -393,7 +410,8 @@ def main() -> int:
         problems.append(
             "lint deployment rendered no worker=\"fabric\" merged series — "
             "the federated collector is unwired or produced nothing")
-    for phase in ("device_step", "egress_fence", "egress_decode"):
+    for phase in ("device_step", "egress_fence", "egress_decode",
+                  "key_lookup", "key_lookup_cpu"):
         if f'phase="{phase}"' not in text:
             problems.append(
                 f"lint deployment's device query rendered no "

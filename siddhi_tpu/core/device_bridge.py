@@ -585,6 +585,8 @@ def _device_options(ann, query: Query, stream_defs: dict) -> dict:
         "pipeline": int(ann.get("pipeline") or 2),
         # key lanes of a served `partition with` block
         "lanes": int(ann.get("lanes") or 64),
+        # keys a served keyed window holds a window for (its table's rows)
+        "keys": int(ann.get("keys") or 65536),
         "async": async_mode,
     }
 
@@ -774,18 +776,30 @@ def try_build_device_partition(partition_ast, app_context, stream_defs: dict,
                                get_junction,
                                name: str) -> Optional[DeviceQueryBridge]:
     """The device branch of a ``partition with`` block: ONE bridge (kind
-    ``'partition'``) over a served ``PartitionedNFARuntime`` when an inner
-    query opts in via ``@device`` and the block is one value partition
-    ``partition with (<attr> of <Stream>)`` holding one pattern the device
-    NFA compiler takes: keys hash to ``@device(lanes=)`` lane-stacked match
-    tables and every lane steps in one vmapped program, the blocked kernel
-    for a chain of stream states under ``every``, the per-event scan for
-    count (``<m:n>``, a Kleene closure), logical and absent states. What
-    still keeps the caller's tiers (fleet, host partition, per-key
+    ``'partition'``) when an inner query opts in via ``@device`` and the
+    block is one value partition ``partition with (<attr> of <Stream>)``
+    holding one query the chip serves:
+
+    - a pattern the device NFA compiler takes, over a served
+      ``PartitionedNFARuntime``: keys hash to ``@device(lanes=)``
+      lane-stacked match tables and every lane steps in one vmapped
+      program, the blocked kernel for a chain of stream states under
+      ``every``, the per-event scan for count (``<m:n>``, a Kleene
+      closure), logical and absent states;
+    - a single-stream query over a sliding ``window.length(N)`` with
+      aggregates (``sum``, ``count``, ``avg``, ``min``, ``max``, a filter,
+      ``having``, the current event's columns), over a
+      ``tpu/keyed_window.py`` ``KeyedWindowRuntime``: every key's window
+      one row of one table of ``@device(keys=)`` rows, the keys given
+      stable slots on the host.
+
+    What still keeps the caller's tiers (fleet, host partition, per-key
     interpreter; None here, a raise under ``strict='true'``): sequences
     (strictness is per key), first states that bind no alias, several
     queries in the block, multi-stream, range or expression partitions,
-    non-pattern queries, and whatever the NFA compiler itself refuses."""
+    output rate limiting, joins, a keyed query with another window or
+    none, with group-by, ``stdDev`` or no aggregate, a key of a FLOAT or
+    DOUBLE attribute, and whatever the NFA compiler itself refuses."""
     from ..query_api import Variable
     from ..tpu.expr_compile import DeviceCompileError
 
@@ -811,19 +825,35 @@ def try_build_device_partition(partition_ast, app_context, stream_defs: dict,
                 pt.value_expr.stream_index is not None:
             raise DeviceCompileError(
                 "range/expression partitions keep the host tiers")
-        if not isinstance(query.input_stream, StateInputStream):
+        ist = query.input_stream
+        if not isinstance(ist, (StateInputStream, SingleInputStream)):
             raise DeviceCompileError(
-                "non-pattern partition queries keep the host tiers")
+                "joins in a partition keep the host tiers")
+        if isinstance(ist, SingleInputStream) and \
+                ist.stream_id != pt.stream_id:
+            raise DeviceCompileError(
+                "a partition query over a stream the block does not key "
+                "keeps the host tiers")
         if query.output_rate is not None:
             raise DeviceCompileError(
                 "output rate limiting in a partition is per key (host "
                 "tiers)")
         target = _audit_device_surface(query, app_context, get_junction)
-        from ..tpu.partition import PartitionedNFARuntime
-        rt = PartitionedNFARuntime(
-            None, opts["lanes"], pt.value_expr.attribute,
-            slot_capacity=opts["slots"], batch=batch, query=query,
-            stream_defs=stream_defs)
+        if isinstance(ist, StateInputStream):
+            from ..tpu.partition import PartitionedNFARuntime
+            rt = PartitionedNFARuntime(
+                None, opts["lanes"], pt.value_expr.attribute,
+                slot_capacity=opts["slots"], batch=batch, query=query,
+                stream_defs=stream_defs)
+            stream_ids = rt.compiler.compiled.stream_ids
+            out_specs = rt.compiler.out_specs
+        else:
+            from ..tpu.keyed_window import KeyedWindowRuntime
+            rt = KeyedWindowRuntime(query, stream_defs,
+                                    pt.value_expr.attribute, batch,
+                                    opts["keys"])
+            stream_ids = [ist.stream_id]
+            out_specs = rt.out_specs
     except DeviceCompileError as e:
         if opts["strict"]:
             raise
@@ -831,11 +861,10 @@ def try_build_device_partition(partition_ast, app_context, stream_defs: dict,
         return None
     qname = query.name() or f"{name}-query-0"
     bridge = DeviceQueryBridge(
-        "partition", rt, app_context, rt.compiler.compiled.stream_ids,
-        target, qname, async_mode=opts["async"],
-        pipeline_window=opts["pipeline"])
-    bridge.output_schema = ([n for n, _, _ in rt.compiler.out_specs],
-                            [t for _, _, t in rt.compiler.out_specs])
+        "partition", rt, app_context, stream_ids, target, qname,
+        async_mode=opts["async"], pipeline_window=opts["pipeline"])
+    bridge.output_schema = ([n for n, _, _ in out_specs],
+                            [t for _, _, t in out_specs])
     return _finish_bridge(bridge, partition_ast, qname, app_context,
                           stream_defs, get_junction, batch)
 

@@ -137,7 +137,8 @@ class DeviceStepProbe:
         ``fence_s``, ``decode_s``, ``decode_full_s``, ``hop_drain_s``,
         ``hop_flush_s``, ``lock_s``, ``publish_s``, ``publish_build_s``,
         ``host_s``, ``cause``; the threads' CPU clocks ``step_cpu_s``,
-        ``route_cpu_s``, ``fence_cpu_s``, ``decode_cpu_s``,
+        ``route_cpu_s``, ``key_lookup_cpu_s``, ``fence_cpu_s``,
+        ``decode_cpu_s``,
         ``publish_cpu_s``, ``client_cycle_s``, ``client_cpu_s``,
         ``driver_cpu_s``) — recorded event-weighted into the per-phase
         histograms."""
@@ -327,7 +328,7 @@ class ObservabilitySubsystem:
                 sm.gauge_tracker(
                     f"device.{q}.flush_{cause}_total",
                     lambda p=probe, c=cause: p.flush_causes.get(c, 0))
-            if bridge.kind == "partition":
+            if hasattr(bridge.runtime, "lane_gauges"):
                 # a served partition's lanes, as its drain points last
                 # read them (tpu/partition.py on_drained; never per step)
                 for g in bridge.runtime.lane_gauges:
@@ -340,8 +341,10 @@ class ObservabilitySubsystem:
                     f"device.{q}.lanes_kernel_scan",
                     lambda r=bridge.runtime: int(r.kernel == "scan"))
             # a single-stream step's own gauges (the batches its compaction
-            # moved, a grouped hopping flush's window), as its drain points
-            # last read them (tpu/runtime.py on_drained)
+            # moved, a grouped hopping flush's window; a keyed window's
+            # keys held and the share of its table they fill), as its drain
+            # points last read them (tpu/runtime.py, tpu/keyed_window.py
+            # on_drained)
             for g in getattr(bridge.runtime, "step_gauges", {}):
                 sm.gauge_tracker(
                     f"device.{q}.{g}",
@@ -423,7 +426,7 @@ class ObservabilitySubsystem:
             rep = out["queries"].get(bridge.query_name)
             if rep is not None:
                 rep["egress"] = bridge.egress_report()
-                if bridge.kind == "partition":
+                if hasattr(bridge.runtime, "lane_gauges"):
                     rep["lanes"] = dict(bridge.runtime.lane_gauges)
                     rep["kernel"] = bridge.runtime.kernel
                 if getattr(bridge.runtime, "step_gauges", None):

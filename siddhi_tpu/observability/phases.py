@@ -50,7 +50,10 @@ trackers, and the ``/latency`` report):
 Two parts of a phase are told apart without joining the serial sum
 (``NESTED``): ``route`` — a served partition's lane layout of the flat
 batch, inside ``device_step`` (``tpu/partition.py`` ``dispatch``; span
-``siddhi:dispatch.route``); ``decode_full`` — an NFA's decode of its
+``siddhi:dispatch.route``); ``key_lookup`` — a served keyed window's way
+from a key to its slot, inside ``device_step``: the directory's search and
+the keys it admits (``tpu/keyed_window.py`` ``dispatch``; span
+``siddhi:dispatch.key_lookup``); ``decode_full`` — an NFA's decode of its
 ``full`` table (the blocked kernel's whole candidate table, the scan
 kernel's table of the plan's bound), inside ``egress_decode``, which runs
 only for a batch in which a lane emitted more rows than the packed row
@@ -88,6 +91,7 @@ tracker              thread read at
                             sync path, ``StepRuntime._timed_process``, on
                             the client's thread, as all five are there)
 ``route_cpu``        driver ``route``'s (``PartitionedNFARuntime.dispatch``)
+``key_lookup_cpu``   driver ``key_lookup``'s (``KeyedWindowRuntime.dispatch``)
 ``egress_fence_cpu`` driver ``egress_fence``'s (``StepRuntime._fence``)
 ``egress_decode_cpu`` driver ``collect`` less the fence, as ``egress_decode``
 ``sink_publish_cpu`` driver ``sink_publish``'s (``_collect_oldest``, lock
@@ -136,13 +140,15 @@ PHASES = ("ingress_parse", "ingress_queue", "ring_wait", "fill_wait", "pack",
 
 # a part of a phase told apart: recorded like a phase, outside the serial
 # sum (its parent already carries the time)
-NESTED = {"route": "device_step", "decode_full": "egress_decode",
+NESTED = {"route": "device_step", "key_lookup": "device_step",
+          "decode_full": "egress_decode",
           "hop_drain": "egress_decode", "hop_flush": "egress_decode",
           "publish_build": "sink_publish"}
 
 # a thread's own CPU clock beside the wall clock: phase -> the tracker of
 # its thread's CPU seconds over the same stretch
 CPU_OF = {"device_step": "device_step_cpu", "route": "route_cpu",
+          "key_lookup": "key_lookup_cpu",
           "egress_fence": "egress_fence_cpu",
           "egress_decode": "egress_decode_cpu",
           "sink_publish": "sink_publish_cpu"}
@@ -204,11 +210,13 @@ class PhaseBreakdown:
                      publish_s: float = 0.0, host_s: float = 0.0,
                      parse_s: float = 0.0, ring_s: float = 0.0,
                      decode_s: float = 0.0, lock_s: float = 0.0,
-                     route_s: float = 0.0, decode_full_s: float = 0.0,
+                     route_s: float = 0.0, key_lookup_s: float = 0.0,
+                     decode_full_s: float = 0.0,
                      hop_drain_s: float = 0.0, hop_flush_s: float = 0.0,
                      publish_build_s: float = 0.0,
                      step_cpu_s: Optional[float] = None,
                      route_cpu_s: Optional[float] = None,
+                     key_lookup_cpu_s: Optional[float] = None,
                      fence_cpu_s: Optional[float] = None,
                      decode_cpu_s: Optional[float] = None,
                      publish_cpu_s: Optional[float] = None,
@@ -236,6 +244,7 @@ class PhaseBreakdown:
                 ("sink_publish", publish_s, publish_cpu_s),
                 ("host_exec", host_s, None),
                 ("route", route_s, route_cpu_s),
+                ("key_lookup", key_lookup_s, key_lookup_cpu_s),
                 ("decode_full", decode_full_s, None),
                 ("hop_drain", hop_drain_s, None),
                 ("hop_flush", hop_flush_s, None))

@@ -10,6 +10,12 @@ inside one JVM (``PartitionStreamReceiver.java:82-117``). TPU-native redesign:
   host-side to their lane's sub-batch (the reference's key→instance dispatch);
   on device nothing crosses lanes, so no collectives are needed in steady state
   — ICI traffic appears only if lanes rebalance (not needed this round).
+
+This module serves a partition's pattern. A partition's keyed sliding
+``window.length(N)`` with aggregates is served by ``tpu/keyed_window.py``
+(no lanes: every key's window one row of one table, the key given a stable
+slot on the host); what keeps the host tiers is listed in
+``core/device_bridge.py`` ``try_build_device_partition``.
 """
 
 from __future__ import annotations

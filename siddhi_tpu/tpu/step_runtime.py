@@ -6,11 +6,11 @@ deliver, with a flush rule in front and a ``phases`` record behind.
 plans: a ``builder`` to stage into, ``dispatch(batch)`` (fire the jitted step,
 return its un-fenced outputs), ``_decode(out)`` (those outputs as one
 ``ColumnsOut``) and ``fence_key`` (the output the decode reads first). The
-four device runtimes subclass it beside their compilers
+five device runtimes subclass it beside their compilers
 (``DeviceStreamRuntime``, ``DeviceNFARuntime``, ``DeviceJoinRuntime``,
-``PartitionedNFARuntime``); the columnar host tier (``core/host_bridge.py``)
-inherits the flush rule and the cause bookkeeping and times its one-segment
-step itself.
+``PartitionedNFARuntime``, ``KeyedWindowRuntime``); the columnar host tier
+(``core/host_bridge.py``) inherits the flush rule and the cause bookkeeping
+and times its one-segment step itself.
 
 What plugs in from outside: ``batch_controller`` (``flow/adaptive_batch.py``,
 through ``@app:adaptive``), ``step_observer`` / ``step_sealer`` /
@@ -223,7 +223,8 @@ class StepRuntime:
                     step_cpu_s: float, collect_s: float,
                     collect_cpu_s: float, **driver_s) -> dict:
         """One device batch's waterfall as ``PhaseBreakdown.record_batch``
-        names it: what the batch carries (fill span, pack, route, cause,
+        names it: what the batch carries (fill span, pack, route or key
+        lookup, cause,
         the client's cycle), what whoever stepped it measured on the wall
         clock and, beside it, on its thread's CPU clock (``collect`` is cut
         here into the fence its ``_fence`` left on the runtime and the
@@ -247,6 +248,8 @@ class StepRuntime:
             "step_cpu_s": step_cpu_s,
             "route_s": batch.get("_route_s", 0.0),
             "route_cpu_s": batch.get("_route_cpu_s"),
+            "key_lookup_s": batch.get("_key_lookup_s", 0.0),
+            "key_lookup_cpu_s": batch.get("_key_lookup_cpu_s"),
             "fence_s": fence_s,
             "fence_cpu_s": fence_cpu_s,
             "decode_s": collect_s - fence_s,

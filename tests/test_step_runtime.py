@@ -1,4 +1,4 @@
-"""One step protocol (ISSUE 31): the four served device runtimes are
+"""One step protocol (ISSUE 31): the five served device runtimes are
 ``StepRuntime``s and take ``process`` / ``deliver`` / ``flush`` / ``collect``
 from it, so a batch's seal → dispatch → fence → decode → deliver is the same
 code whatever the plan. Pinned here on the CPU at small sizes: whose
@@ -51,6 +51,16 @@ select e1.price as p1, e2.price as p2, e3.price as p3 insert into O;
 end;
 """
 
+KEYED_APP = """
+define stream S (sym string, price double, vol long);
+partition with (sym of S) begin
+{device}
+from S[price > 20.0]#window.length(3)
+select sym, max(price) as hi, sum(vol) as total, price
+having hi > 60.0 insert into O;
+end;
+"""
+
 
 # values exact in float32: the device computes DOUBLE in float32
 def _s_events(n, seed):
@@ -81,7 +91,10 @@ KINDS = {
     # a lane orders its own keys' matches, the interpreter orders all of them
     "partition": (PARTITION_APP, _s_events(200, 7), 64,
                   ", slots='32', lanes='4'", False),
+    # a keyed window (kind 'partition' too): a batch's rows in slot order
+    "keyed": (KEYED_APP, _s_events(200, 8), 32, ", keys='8'", False),
 }
+BRIDGE_KIND = {"keyed": "partition"}
 
 # the names ISSUE 31's acceptance keeps out of the four class bodies
 SHARED = ("process", "deliver", "collect", "_fence", "_emit_batch",
@@ -139,7 +152,8 @@ def test_a_served_runtime_runs_the_one_protocol(manager, kind, mode):
         manager, app, f"@device(strict='true', batch='{batch}'{options}{a})")
     bridge, = rt.device_bridges
     r = bridge.runtime
-    assert bridge.kind == kind and isinstance(r, StepRuntime)
+    assert bridge.kind == BRIDGE_KIND.get(kind, kind)
+    assert isinstance(r, StepRuntime)
     assert (bridge.driver is not None) == (mode == "async")
     cls = type(r)
     for name in ("process", "deliver", "collect"):

@@ -34,6 +34,7 @@ select v, sum(v) as t insert into O;
 """
 BATCH = 64
 COMPANIONS = {"device_step_cpu": "device_step", "route_cpu": "route",
+              "key_lookup_cpu": "key_lookup",
               "egress_fence_cpu": "egress_fence",
               "egress_decode_cpu": "egress_decode",
               "sink_publish_cpu": "sink_publish"}
@@ -197,6 +198,7 @@ def test_each_companion_counts_what_its_wall_tracker_counts(manager, kind,
             + 1e-4 * batches * batch, cpu
     assert trackers["device_step_cpu"].count == len(events)
     assert (trackers["route_cpu"].count > 0) == (kind == "partition")
+    assert (trackers["key_lookup_cpu"].count > 0) == (kind == "keyed")
     # the publishing is timed where the driver does it; the sync path
     # delivers behind its phases record
     assert (trackers["sink_publish_cpu"].count > 0) == (mode == "async")
@@ -212,9 +214,14 @@ def test_each_companion_counts_what_its_wall_tracker_counts(manager, kind,
     assert rep["end_to_end"]["count"] == len(events)
 
 
+@pytest.mark.parametrize("kind, other", [("partition", "key_lookup"),
+                                         ("keyed", "route")])
 def test_the_latency_report_says_ran_and_waited_for_the_tables_phases(
-        manager):
-    app, events, batch, options, _ordered = KINDS["partition"]
+        manager, kind, other):
+    """A served partition runs every segment the table names but the
+    other runtime's way to its keys: a pattern's lane layout (``route``)
+    or a keyed window's directory (``key_lookup``)."""
+    app, events, batch, options, _ordered = KINDS[kind]
     rt, _got = _deploy(
         manager, app,
         f"@device(strict='true', batch='{batch}'{options}, async='true')")
@@ -224,9 +231,9 @@ def test_the_latency_report_says_ran_and_waited_for_the_tables_phases(
     rt.flush_device()
     rep = rt.observability.latency_report()["queries"][bridge.query_name]
     with_cpu = {p for p, v in rep["phases"].items() if "cpu_ms" in v}
-    assert with_cpu == set(CPU_OF)
+    assert with_cpu == set(CPU_OF) - {other}
     assert {p for p, v in rep["phases"].items() if "off_cpu_share" in v} \
-        == set(CPU_OF)
+        == set(CPU_OF) - {other}
     for p in with_cpu:
         entry = rep["phases"][p]
         assert entry["cpu_ms"] >= 0.0
@@ -323,7 +330,7 @@ def test_a_guard_replay_records_no_companion(manager):
     assert bridge.guard.failures == 1 and bridge.guard.fallback_events == BATCH
     trackers = bridge.probe.phases.trackers
     for cpu, wall in COMPANIONS.items():
-        if cpu != "route_cpu":
+        if cpu not in ("route_cpu", "key_lookup_cpu"):
             assert trackers[cpu].count == trackers[wall].count == 2 * BATCH, cpu
     assert trackers["driver_cpu"].count == 2 * BATCH
     rep = rt.observability.latency_report()["queries"]["agg"]
