@@ -66,7 +66,10 @@ class AsyncDeviceDriver:
     Tokens collect strictly FIFO, so a mid-pipeline device fault surfaces at
     its own egress slot — the DeviceGuard replays the failed batch's shadow
     there, after every earlier batch delivered, and can neither reorder nor
-    double-emit a micro-batch.
+    double-emit a micro-batch. A batch the runtime's dispatch marked
+    ``_serial`` (its collect reads live state back: a hopping window whose
+    step may have deferred a boundary) is the last one in flight until it
+    is collected, as with ``window=1``.
 
     A latency-mode adaptive controller (``@app:adaptive(latency.target.ms)``)
     adds a **deadline flush**: when the pipeline idles with a partial batch
@@ -81,9 +84,9 @@ class AsyncDeviceDriver:
         self.rt = rt
         self.app_context = app_context
         self.depth = max(1, depth)
-        # in-flight dispatch window: 2 = double buffering; runtimes whose
-        # collect() reads live state (hopping drain) pin it to 1
-        self.window = max(1, window) if rt.pipeline_safe else 1
+        # in-flight dispatch window: 2 = double buffering (a serial batch
+        # closes it until it is collected: _next_action)
+        self.window = max(1, window)
         self._q = collections.deque()            # packed, undispatched
         self._inflight = collections.deque()     # (batch, token, t_disp0,
         # disp_s, disp_cpu_s, err)
@@ -163,12 +166,15 @@ class AsyncDeviceDriver:
         with self._cv:
             while True:
                 if self._q and not self._paused \
-                        and len(self._inflight) < self.window:
+                        and len(self._inflight) < self.window \
+                        and not (self._inflight
+                                 and self._inflight[-1][0].get("_serial")):
                     self._busy = True
                     return "dispatch", self._q.popleft()
                 if self._inflight:
-                    # window full, paused, or queue empty: fence the oldest
-                    # token (strict FIFO egress)
+                    # window full or closed by a serial batch, paused, or
+                    # queue empty: fence the oldest token (strict FIFO
+                    # egress)
                     return "collect", None
                 # pipeline drained: idle-wait (the drained bookkeeping runs
                 # in _run, outside this lock)

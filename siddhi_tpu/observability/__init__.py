@@ -344,7 +344,8 @@ class ObservabilitySubsystem:
             # moved, a grouped hopping flush's window; a keyed window's
             # keys held and the share of its table they fill), as its drain
             # points last read them (tpu/runtime.py, tpu/keyed_window.py
-            # on_drained)
+            # on_drained); a hopping window's serial batches, counted on the
+            # host as they are dispatched
             for g in getattr(bridge.runtime, "step_gauges", {}):
                 sm.gauge_tracker(
                     f"device.{q}.{g}",

@@ -61,10 +61,14 @@ table holds (``tpu/nfa.py`` ``decode_rows``; span
 ``siddhi:collect.decode.full``): its count over ``egress_decode``'s says how
 often that was, and for the scan kernel how often the step took its whole
 pack; ``hop_drain`` — a
-hopping window's drain after every batch, inside ``egress_decode``: the read
-of ``hop_next`` / ``last_ts`` out of the live state and any empty steps for
-deferred boundaries (``tpu/runtime.py`` ``_decode``; span
-``siddhi:collect.decode.hop_drain``); ``hop_flush`` — the decode of a batch
+hopping window's drain, inside ``egress_decode``, recorded for every batch:
+the test of whether the batch was dispatched serial (its step may have left
+a boundary deferred, decided on the host from its timestamps) and, for a
+serial batch only, the read of ``hop_next`` / ``last_ts`` out of the live
+state and any empty steps for deferred boundaries (``tpu/runtime.py``
+``_decode``; span ``siddhi:collect.decode.hop_drain``, round a drain that
+runs; the serial batches are the gauge ``hop_serial_batches``);
+``hop_flush`` — the decode of a batch
 whose step fired a boundary with rows (span
 ``siddhi:collect.decode.hop_flush``): its count over ``egress_decode``'s is
 the share of batches that carried a boundary; ``publish_build`` — what the

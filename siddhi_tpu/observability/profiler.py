@@ -34,8 +34,10 @@ span                             thread    opens / closes
 ``siddhi:collect.decode``        driver    inside it, a hopping window only:
 ``.hop_flush:<q>``                         the decode of a batch whose step
                                            fired a boundary with rows
-``siddhi:collect.decode``        driver    inside it, a hopping window only:
-``.hop_drain:<q>``                         the read of ``hop_next`` out of
+``siddhi:collect.decode``        driver    inside it, a hopping window's
+``.hop_drain:<q>``                         serial batch only (one whose step
+                                           may have deferred a boundary):
+                                           the read of ``hop_next`` out of
                                            the live state and any empty
                                            steps for deferred boundaries
 ``siddhi:deliver:<q>``           driver    from asking for the engine lock

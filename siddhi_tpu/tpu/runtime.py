@@ -29,17 +29,17 @@ def drain_hop_boundaries(compiled, state, drain_builder, on_out):
     """Hopping defers boundary flushes past the per-step flush capacity (a
     long time gap can span more hops than one step covers): step EMPTY
     batches until the next boundary is in the future, handing each step's
-    outputs to ``on_out``. Returns the advanced state."""
+    outputs to ``on_out``. Returns the advanced state and its ``last_ts``
+    (an empty step leaves it where it was)."""
     from .query_compile import _TS_NEG
     while True:
         hop_next, last_ts = (
             int(v) for v in jax.device_get(
                 (state["hop_next"], state["last_ts"])))
         if hop_next <= _TS_NEG or hop_next > last_ts:
-            break
+            return state, last_ts
         state, out = compiled.step(state, drain_builder.emit())
         on_out(out)
-    return state
 
 
 class DeviceStreamRuntime(StepRuntime):
@@ -66,17 +66,31 @@ class DeviceStreamRuntime(StepRuntime):
         self.compiled = compiled
         self.definition = compiled.definition
         self.builder = BatchBuilder(compiled.schema, compiled.B)
-        # hopping's drain steps run inside _decode, on the driver's thread
-        # in async mode: they take their empty batches from a builder of
-        # their own, the live one may hold the NEXT batch's rows by then
-        self._drain_builder = BatchBuilder(compiled.schema, compiled.B)
-        # ... and read live state between steps, so the driver keeps exactly
-        # one step in flight (window=1): the state read is that step's own
-        self.pipeline_safe = compiled.window_kind != "hopping"
         # the step's own gauges, state scalars read at drain points
         # (on_drained): the steps whose batch the compaction had to move
         # (over the probe's `steps`: the share of batches a filter cut into)
         self.step_gauges: dict = {"compact_moves": 0}
+        self._hopping = compiled.window_kind == "hopping"
+        if self._hopping:
+            # hopping's drain steps run inside _decode, on the driver's
+            # thread in async mode: they take their empty batches from a
+            # builder of their own, the live one may hold the NEXT batch's
+            # rows by then ...
+            self._drain_builder = BatchBuilder(compiled.schema, compiled.B)
+            # ... and read live state back, which is that batch's own only
+            # while nothing is in flight behind it: a batch whose step may
+            # leave a boundary deferred is dispatched serial
+            # (_hop_pipelined), the others pipeline. The boundaries a step
+            # resolves: the grouped flush's `flush_cap`, the ungrouped B
+            self._hop_cap = compiled.flush_cap if compiled.grouped_flush \
+                else compiled.B
+            # the newest timestamp stepped, filtered events included; None
+            # until a drain has read the device's own back (deploy, restore,
+            # a serial batch not yet drained)
+            self._hop_newest = None
+            # the batches dispatched serial since deploy: how often the
+            # fallback engages (host-side, exact at every read)
+            self.step_gauges["hop_serial_batches"] = 0
         # a grouped hopping flush hands out its rows compacted a boundary,
         # and the row counts are what the decode reads first; its window's
         # gauges join the step's
@@ -134,16 +148,49 @@ class DeviceStreamRuntime(StepRuntime):
                 self.flush()
 
     def dispatch(self, batch: dict):
+        if not self._hopping:
+            self.state, out = self.compiled.step(self.state, batch)
+            return out
+        newest = self._hop_pipelined(batch)
         self.state, out = self.compiled.step(self.state, batch)
+        self._hop_newest = newest
+        if newest is None:
+            # the driver dispatches nothing behind this batch until it is
+            # collected (``_serial``), and its decode drains (``hop_serial``)
+            batch["_serial"] = out["hop_serial"] = True
+            self.step_gauges["hop_serial_batches"] += 1
         return out
+
+    def _hop_pipelined(self, batch: dict):
+        """The newest timestamp after this batch, where its step cannot
+        leave a boundary deferred; None where it may, and the batch is
+        serial: its decode drains the deferred ones from the live state,
+        which is its own only while nothing is in flight behind it.
+        Decided on the host from the batch's own timestamps: the boundaries
+        a step fires lie in (the newest stepped before, the newest after
+        it], because a pipelined batch leaves none deferred and a drain
+        none due, so the next one the device holds lies past the newest
+        stepped before. Those are at most ceil(span / H) instants against
+        the ``_hop_cap`` a step resolves. A batch with no newest before it
+        (after deploy or restore, or behind a serial batch whose drain never
+        ran) is serial too; its drain reads the device's newest back."""
+        prev, n = self._hop_newest, batch["count"]
+        if prev is None:
+            return None
+        newest = max(prev, int(batch["ts"][:n].max())) if n else prev
+        if -(-(newest - prev) // self.compiled.hop_ms) > self._hop_cap:
+            return None
+        return newest
 
     def _decode(self, out):
         """Hopping drains deferred boundary flushes here with empty steps,
-        and their chunks follow the batch's in order. Both are timed apart,
-        inside ``egress_decode``: the decode of a batch whose step fired a
-        boundary (``hop_flush``) and the drain, which reads live state back
-        (``hop_drain``: why a hopping runtime keeps one step in flight)."""
-        if self.compiled.window_kind != "hopping":
+        for a serial batch only (``_hop_pipelined``), and their chunks follow
+        the batch's in order. Both are timed apart, inside
+        ``egress_decode``: the decode of a batch whose step fired a boundary
+        (``hop_flush``) and, for every batch, the drain's test with, where
+        the batch is serial, the drain itself (``hop_drain``; its span only
+        round a drain that runs)."""
+        if not self._hopping:
             return self.compiled.decode_outputs(out)
         q = self.query_name
         t0 = time.perf_counter()
@@ -155,10 +202,11 @@ class DeviceStreamRuntime(StepRuntime):
         t1 = time.perf_counter()
         if fired:
             self.hop_flush_s = t1 - t0
-        with span(f"siddhi:collect.decode.hop_drain:{q}"):
-            self.state = drain_hop_boundaries(
-                self.compiled, self.state, self._drain_builder,
-                lambda o: chunks.append(self.compiled.decode_outputs(o)))
+        if out.get("hop_serial"):
+            with span(f"siddhi:collect.decode.hop_drain:{q}"):
+                self.state, self._hop_newest = drain_hop_boundaries(
+                    self.compiled, self.state, self._drain_builder,
+                    lambda o: chunks.append(self.compiled.decode_outputs(o)))
         self.hop_drain_s = time.perf_counter() - t1
         return ColumnsOut.concat(chunks)
 
@@ -236,3 +284,5 @@ class DeviceStreamRuntime(StepRuntime):
     def restore_state(self, state) -> None:
         from .batch import device_state_restore
         self.state = device_state_restore(state, self.compiled.schema)
+        if self._hopping:
+            self._hop_newest = None     # the next batch drains: serial

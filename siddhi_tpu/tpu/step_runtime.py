@@ -47,8 +47,9 @@ class StepRuntime:
     decode_full_s = None        # left by a decode that read an NFA's
     # ``full`` table (nfa.decode_rows); step_phases takes it
     hop_drain_s = None          # left by a hopping decode: its drain (the
-    # state read and any empty steps), and, where the step fired a
-    # boundary, the decode of its rows; step_phases takes both
+    # test of the serial mark and, for a serial batch, the state read and
+    # any empty steps), and, where the step fired a boundary, the decode of
+    # its rows; step_phases takes both
     hop_flush_s = None
     publish_build_s = None      # left by the bridge's egress while deliver
     # ran: what the engine built for a subscriber that takes events
@@ -57,7 +58,6 @@ class StepRuntime:
     _pending_cause = None       # cause of the flush whose emit comes next
     driver = None               # AsyncDeviceDriver when the bridge pipelines
     callback = None             # deliver()'s fn(chunk, emit_ts)
-    pipeline_safe = True        # False → the driver pins its window to 1
     fence_key = "valid"         # the step output _decode reads first
 
     def add_callback(self, fn) -> None:
@@ -131,7 +131,10 @@ class StepRuntime:
     def dispatch(self, batch: dict):
         """Fire-and-forget device step: advances ``self.state`` through
         donated buffers and returns the un-fenced output pytree, the token
-        ``collect`` fences at the egress edge."""
+        ``collect`` fences at the egress edge. A runtime whose ``collect``
+        of this token will read live state back marks the batch
+        ``_serial``: the driver dispatches nothing behind it until it is
+        collected (a hopping window's drain, ``tpu/runtime.py``)."""
         raise NotImplementedError
 
     def _decode(self, out):
