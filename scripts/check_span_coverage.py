@@ -18,7 +18,8 @@ Hops checked:
    packing, the seal closes groups FIFO, the driver's egress observes
    every consumed batch (so groups can't desynchronize) with its segments
    told apart (fence / decode, lock wait / publish, ring wait), each also
-   a span on the profiler's clock;
+   a span on the profiler's clock; a keyed window's key lookup runs and is
+   spanned inside the seal, never in ``dispatch``;
 3. **DCN forward/receive** — outgoing frames carry sampled TraceContexts;
    both receive paths parse and re-activate them with a ``dcn`` hop span;
 4. **fleet group step** — staging registers the active trace per member;
@@ -100,6 +101,13 @@ def main() -> int:
           and "ring_wait" in src(AsyncDeviceDriver.submit)
           and "collect.fence" in src(StepRuntime._fence)
           and "seal.pack" in src(StepRuntime._emit_batch))
+    from siddhi_tpu.tpu.keyed_window import KeyedWindowRuntime
+    check("a keyed window looks its keys up inside the seal, on the "
+          "sealing thread, and its dispatch only launches the step",
+          "_sealing" in src(StepRuntime._emit_batch)
+          and "seal.key_lookup" in src(KeyedWindowRuntime._sealing)
+          and "thread_time" in src(KeyedWindowRuntime._sealing)
+          and "slots_of" not in src(KeyedWindowRuntime.dispatch))
     check("sync path measures the same split",
           "collect_s" in src(StepRuntime._timed_process)
           and "step_phases" in src(StepRuntime._timed_process))

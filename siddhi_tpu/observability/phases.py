@@ -51,17 +51,18 @@ Two parts of a phase are told apart without joining the serial sum
 (``NESTED``): ``route`` — a served partition's lane layout of the flat
 batch, inside ``device_step`` (``tpu/partition.py`` ``dispatch``; span
 ``siddhi:dispatch.route``); ``key_lookup`` — a served keyed window's way
-from a key to its slot, inside ``device_step``: the directory's search and
-the keys it admits (``tpu/keyed_window.py`` ``dispatch``; span
-``siddhi:dispatch.key_lookup``); ``decode_full`` — an NFA's decode of its
-``full`` table (the blocked kernel's whole candidate table, the scan
-kernel's table of the plan's bound), inside ``egress_decode``, which runs
-only for a batch in which a lane emitted more rows than the packed row
-table holds (``tpu/nfa.py`` ``decode_rows``; span
-``siddhi:collect.decode.full``): its count over ``egress_decode``'s says how
-often that was, and for the scan kernel how often the step took its whole
-pack; ``hop_drain`` — a
-hopping window's drain, inside ``egress_decode``, recorded for every batch:
+from a key to its slot, inside ``pack``, on the thread that seals the batch
+(the client's for a capacity flush): the directory's search and the keys it
+admits (``tpu/keyed_window.py`` ``_sealing``; span
+``siddhi:seal.key_lookup``, inside ``siddhi:seal.pack``); ``decode_full``
+— an NFA's decode of its ``full`` table (the blocked kernel's whole
+candidate table, the scan kernel's table of the plan's bound), inside
+``egress_decode``, which runs only for a batch in which a lane emitted
+more rows than the packed row table holds (``tpu/nfa.py``
+``decode_rows``; span ``siddhi:collect.decode.full``): its count over
+``egress_decode``'s says how often that was, and for the scan kernel how
+often the step took its whole pack; ``hop_drain`` — a hopping window's
+drain, inside ``egress_decode``, recorded for every batch:
 the test of whether the batch was dispatched serial (its step may have left
 a boundary deferred, decided on the host from its timestamps) and, for a
 serial batch only, the read of ``hop_next`` / ``last_ts`` out of the live
@@ -95,7 +96,8 @@ tracker              thread read at
                             sync path, ``StepRuntime._timed_process``, on
                             the client's thread, as all five are there)
 ``route_cpu``        driver ``route``'s (``PartitionedNFARuntime.dispatch``)
-``key_lookup_cpu``   driver ``key_lookup``'s (``KeyedWindowRuntime.dispatch``)
+``key_lookup_cpu``   client ``key_lookup``'s (``KeyedWindowRuntime._sealing``;
+                            the thread that seals the batch)
 ``egress_fence_cpu`` driver ``egress_fence``'s (``StepRuntime._fence``)
 ``egress_decode_cpu`` driver ``collect`` less the fence, as ``egress_decode``
 ``sink_publish_cpu`` driver ``sink_publish``'s (``_collect_oldest``, lock
@@ -117,7 +119,8 @@ A ``<phase>_cpu`` companion is recorded for every batch its wall tracker is
 recorded for, zero included, so the two counts are equal: wall less CPU is
 what the thread waited (in ``egress_fence`` for the device; elsewhere for
 the GIL, a lock or the scheduler). ``lock_wait`` and ``ring_wait`` are
-waits by definition and have none; ``pack`` lies inside ``client_cycle``.
+waits by definition and have none; ``pack`` lies inside ``client_cycle``,
+and ``key_lookup`` inside ``pack``.
 The clock is as fine as the kernel keeps it. Where thread CPU time is kept
 by ticks (the TPU v5e hosts this repo is measured on: ``thread_time``
 advances in steps of 10 ms, one read is a system call of about 6 us, and a
@@ -127,11 +130,11 @@ reading is 0 or a tick and only the MEAN over hundreds of batches reads
 true: ``/latency`` gives ``cpu_ms`` as a mean and no percentile of it.
 
 The driver's segments are also spans on the profiler's clock
-(``profiler.py``): ``siddhi:seal.pack`` = ``pack``, ``submit.ring_wait`` =
-``ring_wait``, ``dispatch`` = ``device_step``, ``collect.fence`` =
-``egress_fence``, ``collect.decode`` = ``egress_decode``, ``deliver.lock`` =
-``lock_wait``, ``deliver.publish`` = ``sink_publish``,
-``deliver.publish.build`` = ``publish_build``.
+(``profiler.py``): ``siddhi:seal.pack`` = ``pack``, ``seal.key_lookup`` =
+``key_lookup``, ``submit.ring_wait`` = ``ring_wait``, ``dispatch`` =
+``device_step``, ``collect.fence`` = ``egress_fence``, ``collect.decode`` =
+``egress_decode``, ``deliver.lock`` = ``lock_wait``, ``deliver.publish`` =
+``sink_publish``, ``deliver.publish.build`` = ``publish_build``.
 """
 
 from __future__ import annotations
@@ -144,7 +147,7 @@ PHASES = ("ingress_parse", "ingress_queue", "ring_wait", "fill_wait", "pack",
 
 # a part of a phase told apart: recorded like a phase, outside the serial
 # sum (its parent already carries the time)
-NESTED = {"route": "device_step", "key_lookup": "device_step",
+NESTED = {"route": "device_step", "key_lookup": "pack",
           "decode_full": "egress_decode",
           "hop_drain": "egress_decode", "hop_flush": "egress_decode",
           "publish_build": "sink_publish"}
@@ -236,8 +239,8 @@ class PhaseBreakdown:
             return
         fill_avg = max(0.0, fill_span_s) / 2.0
         # (tracker, wall seconds, its thread's CPU seconds or None); the
-        # first eleven are the serial sum, the rest lie inside device_step /
-        # egress_decode and are not segments
+        # first eleven are the serial sum, the rest lie inside pack /
+        # device_step / egress_decode and are not segments
         segs = (("ingress_parse", parse_s, None),
                 ("fill_wait", fill_avg, None), ("pack", pack_s, None),
                 ("ring_wait", ring_s, None), ("ingress_queue", queue_s, None),

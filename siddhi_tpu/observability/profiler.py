@@ -14,6 +14,9 @@ span                             thread    opens / closes
 ``siddhi:seal.pack:<q>``         client    the builder's ``emit()`` in the
                                            runtime's ``flush`` (engine lock
                                            held)
+``siddhi:seal.key_lookup:<q>``   client    inside it, a keyed window only:
+                                           the batch's keys to slots in the
+                                           key directory
 ``siddhi:submit.ring_wait:<q>``  client    the wait of
                                            ``AsyncDeviceDriver.submit``,
                                            only when the ring is full

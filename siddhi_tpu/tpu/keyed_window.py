@@ -10,7 +10,11 @@ step serves a whole batch of any keys:
 - the host maps a key (a long, an int, a string's dictionary code) to a
   stable int32 slot once (``KeyDirectory``: a hash table searched as whole
   arrays once a batch, new keys given the next slots; a key past the
-  table's capacity gets no slot and its events are counted, ``drops``);
+  table's capacity gets no slot and its events are counted, ``drops``).
+  The search runs where the batch is sealed, on the thread that seals it
+  (the client's, inside its ``send`` / ``send_columns``, engine lock held),
+  and the slots ride on the batch: the driver's thread only launches the
+  step;
 - the table ``windows`` [K, 1 + (W-1) x words] int32 holds a slot's fill
   (readings held, at most W-1) and its newest W-1 readings of every
   aggregate argument, newest first, bit for bit as 32-bit words, so a
@@ -356,9 +360,12 @@ class KeyedWindowRuntime(StepRuntime):
     """The served runtime of a keyed window: a flat ``BatchBuilder`` of
     ``batch`` events in arrival order, the host's ``KeyDirectory`` and the
     device's table of windows; ``StepRuntime``'s protocol for the rest.
-    The slot lookup runs in ``dispatch`` on the driver's thread (tracker
-    ``key_lookup``, span ``siddhi:dispatch.key_lookup``), so a chunk's
-    ``send_columns`` is a slice copy."""
+    The slot lookup runs as a batch is sealed (``_sealing``, inside
+    ``seal.pack``), on the thread that seals it: the client's for a
+    capacity flush, whoever flushes otherwise (tracker ``key_lookup``,
+    nested in ``pack``; span ``siddhi:seal.key_lookup``). The batch carries
+    its ``slot`` column to ``dispatch``, which only launches the step, so
+    the driver's thread never touches the directory."""
 
     fence_key = "n"
 
@@ -391,20 +398,32 @@ class KeyedWindowRuntime(StepRuntime):
             start += self.builder.append_columns(cols, ts, start)
             self._maybe_flush()
 
-    def dispatch(self, batch: dict):
-        """The batch's keys to slots (the directory admits new ones), then
-        the step on donated state. Returns the un-fenced outputs."""
+    def _sealing(self, batch: dict) -> None:
+        """The batch's keys to slots as it is sealed (the directory admits
+        new ones in order of first appearance): ``batch["slot"]``, int32,
+        the padding 0. The lookup is a part of ``pack``: the batch's
+        ``pack_exec_s`` and ``_t_emit`` are moved to its end, so the serial
+        sum reads every second once."""
         t0, c0 = time.perf_counter(), time.thread_time()
-        with span(f"siddhi:dispatch.key_lookup:{self.query_name}"):
+        with span(f"siddhi:seal.key_lookup:{self.query_name}"):
             valid = batch["valid"]
             live = np.flatnonzero(valid)
             slot = np.zeros(valid.shape[0], np.int32)
             slot[live] = self.directory.slots_of(
                 batch["cols"][self.key_attr][live])
-        batch["_key_lookup_s"] = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        batch["slot"] = slot
+        batch["_key_lookup_s"] = t1 - t0
         batch["_key_lookup_cpu_s"] = time.thread_time() - c0
+        batch["pack_exec_s"] += t1 - batch["_t_emit"]
+        batch["_t_emit"] = t1
+
+    def dispatch(self, batch: dict):
+        """The step on donated state, over the slots the batch was sealed
+        with. Returns the un-fenced outputs."""
         self.state, out = self.compiled.step(
-            self.state, batch["cols"], batch["ts"], valid, slot)
+            self.state, batch["cols"], batch["ts"], batch["valid"],
+            batch["slot"])
         return out
 
     def _decode(self, out):

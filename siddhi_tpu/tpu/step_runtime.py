@@ -106,6 +106,11 @@ class StepRuntime:
         if s is not None:
             s()
 
+    def _sealing(self, batch: dict) -> None:
+        """A runtime's own work on a batch just emitted, inside the
+        ``seal.pack`` span on the thread that seals it (a keyed window
+        looks its keys up here); nothing by default."""
+
     def _emit_batch(self) -> dict:
         """Seal and emit the staged batch (every flush's first half): the
         probe's trace group closes exactly at the emit, and the flush cause
@@ -121,6 +126,7 @@ class StepRuntime:
         self._seal()
         with span(f"siddhi:seal.pack:{self.query_name}"):
             batch = self.builder.emit()
+            self._sealing(batch)
         batch["_cause"] = self._take_cause()
         if last is not None and last[0] == here[0]:
             batch["_client_cycle_s"] = here[1] - last[1]

@@ -306,6 +306,228 @@ def test_snapshot_then_restore_mid_stream_equals_an_uninterrupted_run(
 
 
 # ---------------------------------------------------------------------------
+# the keys are looked up where the batch is sealed
+# ---------------------------------------------------------------------------
+
+def _deployed(device, **app):
+    """(runtime, manager, rows, keyed runtime) of a started keyed app."""
+    m = SiddhiManager()
+    rows: list = []
+    rt = m.create_siddhi_app_runtime(_app(device, **app), playback=True)
+    rt.add_callback("DeviceTempStream", StreamCallback(
+        lambda evs: rows.extend(list(e.data) for e in evs)))
+    rt.start()
+    return rt, m, rows, rt.device_bridges[0].runtime
+
+
+def _tap_seals(r) -> list:
+    """Every batch the runtime seals, as its flush hands it on."""
+    sealed, inner = [], r._emit_batch
+
+    def emit():
+        sealed.append(inner())
+        return sealed[-1]
+
+    r._emit_batch = emit
+    return sealed
+
+
+@pytest.mark.parametrize("async_", ["false", "true"])
+def test_a_sealed_batch_carries_the_slots_the_directory_gives(async_):
+    """Batches of 64 over 20 keys and a table of 8: each sealed batch's
+    ``slot`` is what a fresh directory fed the same batches gives, a key
+    admitted in order of first appearance and every key past the table's
+    8 given the capacity; the padding of a partial batch reads 0."""
+    dev, room, temp = _events(500, keys=20, seed=41, sparse=True)
+    rt, m, _rows, r = _deployed(_device(batch=64, keys=8, async_=async_))
+    sealed = _tap_seals(r)
+    try:
+        _send(rt, dev, room, temp, columns=True, chunk=50)
+        rt.flush_device()
+    finally:
+        m.shutdown()
+    assert [b["count"] for b in sealed] == [64] * 7 + [52]
+    twin, first = KeyDirectory(8), {}
+    for b in sealed:
+        n, slot = b["count"], b["slot"]
+        assert slot.dtype == np.int32 and slot.shape == (64,)
+        keys = b["cols"]["deviceID"][:n]
+        assert slot[:n].tolist() == twin.slots_of(keys).tolist()
+        for k in keys.tolist():
+            if k not in first and len(first) < 8:
+                first[k] = len(first)
+        assert slot[:n].tolist() == [first.get(k, 8) for k in keys.tolist()]
+        assert not slot[n:].any()
+    assert (np.concatenate([b["slot"][:b["count"]] for b in sealed])
+            == 8).sum() == int((~np.isin(dev, list(first))).sum()) > 0
+
+
+def test_dispatch_makes_no_directory_call():
+    """An async keyed window: every directory call is made by the thread
+    that sends, inside the seal, and none inside ``dispatch`` (the driver's
+    thread launches the step and nothing else)."""
+    import inspect
+    import threading
+    dev, room, temp = _events(700, keys=30, seed=43)
+    rt, m, rows, r = _deployed(_device(batch=64, async_="true"))
+    calls, inner = [], r.directory.slots_of
+
+    def slots_of(keys):
+        calls.append((threading.get_ident(),
+                      {f.function for f in inspect.stack()}))
+        return inner(keys)
+
+    r.directory.slots_of = slots_of
+    dispatched = []
+    guarded = r.dispatch
+
+    def dispatch(batch):
+        dispatched.append(threading.get_ident())
+        return guarded(batch)
+
+    r.dispatch = dispatch
+    try:
+        _send(rt, dev, room, temp, columns=True, chunk=64)
+        rt.flush_device()
+    finally:
+        m.shutdown()
+    assert len(calls) == len(dispatched) == 11       # ceil(700 / 64)
+    me = threading.get_ident()
+    assert {t for t, _ in calls} == {me}
+    assert set(dispatched) == {r.driver._thread.ident} != {me}
+    for _, stack in calls:
+        assert "_sealing" in stack and "dispatch" not in stack
+    assert len(rows) == 700
+    assert r.step_gauges["keyed_live_keys"] == len(np.unique(dev))
+
+
+def _interrupted(dev, room, temp, cut, staged):
+    """An async keyed window paused after ``cut`` events with ``staged``
+    more sent (sealed batches left in the ring, the rest in the builder),
+    captured as they stand and restored into a second runtime that takes
+    the rest: the rows of both."""
+    head = "@app:name('KeyedStaged')\n"
+    device = _device(batch=64, async_="true")
+    rt1, m1, first, _ = _deployed(device, app_head=head, aggs=AGGS["all"])
+    try:
+        _send(rt1, dev[:cut], room[:cut], temp[:cut], columns=True)
+        rt1.flush_device()
+        driver = rt1.device_bridges[0].driver
+        driver.pause()
+        try:
+            _send(rt1, dev[cut:staged], room[cut:staged], temp[cut:staged],
+                  columns=False, start=cut)
+            assert len(driver.snapshot_staged()) == (staged - cut) // 64
+            assert len(rt1.device_bridges[0].runtime.builder) \
+                == (staged - cut) % 64 > 0
+            blob = rt1.snapshot_service.full_snapshot()
+            before = list(first)
+        finally:
+            driver.resume()
+    finally:
+        m1.shutdown()
+    rt2, m2, rest, _ = _deployed(device, app_head=head, aggs=AGGS["all"])
+    try:
+        rt2.restore(blob)
+        _send(rt2, dev[staged:], room[staged:], temp[staged:], columns=True,
+              start=staged)
+        rt2.flush_device()
+    finally:
+        m2.shutdown()
+    return before + rest
+
+
+@pytest.mark.parametrize("path", ["send", "flush_sync", "restore"])
+def test_every_way_a_batch_is_sealed_gives_the_interpreters_rows(path):
+    """The per-event ``send`` (its capacity flushes seal on the sending
+    thread), a ``flush_sync`` after every odd-sized chunk (every batch a
+    partial one, sealed by the flushing thread), and a snapshot taken with
+    sealed batches in the ring and a partly staged batch, restored into a
+    second runtime: the rows are the scalar interpreter's."""
+    dev, room, temp = _events(1300, keys=70, seed=47, sparse=True)
+    want = _interpreter(dev, room, temp, aggs=AGGS["all"])
+    if path == "restore":
+        got = _interrupted(dev, room, temp, cut=300, staged=300 + 2 * 64 + 37)
+        assert_rows_match(want, got)
+        return
+    rt, m, got, r = _deployed(_device(batch=64, async_="true"),
+                              aggs=AGGS["all"])
+    sealed = _tap_seals(r)
+    try:
+        if path == "send":
+            _send(rt, dev, room, temp, columns=False)
+        else:
+            for i in range(0, len(temp), 45):
+                j = min(i + 45, len(temp))
+                _send(rt, dev[i:j], room[i:j], temp[i:j], columns=True,
+                      start=i)
+                rt.flush_device()
+        rt.flush_device()
+    finally:
+        m.shutdown()
+    assert_rows_match(want, got)
+    assert all("slot" in b for b in sealed) and len(sealed) == (
+        21 if path == "send" else 29)
+
+
+def test_the_key_lookup_is_recorded_once_a_batch_inside_pack(monkeypatch):
+    """The tracker ``key_lookup`` (and its CPU clock) once a batch, inside
+    the batch's ``pack`` as ``phases.NESTED`` says, its span inside
+    ``siddhi:seal.pack`` on the sealing thread; the serial sum still
+    reconciles."""
+    import contextlib
+    import threading
+    from siddhi_tpu.observability.phases import NESTED
+    from siddhi_tpu.tpu import keyed_window, step_runtime
+    assert NESTED["key_lookup"] == "pack"
+    opened = []
+
+    @contextlib.contextmanager
+    def span(name):
+        opened.append(("open", name, threading.get_ident()))
+        yield
+        opened.append(("close", name, threading.get_ident()))
+
+    monkeypatch.setattr(keyed_window, "span", span)
+    monkeypatch.setattr(step_runtime, "span", span)
+    dev, room, temp = _events(640, keys=30, seed=53)
+    rt, m, rows, r = _deployed(_device(batch=64, async_="true"))
+    bridge = rt.device_bridges[0]
+    records, record = [], bridge.probe.phases.record_batch
+
+    def record_batch(n, **kw):
+        records.append(kw)
+        record(n, **kw)
+
+    bridge.probe.phases.record_batch = record_batch
+    try:
+        _send(rt, dev, room, temp, columns=True, chunk=64)
+        rt.flush_device()
+        rep = rt.observability.latency_report()["queries"][bridge.query_name]
+    finally:
+        m.shutdown()
+    assert len(records) == bridge.probe.steps == 10
+    for kw in records:
+        assert 0.0 < kw["key_lookup_s"] <= kw["pack_s"]
+        assert kw["key_lookup_cpu_s"] is not None
+    trackers = bridge.probe.phases.trackers
+    assert trackers["key_lookup"].count == trackers["key_lookup_cpu"].count \
+        == trackers["pack"].count == 640
+    assert trackers["key_lookup"].hist.sum <= trackers["pack"].hist.sum
+    assert rep["reconciliation_ratio"] == pytest.approx(1.0, abs=1e-5)
+    q = bridge.query_name
+    me = threading.get_ident()
+    lookups = [i for i, (what, name, _) in enumerate(opened)
+               if what == "open" and name == f"siddhi:seal.key_lookup:{q}"]
+    assert len(lookups) == 10
+    for i in lookups:
+        assert opened[i - 1] == ("open", f"siddhi:seal.pack:{q}", me)
+        assert opened[i + 1] == ("close", f"siddhi:seal.key_lookup:{q}", me)
+        assert opened[i + 2] == ("close", f"siddhi:seal.pack:{q}", me)
+    assert not any("dispatch.key_lookup" in name for _, name, _ in opened)
+
+
+# ---------------------------------------------------------------------------
 # what does not lower
 # ---------------------------------------------------------------------------
 
@@ -419,3 +641,28 @@ def test_the_benchmarks_other_programs_lower_as_they_did(name):
     finally:
         m.shutdown()
     assert digest.startswith(LOWERED[name]), digest
+
+
+# the same for the keyed step of `partitioned-window`, over the slot column
+# its seal hands the step: the lookup's move to the seal left the program
+# as it was, so the chip's compile cache still holds it
+KEYED_LOWERED = "0e93038486f6b4a6"
+
+
+def test_the_keyed_step_lowers_as_it_did():
+    path = os.path.join(REPO, "benchmark", "configs",
+                        "partitioned-window.siddhi")
+    with open(path, encoding="utf-8") as f:
+        text = f.read()
+    m = SiddhiManager()
+    try:
+        r = m.create_siddhi_app_runtime(
+            text, playback=True).device_bridges[0].runtime
+        b = r._emit_batch()
+        assert b["slot"].dtype == np.int32
+        low = r.compiled.step.lower(r.state, b["cols"], b["ts"], b["valid"],
+                                    b["slot"])
+        digest = hashlib.sha256(low.as_text().encode()).hexdigest()
+    finally:
+        m.shutdown()
+    assert digest.startswith(KEYED_LOWERED), digest
