@@ -3,13 +3,20 @@
 TPU (v5e) has no native float64 and the VPU/MXU want f32/bf16; f64 and
 uint64 HLOs do lower on a v5e (libtpu 0.0.34, ``chip_smoke.py`` stage S5, PR
 21) but run emulated, at a cost nobody has measured. int64 lowers (as paired
-s32) and is cheap for the compare/subtract arithmetic timestamps need. Policy:
+s32 words: a subtract with a borrow, a compare of two words), fine for
+timestamp arithmetic over ``[B]`` or ``[P]`` and costly per cell of a ``[B,
+P]`` grid: on a v5e the blocked NFA's stage-7 reduce (``[256, 320, 6592]`` cells) took 2.48
+ms with ``(ts[j] - first_ts[p]) <= within`` in int64 and 1.69 ms with the
+int32 ``d[j] <= lim[p]`` (1.41 ms with no test at all), so a grid compares
+int32 deltas against a limit computed once over ``[P]``
+(``nfa_block.make_block_step``). Policy:
 
 - ``DOUBLE``/``FLOAT`` → float32 on device (host interpreter keeps Python
   float64 semantics; parity tests compare with f32 tolerances).
 - ``INT``/string codes → int32.
 - ``LONG`` and event timestamps → int64 (emulated on TPU; used only for
-  compares, min/max and additions — never in hot elementwise math).
+  compares, min/max and additions — never in hot elementwise math, and never
+  per cell of a grid).
 - Aggregation accumulators (sums/counts) → float32 (``FACC``). Sliding-window
   sums use cumsum *differences* over bounded buffers, so error stays at
   O(sqrt(N)·eps·magnitude), well inside the engine's advertised precision.

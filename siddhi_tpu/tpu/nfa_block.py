@@ -18,7 +18,7 @@ state. So the number of partials created at any state during a batch is
 bounded by ``C + B`` (old slots + one per source partial), NOT exponential,
 and the whole batch resolves in S data-parallel stages:
 
-  stage s: grid[j, p] = valid[j] & gate_s[j] & within_ok[j, p]
+  stage s: grid[j, p] = valid[j] & gate_s[j] & (d[j] <= lim_p)
                          & (j > born_p)  & pred_s(event_j, bindings_p)
            j*(p) = first j with grid[j, p]     (vectorized argmax)
            advanced partials become stage s+1's candidates with
@@ -32,7 +32,12 @@ and the whole batch resolves in S data-parallel stages:
 Each stage is one [B, P] masked grid — exactly the "candidate×event pairs as
 one grid per state per batch" shape the verdict names. Sequences add the
 strict-continuity constraint ``vidx[j] == vidx[born]+1`` (``vidx`` = running
-count of valid events); ``within`` is a timestamp mask on the grid.
+count of valid events). ``within`` is one int32 compare a cell: each
+candidate computes once, in int64 over ``[P]``, the largest wire delta it
+admits, ``lim_p = clip(first_ts_p - ts_base + within, -1, 2^31-1)``, and the
+grid tests the batch's own int32 deltas against it (``d[j] <= lim_p``, exact
+for every delta the wire carries); element-level ``within`` likewise from the
+previous element's bind time. No operation of a ``[B, P]`` grid is 64-bit.
 
 What leaves the step: the last stage's candidates that advanced are the
 batch's rows. They are packed on the device (``pack_first``, the survivor
@@ -231,13 +236,27 @@ def make_block_step(nfa: "DeviceNFACompiler"):
         drops = state["drops"]
 
         jidx = jnp.arange(B, dtype=jnp.int32)
-        # wire format: int32 ts deltas + per-batch base, prefix validity
-        ts = ts_base.astype(jnp.int64) + ts.astype(jnp.int64)
+        # wire format: int32 ts deltas + per-batch base, prefix validity.
+        # The deltas lie in [0, 2^31-1] (``MergedBatchBuilder.emit``); the
+        # `within` tests compare them as they came, and the int64 times are
+        # built for what a partial carries
+        d = ts
+        base = ts_base.astype(jnp.int64)
+        ts = base + d.astype(jnp.int64)
         valid = jidx < nvalid
         ev_env = {f"ev_{k}": cols[k] for k in cols}
         n_valid = jnp.sum(valid.astype(jnp.int32))
         vidx = jnp.cumsum(valid.astype(jnp.int32))        # 1-based at valids
-        ts_last = jnp.max(jnp.where(valid, ts, jnp.int64(-(2**62))))
+        # the newest event's delta; -1 (under every limit) in an empty batch
+        d_last = jnp.max(jnp.where(valid, d, -1))
+
+        def limit(t, w):
+            """[P] i32: the largest delta ``d`` with ``ts_base + d - t <=
+            w``, clipped to [-1, 2^31-1], which keeps ``d <= limit`` exact
+            for every delta the wire carries: -1 admits none of them and
+            2^31-1 all. Once a stage over ``[P]`` in int64, so the ``[B, P]``
+            grid compares int32 (a v5e has no 64-bit integer unit)."""
+            return jnp.clip(t - base + w, -1, 2**31 - 1).astype(jnp.int32)
 
         with jax.named_scope("nfa.admit"):
             # ---- seeds: state-0 predicate over the raw batch --------------
@@ -335,12 +354,12 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                     pred = jnp.asarray(st.predicate(env))
                     grid = grid & jnp.broadcast_to(pred, (B, P))
                 if within is not None:
-                    grid = grid & (
-                        (ts[:, None] - cand_first[None, :]) <= within)
+                    lim = limit(cand_first, within)
+                    grid = grid & (d[:, None] <= lim[None, :])
                 if st.within_ms is not None:
                     # element-level: the gap since the PREVIOUS element's bind
-                    grid = grid & ((ts[:, None] - cand_last[None, :])
-                                   <= st.within_ms)
+                    lim_e = limit(cand_last, st.within_ms)
+                    grid = grid & (d[:, None] <= lim_e[None, :])
                 if is_seq:
                     grid = grid & (vidx[:, None] == cand_vb[None, :] + 1)
                 else:
@@ -401,13 +420,13 @@ def make_block_step(nfa: "DeviceNFACompiler"):
                     # drop-newest) ----
                     surv = cand_exists & ~adv
                     if within is not None:
-                        surv = surv & ((ts_last - cand_first) <= within)
+                        surv = surv & (d_last <= lim)
                     if st.within_ms is not None:
                         # an element-window that lapsed against the newest
                         # event can never match again (monotonic time) —
                         # prune, or dead partials wedge the keep-oldest slots
                         # (review finding)
-                        surv = surv & ((ts_last - cand_last) <= st.within_ms)
+                        surv = surv & (d_last <= lim_e)
                     if is_seq:
                         # strict continuity: survive only if no valid event
                         # followed

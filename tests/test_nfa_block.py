@@ -821,3 +821,138 @@ def test_a_batch_that_emits_more_than_the_packed_table_reads_the_full_one():
         assert entry["reconciliation_ratio"] == pytest.approx(1.0, abs=1e-6)
     finally:
         m.shutdown()
+
+
+# -- `within` as an int32 limit a candidate computes once ---------------------
+# A stage tests the wire's int32 delta ``d[j]`` against ``lim[p] =
+# clip(first_ts[p] - ts_base + within, -1, 2^31-1)``: each case sits on an
+# edge of that arithmetic, its batches flushed as listed, against the scalar
+# interpreter and the columnar host engine (``HostBlockNFA``, int64 times).
+
+DAY = 86_400_000
+WITHIN_PAIR = """
+define stream S (v double);
+from every e1=S[v > 20.0] -> e2=S[v > e1.v] within {within}
+select e1.v as a, e2.v as b insert into O;
+"""
+WITHIN_ELEMENT = """
+define stream S (v double);
+from every e1=S[v > 10.0] -> e2=S[v > e1.v] within 100 -> e3=S[v > e2.v]
+select e1.v as a, e2.v as b, e3.v as c insert into O;
+"""
+WITHIN_EDGES = {
+    # exactly `within` after the first matches, 1 ms more does not
+    "exact_edge": (WITHIN_PAIR.format(within=100), [
+        [("S", [25.0], 1000), ("S", [30.0], 1100)],
+        [("S", [40.0], 2000), ("S", [50.0], 2101)],
+    ], [[25.0, 30.0]], [[40.0, 50.0]]),
+    # a carried partial whose first_ts lies 2^32 ms before the batch's base:
+    # its limit clamps to -1 (unclipped it would wrap to +500)
+    "partial_far_before_base": (WITHIN_PAIR.format(within=1000), [
+        [("S", [25.0], 1000)],
+        [("S", [30.0], 1000 + 2**32 + 500), ("S", [40.0], 1100 + 2**32 + 500)],
+    ], [[30.0, 40.0]], [[25.0, 30.0], [25.0, 40.0]]),
+    # `within 30 days` passes 2^31 ms: a creation's limit clamps to
+    # 2^31-1, a carried one does not, and 31 days later nothing matches
+    "within_past_2_31": (WITHIN_PAIR.format(within="30 days"), [
+        [("S", [25.0], 1000)],
+        [("S", [30.0], 1000 + 20 * DAY), ("S", [35.0], 1005 + 20 * DAY)],
+        [("S", [40.0], 1005 + 51 * DAY)],
+    ], [[25.0, 30.0], [30.0, 35.0]], [[35.0, 40.0]]),
+    # element-level: the gap since the previous element's bind, at its edge
+    "element_edge": (WITHIN_ELEMENT, [
+        [("S", [11.0], 1000), ("S", [12.0], 1100)],
+        [("S", [13.0], 5000)],
+        [("S", [20.0], 6000), ("S", [21.0], 6101), ("S", [22.0], 6150)],
+    ], [[11.0, 12.0, 13.0]], [[20.0, 21.0, 22.0]]),
+    # one batch whose deltas reach 2^31-1: the largest delta the wire
+    # carries, one past `within` of the first seed and on it for the second
+    "delta_span_near_2_31": (WITHIN_PAIR.format(within=2**31 - 2), [
+        [("S", [25.0], 1000), ("S", [26.0], 1001),
+         ("S", [30.0], 1000 + 2**31 - 1)],
+    ], [[26.0, 30.0]], [[25.0, 30.0]]),
+}
+
+
+def _device_batches(app, batches):
+    rt = DeviceNFARuntime(app, slot_capacity=16, batch_capacity=8)
+    assert rt.compiler.blocked
+    rows = []
+    rt.add_callback(rows.extend)
+    for batch in batches:
+        for sid, row, ts in batch:
+            rt.send(sid, row, ts)
+        rt.flush()
+    assert rt.drop_count == 0 and rt.builder.ts_clamped == 0
+    return [list(r) for r in rows]
+
+
+def _host_block_batches(app, batches):
+    m = SiddhiManager()
+    try:
+        rt = m.create_siddhi_app_runtime(
+            "@app:host_batch(batch='8', strict='true')\n" + app,
+            playback=True)
+        assert [b.kind for b in rt.host_bridges] == ["host_nfa"]
+        got = []
+        rt.add_callback("O", StreamCallback(
+            lambda evs: got.extend(list(e.data) for e in evs)))
+        rt.start()
+        ih = rt.input_handler("S")
+        for batch in batches:
+            ih.send_rows([row for _, row, _ in batch],
+                         [ts for _, _, ts in batch])
+        rt.shutdown()
+    finally:
+        m.shutdown()
+    return got
+
+
+@pytest.mark.parametrize("case", list(WITHIN_EDGES))
+def test_within_at_the_edges_of_the_int32_limit(case):
+    app, batches, held, absent = WITHIN_EDGES[case]
+    want = oracle(app, [e for batch in batches for e in batch])
+    for row in held:
+        assert row in want
+    for row in absent:
+        assert not any(w[:len(row)] == row for w in want)
+    assert_rows_match(want, _device_batches(app, batches))
+    assert_rows_match(want, _host_block_batches(app, batches))
+
+
+@pytest.mark.parametrize("lanes", [0, 4], ids=["single", "vmap_lanes"])
+@pytest.mark.parametrize("app", ["stream_within", "element_within"])
+def test_no_op_of_a_stage_grid_is_64_bit(app, lanes):
+    """The lowered step of a chain with a `within` holds no array of a
+    64-bit element type at any stage grid's ``[B, P]`` shape (an int64 test
+    there runs as word pairs with a borrow on a v5e), while the grids
+    themselves are there, alone and vmapped over lanes."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    text = CHAIN4 if app == "stream_within" else \
+        PLAN_FEATURES["element_within"][0]
+    rt = blocked_runtime(text, slot_capacity=16, batch_capacity=32)
+    nfa = rt.compiler
+    assert (nfa.within is not None) == (app == "stream_within")
+    assert any(st.within_ms is not None for st in nfa.states) == \
+        (app == "element_within")
+    b = rt.builder.emit()
+    args = (nfa.init_state(), b["cols"], b["tag"], b["ts"],
+            jnp.asarray(b["ts_base"]), jnp.asarray(np.int32(b["count"])))
+    step = nfa.make_step()
+    if lanes:
+        step = jax.vmap(step)
+        args = jax.tree.map(
+            lambda x: jnp.broadcast_to(jnp.asarray(x)[None],
+                                       (lanes,) + jnp.shape(x)), args)
+    low = jax.jit(step).lower(*args).as_text()
+    lead = f"{lanes}x" if lanes else ""
+    grids = [f"{lead}{nfa.B}x{s * nfa.C + nfa.B}" for s in range(1, nfa.S)]
+    for g in grids:
+        assert f"tensor<{g}xi1>" in low, g
+    wide = re.findall(r"tensor<([\dx]+)x(?:i64|ui64|f64)>", low)
+    assert not [w for w in wide if w in grids], sorted(set(wide))

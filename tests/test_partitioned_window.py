@@ -603,14 +603,16 @@ def test_a_float_key_and_a_range_partition_are_refused(text, why):
 # configuration's one jitted step at its benchmark sizes, on this backend,
 # as the parent of PR 39 lowers them. A program that changes misses the
 # compile cache on the chip, and `setup_s` jumps (PR 38's first draft:
-# 35.8 against 19.7 s)
+# 35.8 against 19.7 s). The two blocked-NFA programs (`pattern-chain8`,
+# `partitioned-chain`) are pinned as they lower since their stage grids
+# test `within` as an int32 delta against a per-candidate limit
 LOWERED = {
     "pattern-chain8":
-        "67550beb7254a360",
+        "e8387c145d7f36c8",
     "window-groupby":
         "3415b13afb44a26f",
     "partitioned-chain":
-        "b60c0d684b71379f",
+        "5781f06553a2f236",
     "partitioned-kleene":
         "387d916cb49ba53c",
     "nexmark-q5":

@@ -922,12 +922,12 @@ CHAIN8 = ("from every e1=S[v > 50.0] -> e2=S[v > e1.v] -> e3=S[v > e2.v] "
 BLOCKED_PROGRAMS = {
     "pattern-chain8": (
         "define stream S (dev string, v double);\n"
-        "@device(batch='64', slots='16')\n" + CHAIN8, 2696),
+        "@device(batch='64', slots='16')\n" + CHAIN8, 2790),
     "partitioned-chain": (
         "define stream S (dev string, v double);\n"
         "partition with (dev of S) begin\n"
         "@device(batch='256', slots='16', lanes='4')\n" + CHAIN8
-        + "\nend;", 2663),
+        + "\nend;", 2757),
 }
 
 
@@ -997,6 +997,10 @@ def test_the_blocked_programs_are_the_programs_they_were(name):
     pack, the runtimes) must leave the blocked kernel's step the same
     program: the instruction count of its optimized HLO as PR 34 left it,
     which is PR 33's (2461 / 2431) plus the one pack of the emitted rows
-    into the ``[B]`` row table and the count ``n``."""
+    into the ``[B]`` row table and the count ``n``, and 94 more since the
+    stage grids test `within` as an int32 delta against a limit each
+    candidate computes once (its int64 sum and clip over ``[P]``, a stage:
+    on this backend an int64 compare is one instruction, on a v5e a word
+    pair's)."""
     app_text, pinned = BLOCKED_PROGRAMS[name]
     assert _instructions(_compiled_step_text(app_text)) == pinned
