@@ -366,7 +366,7 @@ def check(text: str) -> list[str]:
             problems.append(
                 f"{family}{dict(series)}: +Inf bucket {buckets[-1][1]} "
                 f"!= _count {total}")
-    from siddhi_tpu.observability.phases import NESTED, PHASES, THREAD_CLOCKS
+    from siddhi_tpu.observability.phases import SEGMENTS, THREAD_CLOCKS
     for (family, label), values in label_values.items():
         if len(values) > MAX_LABEL_VALUES:
             problems.append(
@@ -375,13 +375,13 @@ def check(text: str) -> list[str]:
                 f"scale with population")
         if label == "phase":
             # one vocabulary: a phase label outside observability.phases
-            # PHASES is a tracker somebody named by hand
-            stray = values - set(PHASES) - set(NESTED) \
-                - set(THREAD_CLOCKS) - {"end_to_end"}
+            # SEGMENTS is a tracker somebody named by hand
+            stray = values - set(SEGMENTS) - set(THREAD_CLOCKS) \
+                - {"end_to_end"}
             if stray:
                 problems.append(
                     f"{family}: phase label values {sorted(stray)} are "
-                    f"not in observability.phases.PHASES")
+                    f"not in observability.phases.SEGMENTS")
     # parent/child collision: a federated sample that equals a parent
     # sample once its worker label is stripped would make the two series
     # indistinguishable under sum()/avg() aggregation over workers

@@ -6,20 +6,23 @@ hop — each asynchronous boundary either records a span or explicitly hands
 the trace to the far side. A hop that drops the trace makes every
 waterfall read as if the time vanished, which is exactly the blind spot
 the attribution layer exists to remove. Modeled on
-``check_guard_coverage.py``: structural source checks per hop plus one
-end-to-end build that asserts a real trace crossed them.
+``check_guard_coverage.py``: structural source checks per hop, the device
+hop's served deployments, and one end-to-end build that asserts a real
+trace crossed them.
 
 Hops checked:
 
 1. **@async enqueue/delivery** — the junction stamps the trace + handoff
    mark at enqueue; delivery closes the queue wait as an ``ingress-queue``
    span and re-activates the trace;
-2. **device dispatch/collect** — the bridge registers pending traces at
-   packing, the seal closes groups FIFO, the driver's egress observes
-   every consumed batch (so groups can't desynchronize) with its segments
-   told apart (fence / decode, lock wait / publish, ring wait), each also
-   a span on the profiler's clock; a keyed window's key lookup runs and is
-   spanned inside the seal, never in ``dispatch``;
+2. **device dispatch/collect** — run, not read: one small served
+   deployment of each kind (stream, hopping stream, NFA, partitioned NFA,
+   keyed window) over a few batches, every span it opens and every tracker
+   it counts held to ``observability.phases.SEGMENTS``: each segment's span
+   opens on its thread and a part's inside its parent's (so a keyed
+   window's key lookup opens inside the seal, never in ``dispatch``), every
+   span of the table opens somewhere, and each tracker counts the events
+   its entry says; a sampled trace crosses the device hop (below);
 3. **DCN forward/receive** — outgoing frames carry sampled TraceContexts;
    both receive paths parse and re-activate them with a ``dcn`` hop span;
 4. **fleet group step** — staging registers the active trace per member;
@@ -30,9 +33,11 @@ Hops checked:
 Run from tier-1 (tests/test_xray.py); exits non-zero on any gap.
 """
 
+import contextlib
 import inspect
 import os
 import sys
+import threading
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -52,15 +57,121 @@ def src(obj) -> str:
     return inspect.getsource(obj)
 
 
+SERVED = {
+    # kind -> (query, batch, @device options, its parts: counted for every
+    # event ("n") or for some)
+    "stream": ("from S[v > 10]#window.length(8) select k, sum(v) as t", 16,
+               "", {}),
+    "hopping": ("from S#window.hopping(200, 40) select k, count() as c "
+                "group by k order by c desc limit 1", 16, ", window='256'",
+                {"hop_drain": "n", "hop_flush": "some"}),
+    "nfa": ("from every a=S[v > 90] -> b=S[v < 10] within 40 "
+            "select a.k as k, b.v as v", 32, ", slots='64'",
+            {"decode_full": "some"}),
+    "partition": ("from every a=S[v > 50] -> b=S[v > a.v] within 400 "
+                  "select a.v as v1, b.v as v2", 64, ", slots='32', lanes='4'",
+                  {"route": "n"}),
+    "keyed": ("from S[v > 20]#window.length(3) select k, max(v) as hi", 32,
+              ", keys='8'", {"key_lookup": "n"}),
+}
+# every kind's: the serial phases of every batch, and what every delivery
+# counts; the spans that open on the thread that seals (or submits) a batch
+EVERY = {"pack": "n", "device_step": "n", "egress_fence": "n",
+         "egress_decode": "n", "publish_build": "sink_publish"}
+CLIENT = {"ring_wait", "pack", "key_lookup"}
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler``: each annotation logs (open | close,
+    name, thread)."""
+    log: list = []
+
+    @classmethod
+    @contextlib.contextmanager
+    def TraceAnnotation(cls, name):     # noqa: N802 — jax.profiler's name
+        cls.log.append(("open", name, threading.get_ident()))
+        yield
+        cls.log.append(("close", name, threading.get_ident()))
+
+
+def _serve(kind, query, batch, options):
+    """The kind served async, 160 events a batch at a time, once behind a
+    full ring: the events each of its probe's trackers counted."""
+    from siddhi_tpu import SiddhiManager, StreamCallback
+    app = f"@device(strict='true', batch='{batch}'{options}, async='true')" \
+        f"\n{query} insert into O;"
+    if kind in ("partition", "keyed"):
+        app = f"partition with (k of S) begin\n{app}\nend;"
+    m = SiddhiManager()
+    rt = m.create_siddhi_app_runtime(
+        "define stream S (k long, v long);\n" + app, playback=True)
+    rt.add_callback("O", StreamCallback(lambda evs: None))
+    rt.start()
+    driver = rt.device_bridges[0].driver
+    held = range(batch, batch * (driver.depth + 2))
+    for i in range(160):
+        if i == held.start:         # a ring full behind a paused driver
+            driver.pause()
+        v = (95 if i % 40 < 38 else 5) if kind == "nfa" else i * 37 % 100
+        rt.input_handler("S").send([i % 6, v], timestamp=1000 + i)
+        if i == held.stop - 1:
+            driver.resume()
+        if i % batch == batch - 1 and i not in held:
+            rt.flush_device()
+    driver.resume()
+    rt.flush_device()
+    n = {p: t.count for p, t in
+         rt.device_bridges[0].probe.phases.trackers.items()}
+    m.shutdown()
+    return n
+
+
+def check_served_segments():
+    from siddhi_tpu.observability import profiler
+    from siddhi_tpu.observability.phases import CPU_OF, NESTED, SEGMENTS
+    seg_of = {s.span: p for p, s in SEGMENTS.items() if s.span}
+    opened, real = set(), profiler._jax_profiler
+    profiler._jax_profiler = lambda: _Annotations
+    for kind, (query, batch, options, own) in SERVED.items():
+        _Annotations.log = []
+        n = _serve(kind, query, batch, options)
+        bad, stacks = [], {}
+        for what, name, thread in _Annotations.log:
+            stack = stacks.setdefault(thread, [])
+            call = name.split(":")[1]
+            p = seg_of.get(call)
+            if what == "close":
+                stack.pop()
+            elif p is not None and (
+                    (thread == threading.get_ident()) != (p in CLIENT)
+                    or p in NESTED
+                    and stack[-1:] != [SEGMENTS[NESTED[p]].span]):
+                bad.append((call, stack[-1:]))
+            if what == "open":
+                opened.add(p)
+                stack.append(call)
+        check(f"{kind}: each segment's span opens on its thread, a part's "
+              f"inside its parent's", not bad, f"{bad[:3]}")
+        rule, wrong = {**EVERY, **own}, []
+        for p in SEGMENTS:
+            want = rule.get(p, "none" if p in NESTED else "some")
+            ok = {"n": n[p] == 160, "none": n[p] == 0,
+                  "some": n[p] <= n.get(NESTED.get(p), 160)}.get(
+                want, n[p] == n.get(want))
+            if not ok or n.get(CPU_OF.get(p), n[p]) != n[p]:
+                wrong.append((p, n[p]))
+        check(f"{kind}: each tracker counts the events its entry says",
+              not wrong, f"{wrong}")
+    profiler._jax_profiler = real
+    spanned = {p for p, s in SEGMENTS.items() if s.span}
+    check("every span of the table opens in a served deployment",
+          spanned <= opened, f"(never: {sorted(spanned - opened)})")
+
+
 def main() -> int:
     from siddhi_tpu import SiddhiManager
-    from siddhi_tpu.core.device_bridge import (
-        AsyncDeviceDriver,
-        DeviceQueryBridge,
-    )
     from siddhi_tpu.core.stream import InputHandler, StreamJunction
     from siddhi_tpu.fleet.group import FleetGroup
-    from siddhi_tpu.tpu.step_runtime import StepRuntime
     from siddhi_tpu.observability import DeviceStepProbe, phase_of_stage
     from siddhi_tpu.resilience.device_guard import DeviceGuard
     from siddhi_tpu.resilience.fleet_guard import FleetGuard
@@ -78,48 +189,8 @@ def main() -> int:
           "maybe_trace" in src(InputHandler.send)
           and "maybe_trace" in src(InputHandler.send_rows))
 
-    # 2) device dispatch/collect
-    check("device bridge registers pending traces at packing",
-          "probe.pending" in src(DeviceQueryBridge.on_event))
-    check("every flush seals its trace group at the emit",
-          "_seal" in src(StepRuntime._maybe_flush)
-          or "step_sealer" in src(StepRuntime._seal))
-    check("driver egress observes every consumed batch (probe drains FIFO)",
-          "observe" in src(AsyncDeviceDriver._collect_oldest)
-          and "phases" in src(AsyncDeviceDriver._collect_oldest))
-    from siddhi_tpu.observability.phases import PHASES
-    check("phase vocabulary tells fence / decode, lock wait / publish and "
-          "the ring wait apart",
-          {"egress_fence", "egress_decode", "lock_wait", "sink_publish",
-           "ring_wait", "ingress_queue"} <= set(PHASES))
-    check("driver egress measures the split and puts it on the profiler's "
-          "clock",
-          all(k in src(AsyncDeviceDriver._collect_oldest) for k in (
-              "collect_s", "lock_s", "ring_s", "deliver.lock",
-              "deliver.publish"))
-          and "decode_s" in src(StepRuntime.step_phases)
-          and "ring_wait" in src(AsyncDeviceDriver.submit)
-          and "collect.fence" in src(StepRuntime._fence)
-          and "seal.pack" in src(StepRuntime._emit_batch))
-    from siddhi_tpu.tpu.keyed_window import KeyedWindowRuntime
-    check("a keyed window looks its keys up inside the seal, on the "
-          "sealing thread, and its dispatch only launches the step",
-          "_sealing" in src(StepRuntime._emit_batch)
-          and "seal.key_lookup" in src(KeyedWindowRuntime._sealing)
-          and "thread_time" in src(KeyedWindowRuntime._sealing)
-          and "slots_of" not in src(KeyedWindowRuntime.dispatch))
-    check("sync path measures the same split",
-          "collect_s" in src(StepRuntime._timed_process)
-          and "step_phases" in src(StepRuntime._timed_process))
-    check("every segment a thread works in reads the thread's CPU clock "
-          "beside the wall clock",
-          all("thread_time" in src(f) for f in (
-              AsyncDeviceDriver._dispatch, AsyncDeviceDriver._collect_oldest,
-              StepRuntime._fence, StepRuntime._timed_process,
-              StepRuntime._emit_batch)))
-    check("probe closes fill-wait + device spans per batch",
-          "fill-wait" in src(DeviceStepProbe.on_step)
-          and "add_span" in src(DeviceStepProbe.on_step))
+    # 2) device dispatch/collect: served deployments, run
+    check_served_segments()
 
     # 3) DCN forward/receive
     check("DCN ingest samples and forwards trace contexts",

@@ -29,6 +29,7 @@ from ..query_api import (
     StateInputStream,
 )
 from ..query_api.annotation import find_annotation
+from ..observability.phases import span_name
 from ..observability.profiler import span
 from .egress import ChunkEgress
 from .event import EventType, StreamEvent
@@ -96,11 +97,12 @@ class AsyncDeviceDriver:
         self._stopped = False
         self.batches_stepped = 0
         self.step_seconds = 0.0     # cumulative dispatch + collect time
-        self.deadline_flushes = 0
         q = rt.query_name
-        self._spans = {call: f"siddhi:{call}:{q}" for call in (
-            "submit.ring_wait", "dispatch", "collect", "deliver",
-            "deliver.lock", "deliver.publish")}
+        # the segments' spans (phases.SEGMENTS), and the two wholes
+        self._spans = {p: span_name(p, q) for p in (
+            "ring_wait", "device_step", "lock_wait", "sink_publish")}
+        self._spans.update({call: f"siddhi:{call}:{q}"
+                            for call in ("collect", "deliver")})
         # counter-check cadence under sustained load: on_drained normally
         # runs when the pipeline empties, but a saturated pipeline never
         # empties — force the bookkeeping every N collected batches (one
@@ -119,7 +121,7 @@ class AsyncDeviceDriver:
             # briefly then grows (bounded in practice by the wait)
             if len(self._q) >= self.depth:
                 t0 = time.perf_counter()
-                with span(self._spans["submit.ring_wait"]):
+                with span(self._spans["ring_wait"]):
                     self._cv.wait(timeout=0.2)
                 batch["_ring_wait_s"] = time.perf_counter() - t0
             self._q.append(batch)
@@ -227,7 +229,6 @@ class AsyncDeviceDriver:
             if due is None or due > 0.0:
                 return      # raced with a producer flush — nothing to do
             self.rt._count_flush("deadline")
-            self.deadline_flushes += 1
             # the runtime's own flush: seal + emit + driver submit, so the
             # deadline path can never diverge from producer-side flushes
             self.rt.flush()
@@ -237,7 +238,7 @@ class AsyncDeviceDriver:
         err = None
         token = None
         try:
-            with span(self._spans["dispatch"]):
+            with span(self._spans["device_step"]):
                 token = self.rt.dispatch(batch)
         except Exception as e:  # noqa: BLE001 — without a DeviceGuard
             # installed a dispatch failure must not kill the worker; the
@@ -254,8 +255,6 @@ class AsyncDeviceDriver:
             batch, token, t_disp0, disp_s, disp_cpu_s, err = \
                 self._inflight.popleft()
         rt = self.rt
-        rt.fence_s = None       # the runtime's own collect leaves its wait
-        # for the device here; a guard replay, which fences nothing, none
         t0, c0 = time.perf_counter(), time.thread_time()
         rows = []
         ok = False
@@ -280,14 +279,14 @@ class AsyncDeviceDriver:
             lock = self.app_context.root_lock
             tp0 = time.perf_counter()
             with span(self._spans["deliver"]):
-                with span(self._spans["deliver.lock"]):
+                with span(self._spans["lock_wait"]):
                     lock.acquire()
                 tp1, cp1 = time.perf_counter(), time.thread_time()
                 try:
                     # stamp outputs with the batch's own last event time —
                     # the producer-side _out_ts has already advanced to
                     # newer events by delivery time
-                    with span(self._spans["deliver.publish"]):
+                    with span(self._spans["sink_publish"]):
                         rt.deliver(rows, batch.get("last_ts"))
                 except Exception:   # noqa: BLE001 — a raising downstream
                     # receiver must not kill the sole device worker, and the

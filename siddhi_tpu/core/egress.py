@@ -21,23 +21,18 @@ There is no per-row path. ``egress`` counts deliveries and rows by shape, so
 rows per delivery can be read (``egress_report``). What the ENGINE builds for
 a subscriber that takes events is timed apart from what the subscriber does
 with it: on the second path the timestamps, the rows, a ``StreamEvent`` a
-row and the ``Event`` list of a query callback (span
-``siddhi:deliver.publish.build:<query>``); on the first the ``Event`` list a
-``StreamCallback``'s receiver builds from the columns
-(``core/stream.py`` ``receive_columns``, ``built_s``; the same span, named by
-the stream). The seconds are left on the runtime for its ``phases`` record,
-``publish_build_s`` (``observability/phases.py`` ``publish_build``, inside
-``sink_publish``): 0 for a subscriber that takes the columns as they are.
+row and the ``Event`` list of a query callback; on the first the ``Event``
+list a ``StreamCallback``'s receiver builds from the columns
+(``core/stream.py`` ``receive_columns``, which times it apart from the
+user's function). Both are filed in the runtime's parts as ``publish_build``
+(``observability/phases.py``): 0 for a subscriber that takes the columns as
+they are.
 """
 
 from __future__ import annotations
 
-import contextlib
-import time
-
 import numpy as np
 
-from ..observability.profiler import span
 from .event import Event, EventType, StreamEvent
 
 
@@ -51,18 +46,10 @@ class ChunkEgress:
         # shape -> [deliveries, rows]
         self.egress = {"columns": [0, 0], "events": [0, 0]}
 
-    @contextlib.contextmanager
-    def _building(self, first: bool):
-        """Round what the engine builds for a subscriber that takes events.
-        The seconds are left on the runtime for its ``step_phases``: a
-        chunk's ``first`` build sets them, the ``Event`` list of its query
-        callbacks adds to them."""
-        rt = self.runtime
-        t0 = time.perf_counter()
-        with span(f"siddhi:deliver.publish.build:{self.query_name}"):
-            yield
-        dt = time.perf_counter() - t0
-        rt.publish_build_s = dt if first else (rt.publish_build_s or 0.0) + dt
+    def _building(self):
+        """Round what the engine builds for a subscriber that takes
+        events."""
+        return self.runtime.measure("publish_build")
 
     def _on_out(self, out) -> None:
         """``out`` is a stamped :class:`~siddhi_tpu.core.columns.ColumnsOut`
@@ -92,12 +79,12 @@ class ChunkEgress:
         oj.deliver_columns(cols, np.asarray(out.ts, dtype=np.int64), out.n)
         # a StreamCallback asked for events: its receiver built them from
         # the columns and says what that took
-        self.runtime.publish_build_s = sum(
-            getattr(r, "built_s", 0.0) for r in oj.receivers)
+        self.runtime.file_part("publish_build", sum(
+            getattr(r, "built_s", 0.0) for r in oj.receivers))
 
     def _deliver_events_out(self, out) -> None:
         cur = EventType.CURRENT
-        with self._building(first=True):
+        with self._building():
             events = [StreamEvent(ts, row, cur)
                       for ts, row in zip(out.ts_list(), out.rows())]
         if self.rate_limiter is not None:
@@ -114,7 +101,7 @@ class ChunkEgress:
         count[0] += 1
         count[1] += len(events)
         if self.query_callbacks:
-            with self._building(first=False):
+            with self._building():
                 evs = [Event(e.timestamp, e.data) for e in events]
             for cb in self.query_callbacks:
                 cb.receive(events[-1].timestamp, evs, None)

@@ -149,6 +149,7 @@ class HostQueryBridge(ChunkEgress):
         self.stream_ids = stream_ids
         self.output_junction = output_junction
         self.query_name = query_name
+        runtime.query_name = query_name     # the profiler spans' <query>
         self.query_callbacks: list = []
         self.events_in = 0
         self.batches = 0

@@ -15,6 +15,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from ..observability.phases import span_name
 from ..observability.profiler import span
 from ..query_api.definition import AbstractDefinition
 from .event import Event, EventType, StreamEvent
@@ -597,13 +598,13 @@ class _StreamCallbackReceiver:
 
     built_s = 0.0   # what the last columnar delivery spent on this
     # subscriber's ``Event`` list, the user's function not included: the
-    # bridge that delivered reads it (``core/egress.py``, ``publish_build``)
+    # bridge that delivered files it in its runtime's parts (core/egress.py)
 
     def __init__(self, callback: StreamCallback,
                  names: Optional[list] = None, stream_id: str = ""):
         self.callback = callback
         self.names = names      # the stream's attribute names, in order
-        self._build_span = f"siddhi:deliver.publish.build:{stream_id}"
+        self._build_span = span_name("publish_build", stream_id)
 
     def receive(self, event: StreamEvent) -> None:
         if event.type in (EventType.CURRENT, EventType.EXPIRED):

@@ -131,17 +131,10 @@ class DeviceStepProbe:
                 device_path: bool = True,
                 phases: Optional[dict] = None) -> None:
         """One consumed batch. ``phases`` (async driver / sync flush)
-        carries the measured serial segments of this batch's waterfall,
-        keyed as :meth:`PhaseBreakdown.record_batch` names them
-        (``fill_span_s``, ``pack_s``, ``ring_s``, ``queue_s``, ``step_s``,
-        ``fence_s``, ``decode_s``, ``decode_full_s``, ``hop_drain_s``,
-        ``hop_flush_s``, ``lock_s``, ``publish_s``, ``publish_build_s``,
-        ``host_s``, ``cause``; the threads' CPU clocks ``step_cpu_s``,
-        ``route_cpu_s``, ``key_lookup_cpu_s``, ``fence_cpu_s``,
-        ``decode_cpu_s``,
-        ``publish_cpu_s``, ``client_cycle_s``, ``client_cpu_s``,
-        ``driver_cpu_s``) — recorded event-weighted into the per-phase
-        histograms."""
+        carries the measured segments of this batch's waterfall, keyed as
+        :meth:`PhaseBreakdown.record_batch` names them (the serial phases,
+        their CPU clocks, the threads' clocks, ``cause`` and one ``parts``
+        dict), recorded event-weighted into the per-phase histograms."""
         if device_path:
             self.steps += 1
             self.events += int(n_events)

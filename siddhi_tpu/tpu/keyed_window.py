@@ -44,14 +44,12 @@ any window but ``length``, no aggregate, group-by, ``stdDev``, W over
 from __future__ import annotations
 
 import logging
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.columns import ColumnsOut
-from ..observability.profiler import span
 from ..query_api.definition import DataType
 from .batch import BatchBuilder
 from .dtypes import FACC
@@ -404,19 +402,15 @@ class KeyedWindowRuntime(StepRuntime):
         the padding 0. The lookup is a part of ``pack``: the batch's
         ``pack_exec_s`` and ``_t_emit`` are moved to its end, so the serial
         sum reads every second once."""
-        t0, c0 = time.perf_counter(), time.thread_time()
-        with span(f"siddhi:seal.key_lookup:{self.query_name}"):
+        with self.measure("key_lookup", batch) as lookup:
             valid = batch["valid"]
             live = np.flatnonzero(valid)
             slot = np.zeros(valid.shape[0], np.int32)
             slot[live] = self.directory.slots_of(
                 batch["cols"][self.key_attr][live])
-        t1 = time.perf_counter()
         batch["slot"] = slot
-        batch["_key_lookup_s"] = t1 - t0
-        batch["_key_lookup_cpu_s"] = time.thread_time() - c0
-        batch["pack_exec_s"] += t1 - batch["_t_emit"]
-        batch["_t_emit"] = t1
+        batch["pack_exec_s"] += lookup.end - batch["_t_emit"]
+        batch["_t_emit"] = lookup.end
 
     def dispatch(self, batch: dict):
         """The step on donated state, over the slots the batch was sealed
@@ -433,10 +427,8 @@ class KeyedWindowRuntime(StepRuntime):
         if n <= self.compiled.M:
             cols = jax.device_get(out["rows"])
         else:
-            t0 = time.perf_counter()
-            with span(f"siddhi:collect.decode.full:{self.query_name}"):
+            with self.measure("decode_full"):
                 cols = jax.device_get(out["full"])
-            self.decode_full_s = time.perf_counter() - t0
         # copies: the host's arrays of a fetch are read-only views
         return ColumnsOut(None, {k: np.array(v[:n]) for k, v in
                                  cols.items()}, n, self.out_specs,

@@ -59,7 +59,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.pattern import CompiledPattern, PatternCompiler
-from ..observability.profiler import span
 from ..query_api import (
     Query,
     StateInputStream,
@@ -2109,19 +2108,15 @@ def decode_rows(rt, ys, lane_batch: Optional[int] = None):
     step packs at that size for such a batch alone and else fills with
     the packed table's rows): no row is lost, and none is counted as a
     drop that was not one before. That decode is timed apart
-    (``rt.decode_full_s``, the ``decode_full`` tracker; span
-    ``siddhi:collect.decode.full``): how often it runs is what the packed
+    (``decode_full``): how often it runs is what the packed
     table's size is judged by, and for the scan kernel how often the step
     took the whole pack. A single-state blocked plan has no ``full``."""
     nfa = rt.compiler
     full = ys.get("full")
     if full is None or int(np.max(jax.device_get(ys["n"]))) <= nfa.M:
         return nfa.decode_outputs(ys, lane_batch)
-    t0 = time.perf_counter()
-    with span(f"siddhi:collect.decode.full:{rt.query_name}"):
-        out = nfa.decode_outputs(full, lane_batch)
-    rt.decode_full_s = time.perf_counter() - t0
-    return out
+    with rt.measure("decode_full"):
+        return nfa.decode_outputs(full, lane_batch)
 
 
 class DeviceNFARuntime(StepRuntime):

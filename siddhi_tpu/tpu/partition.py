@@ -32,7 +32,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..compiler import parse as _parse
-from ..observability.profiler import span
 from ..query_api.definition import DataType
 from .expr_compile import DeviceCompileError
 from .nfa import DeviceNFACompiler, MergedBatchBuilder, decode_rows
@@ -675,11 +674,8 @@ class PartitionedNFARuntime(StepRuntime):
         ``[P, lane_batch]`` here, on the driver's thread, by one stable
         argsort by lane (a key's events keep their order), then ``vstep``
         on donated state. Returns the un-fenced outputs."""
-        t0, c0 = time.perf_counter(), time.thread_time()
-        with span(f"siddhi:dispatch.route:{self.query_name}"):
+        with self.measure("route", batch):
             feed = self._lay_out(batch)
-        batch["_route_s"] = time.perf_counter() - t0
-        batch["_route_cpu_s"] = time.thread_time() - c0
         self.state, ys = self.vstep(self.state, *feed)
         return ys
 

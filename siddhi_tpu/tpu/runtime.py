@@ -10,14 +10,12 @@ from __future__ import annotations
 
 import contextlib
 import logging
-import time
 
 import jax
 import numpy as np
 
 from ..compiler import parse as _parse
 from ..core.columns import ColumnsOut
-from ..observability.profiler import span
 from .batch import BatchBuilder
 from .query_compile import CompiledStreamQuery
 from .step_runtime import StepRuntime
@@ -192,22 +190,17 @@ class DeviceStreamRuntime(StepRuntime):
         round a drain that runs)."""
         if not self._hopping:
             return self.compiled.decode_outputs(out)
-        q = self.query_name
-        t0 = time.perf_counter()
         # the fence has fetched this output: rows out = a boundary fired
         fired = np.asarray(out[self.fence_key]).any()
-        with span(f"siddhi:collect.decode.hop_flush:{q}") if fired \
+        with self.measure("hop_flush") if fired \
                 else contextlib.nullcontext():
             chunks = [self.compiled.decode_outputs(out)]
-        t1 = time.perf_counter()
-        if fired:
-            self.hop_flush_s = t1 - t0
-        if out.get("hop_serial"):
-            with span(f"siddhi:collect.decode.hop_drain:{q}"):
+        serial = bool(out.get("hop_serial"))
+        with self.measure("hop_drain", spanned=serial):
+            if serial:
                 self.state, self._hop_newest = drain_hop_boundaries(
                     self.compiled, self.state, self._drain_builder,
                     lambda o: chunks.append(self.compiled.decode_outputs(o)))
-        self.hop_drain_s = time.perf_counter() - t1
         return ColumnsOut.concat(chunks)
 
     def finalize(self) -> None:

@@ -28,7 +28,36 @@ from typing import Optional
 
 import numpy as np
 
+from ..observability.phases import SEGMENTS, span_name
 from ..observability.profiler import span
+
+
+class _Measured:
+    """``StepRuntime.measure``'s context: the clocks read outside the span,
+    as a ``with`` round them would; ``end`` is the wall clock at the close.
+    A stretch that raises files nothing."""
+
+    def __init__(self, parts: dict, name: str, label: Optional[str]):
+        self.parts, self.name, self.label = parts, name, label
+        self.cpu = SEGMENTS[name].cpu
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        self.c0 = time.thread_time() if self.cpu else 0.0
+        if self.label:
+            self.span = span(self.label)
+            self.span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self.label:
+            self.span.__exit__(*exc)
+        if exc[0] is None:
+            self.end, parts = time.perf_counter(), self.parts
+            parts[self.name] = parts.get(self.name, 0.0) + self.end - self.t0
+            if self.cpu:
+                parts[self.cpu] = parts.get(self.cpu, 0.0) \
+                    + time.thread_time() - self.c0
 
 
 class StepRuntime:
@@ -40,20 +69,8 @@ class StepRuntime:
     flight = None               # FlightRecorder (observability wiring)
     flight_site = ""
     query_name = ""             # the profiler spans' <query> (bridge sets it)
-    fence_s = None              # the last collect's wait for the device,
-    # left by _fence for whoever called collect (driver thread, or the
-    # client on the sync path); None after a collect that never fenced
-    fence_cpu_s = None          # that thread's CPU seconds over the same wait
-    decode_full_s = None        # left by a decode that read an NFA's
-    # ``full`` table (nfa.decode_rows); step_phases takes it
-    hop_drain_s = None          # left by a hopping decode: its drain (the
-    # test of the serial mark and, for a serial batch, the state read and
-    # any empty steps), and, where the step fired a boundary, the decode of
-    # its rows; step_phases takes both
-    hop_flush_s = None
-    publish_build_s = None      # left by the bridge's egress while deliver
-    # ran: what the engine built for a subscriber that takes events
-    # (core/egress.py); step_phases takes it
+    parts = None                # what collect and deliver filed for the
+    # batch in hand (``measure``), taken by step_phases
     _sealed = None              # (thread, wall, CPU) at the last seal
     _pending_cause = None       # cause of the flush whose emit comes next
     driver = None               # AsyncDeviceDriver when the bridge pipelines
@@ -124,7 +141,7 @@ class StepRuntime:
                 time.thread_time())
         last, self._sealed = self._sealed, here
         self._seal()
-        with span(f"siddhi:seal.pack:{self.query_name}"):
+        with span(span_name("pack", self.query_name)):
             batch = self.builder.emit()
             self._sealing(batch)
         batch["_cause"] = self._take_cause()
@@ -157,18 +174,15 @@ class StepRuntime:
         fence and costs a paced query a millisecond of detection latency:
         the first copy then no longer queues behind the step on the device
         but waits for the host to wake and ask (PERF.md, PR 25)."""
-        t0, c0 = time.perf_counter(), time.thread_time()
-        with span(f"siddhi:collect.fence:{self.query_name}"):
+        with self.measure("egress_fence"):
             np.asarray(first)
-        self.fence_s = time.perf_counter() - t0
-        self.fence_cpu_s = time.thread_time() - c0
 
     def collect(self, out):
         """Egress fence + decode for one dispatched step: one ``ColumnsOut``
         chunk (falsy when empty), its string codes already resolved so that
         ``deliver`` holds the engine lock for the junction alone."""
         self._fence(out[self.fence_key])
-        with span(f"siddhi:collect.decode:{self.query_name}"):
+        with span(span_name("egress_decode", self.query_name)):
             chunk = self._decode(out)
             chunk.decoded()
             return chunk
@@ -228,25 +242,45 @@ class StepRuntime:
         if obs is not None:
             obs(n_events, latency_s, device_path, phases=phases)
 
+    def measure(self, name: str, batch: Optional[dict] = None,
+                spanned: bool = True) -> _Measured:
+        """``with self.measure(name):`` round a segment of
+        ``phases.SEGMENTS``: its span (where ``spanned``), the wall clock
+        and, where the table names a companion, the thread's CPU clock. The
+        seconds are filed on ``batch`` where the batch is in hand (seal,
+        dispatch), else in ``parts`` (collect, deliver)."""
+        return _Measured(self._parts_of(batch), name,
+                         span_name(name, self.query_name) if spanned else None)
+
+    def _parts_of(self, batch: Optional[dict] = None) -> dict:
+        if batch is not None:
+            return batch.setdefault("_parts", {})
+        if self.parts is None:
+            self.parts = {}
+        return self.parts
+
+    def file_part(self, name: str, seconds: float) -> None:
+        """Add a reading taken elsewhere (a subscriber's own build) to the
+        batch in hand's ``name``."""
+        parts = self._parts_of()
+        parts[name] = parts.get(name, 0.0) + seconds
+
     def step_phases(self, batch: dict, queue_s: float, step_s: float,
                     step_cpu_s: float, collect_s: float,
                     collect_cpu_s: float, **driver_s) -> dict:
         """One device batch's waterfall as ``PhaseBreakdown.record_batch``
-        names it: what the batch carries (fill span, pack, route or key
-        lookup, cause,
-        the client's cycle), what whoever stepped it measured on the wall
-        clock and, beside it, on its thread's CPU clock (``collect`` is cut
-        here into the fence its ``_fence`` left on the runtime and the
-        decode; a collect that never fenced is all wait), what its decode
-        and its delivery left on the runtime (``decode_full_s``,
-        ``hop_drain_s``, ``hop_flush_s``, ``publish_build_s``, taken here)
-        and in ``driver_s`` what only the async driver has (``ring_s``,
-        ``lock_s``, ``publish_s``, ``publish_cpu_s``, ``driver_cpu_s``)."""
-        full_s, self.decode_full_s = self.decode_full_s, None
-        drain_s, self.hop_drain_s = self.hop_drain_s, None
-        flush_s, self.hop_flush_s = self.hop_flush_s, None
-        build_s, self.publish_build_s = self.publish_build_s, None
-        fence_s, fence_cpu_s = self.fence_s, self.fence_cpu_s
+        names it: what the batch carries (fill span, pack, cause, the
+        client's cycle), what whoever stepped it measured on the wall clock
+        and, beside it, on its thread's CPU clock, in ``driver_s`` what only
+        the async driver has (``ring_s``, ``lock_s``, ``publish_s``,
+        ``publish_cpu_s``, ``driver_cpu_s``), and the batch's ``parts``:
+        what ``measure`` filed on it and in the runtime's ``parts``, taken
+        here. ``collect`` is cut into the fence filed there and the decode;
+        a collect that never fenced is all wait."""
+        parts = {**batch.get("_parts", {}), **(self.parts or {})}
+        self.parts = None
+        fence_s = parts.pop("egress_fence", None)
+        fence_cpu_s = parts.pop(SEGMENTS["egress_fence"].cpu, None)
         if fence_s is None:
             fence_s, fence_cpu_s = collect_s, collect_cpu_s
         return {
@@ -255,21 +289,14 @@ class StepRuntime:
             "queue_s": queue_s,
             "step_s": step_s,
             "step_cpu_s": step_cpu_s,
-            "route_s": batch.get("_route_s", 0.0),
-            "route_cpu_s": batch.get("_route_cpu_s"),
-            "key_lookup_s": batch.get("_key_lookup_s", 0.0),
-            "key_lookup_cpu_s": batch.get("_key_lookup_cpu_s"),
             "fence_s": fence_s,
             "fence_cpu_s": fence_cpu_s,
             "decode_s": collect_s - fence_s,
             "decode_cpu_s": collect_cpu_s - fence_cpu_s,
-            "decode_full_s": full_s or 0.0,
-            "hop_drain_s": drain_s or 0.0,
-            "hop_flush_s": flush_s or 0.0,
-            "publish_build_s": build_s or 0.0,
             "client_cycle_s": batch.get("_client_cycle_s"),
             "client_cpu_s": batch.get("_client_cpu_s"),
             "cause": batch.get("_cause"),
+            "parts": parts,
             **driver_s,
         }
 
@@ -282,10 +309,10 @@ class StepRuntime:
         if self.batch_controller is None and self.step_observer is None:
             return self.process(batch)
         q = self.query_name
-        self.fence_s = None
+        self.parts = None
         t0, c0 = time.perf_counter(), time.thread_time()
         try:
-            with span(f"siddhi:dispatch:{q}"):
+            with span(span_name("device_step", q)):
                 token = self.dispatch(batch)
             t1, c1 = time.perf_counter(), time.thread_time()
             with span(f"siddhi:collect:{q}"):

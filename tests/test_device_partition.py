@@ -504,7 +504,7 @@ def test_the_packed_lane_tables_hold_the_full_tables_rows(served, zipf):
             r.flush()
         assert nfa.M == r.lane_batch
         assert len(steps) > 5 and sum(steps) == len(rows) > 20
-        assert r.decode_full_s is None and r.drop_count == 0
+        assert "decode_full" not in (r.parts or {}) and r.drop_count == 0
         assert _f32(rows) == want
         if served:
             phases = rt.device_bridges[0].probe.phases
@@ -623,7 +623,8 @@ def test_one_compile_and_only_the_packed_tables_cross_to_the_host(
             assert step == [[(lanes,)], [(lanes, rows_m)] * 5]
             assert sum(int(np.prod(s)) for s in step[1]) \
                 == (2 + 3) * lanes * rows_m
-        assert r.decode_full_s is None
+        assert rt.device_bridges[0].probe.phases.trackers[
+            "decode_full"].count == 0
     finally:
         m.shutdown()
 
@@ -975,7 +976,8 @@ def test_count_state_one_compile_one_chunk_and_one_row_table_a_step(
             assert step == [[(lanes,)], [(lanes, rows_m)] * 6]
             assert sum(int(np.prod(shape)) for shape in step[1]) \
                 == (2 + 4 + 0) * lanes * rows_m
-        assert r.decode_full_s is None
+        assert rt.device_bridges[0].probe.phases.trackers[
+            "decode_full"].count == 0
     finally:
         m.shutdown()
 

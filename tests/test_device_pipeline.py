@@ -241,7 +241,6 @@ def test_deadline_flush_bounds_partial_batch_wait():
     while len(got) < 3 and time.time() < deadline:
         time.sleep(0.02)
     assert got == [1.0, 2.0, 3.0]
-    assert bridge.driver.deadline_flushes >= 1
     assert bridge.probe.flush_causes.get("deadline", 0) >= 1
     m.shutdown()
 

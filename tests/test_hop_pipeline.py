@@ -175,7 +175,6 @@ class _StubRuntime:
     """What the driver calls, logged: batch ``i`` is token ``i``."""
     query_name = "stub"
     batch_controller = None
-    fence_s = None
 
     def __init__(self, serial):
         self.serial, self.log, self.delivered = serial, [], []

@@ -122,15 +122,11 @@ def test_empty_fields_become_none_zero():
 
 
 def test_throughput_smoke():
-    # not a benchmark — just ensures bulk path handles 100k rows quickly
-    import time
+    # not a benchmark — just ensures the bulk path takes 100k rows whole
     ing = NativeIngress("sd", key_col=0, n_lanes=16, capacity=100_000)
     rows = "".join(f"dev{i % 50},{i * 0.5}\n" for i in range(100_000)).encode()
-    t0 = time.perf_counter()
     assert ing.ingest_csv(rows) == len(rows)
-    dt = time.perf_counter() - t0
     assert sum(ing.lane_len(i) for i in range(16)) == 100_000
-    assert dt < 2.0
 
 
 def test_partitioned_nfa_native_csv_parity():

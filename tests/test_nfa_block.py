@@ -712,10 +712,10 @@ def test_the_packed_rows_are_the_full_tables_rows(name, batch):
         by_lane = []
         for lane, b in enumerate(batches):
             single[lane], ys = nfa._step(single[lane], *_wire(b))
-            rt.decode_full_s = None
+            rt.parts = None
             by_lane.append(_check_tables(rt, ys))
             over = "full" in ys and len(by_lane[-1]) > nfa.M
-            assert (rt.decode_full_s is not None) == over
+            assert ("decode_full" in (rt.parts or {})) == over
             n_over += over
         stacked, ys = vstep(stacked, *jax.tree.map(
             lambda *xs: np.stack(xs), *[_wire(b) for b in batches]))

@@ -174,7 +174,7 @@ def test_a_served_runtime_runs_the_one_protocol(manager, kind, mode):
     assert len(keys) == 1, keys
     first_kind, first_keys = _PHASE_KEYS.setdefault(mode, (kind, keys))
     assert keys == first_keys, (kind, first_kind, keys ^ first_keys)
-    assert {"fence_s", "decode_s", "step_s", "route_s", "cause"} <= \
+    assert {"fence_s", "decode_s", "step_s", "parts", "cause"} <= \
         set(seen[0])
     assert ("lock_s" in seen[0]) == (mode == "async")
 

@@ -105,7 +105,7 @@ def test_record_batch_records_a_companion_wherever_its_wall_tracker_is():
     pb.record_batch(8, step_s=0.002, step_cpu_s=0.0, fence_s=0.001,
                     fence_cpu_s=0.0004, decode_s=0.0, decode_cpu_s=0.0,
                     publish_s=0.003, publish_cpu_s=0.003,
-                    publish_build_s=0.0, route_s=0.0, route_cpu_s=None,
+                    parts={"publish_build": 0.0},
                     client_cycle_s=0.01, client_cpu_s=0.004,
                     driver_cpu_s=0.005)
     # nobody read the CPU clock (a host tier): the wall alone
